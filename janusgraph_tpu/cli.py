@@ -27,11 +27,51 @@ def _load_config(path: Optional[str]) -> dict:
         return json.load(f)
 
 
+def build_server(
+    graph, manager, graph_name: str, host: str, port: int,
+    authenticator=None, replica=None,
+):
+    """The query server as `server` runs it: every knob read from the
+    graph's configuration. Returned unstarted. (`chip_smoke.py` builds its
+    in-process server through here, so what it drives is what users get.)"""
+    from janusgraph_tpu.server import JanusGraphServer
+
+    admission = None
+    if graph.config.get("server.admission.enabled"):
+        from janusgraph_tpu.server.admission import AdmissionController
+
+        admission = AdmissionController.from_config(graph.config)
+    return JanusGraphServer(
+        manager=manager,
+        default_graph=graph_name,
+        authenticator=authenticator,
+        host=host,
+        port=port,
+        max_request_bytes=graph.config.get("server.max-request-bytes"),
+        max_query_length=graph.config.get("server.max-query-length"),
+        request_timeout_s=graph.config.get("server.request-timeout-s"),
+        auto_commit=graph.config.get("server.auto-commit"),
+        admission=admission,
+        admission_enabled=graph.config.get("server.admission.enabled"),
+        default_deadline_ms=graph.config.get("server.deadline.default-ms"),
+        max_deadline_ms=graph.config.get("server.deadline.max-ms"),
+        history_enabled=graph.config.get("metrics.history-enabled"),
+        slo_enabled=graph.config.get("metrics.slo-enabled"),
+        slo_specs=_slo_specs_from_config(graph.config),
+        replica_name=replica,
+        profiler_enabled=graph.config.get("metrics.profile-enabled"),
+        watchdog_enabled=graph.config.get("server.watchdog-enabled"),
+        bundle_dir=graph.config.get("metrics.bundle-dir"),
+    )
+
+
 def cmd_server(args) -> int:
     from janusgraph_tpu.core.graph import open_graph
     from janusgraph_tpu.observability import set_replica
-    from janusgraph_tpu.server import JanusGraphManager, JanusGraphServer
+    from janusgraph_tpu.olap.device import configure_compile_cache
+    from janusgraph_tpu.server import JanusGraphManager
 
+    configure_compile_cache()
     cfg = _load_config(args.config)
     graph = open_graph(cfg)
     replica = args.replica_name or graph.config.get(
@@ -73,32 +113,9 @@ def cmd_server(args) -> int:
             ),
         )
 
-    admission = None
-    if graph.config.get("server.admission.enabled"):
-        from janusgraph_tpu.server.admission import AdmissionController
-
-        admission = AdmissionController.from_config(graph.config)
-    server = JanusGraphServer(
-        manager=manager,
-        default_graph=args.graph_name,
-        authenticator=authenticator,
-        host=args.host,
-        port=args.port,
-        max_request_bytes=graph.config.get("server.max-request-bytes"),
-        max_query_length=graph.config.get("server.max-query-length"),
-        request_timeout_s=graph.config.get("server.request-timeout-s"),
-        auto_commit=graph.config.get("server.auto-commit"),
-        admission=admission,
-        admission_enabled=graph.config.get("server.admission.enabled"),
-        default_deadline_ms=graph.config.get("server.deadline.default-ms"),
-        max_deadline_ms=graph.config.get("server.deadline.max-ms"),
-        history_enabled=graph.config.get("metrics.history-enabled"),
-        slo_enabled=graph.config.get("metrics.slo-enabled"),
-        slo_specs=_slo_specs_from_config(graph.config),
-        replica_name=replica,
-        profiler_enabled=graph.config.get("metrics.profile-enabled"),
-        watchdog_enabled=graph.config.get("server.watchdog-enabled"),
-        bundle_dir=graph.config.get("metrics.bundle-dir"),
+    server = build_server(
+        graph, manager, args.graph_name, args.host, args.port,
+        authenticator=authenticator, replica=replica,
     ).start()
     print(f"JanusGraph-TPU server listening on {args.host}:{server.port}")
     try:
@@ -130,8 +147,10 @@ def cmd_fleet(args) -> int:
         JanusGraphServer,
         StateGossip,
     )
+    from janusgraph_tpu.olap.device import configure_compile_cache
     from janusgraph_tpu.server.fleet import warm_replica
 
+    configure_compile_cache()
     cfg = _load_config(args.config)
     set_replica("fleet-frontend")
     # one shared backing for every replica: inmemory shares the manager
@@ -320,7 +339,9 @@ def cmd_console(args) -> int:
             T,
             __ as _anon,
         )
+        from janusgraph_tpu.olap.device import configure_compile_cache
 
+        configure_compile_cache()
         graph = open_graph(_load_config(args.config))
         if args.load_gods:
             from janusgraph_tpu.core import gods
@@ -343,8 +364,9 @@ def cmd_bench(args) -> int:
     sys.path.insert(0, root)
     import bench
 
-    bench.main()
-    return 0
+    # the bench supervisor stays off JAX (its worker holds the chip and
+    # places the compile cache itself), and its exit code is the result
+    return bench.main()
 
 
 def cmd_storage_server(args) -> int:

@@ -25,6 +25,9 @@ _SRC = os.path.join(_DIR, "graphcsr.cpp")
 
 _lib = None
 _tried = False
+#: how the library came to be: "compiled" (g++ ran in this process),
+#: "loaded" (a hash-named .so was already there) or "unavailable"
+_status = "unavailable"
 _lock = threading.Lock()
 
 
@@ -35,7 +38,7 @@ def _so_path() -> str:
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _tried
+    global _lib, _tried, _status
     if _lib is not None or _tried:
         return _lib
     with _lock:
@@ -45,7 +48,9 @@ def _load() -> Optional[ctypes.CDLL]:
         if os.environ.get("JG_TPU_NO_NATIVE"):
             return None
         so = _so_path()
+        status = "loaded"
         if not os.path.exists(so):
+            status = "compiled"
             # unique tmp name: concurrent processes may compile at once;
             # os.replace makes whoever finishes last win atomically
             tmp = f"{so}.{os.getpid()}.tmp"
@@ -106,11 +111,19 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_double, ctypes.c_double, ctypes.c_double, I32, I32,
         ]
         _lib = lib
+        _status = status
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_status() -> str:
+    """"compiled", "loaded" or "unavailable" (the numpy fallbacks are in
+    use) — for launchers that must not run on the fallback unnoticed."""
+    _load()
+    return _status
 
 
 # ------------------------------------------------------------- entry points

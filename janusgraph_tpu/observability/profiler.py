@@ -488,25 +488,27 @@ def load_price_book(path: str) -> Dict[str, List[dict]]:
 # Roofline cost model
 # --------------------------------------------------------------------------
 
-#: (device_kind substring, peak flops/s, peak HBM bytes/s, peak MXU
-#: flops/s). Order matters: first match wins. Conservative public figures;
-#: override exactly via metrics.roofline-peak-flops /
-#: metrics.roofline-peak-bytes-per-s / metrics.roofline-peak-mxu-flops.
-#: The MXU column is the dense-matmul (systolic-array) ceiling the
-#: dense-feature tier's `mxu_utilization` divides by — the TPU marketing
-#: numbers ARE the MXU peaks, so those columns coincide; CPU gets a
-#: modest BLAS-class figure so the ratio stays meaningful on every
-#: backend (relative shape, not absolute truth).
-_DEVICE_PEAKS: Tuple[Tuple[str, float, float, float], ...] = (
-    ("v5e", 197e12, 819e9, 197e12),
-    ("v5p", 459e12, 2765e9, 459e12),
-    ("v4", 275e12, 1228e9, 275e12),
-    ("v3", 123e12, 900e9, 123e12),
-    ("v2", 45e12, 700e9, 45e12),
-    # CPU fallback: a generous server-class core count; the point on CPU
-    # is the RELATIVE utilization shape, not absolute truth
-    ("cpu", 5e11, 5e10, 1e11),
-)
+#: device_kind, lower-cased, exactly as ``jax.devices()[0].device_kind``
+#: reports it -> (peak flops/s, peak HBM bytes/s, peak MXU flops/s, where
+#: the figures come from). The MXU column is the dense-matmul ceiling the
+#: dense-feature tier's ``mxu_utilization`` divides by; a TPU's published
+#: bf16 peak IS its MXU peak, so the columns coincide. Override via
+#: metrics.roofline-peak-flops / -peak-bytes-per-s / -peak-mxu-flops.
+#: A kind that is not listed raises in ``device_peaks``: a utilization
+#: against another chip's peak is worse than none. The table holds the one
+#: accelerator this repo has run on; its key was read off the chip by
+#: ``chip_smoke.py`` phase 0 (PR 21).
+_DEVICE_PEAKS: Dict[str, Tuple[float, float, float, str]] = {
+    "tpu v5 lite": (
+        197e12, 819e9, 197e12,
+        "Google Cloud documentation, TPU v5e: 197 TFLOP/s bf16, "
+        "819 GB/s HBM",
+    ),
+    # platform == "cpu" only (its device_kind is "cpu"): an invented
+    # server-class figure that keeps the RELATIVE utilization shape
+    # readable in CPU test runs. Not a measurement; goes with ROADMAP D7.
+    "cpu": (5e11, 5e10, 1e11, "placeholder, not a measurement"),
+}
 
 _ROOFLINE_OVERRIDE = {
     "peak_flops": 0.0, "peak_bytes_per_s": 0.0, "peak_mxu_flops": 0.0,
@@ -529,25 +531,21 @@ def configure_roofline(
 
 def device_peaks(device_kind: Optional[str] = None) -> dict:
     """{peak_flops, peak_bytes_per_s, peak_mxu_flops, device_kind, source}
-    for the current (or named) device. Host-side metadata only — no
-    device sync."""
+    for the current (or named) device. Raises ``KeyError`` for a kind the
+    table does not list. Host-side metadata only — no device sync."""
     if device_kind is None:
-        try:
-            import jax
+        import jax
 
-            device_kind = jax.devices()[0].device_kind
-        except Exception:  # noqa: BLE001 - jax may be absent/uninitialized
-            device_kind = "cpu"
-    kind = (device_kind or "cpu").lower()
-    flops, bw, mxu, source = 0.0, 0.0, 0.0, "default"
-    for sub, pf, pb, pm in _DEVICE_PEAKS:
-        if sub in kind:
-            flops, bw, mxu, source = pf, pb, pm, f"table:{sub}"
-            break
-    if not flops:
-        flops, bw, mxu = (
-            _DEVICE_PEAKS[-1][1], _DEVICE_PEAKS[-1][2], _DEVICE_PEAKS[-1][3]
+        device_kind = jax.devices()[0].device_kind
+    row = _DEVICE_PEAKS.get(device_kind.lower())
+    if row is None:
+        raise KeyError(
+            f"device kind {device_kind!r} is not in the roofline peaks "
+            f"table (known: {sorted(_DEVICE_PEAKS)}); add its published "
+            "peaks to observability/profiler._DEVICE_PEAKS"
         )
+    flops, bw, mxu, _origin = row
+    source = f"table:{device_kind.lower()}"
     if _ROOFLINE_OVERRIDE["peak_flops"]:
         flops, source = _ROOFLINE_OVERRIDE["peak_flops"], "config"
     if _ROOFLINE_OVERRIDE["peak_bytes_per_s"]:

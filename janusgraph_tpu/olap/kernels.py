@@ -29,7 +29,8 @@ poorly on TPU. Two TPU-native alternatives here:
    already destination-sorted (CSR); host-side alignment pads each output
    tile's edge range to whole blocks, so each edge block accumulates into
    exactly one output tile. The kernel one-hot-expands local segment ids and
-   reduces on the MXU/VPU, revisiting the same output block across grid
+   reduces on the MXU (values split into three bfloat16 pieces, so the sum
+   is float32-accurate), revisiting the same output block across grid
    steps (zeroed on first touch). SUM monoid; used for PageRank-shaped
    programs.
 
@@ -730,7 +731,6 @@ class _SegSumPlan:
         pad_mask = np.zeros(padded_m, dtype=np.float32)
         seg_local = np.zeros(padded_m, dtype=np.int32)
         out_tile = np.zeros(total_blocks, dtype=np.int32)
-        is_first = np.zeros(total_blocks, dtype=np.int32)
 
         edge_starts = np.zeros(num_tiles + 1, dtype=np.int64)
         np.cumsum(counts, out=edge_starts[1:])
@@ -744,15 +744,31 @@ class _SegSumPlan:
             seg_local[w : w + k] = (seg[lo:hi] - t * tile).astype(np.int32)
             nb = int(blocks_per_tile[t])
             out_tile[b : b + nb] = t
-            is_first[b] = 1
             b += nb
             w += nb * block
         self.gather_idx = gather_idx
         self.pad_mask = pad_mask
         self.seg_local = seg_local
         self.out_tile = out_tile
-        self.is_first = is_first
         self.num_blocks = total_blocks
+        self._device_args = None
+
+    def device_args(self, jnp) -> dict:
+        """The plan's arrays on the device, shipped once and handed to the
+        compiled superstep as ARGUMENTS (closed over, they would be
+        constant-folded into the module; see TPUExecutor._graph_args).
+        ``seg_local`` is shaped (blocks, 1, B) so a kernel block is a
+        (1, B) row whose last two dims equal the array's."""
+        if self._device_args is None:
+            self._device_args = {
+                "gather_idx": jnp.asarray(self.gather_idx),
+                "pad_mask": jnp.asarray(self.pad_mask),
+                "seg_local": jnp.asarray(
+                    self.seg_local.reshape(self.num_blocks, 1, self.block)
+                ),
+                "out_tile": jnp.asarray(self.out_tile),
+            }
+        return self._device_args
 
 
 def make_segsum_plan(
@@ -764,12 +780,20 @@ def make_segsum_plan(
 def pallas_sorted_segment_sum(
     data,
     plan: _SegSumPlan,
+    args: Optional[dict] = None,
     interpret: bool = False,
 ):
     """Segment-sum of `data` (per-edge values, original edge order) using a
-    Pallas TPU kernel over the precomputed tile-aligned plan.
+    Pallas TPU kernel over the precomputed tile-aligned plan. `args` is
+    ``plan.device_args(jnp)``, passed through a jit boundary by callers
+    that trace this function; left out, the arrays are taken from the plan.
 
-    Returns (num_segments,) float32 sums.
+    Returns (num_segments,) float32 sums, accurate to float32: the MXU
+    multiplies in bfloat16, so each value is split into three bfloat16
+    pieces that sum to it exactly, the one-hot is exact in bfloat16, and
+    the products accumulate in float32. (A plain float32 dot at default
+    precision rounds every value to 8 bits: 2.4e-3 relative error measured
+    on a TPU v5e, against 1.2e-7 for this form at the same speed.)
     """
     import jax
     import jax.numpy as jnp
@@ -777,47 +801,62 @@ def pallas_sorted_segment_sum(
     from jax.experimental.pallas import tpu as pltpu
 
     B, T = plan.block, plan.tile
+    nb = plan.num_blocks
+    if args is None:
+        args = plan.device_args(jnp)
 
     # align + pad on device (monotone gather, cheap)
-    gidx = jnp.asarray(plan.gather_idx)
-    mask = jnp.asarray(plan.pad_mask)
-    segl = jnp.asarray(plan.seg_local)
-    data_p = data[gidx] * mask
+    data_p = (data[args["gather_idx"]] * args["pad_mask"]).astype(jnp.float32)
 
-    def kernel(out_tile_ref, is_first_ref, data_ref, seg_ref, out_ref):
+    # Every block is 3-D with a squeezed leading dim, so what the kernel
+    # sees is a (1, B) or (1, T) row: Mosaic wants a block's last two dims
+    # to be tile-aligned or equal to the array's, and 1-D blocks leave the
+    # layout to reshapes inside the kernel.
+    def kernel(out_tile_ref, data_ref, seg_ref, out_ref):
         b = pl.program_id(0)
+        prev = out_tile_ref[jnp.maximum(b - 1, 0)]
 
-        @pl.when(is_first_ref[b] == 1)
+        # first block of an output tile (blocks of one tile are contiguous)
+        @pl.when(jnp.logical_or(b == 0, out_tile_ref[b] != prev))
         def _():
-            out_ref[:] = jnp.zeros_like(out_ref)
+            out_ref[...] = jnp.zeros_like(out_ref)
 
-        seg_block = seg_ref[:]                      # (B,)
-        d = data_ref[:]                             # (B,)
-        cols = jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
-        onehot = (seg_block[:, None] == cols).astype(jnp.float32)
-        partial = jnp.dot(
-            d.reshape(1, B), onehot, preferred_element_type=jnp.float32
-        ).reshape(T)
-        out_ref[:] = out_ref[:] + partial
+        # transposed one-hot (T, B): segment ids stay a lane-major row
+        rows = jax.lax.broadcasted_iota(jnp.int32, (T, B), 0)
+        onehot_t = (rows == seg_ref[...]).astype(jnp.bfloat16)
+        d = data_ref[...]                                   # (1, B) f32
+        hi = d.astype(jnp.bfloat16).astype(jnp.float32)
+        rest = d - hi
+        mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
+        lo = rest - mid
+        # pieces as rows 0..2 of one (16, B) bf16 operand: a native bf16
+        # tile, and one MXU pass for all three
+        piece = jax.lax.broadcasted_iota(jnp.int32, (16, B), 0)
+        lhs = jnp.where(
+            piece == 0, hi,
+            jnp.where(piece == 1, mid, jnp.where(piece == 2, lo, 0.0)),
+        ).astype(jnp.bfloat16)
+        part = jax.lax.dot_general(
+            lhs, onehot_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                   # (16, T)
+        out_ref[...] += jnp.sum(part, axis=0, keepdims=True)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(plan.num_blocks,),
+        num_scalar_prefetch=1,
+        grid=(nb,),
         in_specs=[
-            pl.BlockSpec((B,), lambda b, ot, fi: (b,)),
-            pl.BlockSpec((B,), lambda b, ot, fi: (b,)),
+            pl.BlockSpec((None, 1, B), lambda b, ot: (b, 0, 0)),
+            pl.BlockSpec((None, 1, B), lambda b, ot: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((T,), lambda b, ot, fi: (ot[b],)),
+        out_specs=pl.BlockSpec((None, 1, T), lambda b, ot: (ot[b], 0, 0)),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((plan.padded_segments,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (plan.padded_segments // T, 1, T), jnp.float32
+        ),
         interpret=interpret,
-    )(
-        jnp.asarray(plan.out_tile),
-        jnp.asarray(plan.is_first),
-        data_p.astype(jnp.float32),
-        segl,
-    )
-    return out[: plan.num_segments]
+    )(args["out_tile"], data_p.reshape(nb, 1, B), args["seg_local"])
+    return out.reshape(plan.padded_segments)[: plan.num_segments]

@@ -77,13 +77,6 @@ def init_multihost(
             "multi-host run needs a coordinator address "
             "(JAX_COORDINATOR_ADDRESS or coordinator_address=)"
         )
-    # fail the init fast (and say which spelling resolved) if this jax has
-    # no usable shard_map — every sharded program compiled after distributed
-    # init goes through the compat shim, so a broken resolution should
-    # surface here, not at the first superstep compile on every host
-    from janusgraph_tpu.parallel.compat import resolve_shard_map
-
-    resolve_shard_map()
     import jax
 
     # CPU multi-process needs an explicit cross-host collectives transport:

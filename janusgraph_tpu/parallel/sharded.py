@@ -1191,6 +1191,16 @@ class ShardedExecutor:
 
         return P(self.axis), P()
 
+    def _place_state(self, host_array):
+        """Initial vertex state goes straight to its shards, like the
+        graph arrays (`_dev`): built on the default device it would sit
+        whole on device 0 until the first dispatch resharded it."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        return self.jax.device_put(
+            np.asarray(host_array), NamedSharding(self.mesh, P(self.axis))
+        )
+
     def _superstep_fn(
         self, program: VertexProgram, op: str, sc: ShardedCSR, channel: str = None
     ):
@@ -1201,11 +1211,10 @@ class ShardedExecutor:
         self._new_execs += 1
 
         import jax
-        from janusgraph_tpu.parallel.compat import shard_map
 
         body = self._shard_body(program, op, sc)
         sharded_spec, rep = self._specs()
-        fn = shard_map(
+        fn = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(
@@ -1235,7 +1244,6 @@ class ShardedExecutor:
 
         import jax
         import jax.numpy as jnp
-        from janusgraph_tpu.parallel.compat import shard_map
 
         body = self._shard_body(program, op, sc)
 
@@ -1263,7 +1271,7 @@ class ShardedExecutor:
             return jax.lax.while_loop(cond, loop, (state, mem, steps_done0))
 
         sharded_spec, rep = self._specs()
-        fn = shard_map(
+        fn = jax.shard_map(
             run_span,
             mesh=self.mesh,
             in_specs=(sharded_spec, rep, rep, rep, sharded_spec),
@@ -1415,10 +1423,7 @@ class ShardedExecutor:
         return ck
 
     def _device_kind(self) -> str:
-        try:
-            return str(np.asarray(self.mesh.devices).flat[0].platform)
-        except Exception:
-            return "cpu"
+        return self.mesh.devices.flat[0].device_kind
 
     # -------------------------------------------------- per-shard reporting
     #: skip the measured-wall probe past this many edges — the probe runs
@@ -1847,8 +1852,11 @@ class ShardedExecutor:
         routed to. Host code only."""
         from janusgraph_tpu.observability import registry, tracer
 
+        from janusgraph_tpu.olap.device import describe_devices
+
         info = self.last_run_info
         info["executor"] = "sharded"
+        info.update(describe_devices(self.mesh.devices.flat))
         info["wall_s"] = round(wall_s, 4)
         info["retraces"] = self._new_execs
         info["h2d_arg_bytes"] = int(self._h2d_bytes)
@@ -1974,12 +1982,12 @@ class ShardedExecutor:
                 for k, pad in fresh.items():
                     arr = np.asarray(pad).copy()
                     arr[: sc.real_n] = np.asarray(ck_state[k])
-                    state[k] = jnp.asarray(arr)
+                    state[k] = self._place_state(arr)
                 memory.values = {k: float(v) for k, v in ck_mem.items()}
                 memory.superstep = start_step
         if state is None:
             state, init_metrics = program.setup(_GlobalView(sc), np)
-            state = {k: jnp.asarray(v) for k, v in state.items()}
+            state = {k: self._place_state(v) for k, v in state.items()}
             memory.reduce_in(init_metrics)
             memory.superstep = 0
         device_memory = {
@@ -2081,12 +2089,12 @@ class ShardedExecutor:
                 for k, pad in fresh.items():
                     arr = np.asarray(pad).copy()
                     arr[: sc.real_n] = np.asarray(ck_state[k])
-                    state[k] = jnp.asarray(arr)
+                    state[k] = self._place_state(arr)
                 mem = {k: jnp.asarray(v, jnp.float32) for k, v in ck_mem.items()}
 
         if state is None:
             state, init_metrics = program.setup(_GlobalView(sc), np)
-            state = {k: jnp.asarray(v) for k, v in state.items()}
+            state = {k: self._place_state(v) for k, v in state.items()}
             mem0 = {
                 k: jnp.asarray(v, dtype=jnp.float32)
                 for k, (_o, v) in init_metrics.items()

@@ -133,7 +133,6 @@ class ShardedFrontierEngine:
             return cache[key]
         import jax
         import jax.numpy as jnp
-        from janusgraph_tpu.parallel.compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -192,7 +191,7 @@ class ShardedFrontierEngine:
                 )
 
         sh, rep = P(self.axis), P()
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             plan_body,
             mesh=self.mesh,
             in_specs=(sh, sh, sh),
@@ -216,7 +215,6 @@ class ShardedFrontierEngine:
             return cache[key]
         import jax
         import jax.numpy as jnp
-        from janusgraph_tpu.parallel.compat import shard_map
         from jax.sharding import PartitionSpec as P
 
         axis = self.axis
@@ -267,7 +265,7 @@ class ShardedFrontierEngine:
 
             in_specs = (sh, sh, rep, sh)
         out_specs = (sh, sh, sh, rep) if track else (sh, sh, rep)
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=in_specs,

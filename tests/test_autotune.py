@@ -77,7 +77,7 @@ def test_decision_device_kind_sensitivity():
     roofline peaks it selects are what the model prices against."""
     s = GraphStats.from_csr(skewed_graph())
     d_cpu = decide(s, "cpu")
-    d_tpu = decide(s, "TPU v5e lite")
+    d_tpu = decide(s, "TPU v5 lite")
     assert d_cpu.device_kind != d_tpu.device_kind
     assert d_cpu == decide(s, "cpu")
 

@@ -407,6 +407,10 @@ def test_sharded_auto_routing_records_run_info():
         assert routing["requested"] == "tpu"
         assert routing["routed"] == "sharded"
         assert "mesh of 8" in routing["reason"]
+        # the sharded record names every device of the mesh it ran on
+        assert res.run_info["platform"] == "cpu"
+        assert res.run_info["device_kind"] == "cpu"
+        assert res.run_info["device_count"] == 8
         assert res.run_info["exchange"]["batches_per_superstep"] == 1
         assert abs(res.states["rank"].sum() - 1.0) < 1e-4
     finally:

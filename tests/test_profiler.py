@@ -536,6 +536,12 @@ def test_tpu_run_records_report_roofline_via_cost_analysis():
         assert r["cost_source"] == "xla"  # CPU XLA exposes cost_analysis
     assert info["roofline"]["peak_flops"] > 0
     assert "dense" in info["roofline_by_tier"]
+    # the record names the device the executor placed its arrays on, and
+    # whether Pallas kernels are compiled there (only on a TPU)
+    assert (info["platform"], info["device_kind"], info["device_count"]) == (
+        "cpu", "cpu", 1
+    )
+    assert info["pallas_interpret"] is True
     assert info["resources"]["h2d_bytes"] == info["h2d_arg_bytes"]
     # the run billed its transfer bytes to the ambient ledger
     assert led.get("h2d_bytes") == info["h2d_arg_bytes"]
@@ -586,7 +592,7 @@ def test_roofline_peak_config_override():
         profiler.configure_roofline(
             peak_flops=1e12, peak_bytes_per_s=1e11
         )
-        peaks = profiler.device_peaks("anything")
+        peaks = profiler.device_peaks("TPU v5 lite")
         assert peaks["peak_flops"] == 1e12
         assert peaks["peak_bytes_per_s"] == 1e11
         assert peaks["source"] == "config"
@@ -597,6 +603,23 @@ def test_roofline_peak_config_override():
         assert abs(point["roofline_utilization"] - 0.1) < 1e-9
     finally:
         profiler.configure_roofline(peak_flops=0.0, peak_bytes_per_s=0.0)
+
+
+def test_device_peaks_keyed_by_reported_kind():
+    """The table is keyed by what the chip reports: a v5e's device_kind is
+    "TPU v5 lite", and a kind the table does not list is an error, not a
+    default (a utilization against another chip's peak is worse than
+    none)."""
+    from janusgraph_tpu.observability import profiler
+
+    v5e = profiler.device_peaks("TPU v5 lite")
+    assert v5e["peak_bytes_per_s"] == 819e9
+    assert v5e["peak_flops"] == 197e12
+    assert v5e["source"] == "table:tpu v5 lite"
+    assert profiler.device_peaks("cpu")["source"] == "table:cpu"
+    for unknown in ("TPU v9", "NVIDIA H100", "tpu"):
+        with pytest.raises(KeyError, match="not in the roofline peaks"):
+            profiler.device_peaks(unknown)
 
 
 # ------------------------------------------------------------------- CLI
