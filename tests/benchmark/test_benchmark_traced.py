@@ -1,0 +1,42 @@
+"""The traced run of benchmark/run.py in its CPU rehearsal: per-layer
+metrics only, device readers that find nothing report nothing, the served
+cell is promoted and its loop stays closed."""
+
+import json
+import os
+
+import pytest
+
+from rehearsal import MANIFEST, REPO, declared, rehearse
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
+def test_traced_rehearsal_reports_layer_metrics_only(cell, tmp_path):
+    line, notes, _ = rehearse(cell, tmp_path, trace=1)
+    assert line["correct"] is True and line["failed"] == 0
+    reported = set(line["metrics"])
+    # the CPU's trace has no device plane: readers of the device trace
+    # find nothing and their metrics are left out, not invented
+    assert reported and reported <= declared("per_layer", cell)
+    assert not reported & declared("end_to_end", cell)
+    assert not any(n.startswith(("superstep_", "device_idle"))
+                   for n in reported)
+    assert "busy_s" not in line["device"] and "breakdown" not in line
+    assert {"setup_snapshot_s", "setup_cache_misses"} <= reported
+    assert notes["spans"]["traced"] > 0
+    assert not os.path.exists(os.path.join(
+        tmp_path, "out", f"{cell}.seed{2**31 + 11}.trace1", "trace"))
+
+
+def test_served_rehearsal_promotes_and_keeps_the_loop_closed(tmp_path):
+    line, notes, lines = rehearse("g500-served.twohop", tmp_path, trace=1)
+    assert any("spilled" in ln and "promotion attempt" in ln for ln in lines)
+    assert line["metrics"]["spilled_share"]["value"] == 100.0
+    assert line["metrics"]["server_request_ms"]["value"] > 0
+    # the tail, which spreads too widely for a bound, is a layer metric
+    assert (line["metrics"]["request_p95_layer_ms"]["value"]
+            == notes["end_to_end"]["request_p95_ms"])
+    assert notes["notes"]["run_info"]["path"] == "host-loop"
+    clients = json.load(open(os.path.join(
+        REPO, "benchmark", "traffic", "twohop-closed.json")))["clients"]
+    assert 1 <= notes["counts"]["max_outstanding"] <= clients
