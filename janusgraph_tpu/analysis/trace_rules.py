@@ -17,9 +17,10 @@ JG105  host sync in a jit context: `.item()`, `.tolist()`,
        `.block_until_ready()`, `jax.device_get` on traced values.
 JG106  telemetry recording inside a jit context: a metric/span call on
        the observability registry/tracer (`metrics.counter(...).inc()`,
-       `with span("...")`, `registry.time(...)`, ...) in a traced body
-       runs at TRACE time — it records once per compile, not per
-       execution, and any traced attribute value is a host-sync hazard.
+       `with span("...")`, `tracer.phase("...")`, `registry.time(...)`,
+       ...) in a traced body runs at TRACE time — it records once per
+       compile, not per execution, and any traced attribute value is a
+       host-sync hazard.
        Record from host code after the dispatch (see
        TPUExecutor._finish_run for the sanctioned pattern).
 JG107  structured-log / flight-recorder call inside a jit context:
@@ -171,7 +172,7 @@ def _check_jit_callsites(mod) -> List[Finding]:
 _TELEMETRY_ROOTS = {"metrics", "registry", "tracer", "telemetry"}
 #: method names that record into that layer
 _TELEMETRY_RECORDERS = {
-    "counter", "timer", "histogram", "gauge", "time", "span",
+    "counter", "timer", "histogram", "gauge", "time", "span", "phase",
     "record_span", "record_run", "inc", "update", "observe", "set_gauge",
     "annotate",
 }

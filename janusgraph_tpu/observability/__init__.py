@@ -116,6 +116,9 @@ registry = TelemetryRegistry()
 #: convenience alias: `with span("name", attr=...):` on the global tracer
 span = tracer.span
 
+# phases put their self time into this registry (timers `phase.<name>`)
+tracer.registry = registry
+
 
 def _slow_span_to_flight(event: dict) -> None:
     # the query digest (annotated onto the span by traversal execution)

@@ -27,6 +27,14 @@ Design:
   ``X-Trace-Context`` header — so one user query stitches into ONE trace
   across client, server, and storage nodes (inspect via ``GET /telemetry``
   or ``janusgraph_tpu trace <trace_id>``).
+- a *phase* (``Tracer.phase``) says where a thread's time went: phases
+  of one thread suspend each other, so each adds only its SELF time to
+  the registry timer ``phase.<name>`` and the phases of a request tile
+  it; while it runs, a phase is a ``jax.profiler.TraceAnnotation`` too,
+  which puts the same segments on the device trace's clock (a profiler
+  session labels each idle gap of the device by the innermost phase);
+  and it joins the span tree as a timed child. Host-only like every
+  recording call (graphlint JG106).
 """
 
 from __future__ import annotations
@@ -34,6 +42,7 @@ from __future__ import annotations
 import contextvars
 import random
 import struct
+import sys
 import threading
 import time
 from collections import deque
@@ -198,6 +207,85 @@ class Span:
         return out
 
 
+class _Phase:
+    """One entry of ``Tracer.phase``: the self-time clock and the profiler
+    annotation of a phase, suspended while an inner phase of the same
+    thread runs."""
+
+    __slots__ = (
+        "_tracer", "_name", "_wait", "_attrs", "_parent", "_outer",
+        "_wall_t", "_start_ns", "_since", "_self_ns", "_annotation",
+    )
+
+    def __init__(self, tracer: "Tracer", name: str, wait: bool, attrs: dict):
+        self._tracer = tracer
+        self._name = name
+        self._wait = wait
+        self._attrs = attrs
+        self._self_ns = 0
+        self._annotation = None
+
+    def _resume(self, now: int) -> None:
+        self._since = now
+        if not self._wait:
+            open_event = self._tracer._annotation()
+            if open_event is not None:
+                self._annotation = open_event(self._name)
+                self._annotation.__enter__()
+
+    def _suspend(self, now: int) -> None:
+        self._self_ns += now - self._since
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
+
+    def __enter__(self) -> "_Phase":
+        tr = self._tracer
+        stack = tr._phase_stack()
+        # ONE clock read ends the outer segment and starts this one, so
+        # the phases of a thread tile its time with nothing counted twice
+        now = tr._clock()
+        self._outer = stack[-1] if stack else None
+        if self._outer is not None:
+            self._outer._suspend(now)
+        stack.append(self)
+        self._parent = _CURRENT.get()
+        self._wall_t = time.time()
+        self._start_ns = now
+        self._resume(now)
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        tr = self._tracer
+        now = tr._clock()
+        self._suspend(now)
+        tr._phase_stack().pop()
+        if tr.registry is not None:
+            # graphlint: disable=JG110 -- phase names are literals at the call sites (the table in docs/observability.md)
+            tr.registry.timer("phase." + self._name).update(self._self_ns)
+        parent = self._parent
+        if parent is not None:
+            # the phase joins the tree as a timed child of the span that
+            # was current when it began, WITHOUT ever being the current
+            # span: spans opened inside it and annotations of "the current
+            # operation" (digests, ledger fields) land where they did
+            # before phases existed. Outside any span a phase is a
+            # fragment (the body read before `server.request` opens):
+            # timed and annotated, not kept
+            s = Span(self._name, self._attrs)
+            s.attrs["self_ms"] = round(self._self_ns / 1e6, 4)
+            s.wall_t = self._wall_t
+            s.start_ns = self._start_ns
+            s.end_ns = now
+            s.trace_id = parent.trace_id
+            s.sampled = parent.sampled
+            parent.children.append(s)
+            tr._finished(s, root=False)
+        if self._outer is not None:
+            self._outer._resume(now)
+        return False
+
+
 class Tracer:
     """Owns the current-span context plus the two ring buffers."""
 
@@ -206,8 +294,20 @@ class Tracer:
         max_roots: int = 256,
         slow_threshold_ms: float = 100.0,
         slow_buffer: int = 128,
+        clock=time.perf_counter_ns,
     ):
         self.slow_threshold_ms = slow_threshold_ms
+        #: monotonic nanoseconds behind phase self times (tests inject one)
+        self._clock = clock
+        self._phases = threading.local()
+        #: where phases put their self time (timer ``phase.<name>``);
+        #: observability/__init__.py wires the process registry
+        self.registry = None
+        #: opens the profiler trace event of a running phase: a callable
+        #: name -> context manager. None = jax.profiler.TraceAnnotation
+        #: once JAX is imported (a process without JAX has no device
+        #: trace to share a clock with)
+        self.annotation = None
         self._roots: deque = deque(maxlen=max_roots)
         self._slow: deque = deque(maxlen=slow_buffer)
         self._lock = threading.Lock()
@@ -278,6 +378,40 @@ class Tracer:
             s.end_ns = time.perf_counter_ns()
             _CURRENT.reset(token)
             self._finished(s, root=parent is None)
+
+    def phase(self, name: str, wait: bool = False, **attrs) -> _Phase:
+        """``with tracer.phase("spill.plan"):`` — a timed child in the
+        span tree (wall, ``self_ms``) that also tiles its thread's time.
+        Entering an inner phase suspends the outer one of the same thread,
+        so each phase adds its SELF time (its wall less the inner phases')
+        to the registry timer ``phase.<name>``: the phases of a request
+        sum to its wall, nothing counted twice.
+
+        While it runs (not while suspended) the phase is also a profiler
+        trace event named ``name``, on the device trace's clock; with no
+        profiler session that is a flag check. The segments of one thread
+        never overlap, so a reducer that gives a device idle gap to the
+        host event overlapping it most names the innermost phase.
+
+        ``wait=True`` is for time spent blocked on another thread (a lock):
+        timed and in the span tree, but never a trace event — N waiters
+        would each out-overlap the one thread doing the work.
+
+        Enter and exit on the same thread; host code only (JG106)."""
+        return _Phase(self, name, wait, attrs)
+
+    def _phase_stack(self) -> list:
+        try:
+            return self._phases.stack
+        except AttributeError:
+            stack = self._phases.stack = []
+            return stack
+
+    def _annotation(self):
+        if self.annotation is None:
+            profiler = getattr(sys.modules.get("jax"), "profiler", None)
+            self.annotation = getattr(profiler, "TraceAnnotation", None)
+        return self.annotation
 
     def record_span(self, name: str, duration_ms: float, **attrs) -> Span:
         """Attach a pre-timed span under the current span (or as a root).

@@ -34,6 +34,9 @@ from typing import Dict, Optional
 
 import numpy as np
 
+from janusgraph_tpu.observability import tracer
+from janusgraph_tpu.olap.device import await_arrays
+
 # the reached-ness tests below ("dist >= INF") are parity-equivalent to the
 # dense program only because both use the IDENTICAL constant
 from janusgraph_tpu.olap.programs.shortest_path import INF
@@ -268,42 +271,53 @@ class FrontierEngine:
     ):
         """The shared host-driven loop: plan (3 scalars) -> pick tier ->
         one compiled step. Two device round trips per hop; per-step output
-        is identical to the dense BSP path's."""
+        is identical to the dense BSP path's. The host's time in it is
+        tiled by phases: per hop `executor.tier` (the plan's dispatch and
+        the tier choice; the wait for its three scalars is the
+        `executor.sync` inside it) and `executor.dispatch` (the step)."""
         jax, jnp = self.jax, self.jnp
         plan = self._plan_fn(und)
         if self.m == 0:
             mask = jnp.zeros_like(mask)
         trace = []
         for t in range(max_iterations):
-            count, tot_out, tot_in = (
-                int(x) for x in jax.device_get(plan(mask, fargs))
-            )
-            if count == 0:
-                break
-            if self.f_schedule and self.e_schedule:
-                from janusgraph_tpu.olap.autotune import pick_tier
+            with tracer.phase("executor.tier"):
+                planned = plan(mask, fargs)
+                with tracer.phase("executor.sync"):
+                    planned = jax.device_get(planned)
+                count, tot_out, tot_in = (int(x) for x in planned)
+                if count == 0:
+                    break
+                if self.f_schedule and self.e_schedule:
+                    from janusgraph_tpu.olap.autotune import pick_tier
 
-                f_cap = pick_tier(count, self.f_schedule, self.n)
-                e_cap = pick_tier(
-                    max(tot_out, tot_in, 1), self.e_schedule, self.m
+                    f_cap = pick_tier(count, self.f_schedule, self.n)
+                    e_cap = pick_tier(
+                        max(tot_out, tot_in, 1), self.e_schedule, self.m
+                    )
+                else:
+                    f_cap = _tier(count, self.F_MIN, self.n, self.GROWTH)
+                    e_cap = _tier(
+                        max(tot_out, tot_in, 1), self.E_MIN, self.m,
+                        self.GROWTH,
+                    )
+                trace.append(
+                    {"hop": t, "frontier": count,
+                     "edges": max(tot_out, tot_in), "F_cap": f_cap,
+                     "E_cap": e_cap,
+                     "tier_source": (
+                         "autotune" if self.e_schedule else "static"
+                     )}
                 )
-            else:
-                f_cap = _tier(count, self.F_MIN, self.n, self.GROWTH)
-                e_cap = _tier(
-                    max(tot_out, tot_in, 1), self.E_MIN, self.m, self.GROWTH
+            with tracer.phase("executor.dispatch"):
+                fn = self._step_fn(f_cap, e_cap, weighted, track, und)
+                value, pred, mask, _ = fn(
+                    value, pred, mask, jnp.asarray(t, jnp.float32), fargs
                 )
-            trace.append(
-                {"hop": t, "frontier": count,
-                 "edges": max(tot_out, tot_in), "F_cap": f_cap,
-                 "E_cap": e_cap,
-                 "tier_source": (
-                     "autotune" if self.e_schedule else "static"
-                 )}
-            )
-            fn = self._step_fn(f_cap, e_cap, weighted, track, und)
-            value, pred, mask, _ = fn(
-                value, pred, mask, jnp.asarray(t, jnp.float32), fargs
-            )
+        with tracer.phase("executor.sync"):
+            # the last step is still running: wait for it here, so that
+            # the caller's fetch is the copy alone
+            await_arrays((value, pred))
         # observability: which tiers each hop actually priced at — the
         # per-hop analogue of .profile() (read via executor.last_run_info)
         self.last_trace = trace
@@ -316,27 +330,30 @@ class FrontierEngine:
         weighted = program.weighted
         track = program.track_paths
         und = program.undirected
-        idx0 = np.arange(n, dtype=np.int64)
-        dist = jnp.asarray(
-            np.where(idx0 == program.seed_index, 0.0, INF), jnp.float32
-        )
-        pred = None
-        if track:
-            pred = jnp.asarray(
-                np.where(
-                    idx0 == program.seed_index,
-                    float(program.seed_index), -1.0,
-                ),
-                jnp.float32,
+        with tracer.phase("executor.setup"):
+            idx0 = np.arange(n, dtype=np.int64)
+            dist = jnp.asarray(
+                np.where(idx0 == program.seed_index, 0.0, INF), jnp.float32
             )
-        mask = jnp.asarray(idx0 == program.seed_index)
+            pred = None
+            if track:
+                pred = jnp.asarray(
+                    np.where(
+                        idx0 == program.seed_index,
+                        float(program.seed_index), -1.0,
+                    ),
+                    jnp.float32,
+                )
+            mask = jnp.asarray(idx0 == program.seed_index)
+            fargs = self._fargs(und, weighted)
         dist, pred = self._hop_loop(
-            dist, pred, mask, weighted, track, und,
-            self._fargs(und, weighted), program.max_iterations,
+            dist, pred, mask, weighted, track, und, fargs,
+            program.max_iterations,
         )
-        out = {"distance": np.asarray(dist)}
-        if track:
-            out["predecessor"] = np.asarray(pred)
+        with tracer.phase("executor.fetch"):
+            out = {"distance": np.asarray(dist)}
+            if track:
+                out["predecessor"] = np.asarray(pred)
         return out
 
     def run_cc(self, program) -> Dict[str, np.ndarray]:
@@ -350,13 +367,16 @@ class FrontierEngine:
         label was already absorbed by its neighbors when it last changed.
         Labels ride float32 (exact below 2^24 — eligibility-guarded)."""
         jnp = self.jnp
-        labels = jnp.asarray(np.arange(self.n, dtype=np.float32))
-        mask = jnp.ones((self.n,), bool)
-        # both orientations, NO weight arrays: the step fn's value-message
-        # branch adds w[pos] whenever weights are present in fargs, and a
-        # label must never absorb an edge weight
+        with tracer.phase("executor.setup"):
+            labels = jnp.asarray(np.arange(self.n, dtype=np.float32))
+            mask = jnp.ones((self.n,), bool)
+            # both orientations, NO weight arrays: the step fn's
+            # value-message branch adds w[pos] whenever weights are present
+            # in fargs, and a label must never absorb an edge weight
+            fargs = self._fargs(True, False)
         labels, _ = self._hop_loop(
-            labels, None, mask, True, False, True,
-            self._fargs(True, False), program.max_iterations,
+            labels, None, mask, True, False, True, fargs,
+            program.max_iterations,
         )
-        return {"component": np.asarray(labels)}
+        with tracer.phase("executor.fetch"):
+            return {"component": np.asarray(labels)}

@@ -56,6 +56,7 @@ terminal) via :func:`try_spill`; built per graph at open when
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -494,81 +495,118 @@ class SpilloverPlanner:
             1 for s in steps if getattr(s, "_expand_meta", None) is not None
         )
         if n_hops < self.min_hops:
-            return None
-        plan, reason = recognize(traversal, terminal)
-        if plan is None:
-            # not compilable: only a PROMOTED shape's refusal is an event
-            shape, digest = traversal_digest(traversal)
-            with self._lock:
-                hot = digest in self._promoted
-            if hot:
-                return self._fallback(digest, f"unsupported:{reason}")
-            return None
-        with self._lock:
-            if not self._check_promotion(plan.digest, plan.shape):
+            return None  # the row path pays for no phase
+        from janusgraph_tpu.observability import tracer
+
+        with tracer.phase("spill.recognize"):
+            plan, reason = recognize(traversal, terminal)
+            if plan is None:
+                # not compilable: only a PROMOTED shape's refusal is an
+                # event
+                shape, digest = traversal_digest(traversal)
+                with self._lock:
+                    hot = digest in self._promoted
+                if hot:
+                    return self._fallback(digest, f"unsupported:{reason}")
                 return None
-        from janusgraph_tpu.exceptions import ServerOverloadedError
-        from janusgraph_tpu.server.admission import check_olap_admission
-
-        try:
-            check_olap_admission()
-        except ServerOverloadedError:
-            return self._fallback(plan.digest, "brownout")
-        from janusgraph_tpu.exceptions import (
-            DeadlineExceededError,
-            QueryError,
-        )
-
-        try:
+        # The planner's lock is taken twice: for the promotion check and,
+        # straight after it, to run the plan. With several clients the
+        # queue stands at the FIRST (whoever passes it usually takes the
+        # second before a woken waiter can), so ONE wait phase runs from
+        # before the first until the second is held, and between the two
+        # stands only what stood there before phases: a few microseconds
+        # more let the woken waiter in and move the latency's median by a
+        # third (PERF.md, PR 25). As a wait it is timed, never a trace
+        # event.
+        with contextlib.ExitStack() as waiting:
+            waiting.enter_context(tracer.phase("spill.lock_wait", wait=True))
             with self._lock:
-                return self._execute_plan(traversal, plan, terminal)
-        except _SpillRefused as e:
-            return self._fallback(plan.digest, e.reason)
-        except (QueryError, DeadlineExceededError):
-            # semantic refusals (traverser budget, expired deadline) are
-            # the QUERY's errors, not planner defects — the row path
-            # would raise the same way, so surface them directly
-            raise
-        except Exception as e:  # noqa: BLE001 - fallback IS the contract:
-            # a planner defect must degrade to the row walk, never fail
-            # the query (the flight event + counter keep it visible)
-            return self._fallback(
-                plan.digest, f"error:{type(e).__name__}: {e}"[:200]
+                if not self._check_promotion(plan.digest, plan.shape):
+                    return None
+            from janusgraph_tpu.exceptions import ServerOverloadedError
+            from janusgraph_tpu.server.admission import check_olap_admission
+
+            try:
+                check_olap_admission()
+            except ServerOverloadedError:
+                return self._fallback(plan.digest, "brownout")
+            from janusgraph_tpu.exceptions import (
+                DeadlineExceededError,
+                QueryError,
             )
+
+            try:
+                with self._lock:
+                    waiting.close()  # the lock is held: the wait is over
+                    return self._execute_plan(traversal, plan, terminal)
+            except _SpillRefused as e:
+                return self._fallback(plan.digest, e.reason)
+            except (QueryError, DeadlineExceededError):
+                # semantic refusals (traverser budget, expired deadline)
+                # are the QUERY's errors, not planner defects — the row
+                # path would raise the same way, so surface them directly
+                raise
+            except Exception as e:  # noqa: BLE001 - fallback IS the
+                # contract: a planner defect must degrade to the row walk,
+                # never fail the query (the flight event + counter keep it
+                # visible)
+                return self._fallback(
+                    plan.digest, f"error:{type(e).__name__}: {e}"[:200]
+                )
 
     def _execute_plan(self, traversal, plan: SpilloverPlan, terminal):
         import numpy as np
 
         from janusgraph_tpu.core import deadline as _deadline
+        from janusgraph_tpu.observability import tracer
+
+        _deadline.check("spillover compile")
+        t0 = time.perf_counter()
+        # self time: snapshot, overlay, patch, compile and the executor's
+        # choice; the run's executor.* phases suspend it
+        with tracer.phase("spill.plan"):
+            base = self._snapshot()
+            packed_epoch = self._epoch
+            overlay = tx_overlay(traversal.tx)
+            if overlay["size"] > self.max_overlay:
+                raise _SpillRefused("overlay-overflow")
+            csr = patched_csr(base, overlay)
+            program = self._compile(plan, csr, overlay)
+            _deadline.check("spillover run")
+            with tracer.span(
+                "olap.spillover", digest=plan.digest, hops=len(plan.hops),
+            ):
+                states = self._run_program(
+                    csr, program, patched=csr is not base
+                )
+        with tracer.phase("spill.reduce"):
+            counts = np.asarray(states["count"], dtype=np.float64)
+            if counts.size and counts.max() >= float(1 << 24):
+                # per-vertex traverser counts ride float32 on device —
+                # exact only below 2^24; past it the row walk is the
+                # honest answer
+                raise _SpillRefused("count-overflow")
+            result, total = self._reduce(
+                traversal, plan, csr, counts, terminal
+            )
+        wall_ms = (time.perf_counter() - t0) * 1000.0
+        with tracer.phase("spill.publish"):
+            self._publish(
+                plan, terminal, overlay, packed_epoch, wall_ms, total
+            )
+        return result
+
+    def _publish(
+        self, plan, terminal, overlay, packed_epoch, wall_ms, total
+    ) -> None:
+        """The spilled execution still feeds the digest table (the
+        shape's new, cheap reality) and the ambient span, like the row
+        path; then counters, the run record and the flight event."""
         from janusgraph_tpu.observability import (
             flight_recorder,
             registry,
             tracer,
         )
-
-        _deadline.check("spillover compile")
-        t0 = time.perf_counter()
-        base = self._snapshot()
-        packed_epoch = self._epoch
-        overlay = tx_overlay(traversal.tx)
-        if overlay["size"] > self.max_overlay:
-            raise _SpillRefused("overlay-overflow")
-        csr = patched_csr(base, overlay)
-        program = self._compile(plan, csr, overlay)
-        _deadline.check("spillover run")
-        with tracer.span(
-            "olap.spillover", digest=plan.digest, hops=len(plan.hops),
-        ) as sp:
-            states = self._run_program(csr, program, patched=csr is not base)
-        counts = np.asarray(states["count"], dtype=np.float64)
-        if counts.size and counts.max() >= float(1 << 24):
-            # per-vertex traverser counts ride float32 on device — exact
-            # only below 2^24; past it the row walk is the honest answer
-            raise _SpillRefused("count-overflow")
-        result, total = self._reduce(traversal, plan, csr, counts, terminal)
-        wall_ms = (time.perf_counter() - t0) * 1000.0
-        # the spilled execution still feeds the digest table (the shape's
-        # new, cheap reality) and the ambient span, like the row path
         from janusgraph_tpu.observability.profiler import digest_table
 
         digest_table.observe(plan.digest, plan.shape, wall_ms)
@@ -609,7 +647,6 @@ class SpilloverPlanner:
             hops=len(plan.hops), overlay=overlay["size"],
             wall_ms=round(wall_ms, 3), total=total,
         )
-        return result
 
     def _reducer_name(self, plan: SpilloverPlan, terminal) -> str:
         parts = []

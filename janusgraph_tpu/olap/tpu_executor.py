@@ -22,6 +22,7 @@ import numpy as np
 
 from janusgraph_tpu.observability import registry, tracer
 from janusgraph_tpu.olap.csr import CSRGraph
+from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.vertex_program import (
     Combiner,
     EdgeTransform,
@@ -332,8 +333,12 @@ class TPUExecutor:
         self._auto_cache: Dict[Tuple, str] = {}
         # the device every array of this executor lands on (jnp.asarray
         # places on this process's first device); run records carry it
-        from janusgraph_tpu.olap.device import describe_devices
+        from janusgraph_tpu.olap.device import (
+            count_compiles,
+            describe_devices,
+        )
 
+        count_compiles()
         self._device = jax.local_devices()[0]
         self._device_info = describe_devices([self._device])
         # Pallas kernels are compiled by Mosaic on a TPU and interpreted
@@ -1309,11 +1314,14 @@ class TPUExecutor:
                 self.last_run_info["resumes"] = resumes
                 self.last_run_info["resume_steps"] = resume_steps
                 sp.annotate(resumes=resumes)
-            self._finish_run(
-                sp, program, out,
-                time.perf_counter() - t0,
-                len(self._compiled) - compiled_before,
-            )
+            # the run record's own cost (estimate roofline, gauges,
+            # per-superstep record_spans): what ROADMAP D7 would remove
+            with tracer.phase("executor.publish"):
+                self._finish_run(
+                    sp, program, out,
+                    time.perf_counter() - t0,
+                    len(self._compiled) - compiled_before,
+                )
         return out
 
     # ------------------------------------------------------------ telemetry
@@ -1663,58 +1671,64 @@ class TPUExecutor:
         steps_done = 0
         state = mem = None
 
-        if resume and checkpoint_path:
-            from janusgraph_tpu.olap.checkpoint import load_checkpoint
+        with tracer.phase("executor.setup"):
+            if resume and checkpoint_path:
+                from janusgraph_tpu.olap.checkpoint import load_checkpoint
 
-            ck = load_checkpoint(checkpoint_path)
-            if ck is not None:
-                state, mem, steps_done = ck
+                ck = load_checkpoint(checkpoint_path)
+                if ck is not None:
+                    state, mem, steps_done = ck
+                    state = {k: jnp.asarray(v) for k, v in state.items()}
+                    mem = {
+                        k: jnp.asarray(v, jnp.float32) for k, v in mem.items()
+                    }
+
+            if state is None:
+                state, init_metrics = program.setup(self.g, jnp)
                 state = {k: jnp.asarray(v) for k, v in state.items()}
-                mem = {k: jnp.asarray(v, jnp.float32) for k, v in mem.items()}
+                mem0 = {
+                    k: jnp.asarray(v, dtype=jnp.float32)
+                    for k, (_o, v) in init_metrics.items()
+                }
+                if max_iter == 0:
+                    self.last_run_info = {"path": "fused", "supersteps": 0}
+                    return {k: np.asarray(v) for k, v in state.items()}
+                # The while_loop carry must use apply's aggregator pytree,
+                # which can add keys over setup's. Learn it via an abstract
+                # trace (no XLA compile — the trace records each metric's
+                # monoid op as a side effect), then seed missing keys with
+                # the monoid identity so superstep 0 runs INSIDE the fused
+                # executable. One compile per program instead of two (the
+                # separate superstep-0 executable doubled the dominant
+                # bucket-aggregate compile: measured 123s -> ~60s for s20
+                # PageRank).
+                mkey = (program.cache_key(), op)
+                if mkey not in self._metric_ops:
+                    # the view-usage discovery trace records metric ops too;
+                    # reuse this run's state/mem so discovery is abstract-only
+                    self._used_view_keys(program, op, state=state, mem0=mem0)
+                mops = self._metric_ops[mkey]
+                mem = {
+                    k: (
+                        mem0[k]
+                        if k in mem0
+                        else jnp.asarray(
+                            Combiner.IDENTITY[mops[k]], jnp.float32
+                        )
+                    )
+                    for k in mops
+                }
+                steps_done = 0
 
-        if state is None:
-            state, init_metrics = program.setup(self.g, jnp)
-            state = {k: jnp.asarray(v) for k, v in state.items()}
-            mem0 = {
-                k: jnp.asarray(v, dtype=jnp.float32)
-                for k, (_o, v) in init_metrics.items()
-            }
-            if max_iter == 0:
-                self.last_run_info = {"path": "fused", "supersteps": 0}
-                return {k: np.asarray(v) for k, v in state.items()}
-            # The while_loop carry must use apply's aggregator pytree, which
-            # can add keys over setup's. Learn it via an abstract trace (no
-            # XLA compile — the trace records each metric's monoid op as a
-            # side effect), then seed missing keys with the monoid identity
-            # so superstep 0 runs INSIDE the fused executable. One compile
-            # per program instead of two (the separate superstep-0
-            # executable doubled the dominant bucket-aggregate compile:
-            # measured 123s -> ~60s for s20 PageRank).
-            mkey = (program.cache_key(), op)
-            if mkey not in self._metric_ops:
-                # the view-usage discovery trace records metric ops too;
-                # reuse this run's state/mem so discovery is abstract-only
-                self._used_view_keys(program, op, state=state, mem0=mem0)
-            mops = self._metric_ops[mkey]
-            mem = {
-                k: (
-                    mem0[k]
-                    if k in mem0
-                    else jnp.asarray(Combiner.IDENTITY[mops[k]], jnp.float32)
-                )
-                for k in mops
-            }
-            steps_done = 0
-
-        fused_key = ("fused", program.cache_key(), op, self._strategy_cfg,
-                     None, self._delta_sig(program))
-        cold = fused_key not in self._compiled
-        fn = self._fused_fn(program, op)
-        gargs = self._graph_args(program, op)
-        # per-superstep cost from the SINGLE-step kernel's lowering (the
-        # fused while_loop executable's analysis would mix in the loop
-        # plumbing; the step body is the dispatch-equivalent unit)
-        cost = self._superstep_cost(program, op, None, state, mem, gargs)
+            fused_key = ("fused", program.cache_key(), op, self._strategy_cfg,
+                         None, self._delta_sig(program))
+            cold = fused_key not in self._compiled
+            fn = self._fused_fn(program, op)
+            gargs = self._graph_args(program, op)
+            # per-superstep cost from the SINGLE-step kernel's lowering (the
+            # fused while_loop executable's analysis would mix in the loop
+            # plumbing; the step body is the dispatch-equivalent unit)
+            cost = self._superstep_cost(program, op, None, state, mem, gargs)
         records = []
         first_dispatch_s = None
         while steps_done < max_iter:
@@ -1727,14 +1741,16 @@ class TPUExecutor:
             if checkpoint_every:
                 limit = min(steps_done + checkpoint_every, max_iter)
             c0 = time.perf_counter()
-            state, mem, steps_dev = fn(
-                state,
-                mem,
-                jnp.asarray(steps_done, jnp.int32),
-                jnp.asarray(limit, jnp.int32),
-                gargs,
-            )
-            new_steps = int(steps_dev)  # the per-chunk host sync (existing)
+            with tracer.phase("executor.dispatch"):
+                state, mem, steps_dev = fn(
+                    state,
+                    mem,
+                    jnp.asarray(steps_done, jnp.int32),
+                    jnp.asarray(limit, jnp.int32),
+                    gargs,
+                )
+            with tracer.phase("executor.sync"):
+                new_steps = int(steps_dev)  # the per-chunk host sync
             chunk_s = time.perf_counter() - c0
             if first_dispatch_s is None:
                 first_dispatch_s = chunk_s
@@ -1780,7 +1796,8 @@ class TPUExecutor:
             "first_dispatch_s": round(first_dispatch_s or 0.0, 4),
             "compile_in_first_dispatch": cold,
         }
-        return {k: np.asarray(v) for k, v in state.items()}
+        with tracer.phase("executor.fetch"):
+            return {k: np.asarray(v) for k, v in state.items()}
 
     def _run_host_loop(
         self,
@@ -1795,70 +1812,74 @@ class TPUExecutor:
         memory = Memory()
         state = None
         start_step = 0
-        if resume and checkpoint_path:
-            from janusgraph_tpu.olap.checkpoint import load_checkpoint
+        with tracer.phase("executor.setup"):
+            if resume and checkpoint_path:
+                from janusgraph_tpu.olap.checkpoint import load_checkpoint
 
-            ck = load_checkpoint(checkpoint_path)
-            if ck is not None:
-                ck_state, ck_mem, start_step = ck
-                state = {k: jnp.asarray(v) for k, v in ck_state.items()}
-                memory.values = {k: float(v) for k, v in ck_mem.items()}
-                memory.superstep = start_step
-        if state is None:
-            state, init_metrics = program.setup(self.g, jnp)
-            memory.reduce_in(init_metrics)
-            memory.superstep = 0
+                ck = load_checkpoint(checkpoint_path)
+                if ck is not None:
+                    ck_state, ck_mem, start_step = ck
+                    state = {k: jnp.asarray(v) for k, v in ck_state.items()}
+                    memory.values = {k: float(v) for k, v in ck_mem.items()}
+                    memory.superstep = start_step
+            if state is None:
+                state, init_metrics = program.setup(self.g, jnp)
+                memory.reduce_in(init_metrics)
+                memory.superstep = 0
 
-        # device-resident aggregators: no H2D after this point
-        device_memory = {
-            k: jnp.asarray(v, dtype=jnp.float32) for k, v in memory.values.items()
-        }
+            # device-resident aggregators: no H2D after this point
+            device_memory = {
+                k: jnp.asarray(v, dtype=jnp.float32)
+                for k, v in memory.values.items()
+            }
         steps_done = start_step
         records = []
         for step in range(start_step, program.max_iterations):
-            if fault_hook is not None:
-                fault_hook(step)
-            op = program.combiner_for(step)
-            ch = program.channel_for(step)
-            s0 = time.perf_counter()
-            compiled_before = len(self._compiled)
-            # seed view-usage discovery with this run's live pytrees so the
-            # cache-miss path never re-runs program.setup
-            self._used_view_keys(
-                program, op, ch, state=state, mem0=device_memory
-            )
-            fn = self._superstep_fn(program, op, ch)
-            gargs = self._graph_args(program, op, ch)
-            # lower-once cost harvest (memoized per compiled variant):
-            # flops + bytes accessed feed the per-superstep roofline
-            cost = self._superstep_cost(
-                program, op, ch, state, device_memory, gargs
-            )
-            state, metrics = fn(
-                state,
-                jnp.asarray(step, dtype=jnp.int32),
-                device_memory,
-                gargs,
-            )
-            device_memory = {
-                k: metrics.get(k, device_memory.get(k)) for k in
-                set(device_memory) | set(metrics)
-            }
-            # host-side dispatch wall (async enqueue unless the cadence
-            # below syncs) + whether this step built a fresh executable —
-            # the compile-vs-execute split at superstep granularity
-            records.append({
-                "step": step,
-                "wall_ms": round((time.perf_counter() - s0) * 1000.0, 3),
-                "combiner": op,
-                "channel": ch,
-                "compiled": len(self._compiled) > compiled_before,
-                **cost,
-            })
+            with tracer.phase("executor.dispatch"):
+                if fault_hook is not None:
+                    fault_hook(step)
+                op = program.combiner_for(step)
+                ch = program.channel_for(step)
+                s0 = time.perf_counter()
+                compiled_before = len(self._compiled)
+                # seed view-usage discovery with this run's live pytrees so the
+                # cache-miss path never re-runs program.setup
+                self._used_view_keys(
+                    program, op, ch, state=state, mem0=device_memory
+                )
+                fn = self._superstep_fn(program, op, ch)
+                gargs = self._graph_args(program, op, ch)
+                # lower-once cost harvest (memoized per compiled variant):
+                # flops + bytes accessed feed the per-superstep roofline
+                cost = self._superstep_cost(
+                    program, op, ch, state, device_memory, gargs
+                )
+                state, metrics = fn(
+                    state,
+                    jnp.asarray(step, dtype=jnp.int32),
+                    device_memory,
+                    gargs,
+                )
+                device_memory = {
+                    k: metrics.get(k, device_memory.get(k)) for k in
+                    set(device_memory) | set(metrics)
+                }
+                # host-side dispatch wall (async enqueue unless the cadence
+                # below syncs) + whether this step built a fresh executable —
+                # the compile-vs-execute split at superstep granularity
+                records.append({
+                    "step": step,
+                    "wall_ms": round((time.perf_counter() - s0) * 1000.0, 3),
+                    "combiner": op,
+                    "channel": ch,
+                    "compiled": len(self._compiled) > compiled_before,
+                    **cost,
+                })
             steps_done += 1
             last = step == program.max_iterations - 1
             if steps_done % sync_every == 0 or last:
-                host_vals = self.jax.device_get(metrics)  # one round trip
+                with tracer.phase("executor.sync"):
+                    host_vals = self.jax.device_get(metrics)  # one round trip
                 memory.values = {k: float(v) for k, v in host_vals.items()}
                 memory.superstep = steps_done
                 if checkpoint_path and checkpoint_every and (
@@ -1884,7 +1905,12 @@ class TPUExecutor:
             "supersteps": steps_done,
             "superstep_records": records,
         }
-        return {k: np.asarray(v) for k, v in state.items()}
+        with tracer.phase("executor.sync"):
+            # a program without aggregators never waited above: the wait
+            # for its last superstep is here, the fetch is the copy
+            await_arrays(state.values())
+        with tracer.phase("executor.fetch"):
+            return {k: np.asarray(v) for k, v in state.items()}
 
     # ------------------------------------------------------------ write-back
     def write_back(self, graph, result: Dict[str, np.ndarray], keys=None) -> None:

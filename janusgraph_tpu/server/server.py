@@ -829,30 +829,34 @@ class _Handler(BaseHTTPRequestHandler):
             ticket = None
             digest = ""
             if ctl is not None:
-                digest, price_ms, known = ctl.price(query)
-                try:
-                    rem = remaining_ms()
-                    ticket = ctl.acquire(
-                        price_ms=price_ms, known=known, digest=digest,
-                        timeout_s=(
-                            rem / 1000.0 if rem is not None else None
-                        ),
-                    )
-                except ShedError as e:
-                    # shed-503, distinguishable from a degraded /healthz
-                    # 503 by status "shed"; retry_after_s rides the body
-                    # and do_POST mirrors it into a Retry-After header
-                    return {
-                        "result": {"data": None},
-                        "status": {
-                            "code": 503, "status": "shed",
-                            "reason": e.reason,
-                            "retry_after_s": e.retry_after_s,
-                            "message": str(e),
-                        },
-                    }
-                except DeadlineExceededError as e:
-                    return _timeout_payload(e)
+                # admission's own queue wait stays counted by
+                # server.admission.queued; the phase holds pricing and wait
+                with tracer.phase("server.admit"):
+                    digest, price_ms, known = ctl.price(query)
+                    try:
+                        rem = remaining_ms()
+                        ticket = ctl.acquire(
+                            price_ms=price_ms, known=known, digest=digest,
+                            timeout_s=(
+                                rem / 1000.0 if rem is not None else None
+                            ),
+                        )
+                    except ShedError as e:
+                        # shed-503, distinguishable from a degraded
+                        # /healthz 503 by status "shed"; retry_after_s
+                        # rides the body and the HTTP handler mirrors it
+                        # into a Retry-After header
+                        return {
+                            "result": {"data": None},
+                            "status": {
+                                "code": 503, "status": "shed",
+                                "reason": e.reason,
+                                "retry_after_s": e.retry_after_s,
+                                "message": str(e),
+                            },
+                        }
+                    except DeadlineExceededError as e:
+                        return _timeout_payload(e)
             t0 = _time.perf_counter()
             cells = 0
             try:
@@ -939,20 +943,26 @@ class _Handler(BaseHTTPRequestHandler):
     def _execute_request_inner(self, req, query, graph, session, sp) -> dict:
         from janusgraph_tpu.core import deadline as _deadline
         from janusgraph_tpu.exceptions import DeadlineExceededError
+        from janusgraph_tpu.observability import tracer
 
         try:
-            if session is not None:
-                result = self.jg_server.execute_session(
-                    query, graph, session
-                )
-            else:
-                result = self.jg_server.execute(query, graph)
+            # self time: prepare, namespace, evaluation on the row path,
+            # commit/rollback; a spilled traversal's spill.* and
+            # executor.* phases suspend it
+            with tracer.phase("server.evaluate"):
+                if session is not None:
+                    result = self.jg_server.execute_session(
+                        query, graph, session
+                    )
+                else:
+                    result = self.jg_server.execute(query, graph)
             # wall-clock deadline on EVALUATION, not just on reads: an
             # evaluation that ran past the budget returns a structured
             # timeout (nobody is waiting for the late answer) instead of
             # a success on a connection the client already abandoned
             _deadline.check("query evaluation")
-            data = json.loads(graphson_dumps(result))
+            with tracer.phase("server.serialize"):
+                data = json.loads(graphson_dumps(result))
             sp.annotate(code=200)
             return {"result": {"data": data}, "status": {"code": 200}}
         except DeadlineExceededError as e:
@@ -1346,6 +1356,9 @@ class _Handler(BaseHTTPRequestHandler):
                            f"({self.jg_server.max_request_bytes})",
             }})
             return
+        if self.path == "/gremlin" or self.path == "/":
+            self._post_gremlin(length)
+            return
         raw = self.rfile.read(length)
         if self.path == "/session" or self.path == "/token":
             try:
@@ -1378,7 +1391,17 @@ class _Handler(BaseHTTPRequestHandler):
             gossip.merge(body)
             self._send_json(200, gossip.local_digest())
             return
-        if self.path == "/gremlin" or self.path == "/":
+        self._send_json(404, {"status": {"code": 404}})
+
+    def _post_gremlin(self, length: int) -> None:
+        """One query over HTTP. Its host time is tiled by phases:
+        `server.read` here, `server.admit` / `server.evaluate` and the
+        result's part of `server.serialize` under `_run_request`, the
+        response's part of `server.serialize` here."""
+        from janusgraph_tpu.observability import tracer
+
+        with tracer.phase("server.read"):
+            raw = self.rfile.read(length)
             if not self._auth():
                 return
             try:
@@ -1386,11 +1409,12 @@ class _Handler(BaseHTTPRequestHandler):
             except json.JSONDecodeError:
                 self._send_json(400, {"status": {"code": 400, "message": "bad json"}})
                 return
-            payload = self._run_request(
-                req, trace_header=self.headers.get("X-Trace-Context"),
-                deadline_header=self.headers.get("X-Deadline-Ms"),
-            )
-            status = payload.get("status", {})
+        payload = self._run_request(
+            req, trace_header=self.headers.get("X-Trace-Context"),
+            deadline_header=self.headers.get("X-Deadline-Ms"),
+        )
+        status = payload.get("status", {})
+        with tracer.phase("server.serialize"):
             if status.get("status") == "shed" or (
                 status.get("status") == "draining"
             ):
@@ -1404,13 +1428,10 @@ class _Handler(BaseHTTPRequestHandler):
                         "Retry-After": str(status.get("retry_after_s", 1)),
                     },
                 )
-                return
-            if status.get("status") == "timeout":
+            elif status.get("status") == "timeout":
                 self._send_json(504, payload)
-                return
-            self._send_json(200, payload)
-            return
-        self._send_json(404, {"status": {"code": 404}})
+            else:
+                self._send_json(200, payload)
 
     # ------------------------------------------------------------ WebSocket
     def _watch_stream(self) -> None:
