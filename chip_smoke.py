@@ -475,7 +475,7 @@ def phase_analytics(dev: dict, scale: int, seed: int, devices) -> None:
 
 # ------------------------------------------------------------------ phase 3
 
-def phase_kernels(dev: dict, scale: int, seed: int, hybrid: bool) -> None:
+def phase_kernels(dev: dict, scale: int, seed: int) -> None:
     import numpy as np
 
     from janusgraph_tpu.olap.generators import rmat_csr
@@ -509,10 +509,9 @@ def phase_kernels(dev: dict, scale: int, seed: int, hybrid: bool) -> None:
     check(rel <= PALLAS_RTOL,
           f"Pallas vs ELL max relative difference {rel:.2e} <= "
           f"{PALLAS_RTOL:g} (float32 sums in another order)")
-    if hybrid:
-        got, _ = run("hybrid")
-        check(np.array_equal(got, ell),
-              "hybrid pack bitwise-equal to ELL (the same reduction tree)")
+    got, _ = run("hybrid")
+    check(np.array_equal(got, ell),
+          "hybrid pack bitwise-equal to ELL (the same reduction tree)")
 
 
 # --------------------------------------------------------------------- main
@@ -528,9 +527,6 @@ def main(argv=None) -> int:
     ap.add_argument("--store-scale", type=int, default=18,
                     help="phase 1 R-MAT scale through the store (default "
                          "18: the host-bound bulk loader sets it)")
-    ap.add_argument("--hybrid", action="store_true",
-                    help="also run strategy='hybrid' in phase 3 (slow to "
-                         "compile: one traced shape per exact degree)")
     ap.add_argument("--cpu-rehearsal", action="store_true",
                     help="run the control flow on the CPU at tiny scales; "
                          "every output line is marked, none is a result")
@@ -553,7 +549,7 @@ def main(argv=None) -> int:
     dev = phase_device(args.cpu_rehearsal)
     phase_served(dev, args.store_scale, args.seed, graph_cfg)
     phase_analytics(dev, args.scale, args.seed, jax.devices())
-    phase_kernels(dev, kernel_scale, args.seed, args.hybrid)
+    phase_kernels(dev, kernel_scale, args.seed)
 
     say(f"all phases passed in {time.perf_counter() - _T0:.1f}s")
     print(_PREFIX + json.dumps({

@@ -63,7 +63,7 @@ class GraphStats:
     #: candidate hub cutoff -> (cutoff, hybrid gathered slots, hub count,
     #: torso bucket count, tail chunk rows) — the closed-form HybridPack
     #: footprint per cutoff; chunk rows price the tail's partial-table
-    #: scatter, the term that punishes small chunks (measured s18 sweep:
+    #: scatter, the term that punishes small chunks (host XLA, s18 sweep:
     #: 132k chunks = 23.8 ms/superstep vs 6k chunks = 14.4 ms at equal pad)
     hybrid_by_cutoff: Tuple[Tuple[int, int, int, int, int], ...]
 
@@ -163,24 +163,31 @@ def _bytes_per_slot(weighted: bool) -> int:
     return 12 if weighted else 4
 
 
-#: modeled fixed cost per distinct device kernel (gather+fold per bucket).
-#: Small: XLA fuses the per-bucket gathers into one program, so even
-#: hundreds of exact-width torso buckets barely register (measured s18:
-#: the 555-torso-bucket config was among the FASTEST)
-_BUCKET_OVERHEAD_S = 2e-7
+#: The packed layouts' price list, one column per device kind; `decide`
+#: prices every strategy from the SAME column. cpu: host XLA, the round-6
+#: s18 sweep (bench_artifacts/r6_hybrid_autotune_cpu.jsonl). tpu: one v5e,
+#: PageRank at Graph500 s17 and s20, ELL against the single-gather hybrid
+#: pack at eight (cutoff, chunk) settings (PERF.md section 6, PR 26).
 
-#: modeled cost per hybrid tail chunk row: each chunk pays a partial-table
-#: scatter element + fold slot on top of its gathered bytes. Calibrated
-#: from the s18 sweep (126k extra chunks cost ~9.4 ms => ~75 ns/chunk on
-#: host XLA; TPUs scatter relatively better)
-_TAIL_CHUNK_COST_S = {"cpu": 7.5e-8, "tpu": 3e-8}
+#: fixed cost per distinct bucket. cpu: small, XLA fuses the per-bucket
+#: work into one program. tpu: nothing measurable (16 to 374 torso buckets
+#: at s20 fit 3.4 us each, 1% of a superstep and inside the fit's error;
+#: at s17 the fit's sign flips), since every bucket is a slice of ONE
+#: gathered vector
+_BUCKET_OVERHEAD_S = {"cpu": 2e-7, "tpu": 0.0}
 
-#: measured per-gathered-slot cost of the packed aggregation kernels —
-#: the gather unit is the binding resource, well below what the DRAM-peak
-#: bytes/bw term predicts. cpu: ~3.3 ns/slot (s18 sweep this round, both
-#: layouts); tpu: the ~140M gathered elem/s v5e gather wall
-#: (docs/tpu_notes.md) => ~7 ns/slot
-_GATHER_COST_S = {"cpu": 3.3e-9, "tpu": 7e-9}
+#: cost per hybrid tail chunk row: a partial-table scatter element and a
+#: fold slot on top of its gathered slots. cpu: 126k extra chunks cost
+#: ~9.4 ms. tpu: at s20 17k to 1.02M chunks fit 8.3 ns each beside 8.0 ns
+#: a slot, to 0.5% on all seven packs
+_TAIL_CHUNK_COST_S = {"cpu": 7.5e-8, "tpu": 8e-9}
+
+#: cost per gathered slot — the gather unit is the binding resource, far
+#: below what bytes over DRAM bandwidth predict. cpu: ~3.3 ns on both
+#: layouts. tpu: 7.29 (s20) and 7.53 (s17) ns on the ELL pack, 8.05 and
+#: 7.42 on the hybrid's one gather; the mean, which puts every modeled
+#: superstep of the sweep within 7% of its measurement
+_GATHER_COST_S = {"cpu": 3.3e-9, "tpu": 7.6e-9}
 
 #: scatter (segment-reduce) effective-bandwidth derating vs the packed
 #: gather paths — the reason ELL exists at all (serialized scatter-add
@@ -190,8 +197,8 @@ _SEGMENT_PENALTY = {"tpu": 8.0, "cpu": 2.5}
 
 def _modeled_seconds(
     slots: int, n: int, weighted: bool, buckets: int, peaks: dict,
-    penalty: float = 1.0, eff_bw: Optional[float] = None,
-    chunk_rows: int = 0, kind: str = "cpu", cols: int = 1,
+    kind: str, penalty: float = 1.0, eff_bw: Optional[float] = None,
+    chunk_rows: int = 0, cols: int = 1,
 ) -> float:
     """Roofline time model for one superstep of a packed aggregation: the
     binding constraint is max(bytes moved at peak-or-measured bandwidth,
@@ -210,7 +217,7 @@ def _modeled_seconds(
         penalty * slots * _GATHER_COST_S[kind],
     )
     t += slots * cols / max(peaks["peak_flops"], 1.0)
-    t += buckets * _BUCKET_OVERHEAD_S
+    t += buckets * _BUCKET_OVERHEAD_S[kind]
     # the tail's partial-table scatter moves a cols-wide row per chunk, so
     # its cost scales with the message width (measured r7: s16 d=32 GCN,
     # hybrid 276.8 ms vs ELL 190.9 ms per superstep — the scatter term is
@@ -294,13 +301,13 @@ def decide(
     # candidate models ----------------------------------------------------
     modeled: Dict[str, float] = {}
     modeled["segment"] = _modeled_seconds(
-        m, n, stats.weighted, 1, peaks,
+        m, n, stats.weighted, 1, peaks, kind,
         penalty=_SEGMENT_PENALTY[kind], eff_bw=eff_bw, cols=cols,
     )
     ell_buckets = max(1, len(stats.degree_hist))
     ell_pad = stats.ell_slots / max(1, m)
     modeled["ell"] = _modeled_seconds(
-        stats.ell_slots, n, stats.weighted, ell_buckets, peaks,
+        stats.ell_slots, n, stats.weighted, ell_buckets, peaks, kind,
         eff_bw=eff_bw, cols=cols,
     )
 
@@ -313,8 +320,8 @@ def decide(
             continue
         t = _modeled_seconds(
             slots, n, stats.weighted,
-            torso_buckets + (1 if hubs else 0), peaks, eff_bw=eff_bw,
-            chunk_rows=chunk_rows, kind=kind, cols=cols,
+            torso_buckets + (1 if hubs else 0), peaks, kind,
+            eff_bw=eff_bw, chunk_rows=chunk_rows, cols=cols,
         )
         if best is None or t < best[0]:
             best = (t, cutoff, slots)

@@ -47,6 +47,7 @@ from janusgraph_tpu.olap.kernels import (
     _segment_combine,
     flat_take,
     fp_fence,
+    hybrid_fold,
     tree_reduce,
 )
 from janusgraph_tpu.olap.vertex_program import Combiner
@@ -201,19 +202,18 @@ def hybrid_row_dsts(
     src: np.ndarray, dst: np.ndarray, num_vertices: int,
     hub_cutoff: int = 64, tail_chunk: int = 256,
     max_capacity: int = 1 << 14,
-) -> dict:
-    """{"torso": [...], "tail": [...]} destination-index vectors aligned
-    with the equivalent ``HybridPack``'s torso buckets and tail chunks."""
+) -> np.ndarray:
+    """One destination index per row of the equivalent ``HybridPack``, in
+    the order of its flat index vector: torso rows, then tail chunks."""
     dst = np.asarray(dst, dtype=np.int64)
     shadow = HybridPack(
         dst, dst, None, num_vertices,
         hub_cutoff=hub_cutoff, tail_chunk=tail_chunk,
         max_capacity=max_capacity,
     )
-    return {
-        "torso": [np.ascontiguousarray(b["idx"][:, 0]) for b in shadow.torso],
-        "tail": [np.ascontiguousarray(b["idx"][:, 0]) for b in shadow.tail],
-    }
+    return np.ascontiguousarray(
+        shadow.arrays["idx"][shadow.row_first_slots()]
+    )
 
 
 # --------------------------------------------------------------------------
@@ -279,70 +279,33 @@ def sddmm_hybrid_aggregate(xp, pack, row_dsts, msgs, op: str = Combiner.SUM):
     """Fused SDDMM+SpMM over a HybridPack (or view) — bitwise-identical to
     `sddmm_ell_aggregate` by the same aligned-subtree argument as the
     scalar tier: per-slot coefficients are elementwise, so the leaves of
-    every row's reduction tree carry identical bits in both layouts."""
+    every row's reduction tree carry identical bits in both layouts.
+    Two gathers: every slot's source row, and every row's destination
+    (``row_dsts``: hybrid_row_dsts)."""
     _check_sddmm(op, msgs)
-    if len(row_dsts["torso"]) != len(pack.torso_meta) or len(
-        row_dsts["tail"]
-    ) != len(pack.tail_meta):
+    rows_total = sum(r for _d, _cap, r in pack.torso_meta) + pack.tail_chunks
+    if row_dsts.shape != (rows_total,):
         raise ValueError(
-            f"sddmm row-dst counts ({len(row_dsts['torso'])}/"
-            f"{len(row_dsts['tail'])}) != hybrid metadata "
-            f"({len(pack.torso_meta)}/{len(pack.tail_meta)}) (pack drift)"
+            f"sddmm row-dst vector {tuple(row_dsts.shape)} != hybrid "
+            f"metadata ({rows_total},) (pack drift)"
         )
     identity = Combiner.IDENTITY[op]
     pad_shape = (1,) + tuple(msgs.shape[1:])
     msgs_ext = xp.concatenate(
         [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
     )
-    parts = []
-    for entry, (d, cap), rdst in zip(
-        pack.torso, pack.torso_meta, row_dsts["torso"]
-    ):
-        m = flat_take(xp, msgs_ext, entry["idx"])   # (rows, d_deg, d)
-        dstf = flat_take(xp, msgs_ext, rdst)
-        alpha = tree_dot(xp, m, dstf[:, None, :])
-        m = fp_fence(xp, m * alpha[:, :, None])
-        if cap > d:
-            fill = xp.full(
-                (m.shape[0], cap - d) + tuple(m.shape[2:]), identity,
-                dtype=m.dtype,
-            )
-            m = xp.concatenate([m, fill], axis=1)
-        parts.append(tree_reduce(xp, m, op))
+    m = flat_take(xp, msgs_ext, pack.arrays["idx"])   # (slots, d)
+    dstf = flat_take(xp, msgs_ext, row_dsts)          # (rows_total, d)
 
-    if pack.num_zero:
-        parts.append(
-            xp.full(
-                (pack.num_zero,) + tuple(msgs.shape[1:]), identity,
-                dtype=msgs.dtype,
-            )
-        )
+    def coefficients(block, row_lo, row_hi):
+        # (width, rows, d) sources against their rows' destinations; an
+        # identity-padded slot is a zero row, as the ELL sentinel reads
+        alpha = tree_dot(xp, block, dstf[row_lo:row_hi][None, :, :])
+        return fp_fence(xp, block * alpha[:, :, None])
 
-    for entry, (cap, ppr, rows, num_slots), rdst in zip(
-        pack.tail, pack.tail_meta, row_dsts["tail"]
-    ):
-        m = flat_take(xp, msgs_ext, entry["idx"])   # (chunks, T, d)
-        dstf = flat_take(xp, msgs_ext, rdst)        # (chunks, d)
-        alpha = tree_dot(xp, m, dstf[:, None, :])
-        part = tree_reduce(xp, fp_fence(xp, m * alpha[:, :, None]), op)
-        tab_shape = (rows * ppr,) + tuple(part.shape[1:])
-        if _is_jax(xp):
-            table = xp.full(tab_shape, identity, dtype=part.dtype)
-            table = table.at[entry["slot"]].set(part)
-        else:
-            table = xp.full(tab_shape, identity, dtype=part.dtype)
-            table[entry["slot"]] = part
-        table = table.reshape((rows, ppr) + tuple(part.shape[1:]))
-        r = tree_reduce(xp, table, op)
-        rowseg = entry.get("rowseg")
-        if rowseg is not None:
-            r = _segment_combine(xp, op, r, rowseg, num_slots)
-        parts.append(r)
-
-    if not parts:
-        return xp.full(msgs.shape, identity, dtype=msgs.dtype)
-    stacked = xp.concatenate(parts, axis=0)
-    return stacked[pack.unpermute]
+    return hybrid_fold(
+        xp, pack, m, op, msgs.shape, msgs.dtype, leaf_fn=coefficients
+    )
 
 
 # graphlint: traced -- the flat-gather SDDMM fallback (segment strategy)

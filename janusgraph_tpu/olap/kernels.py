@@ -4,17 +4,20 @@ The superstep's hot op is `combine({msg(src) for (src,dst) edges}) by dst` —
 the reference runs it as NonBlockingHashMapLong insert-with-combiner per
 message (reference: FulgoraVertexMemory.java:91-99); the straightforward XLA
 translation is gather + `segment_sum`, whose scatter-add lowering serializes
-poorly on TPU. Two TPU-native alternatives here:
+poorly on TPU. Three alternatives here; `computer.strategy=auto` chooses
+between the first two per graph and device (olap/autotune.decide):
 
 1. **Degree-bucketed ELL** (`ELLPack` / `ell_aggregate`): in-edges are packed
    per destination into power-of-two-capacity row buckets (ELLPACK layout).
    Aggregation becomes gather + dense axis-1 reduction — no scatter at all,
    every monoid (sum/min/max) supported, padding overhead < 2× by the
-   power-of-two bucketing. This is the default device strategy.
+   power-of-two bucketing. One gather per bucket.
 
 2. **Degree-bucketed HYBRID** (`HybridPack` / `hybrid_aggregate`): the
-   ELL pack's power-of-two bucket rounding moves 1.4-1.5x the edge count in
-   sentinel padding on heavy-tailed graphs (every bench round since r01).
+   ELL pack's power-of-two bucket rounding gathers 1.40-1.47 slots an edge
+   on Graph500 R-MAT graphs, and a v5e pays 7.3-8.1 ns for a slot, padding
+   or not (PERF.md section 6, PR 26: 180.2 ms a PageRank superstep at
+   scale 20 on the ELL pack, 136.9 ms on this one at 1.01 slots an edge).
    The hybrid keeps an ELL-shaped torso packed at EXACT degree widths
    (zero padding) for vertices at or below a degree cutoff, and routes hub
    vertices through a chunked CSR tail: contiguous `tail_chunk`-wide slices
@@ -23,7 +26,8 @@ poorly on TPU. Two TPU-native alternatives here:
    through the same fixed adjacent-pair tree (`tree_reduce`): a width-2^k
    ELL row's reduction tree decomposes exactly into the per-chunk subtrees
    plus the partial-table fold, and in-kernel identity padding reproduces
-   the sentinel slots leaf-for-leaf.
+   the sentinel slots leaf-for-leaf. The whole pack is ONE index vector, so
+   an aggregation is one gather whatever the number of exact widths.
 
 3. **Pallas sorted-segment-sum** (`pallas_sorted_segment_sum`): edges are
    already destination-sorted (CSR); host-side alignment pads each output
@@ -42,6 +46,7 @@ arithmetic for cross-executor bitwise checks.
 
 from __future__ import annotations
 
+import itertools
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -206,6 +211,10 @@ class ELLPack:
         pos = np.zeros(n, dtype=np.int64)
         pos[vertex_order] = np.arange(len(vertex_order), dtype=np.int64)
         self.unpermute = pos.astype(np.int32)
+        #: gathered slots, padding included, and their ratio to the edges
+        self.num_edges = len(src)
+        self.slots = sum(int(b[0].size) for b in self.buckets)
+        self.pad_ratio = self.slots / max(1, self.num_edges)
 
     def device_put(self, jnp, sharding=None):
         """Move index/weight matrices to device once (optionally sharded)."""
@@ -231,8 +240,9 @@ def flat_take(jnp, tab, idx):
     """Gather rows/values of `tab` by a 2-D index matrix via a FLAT 1-D
     take + reshape. Identical semantics to tab[idx], but the (rows, 1) 2-D
     gather shape compiles pathologically on TPU (measured 197s for a
-    667k-row cap-1 bucket vs 0.5s flat; run throughput is the same ~140M
-    gathers/s). Shared by the single-chip and sharded ELL paths."""
+    667k-row cap-1 bucket vs 0.5s flat; the run's time is the same, 7.3-8.1
+    ns a gathered element on a v5e: PERF.md section 6, PR 26). Shared by
+    the single-chip and sharded pack paths."""
     flat = idx.reshape(-1)
     if tab.ndim == 1:
         return jnp.take(tab, flat).reshape(idx.shape)
@@ -273,29 +283,45 @@ def fp_fence(xp, a):
 
 
 # graphlint: traced -- the shared reduction tree of every compiled superstep
-def tree_reduce(xp, m, op: str):
-    """Reduce axis 1 of `m` (width MUST be a power of two) through a fixed
-    adjacent-pair halving tree: [a,b,c,d] -> [a+b, c+d] -> [(a+b)+(c+d)].
+def tree_reduce(xp, m, op: str, axis: int = 1):
+    """Reduce `axis` (1, or 0) of `m` (width MUST be a power of two)
+    through a fixed adjacent-pair halving tree:
+    [a,b,c,d] -> [a+b, c+d] -> [(a+b)+(c+d)].
 
     This tree — not the backend's reduce — is the strategies' bitwise
     contract: any aligned power-of-two-sized contiguous sub-range of the
     leaves is a complete subtree, so a row evaluated whole (ELL) and the
     same row evaluated as chunk partials folded afterwards (hybrid tail)
     produce identical bits, on any backend that preserves elementwise
-    float semantics (all of them)."""
-    width = m.shape[1]
+    float semantics (all of them). The axis is the layout's business only:
+    (rows, width) reduced along 1 and (width, rows) along 0 pair the same
+    operands."""
+    width = m.shape[axis]
     if width & (width - 1):
         raise ValueError(f"tree_reduce width {width} is not a power of two")
-    while m.shape[1] > 1:
-        a = m[:, 0::2]
-        b = m[:, 1::2]
+    lead = (slice(None),) * axis
+    if axis == 0 and _is_jax(xp):
+        # lax's strided slice: jnp's `m[0::2]` lowers to a gather by an
+        # iota, and the TPU compiler keeps it one (88 gathers in the
+        # optimized module of one hybrid aggregation, compiled here for
+        # the v5e). Axis 1, the ELL pack's, is left as the chip last
+        # measured it.
+        from jax.lax import slice_in_dim
+
+        def every_other(m, start):
+            return slice_in_dim(m, start, None, 2, axis)
+    else:
+        def every_other(m, start):
+            return m[lead + (slice(start, None, 2),)]
+    while m.shape[axis] > 1:
+        a, b = every_other(m, 0), every_other(m, 1)
         if op == Combiner.SUM:
             m = a + b
         elif op == Combiner.MIN:
             m = xp.minimum(a, b)
         else:
             m = xp.maximum(a, b)
-    return m[:, 0]
+    return m[lead + (0,)]
 
 
 def _segment_combine(xp, op: str, values, seg, num_segments: int):
@@ -401,7 +427,7 @@ class HybridPack:
     """Hybrid layout of an edge list grouped by destination degree.
 
     Torso (in-degree 1..hub_cutoff): one bucket per EXACT degree d — a
-    (rows, d) source-index matrix with no padded slots at all; the
+    block of rows x d source indices with no padded slots at all; the
     reduction pads to next-pow2(d) with the monoid identity *in-kernel*
     (registers/VMEM, never gathered), reproducing the pure-ELL bucket's
     leaves exactly. Zero-degree vertices contribute an identity constant
@@ -410,18 +436,29 @@ class HybridPack:
     Tail (hub vertices, in-degree > hub_cutoff): the hubs' destination-
     sorted CSR edge ranges are cut into contiguous `tail_chunk`-wide
     chunks (the last chunk of a row sentinel-padded — static tail capacity
-    tiers); chunk partials scatter into an identity-filled per-row partial
-    table of width cap/tail_chunk and fold down the remaining tree levels.
-    Degrees above `max_capacity` row-split first, exactly like ELLPack
-    (shared `split_rows`), so the final rows-sized segment fold sees the
-    same operand sequence.
+    tiers); chunk partials scatter into an identity-filled partial table
+    (cap/tail_chunk entries per row) and fold down the remaining tree
+    levels. Degrees above `max_capacity` row-split first, exactly like
+    ELLPack (shared `split_rows`), so the final rows-sized segment fold
+    sees the same operand sequence.
 
     Both `tail_chunk` and every tree width are powers of two, so every
     vertex reduces through the identical `tree_reduce` tree the ELL path
     uses — hybrid and ELL results are bitwise-equal by construction.
     Slots actually gathered: m_torso exact + ceil-per-hub-row chunk
     padding, i.e. pad_ratio ~ 1 + tail_chunk/(2*mean hub degree) instead
-    of ELL's 1.4-1.5x pow2 rounding.
+    of ELL's pow2 rounding.
+
+    The pack ships as ONE flat index vector — the torso buckets in
+    ascending width, then every tail chunk — so an aggregation is one
+    gather whatever the number of exact widths, and the buckets are static
+    slices of the gathered vector (`arrays`: "idx", with "w" beside it and
+    "valid" for the tail's slots on weighted packs; "slot" per tail chunk,
+    "rowseg" when the widest tail bucket row-splits; "unpermute"). Every
+    block lies SLOT-major — (width, rows), slot j of all its rows, then
+    slot j+1 — and reduces along axis 0: the rows, of which a narrow
+    bucket has many, fill the device's minor (lane) dimension, where
+    (rows, 2) row-major would leave 126 lanes of 128 empty.
     """
 
     def __init__(
@@ -448,51 +485,61 @@ class HybridPack:
             raise ValueError(f"hub_cutoff must be >= 1 (got {hub_cutoff})")
         # every hub's tree width is >= next_pow2(cutoff+1); the chunk must
         # divide it so chunks stay aligned subtrees
-        self.tail_chunk = min(
+        T = self.tail_chunk = min(
             tail_chunk, _next_pow2(self.hub_cutoff + 1), int(max_capacity)
         )
 
         order = np.argsort(dst, kind="stable")
         src = np.asarray(src, dtype=np.int64)[order]
         dst = np.asarray(dst, dtype=np.int64)[order]
-        w = (
-            np.asarray(weight, dtype=np.float32)[order]
-            if weight is not None
-            else None
-        )
         deg = np.bincount(dst, minlength=n).astype(np.int64)
         indptr = np.zeros(n + 1, dtype=np.int64)
         np.cumsum(deg, out=indptr[1:])
         src32 = np.ascontiguousarray(src, dtype=np.int32)
         w32 = (
-            np.ascontiguousarray(w, dtype=np.float32) if w is not None else None
+            np.ascontiguousarray(
+                np.asarray(weight, dtype=np.float32)[order]
+            )
+            if weight is not None
+            else None
         )
 
-        vertex_order_parts: List[np.ndarray] = []
-        #: torso buckets: array dicts ({"idx", "w"?}) + static (width, tree cap)
-        self.torso: List[dict] = []
-        self.torso_meta: List[Tuple[int, int]] = []
-        torso_degrees = np.unique(deg[(deg >= 1) & (deg <= self.hub_cutoff)])
-        for d in (int(x) for x in torso_degrees):
-            members = np.nonzero(deg == d)[0]
-            pos = indptr[members][:, None] + np.arange(d, dtype=np.int64)
-            entry = {"idx": src32[pos]}
-            if self.has_weight:
-                entry["w"] = w32[pos]
-            self.torso.append(entry)
-            self.torso_meta.append((d, _next_pow2(d)))
-            vertex_order_parts.append(members)
+        # torso: vertices by (degree, id), one (d, rows) block per degree
+        in_torso = np.nonzero((deg >= 1) & (deg <= self.hub_cutoff))[0]
+        members = in_torso[np.argsort(deg[in_torso], kind="stable")]
+        widths, rows_per = np.unique(deg[members], return_counts=True)
+        #: static (width, tree cap, rows) per exact-degree bucket
+        self.torso_meta: List[Tuple[int, int, int]] = [
+            (int(d), _next_pow2(int(d)), int(r))
+            for d, r in zip(widths, rows_per)
+        ]
+        torso_pos, row = [], 0
+        for d, _cap, rows in self.torso_meta:
+            starts = indptr[members[row:row + rows]]
+            row += rows
+            torso_pos.append(
+                (starts[None, :] + np.arange(d, dtype=np.int64)[:, None])
+                .reshape(-1)
+            )
+        torso_pos = (
+            np.concatenate(torso_pos) if torso_pos
+            else np.zeros(0, dtype=np.int64)
+        )
+        self.torso_slots = len(torso_pos)
+        vertex_order_parts: List[np.ndarray] = [members]
 
         zero_members = np.nonzero(deg == 0)[0]
         self.num_zero = len(zero_members)
-        if self.num_zero:
-            vertex_order_parts.append(zero_members)
+        vertex_order_parts.append(zero_members)
 
-        #: tail buckets: array dicts ({"idx", "slot", "w"?, "valid"?,
-        #: "rowseg"?}) + static (tree cap, partials per row, rows, slots)
-        self.tail: List[dict] = []
+        # tail: every hub row's chunks, bucket after bucket, as one (T,
+        # chunks) block; `slot` is each chunk's place in the partial table,
+        # whose per-bucket (cap/T, rows) blocks lie end to end
+        #: static (tree cap, partials per row, rows, slots) per tail bucket
         self.tail_meta: List[Tuple[int, int, int, int]] = []
-        T = self.tail_chunk
+        ch_starts, ch_degs, slots = [], [], []
+        rowseg = None
+        table_rows = 0
         hub = deg > self.hub_cutoff
         if hub.any():
             caps = np.minimum(
@@ -506,11 +553,12 @@ class HybridPack:
                 deg_m = deg[members]
                 starts_m = indptr[members]
                 if c == int(max_capacity) and int(deg_m.max()) > c:
+                    # only the widest bucket can row-split
                     starts_r, degs_r, rowseg = split_rows(
                         members, deg_m, starts_m, c
                     )
                 else:
-                    starts_r, degs_r, rowseg = starts_m, deg_m, None
+                    starts_r, degs_r = starts_m, deg_m
                 rows = len(starts_r)
                 ppr = c // T  # partial-table width per row
                 nch = -(-degs_r // T)  # real chunks per row (degs_r >= 1)
@@ -520,84 +568,185 @@ class HybridPack:
                     np.arange(total, dtype=np.int64)
                     - np.repeat(np.cumsum(nch) - nch, nch)
                 )
-                ch_start = starts_r[row_of] + posr * T
-                ch_deg = np.minimum(T, degs_r[row_of] - posr * T)
-                idx = np.full((total, T), self.sentinel, dtype=np.int32)
-                if self.has_weight:
-                    wmat = np.zeros((total, T), dtype=np.float32)
-                    valid = np.zeros((total, T), dtype=np.float32)
-                else:
-                    wmat = valid = None
-                fill_ell_rows(T, ch_start, ch_deg, src32, w32, idx, wmat, valid)
-                entry = {
-                    "idx": idx,
-                    "slot": (row_of * ppr + posr).astype(np.int32),
-                }
-                if wmat is not None:
-                    entry["w"] = wmat
-                    entry["valid"] = valid
-                if rowseg is not None:
-                    entry["rowseg"] = rowseg.astype(np.int32)
-                self.tail.append(entry)
+                ch_starts.append(starts_r[row_of] + posr * T)
+                ch_degs.append(np.minimum(T, degs_r[row_of] - posr * T))
+                slots.append(table_rows + posr * rows + row_of)
+                table_rows += rows * ppr
                 self.tail_meta.append((c, ppr, rows, len(members)))
                 vertex_order_parts.append(members)
+        self.tail_chunks = sum(len(s) for s in ch_starts)
+        self.table_rows = table_rows
 
-        vertex_order = (
-            np.concatenate(vertex_order_parts)
-            if vertex_order_parts
-            else np.zeros(0, dtype=np.int64)
-        )
+        tail_idx = np.full((self.tail_chunks, T), self.sentinel, dtype=np.int32)
+        if self.has_weight:
+            tail_w = np.zeros((self.tail_chunks, T), dtype=np.float32)
+            tail_valid = np.zeros((self.tail_chunks, T), dtype=np.float32)
+        else:
+            tail_w = tail_valid = None
+        if self.tail_chunks:
+            fill_ell_rows(
+                T, np.concatenate(ch_starts), np.concatenate(ch_degs),
+                src32, w32, tail_idx, tail_w, tail_valid,
+            )
+
+        # a few sentinel slots more bring the vector to a prime length: see
+        # _prime_at_least
+        real = self.torso_slots + self.tail_chunks * T
+        fill = _prime_at_least(real) - real
+        self.arrays = {
+            "idx": np.concatenate([
+                src32[torso_pos], tail_idx.T.reshape(-1),
+                np.full(fill, self.sentinel, dtype=np.int32),
+            ]),
+        }
+        if self.has_weight:
+            no_weight = np.zeros(fill, dtype=np.float32)
+            self.arrays["w"] = np.concatenate(
+                [w32[torso_pos], tail_w.T.reshape(-1), no_weight]
+            )
+            self.arrays["valid"] = np.concatenate(
+                [tail_valid.T.reshape(-1), no_weight]
+            )
+        if self.tail_chunks:
+            self.arrays["slot"] = np.concatenate(slots).astype(np.int32)
+        if rowseg is not None:
+            self.arrays["rowseg"] = rowseg.astype(np.int32)
+
+        vertex_order = np.concatenate(vertex_order_parts)
         pos = np.zeros(n, dtype=np.int64)
         pos[vertex_order] = np.arange(len(vertex_order), dtype=np.int64)
-        self.unpermute = pos.astype(np.int32)
-        #: gathered slots (the bandwidth-proportional number the pad ratio
-        #: prices); partial tables are rows-sized and excluded
-        self.slots = sum(int(b["idx"].size) for b in self.torso) + sum(
-            int(b["idx"].size) for b in self.tail
-        )
-        self.pad_ratio = self.slots / max(1, len(src))
+        self.arrays["unpermute"] = pos.astype(np.int32)
+        #: gathered slots (the number the pad ratio prices); the partial
+        #: table is rows-sized and excluded
+        self.num_edges = len(src)
+        self.slots = int(self.arrays["idx"].size)
+        self.pad_ratio = self.slots / max(1, self.num_edges)
+
+    def row_first_slots(self) -> np.ndarray:
+        """Position in the flat index vector of each row's first slot:
+        torso rows bucket after bucket, then the tail's chunks."""
+        parts, off = [], 0
+        for d, _cap, rows in self.torso_meta:
+            parts.append(off + np.arange(rows, dtype=np.int64))
+            off += d * rows
+        parts.append(off + np.arange(self.tail_chunks, dtype=np.int64))
+        return np.concatenate(parts)
 
     def device_put(self, jnp, sharding=None):
-        """Move index/weight/slot matrices to device once."""
+        """Move the index/weight/slot vectors to device once."""
         put = (lambda a: a) if sharding is None else (
             lambda a: __import__("jax").device_put(a, sharding)
         )
-        self.torso = [
-            {k: put(jnp.asarray(v)) for k, v in b.items()} for b in self.torso
-        ]
-        self.tail = [
-            {k: put(jnp.asarray(v)) for k, v in b.items()} for b in self.tail
-        ]
-        self.unpermute = put(jnp.asarray(self.unpermute))
+        self.arrays = {k: put(jnp.asarray(v)) for k, v in self.arrays.items()}
         return self
 
 
+def _prime_at_least(v: int) -> int:
+    """The least prime >= v: the length the hybrid pack's flat vectors are
+    padded to. A bucket is `gathered[a:b].reshape(width, rows)`; where
+    `rows` divides the whole vector's length XLA hoists the reshape over
+    the slice and materializes the WHOLE vector as (len / rows, rows), and
+    for a bucket of 2 rows that is 64x its bytes in (8, 128) tiles and
+    minutes of compile (compiled here for the v5e, s17 cutoff 128: 119 s
+    and 602 MB of temporaries against 2.3 s and 0.5 MB at a prime length).
+    No block's row count divides a prime."""
+    v = max(2, int(v))
+    while True:
+        r = int(v ** 0.5)
+        if v < 4 or (v % np.arange(2, r + 1)).all():
+            return v
+        v += 1
+
+
 class HybridPackView:
-    """HybridPack-shaped facade over traced bucket arrays (duck-typed for
+    """HybridPack-shaped facade over the traced `arrays` (duck-typed for
     hybrid_aggregate), carrying the compiled variant's static metadata."""
 
-    __slots__ = (
-        "torso", "torso_meta", "tail", "tail_meta", "num_zero",
-        "unpermute", "has_weight",
+    _STATIC = (
+        "torso_meta", "torso_slots", "tail_meta", "tail_chunks",
+        "tail_chunk", "table_rows", "num_zero", "has_weight", "slots",
     )
+    __slots__ = ("arrays",) + _STATIC
 
-    def __init__(self, args, pack: HybridPack):
-        if len(args["torso"]) != len(pack.torso_meta) or len(
-            args["tail"]
-        ) != len(pack.tail_meta):
+    def __init__(self, arrays, pack: HybridPack):
+        if arrays["idx"].shape != (pack.slots,):
             raise ValueError(
-                f"graph-args hybrid bucket counts "
-                f"({len(args['torso'])}/{len(args['tail'])}) != compiled "
-                f"metadata ({len(pack.torso_meta)}/{len(pack.tail_meta)}) "
-                f"(pack drift)"
+                f"graph-args hybrid index vector {arrays['idx'].shape} != "
+                f"compiled metadata ({pack.slots},) (pack drift)"
             )
-        self.torso = args["torso"]
-        self.tail = args["tail"]
-        self.unpermute = args["unpermute"]
-        self.torso_meta = pack.torso_meta
-        self.tail_meta = pack.tail_meta
-        self.num_zero = pack.num_zero
-        self.has_weight = pack.has_weight
+        self.arrays = arrays
+        for name in self._STATIC:
+            setattr(self, name, getattr(pack, name))
+
+
+# graphlint: traced -- shared by the hybrid aggregation bodies
+def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
+    """Fold a HybridPack's gathered (and transformed) `leaves`, shaped
+    (slots[, k]) in the flat index vector's order, into the per-vertex
+    result. Every bucket is a static slice of `leaves`, a (width, rows)
+    block; the torso buckets of one pow2 tree width are identity-padded up
+    to it *in-kernel* (the ELL bucket's sentinel slots, never gathered)
+    and laid side by side, so there is one `tree_reduce` per tree width
+    and not per exact width — same leaves, same tree as `ell_aggregate`.
+    `leaf_fn(block, row_lo, row_hi)` (the SDDMM coefficient pass) maps a
+    (width, rows[, k]) block of rows [row_lo, row_hi) of the pack's row
+    order before it is reduced."""
+    identity = Combiner.IDENTITY[op]
+    cols = tuple(leaves.shape[1:])
+    no_pad = [(0, 0)] * (1 + len(cols))
+    parts = []
+    off = row = 0
+    for cap, group in itertools.groupby(pack.torso_meta, key=lambda t: t[1]):
+        blocks = []
+        for d, _cap, rows in group:
+            m = leaves[off:off + d * rows].reshape((d, rows) + cols)
+            off += d * rows
+            if cap > d:
+                m = xp.pad(
+                    m, [(0, cap - d)] + no_pad, constant_values=identity
+                )
+            blocks.append(m)
+        m = blocks[0] if len(blocks) == 1 else xp.concatenate(blocks, axis=1)
+        if leaf_fn is not None:
+            m = leaf_fn(m, row, row + m.shape[1])
+        row += m.shape[1]
+        parts.append(tree_reduce(xp, m, op, axis=0))
+
+    if pack.num_zero:
+        parts.append(xp.full((pack.num_zero,) + cols, identity, dtype=dtype))
+
+    if pack.tail_chunks:
+        m = leaves[off:off + pack.tail_chunk * pack.tail_chunks].reshape(
+            (pack.tail_chunk, pack.tail_chunks) + cols
+        )
+        if leaf_fn is not None:
+            m = leaf_fn(m, row, row + pack.tail_chunks)
+        part = tree_reduce(xp, m, op, axis=0)  # (chunks[, k]): subtrees
+        table = xp.full((pack.table_rows,) + cols, identity, dtype=part.dtype)
+        slot = pack.arrays["slot"]
+        if _is_jax(xp):
+            table = table.at[slot].set(part)
+        else:
+            table[slot] = part
+        toff = 0
+        for i, (cap, ppr, rows, num_slots) in enumerate(pack.tail_meta):
+            # remaining upper tree levels: fold each row's partial vector
+            r = tree_reduce(
+                xp,
+                table[toff:toff + ppr * rows].reshape((ppr, rows) + cols),
+                op, axis=0,
+            )
+            toff += ppr * rows
+            if i == len(pack.tail_meta) - 1 and "rowseg" in pack.arrays:
+                r = _segment_combine(
+                    xp, op, r, pack.arrays["rowseg"], num_slots
+                )
+            parts.append(r)
+
+    if not parts:
+        return xp.full(out_shape, identity, dtype=dtype)
+    stacked = xp.concatenate(parts, axis=0)
+    return stacked[pack.arrays["unpermute"]]
 
 
 # graphlint: traced -- the hybrid aggregation body of compiled supersteps
@@ -609,88 +758,34 @@ def hybrid_aggregate(
     edge_transform: str = EdgeTransform.NONE,
     edge_transform_cols=None,
 ):
-    """Aggregate per-vertex messages over a HybridPack (or view).
+    """Aggregate per-vertex messages over a HybridPack (or view) with ONE
+    gather: `flat_take(msgs_ext, idx)` reads every slot of the pack.
 
     Same contract as ell_aggregate — msgs (n,) or (n, k), returns the
     per-destination monoid fold — and bitwise-identical results to it
     (both reduce through tree_reduce's fixed adjacent-pair tree)."""
     identity = Combiner.IDENTITY[op]
-    if not pack.has_weight:
-        edge_transform = EdgeTransform.NONE
-        edge_transform_cols = None
     pad_shape = (1,) + tuple(msgs.shape[1:])
     msgs_ext = xp.concatenate(
         [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
     )
-
-    def transform(m, w, valid):
+    m = flat_take(xp, msgs_ext, pack.arrays["idx"])  # (slots[, k])
+    if pack.has_weight:
         # mirrors the ELL weighted path slot-for-slot: transform first,
-        # then force padded slots back to the identity (a transform can
-        # disturb it, e.g. identity*0 = nan for MIN's +inf)
-        if w is None:
-            return m
-        if edge_transform_cols is not None:
-            m = apply_edge_transform(
-                xp, m, w, edge_transform, edge_transform_cols
-            )
-        else:
-            w_ = w[:, :, None] if m.ndim == 3 else w
-            if edge_transform == EdgeTransform.MUL_WEIGHT:
-                m = m * w_
-            elif edge_transform == EdgeTransform.ADD_WEIGHT:
-                m = m + w_
-        if valid is not None:
-            valid_ = valid[:, :, None] if m.ndim == 3 else valid
-            m = xp.where(valid_ > 0, m, identity)
+        # then force the tail's padded slots back to the identity (a
+        # transform can disturb it, e.g. identity*0 = nan for MIN's +inf)
+        m = apply_edge_transform(
+            xp, m, pack.arrays["w"], edge_transform, edge_transform_cols
+        )
+        ts = pack.torso_slots
+        valid = pack.arrays["valid"]
+        valid_ = valid[:, None] if m.ndim == 2 else valid
         # same fence as the ELL weighted branch: the torso's unmasked
         # weight product would otherwise contract into the tree
-        return fp_fence(xp, m)
-
-    parts = []
-    for entry, (d, cap) in zip(pack.torso, pack.torso_meta):
-        m = flat_take(xp, msgs_ext, entry["idx"])  # (rows, d[, k])
-        m = transform(m, entry.get("w"), None)
-        if cap > d:
-            # in-kernel identity pad up to the pow2 tree width: same
-            # leaves as the ELL bucket's sentinel slots, never gathered
-            fill = xp.full(
-                (m.shape[0], cap - d) + tuple(m.shape[2:]), identity,
-                dtype=m.dtype,
-            )
-            m = xp.concatenate([m, fill], axis=1)
-        parts.append(tree_reduce(xp, m, op))
-
-    if pack.num_zero:
-        parts.append(
-            xp.full(
-                (pack.num_zero,) + tuple(msgs.shape[1:]), identity,
-                dtype=msgs.dtype,
-            )
-        )
-
-    for entry, (cap, ppr, rows, num_slots) in zip(pack.tail, pack.tail_meta):
-        m = flat_take(xp, msgs_ext, entry["idx"])  # (chunks, T[, k])
-        m = transform(m, entry.get("w"), entry.get("valid"))
-        part = tree_reduce(xp, m, op)  # (chunks[, k]) — aligned subtrees
-        tab_shape = (rows * ppr,) + tuple(part.shape[1:])
-        if _is_jax(xp):
-            table = xp.full(tab_shape, identity, dtype=part.dtype)
-            table = table.at[entry["slot"]].set(part)
-        else:
-            table = xp.full(tab_shape, identity, dtype=part.dtype)
-            table[entry["slot"]] = part
-        # remaining upper tree levels: fold the per-row partial vector
-        table = table.reshape((rows, ppr) + tuple(part.shape[1:]))
-        r = tree_reduce(xp, table, op)
-        rowseg = entry.get("rowseg")
-        if rowseg is not None:
-            r = _segment_combine(xp, op, r, rowseg, num_slots)
-        parts.append(r)
-
-    if not parts:
-        return xp.full(msgs.shape, identity, dtype=msgs.dtype)
-    stacked = xp.concatenate(parts, axis=0)
-    return stacked[pack.unpermute]
+        m = fp_fence(xp, xp.concatenate(
+            [m[:ts], xp.where(valid_ > 0, m[ts:], identity)], axis=0
+        ))
+    return hybrid_fold(xp, pack, m, op, msgs.shape, msgs.dtype)
 
 
 # --------------------------------------------------------------------------

@@ -488,21 +488,32 @@ class TPUExecutor:
                 self._measured_path, shard_count=1
             )
         stats = autotune.GraphStats.from_csr(
-            self.csr, undirected=undirected,
-            max_capacity=self.ell_max_capacity or (1 << 14),
-            tail_chunk=self._tail_chunk_cfg or 256,
+            self.csr, undirected=undirected, **self._stats_kwargs()
         )
+        decision = self._decide(stats, measured)
+        self._autotune_decisions[key] = decision
+        return decision
+
+    def _stats_kwargs(self) -> dict:
+        return {
+            "max_capacity": self.ell_max_capacity or (1 << 14),
+            "tail_chunk": self._tail_chunk_cfg or 256,
+        }
+
+    def _decide(self, stats, measured: dict = None):
+        """olap/autotune.decide under this executor's device kind and
+        configuration, for the graph's or one edge channel's statistics."""
+        from janusgraph_tpu.olap import autotune
+
         ov = self._autotune_overrides()
         if self._strategy_cfg != "auto":
             ov["strategy"] = self._strategy_cfg
         if self._features_dim_tier:
             ov["feature_dim_tier"] = self._features_dim_tier
-        decision = autotune.decide(
+        return autotune.decide(
             stats, self._device_kind(), overrides=ov, measured=measured,
             feature_dim=self._feature_dim_run,
         )
-        self._autotune_decisions[key] = decision
-        return decision
 
     def _auto_strategy(self, undirected: bool) -> str:
         """'auto' resolution. With the tuner enabled (the default) this is
@@ -594,10 +605,7 @@ class TPUExecutor:
                 hub_cutoff=pack.hub_cutoff, tail_chunk=pack.tail_chunk,
                 max_capacity=cap,
             )
-            rows = {
-                "torso": [self.jnp.asarray(r) for r in host["torso"]],
-                "tail": [self.jnp.asarray(r) for r in host["tail"]],
-            }
+            rows = self.jnp.asarray(host)
         self._sddmm_rows_cache[key] = rows
         return rows
 
@@ -612,21 +620,25 @@ class TPUExecutor:
         """HybridPack for one edge view, with the tuner's (or configured)
         hub cutoff + tail chunk. Built and device-put once, like the ELL
         pack."""
-        from janusgraph_tpu.olap.kernels import HybridPack
-
         pack = self._hybrid_packs.get(undirected)
         if pack is None:
-            d = self._autotune(undirected)
-            cutoff = self._hub_cutoff_cfg or d.hub_cutoff or 512
-            chunk = self._tail_chunk_cfg or d.tail_chunk or 256
-            src, dst, w = self._edge_view(undirected)
-            pack = HybridPack(
-                src, dst, w, self.csr.num_vertices,
-                hub_cutoff=cutoff, tail_chunk=chunk, **self._ell_kwargs(),
+            pack = self._build_hybrid(
+                self._edge_view(undirected), self._autotune(undirected)
             )
-            pack.device_put(self.jnp)
             self._hybrid_packs[undirected] = pack
         return pack
+
+    def _build_hybrid(self, edges, decision):
+        from janusgraph_tpu.olap.kernels import HybridPack
+
+        src, dst, w = edges
+        pack = HybridPack(
+            src, dst, w, self.csr.num_vertices,
+            hub_cutoff=self._hub_cutoff_cfg or decision.hub_cutoff or 512,
+            tail_chunk=self._tail_chunk_cfg or decision.tail_chunk or 256,
+            **self._ell_kwargs(),
+        )
+        return pack.device_put(self.jnp)
 
     #: distinct EdgeChannel views kept device-resident at once; a long-lived
     #: executor answering ad-hoc traverse() queries would otherwise
@@ -634,32 +646,48 @@ class TPUExecutor:
     CHANNEL_CACHE_SIZE = 8
 
     def _channel_pack(self, program: VertexProgram, name: str):
-        """ELL pack for one named EdgeChannel (typed edge view). Built from
-        the channel's filtered edge list; cached per channel VALUE (frozen
+        """(strategy, pack, decision) for one named EdgeChannel (typed edge
+        view): the ELL or the hybrid pack of the channel's filtered edge
+        list, as the tuner decides from THAT list's degrees (a configured
+        'ell' or 'hybrid' holds here too; whatever else resolves to a
+        flat path packs ELL). Cached per channel VALUE (frozen
         dataclass) — names like 's0' recur across different programs on a
         reused executor and must not alias each other's packs. LRU-bounded;
         eviction also drops compiled supersteps that close over the pack."""
+        from janusgraph_tpu.olap import autotune
         from janusgraph_tpu.olap.csr import channel_edges
         from janusgraph_tpu.olap.kernels import ELLPack
 
         channel = program.edge_channels[name]
-        pack = self._channel_packs.get(channel)
-        if pack is not None:
+        entry = self._channel_packs.get(channel)
+        if entry is not None:
             self._channel_packs.move_to_end(channel)
-            return pack
+            return entry
         src, dst, w = channel_edges(self.csr, channel)
-        pack = ELLPack(
-            src, dst, w, self.csr.num_vertices, **self._ell_kwargs()
-        )
-        pack.device_put(self.jnp)
-        self._channel_packs[channel] = pack
+        n = self.csr.num_vertices
+        decision = None
+        if self._strategy_cfg == "hybrid" or (
+            self._strategy_cfg == "auto" and self._autotune_enabled
+        ):
+            decision = self._decide(autotune.GraphStats.from_degrees(
+                np.bincount(dst, minlength=n), len(src), w is not None,
+                **self._stats_kwargs(),
+            ))
+        if decision is not None and decision.strategy == "hybrid":
+            strategy = "hybrid"
+            pack = self._build_hybrid((src, dst, w), decision)
+        else:
+            strategy = "ell"
+            pack = ELLPack(src, dst, w, n, **self._ell_kwargs())
+            pack.device_put(self.jnp)
+        entry = self._channel_packs[channel] = (strategy, pack, decision)
         while len(self._channel_packs) > self.CHANNEL_CACHE_SIZE:
             evicted, _ = self._channel_packs.popitem(last=False)
             self._compiled = {
                 k: v for k, v in self._compiled.items()
                 if not (len(k) >= 5 and k[4] == evicted)
             }
-        return pack
+        return entry
 
     def _segsum_plan(self, orientation: str):
         from janusgraph_tpu.olap.kernels import make_segsum_plan
@@ -745,7 +773,7 @@ class TPUExecutor:
             args["ell"] = self._pack_args(pack)
             args["unpermute"] = pack.unpermute
         elif strategy == "hybrid":
-            args["hyb"] = self._hybrid_args(pack)
+            args["hyb"] = dict(pack.arrays)
         elif strategy == "pallas":
             args["pallas"] = self._pallas_args(program)
         if getattr(program, "message_mode", None) == "sddmm" and strategy in (
@@ -798,17 +826,6 @@ class TPUExecutor:
             buckets.append(b)
         return buckets
 
-    @staticmethod
-    def _hybrid_args(pack):
-        """The hybrid pack's array pytree (shipped as jit arguments, like
-        _pack_args for ELL — closing over the arrays would constant-fold
-        them into the module)."""
-        return {
-            "torso": [dict(b) for b in pack.torso],
-            "tail": [dict(b) for b in pack.tail],
-            "unpermute": pack.unpermute,
-        }
-
     def _graph_args(self, program: VertexProgram, op: str, channel: str = None):
         """The device-array pytree a compiled superstep consumes as an
         ARGUMENT. Closing over device arrays would embed them as constants
@@ -829,7 +846,7 @@ class TPUExecutor:
             args["ell"] = self._pack_args(pack)
             args["unpermute"] = pack.unpermute
         elif strategy == "hybrid":
-            args["hyb"] = self._hybrid_args(pack)
+            args["hyb"] = dict(pack.arrays)
         elif strategy == "pallas":
             args["pallas"] = self._pallas_args(program)
         if getattr(program, "message_mode", None) == "sddmm" and strategy in (
@@ -860,15 +877,14 @@ class TPUExecutor:
         return sig
 
     def _resolve_pack(self, program: VertexProgram, op: str, channel: str = None):
-        """(strategy, ELLPack-or-None) for one combiner monoid + edge view —
+        """(strategy, pack-or-None) for one combiner monoid + edge view —
         the single source of truth shared by `_graph_args` (which ships the
         pack's arrays) and `_superstep_body` (which captures its static
         bucket metadata), so the two can never disagree on bucket count."""
         strategy = self._resolve_strategy(op, program.undirected)
         pack = None
         if channel is not None:
-            strategy = "ell"
-            pack = self._channel_pack(program, channel)
+            strategy, pack, _decision = self._channel_pack(program, channel)
         elif strategy == "ell":
             pack = self._ell_pack(program.undirected)
         elif strategy == "hybrid":
@@ -878,7 +894,7 @@ class TPUExecutor:
     def _superstep_body(self, program: VertexProgram, op: str, channel: str = None):
         """Build the (un-jitted) superstep function for one combiner monoid
         (and, for channel-switching programs, one named edge channel —
-        channel steps always aggregate over the channel's ELL pack). The
+        channel steps aggregate over the channel's own pack). The
         returned function takes the graph-array pytree (`_graph_args`) as
         its final argument; only static metadata is captured by closure."""
 
@@ -1345,15 +1361,30 @@ class TPUExecutor:
         undirected = bool(getattr(program, "undirected", False))
         pad_ratio = None
         strategy_resolved = None
+        # a channel-switching program's packs are its channels' own, the
+        # largest first; any other program's is its edge view's
+        channel_packs = sorted(
+            (
+                self._channel_packs[c]
+                for c in set(program.edge_channels.values())
+                if c in self._channel_packs
+            ),
+            key=lambda entry: -entry[1].slots,
+        )
         hyb = self._hybrid_packs.get(undirected)
         pack = self._ell_packs.get(undirected)
-        edges = self.csr.num_edges * (2 if undirected else 1)
-        if hyb is not None:
+        if channel_packs:
+            pad_ratio = round(
+                sum(p.slots for _s, p, _d in channel_packs)
+                / max(1, sum(p.num_edges for _s, p, _d in channel_packs)),
+                4,
+            )
+            strategy_resolved = channel_packs[0][0]
+        elif hyb is not None:
             pad_ratio = round(hyb.pad_ratio, 4)
             strategy_resolved = "hybrid"
         elif pack is not None:
-            slots = sum(int(b[0].size) for b in pack.buckets)
-            pad_ratio = round(slots / max(1, edges), 4)
+            pad_ratio = round(pack.pad_ratio, 4)
             strategy_resolved = "ell"
         # active pack's pad (legacy key name kept — every BENCH round since
         # r01 tracks it); `pad_ratio` is the strategy-neutral alias
@@ -1369,8 +1400,11 @@ class TPUExecutor:
         # the tuner's decision travels with every run record (bench +
         # /telemetry read it from here); explicit strategies still record
         # a source="config" decision for provenance
-        decision = self._autotune_decisions.get(
-            (undirected, self._feature_dim_run)
+        decision = (
+            channel_packs[0][2] if channel_packs
+            else self._autotune_decisions.get(
+                (undirected, self._feature_dim_run)
+            )
         )
         if decision is None and self._autotune_enabled:
             try:
@@ -1434,7 +1468,9 @@ class TPUExecutor:
         # dense tier: per-superstep MXU utilization (matmul-attributable
         # flops over the device's MXU peak) next to the VPU roofline
         if callable(getattr(program, "matmul_flops", None)):
-            per_step = float(program.matmul_flops(n, edges))
+            per_step = float(program.matmul_flops(
+                n, self.csr.num_edges * (2 if undirected else 1)
+            ))
             info["mxu"] = _profiler.attach_mxu(records, per_step, peaks)
             mean_util = info["mxu"].get("mean_utilization")
             if mean_util is not None:

@@ -133,6 +133,83 @@ def test_tier_schedules_pow2_and_bounded():
         assert mid not in e2
 
 
+# ------------------------------------------------------- one price list
+def _graph500_stats(scale):
+    """Degree statistics of the benchmark's own graph (benchmark/data.py:
+    Graph500 R-MAT .57/.19/.19/.05, edge factor 16, structure seed 500)."""
+    import importlib.util
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "benchmark", "data.py",
+    )
+    spec = importlib.util.spec_from_file_location("benchmark_data", path)
+    data = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(data)
+    n, _src, dst, _perm = data.rmat_edges(scale, 16, 500, 7)
+    return GraphStats.from_degrees(
+        np.bincount(dst, minlength=n), len(dst), weighted=False
+    )
+
+
+@pytest.mark.parametrize("device_kind", ["cpu", "TPU v5 lite"])
+def test_every_pack_is_priced_from_one_device_column(device_kind):
+    """`decide` prices ell and hybrid with the SAME device's constants:
+    less the per-bucket and per-chunk terms, their modeled times are in
+    the ratio of their slot counts, on a tpu kind and on a cpu kind. (The
+    ELL pack was once priced at the cpu's gather cost on every device, so
+    `auto` on a TPU could never pick the pack that gathers less.)"""
+    from janusgraph_tpu.olap import autotune
+
+    kind = "tpu" if "TPU" in device_kind else "cpu"
+    s = GraphStats.from_csr(skewed_graph(n=2000, m=40000))
+    cutoff, slots, hubs, buckets, chunk_rows = s.hybrid_by_cutoff[3]
+    d = decide(s, device_kind, overrides={"hub_cutoff": cutoff})
+    per_bucket = autotune._BUCKET_OVERHEAD_S[kind] * 1e3
+    ell = d.modeled_ms["ell"] - len(s.degree_hist) * per_bucket
+    hyb = (
+        d.modeled_ms["hybrid"]
+        - (buckets + (1 if hubs else 0)) * per_bucket
+        - chunk_rows * autotune._TAIL_CHUNK_COST_S[kind] * 1e3
+    )
+    assert slots < s.ell_slots
+    assert hyb / ell == pytest.approx(slots / s.ell_slots, rel=1e-9)
+    assert ell == pytest.approx(
+        s.ell_slots * autotune._GATHER_COST_S[kind] * 1e3, rel=1e-3
+    )
+
+
+#: what the parent commit decided on a cpu kind for the benchmark's graph
+PARENT_CPU_DECISIONS = {
+    12: ("hybrid", 1024, 256, 1.0008,
+         {"ell": 0.3091, "hybrid": 0.2421, "segment": 0.541}),
+    13: ("hybrid", 512, 256, 1.0109,
+         {"ell": 0.6138, "hybrid": 0.4727, "segment": 1.0818}),
+    14: ("hybrid", 1024, 256, 1.0058,
+         {"ell": 1.2634, "hybrid": 0.9206, "segment": 2.1634}),
+}
+
+
+@pytest.mark.parametrize("scale", sorted(PARENT_CPU_DECISIONS))
+def test_auto_picks_the_zero_padding_pack_on_the_chip(scale):
+    """On the benchmark's degree distribution `auto` on a v5e resolves to
+    the hybrid pack, at most 1.15 slots an edge, with no option set; and a
+    cpu kind decides what it decided before the price lists were joined
+    (the default column WAS the cpu's)."""
+    s = _graph500_stats(scale)
+    tpu = decide(s, "TPU v5 lite")
+    assert tpu.strategy == "hybrid" and tpu.source == "model"
+    assert tpu.pad_ratio_est <= 1.15
+    assert tpu.modeled_ms["hybrid"] < 0.95 * tpu.modeled_ms["ell"]
+    cpu = decide(s, "cpu").as_dict()
+    strategy, cutoff, chunk, pad, modeled = PARENT_CPU_DECISIONS[scale]
+    assert (
+        cpu["strategy"], cpu["hub_cutoff"], cpu["tail_chunk"],
+        cpu["pad_ratio_est"], cpu["modeled_ms"],
+    ) == (strategy, cutoff, chunk, pad, modeled)
+
+
 # ------------------------------------------------- bitwise result identity
 BITWISE_PROGRAMS = [
     ("pagerank", lambda: PageRankProgram(max_iterations=12, tol=0.0), "rank"),

@@ -152,7 +152,7 @@ def test_sddmm_aggregate_layouts_bitwise_and_vs_dense():
     np.testing.assert_array_equal(a, aj)
     hyb_d = HybridPack(src, dst, None, n,
                        hub_cutoff=16, tail_chunk=16).device_put(jnp)
-    hrows_d = {k: [jnp.asarray(r) for r in v] for k, v in hrows.items()}
+    hrows_d = jnp.asarray(hrows)
     bj = np.asarray(
         jax.jit(lambda m: sddmm_hybrid_aggregate(jnp, hyb_d, hrows_d, m))(msgs)
     )

@@ -8,7 +8,9 @@ import pytest
 from janusgraph_tpu.olap import csr_from_edges, run_on
 from janusgraph_tpu.olap.kernels import (
     ELLPack,
+    HybridPack,
     ell_aggregate,
+    hybrid_aggregate,
     make_segsum_plan,
     pallas_sorted_segment_sum,
 )
@@ -91,6 +93,77 @@ def test_ell_supernode_jumbo_bucket():
     got = np.asarray(ell_aggregate(jnp, pack, jnp.asarray(msgs), Combiner.SUM))
     assert got[0] == hub_deg
     assert got[1] == 1 and got[2] == 1
+
+
+def supernode_graph(weights):
+    """One hub past max_capacity (row-split), a heavy tail, and vertices
+    no edge reaches."""
+    rng = np.random.default_rng(2)
+    n, m = 300, 8000
+    dst = np.concatenate([
+        np.zeros(5000, dtype=np.int64),
+        (rng.zipf(1.4, m - 5000) % (n - 40)).astype(np.int64),
+    ])
+    src = rng.integers(0, n, m)
+    w = rng.uniform(0.25, 2.0, m).astype(np.float32) if weights else None
+    assert (np.bincount(dst, minlength=n) == 0).sum() >= 40
+    return n, src, dst, w
+
+
+@pytest.mark.parametrize("weights", [False, True], ids=["unweighted", "w"])
+@pytest.mark.parametrize("cols", [0, 4], ids=["scalar", "n-by-4"])
+@pytest.mark.parametrize("op", [Combiner.SUM, Combiner.MIN, Combiner.MAX])
+def test_single_gather_hybrid_bitwise_equals_ell(op, cols, weights):
+    """The hybrid pack's one-gather aggregation gives the ELL pack's bits,
+    jitted and in the numpy replay of the same body: row-split supernode,
+    zero-degree vertices, scalar and (n, k) messages."""
+    import jax
+    import jax.numpy as jnp
+
+    n, src, dst, w = supernode_graph(weights)
+    rng = np.random.default_rng(5)
+    msgs = rng.uniform(-1, 1, (n, cols) if cols else n).astype(np.float32)
+    transform = EdgeTransform.MUL_WEIGHT if weights else EdgeTransform.NONE
+    ell = ELLPack(src, dst, w, n, max_capacity=64)
+    hyb = HybridPack(
+        src, dst, w, n, hub_cutoff=8, tail_chunk=16, max_capacity=64
+    )
+    assert "rowseg" in hyb.arrays and hyb.num_zero >= 40
+    want = np.asarray(ell_aggregate(jnp, ell, jnp.asarray(msgs), op, transform))
+    replay = hybrid_aggregate(np, hyb, msgs, op, transform)
+    np.testing.assert_array_equal(replay, want)
+    hyb.device_put(jnp)
+    got = jax.jit(
+        lambda x: hybrid_aggregate(jnp, hyb, x, op, transform)
+    )(msgs)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize("strategy,gathers", [("hybrid", 1), ("ell", None)])
+def test_dense_superstep_gathers_once_per_aggregation(strategy, gathers):
+    """The lowered module of one dense superstep on the hybrid pack holds
+    the message gather and the un-permutation of the result and no other
+    gather, whatever the number of exact widths; the ELL pack's holds one
+    per bucket and more (a count of the module's ops, not a timing)."""
+    import jax.numpy as jnp
+
+    n, src, dst, _ = supernode_graph(False)
+    g = csr_from_edges(n, src.astype(np.int32), dst.astype(np.int32), None)
+    ex = TPUExecutor(g, strategy=strategy, hub_cutoff=64, tail_chunk=16)
+    program = PageRankProgram(max_iterations=3, tol=0.0)
+    op = program.combiner
+    step = ex._superstep_body(program, op)
+    state, metrics = program.setup(ex.g, jnp)
+    memory = {k: jnp.float32(v) for k, (_o, v) in metrics.items()}
+    text = ex.jax.jit(step).lower(
+        state, jnp.int32(0), memory, ex._graph_args(program, op)
+    ).as_text()
+    count = text.count('"stablehlo.gather"') + text.count(" stablehlo.gather ")
+    if gathers is None:
+        assert count > 2 + len(ex._ell_pack(False).buckets)
+    else:
+        assert len(ex._hybrid_pack(False).torso_meta) > 20
+        assert count == gathers + 1  # + the result's `stacked[unpermute]`
 
 
 def test_pallas_sorted_segment_sum_matches():
