@@ -76,6 +76,12 @@ def _import_file(path: str):
     return module
 
 
+def reports(metric_entry: dict, cell: str) -> bool:
+    """Whether a cell reports a metric of the manifest: the entry lists the
+    cell under `workloads`, or has no such list and is every cell's."""
+    return cell in metric_entry.get("workloads", (cell,))
+
+
 class Catalog:
     """Everything the harness finds by name, under the roots it is given.
 
@@ -84,8 +90,9 @@ class Catalog:
     `traffic/<mix>.json`, `layer_metrics/<metric>.json`, `drivers/<driver>.py`
     and any `readers/*.py` / `references/*.py`, each of which offers a dict
     `READERS` / `REFERENCES` by name. A configuration's file is the `file`
-    of its BENCHMARK.json entry, relative to that root. Earlier roots win,
-    so a test's root can add a cell without touching the checkout's."""
+    of its BENCHMARK.json entry, relative to that root. Earlier roots win
+    (`merged` says how entries of one name combine), so a test's root can
+    add a cell without touching the checkout's."""
 
     def __init__(self, roots):
         self.roots = [os.path.abspath(r) for r in roots]
@@ -119,22 +126,51 @@ class Catalog:
             f"no benchmark/{kind}/{filename} under {self.roots}"
         )
 
+    def files(self, kind: str, pattern: str) -> list:
+        """Every `benchmark/<kind>/<pattern>` under the roots, first root
+        first, sorted within a root."""
+        return [
+            path for root in self.roots for path in sorted(glob.glob(
+                os.path.join(root, "benchmark", kind, pattern)))
+        ]
+
     def plugins(self, kind: str, table: str) -> dict:
-        """The merged `table` dicts of every `benchmark/<kind>/*.py`."""
+        """The merged `table` dicts of every `benchmark/<kind>/*.py`, an
+        earlier root's entry winning."""
         merged = {}
-        for root in reversed(self.roots):
-            pattern = os.path.join(root, "benchmark", kind, "*.py")
-            for path in sorted(glob.glob(pattern)):
-                merged.update(getattr(_import_file(path), table, {}))
+        for path in reversed(self.files(kind, "*.py")):
+            merged.update(getattr(_import_file(path), table, {}))
         return merged
 
     def driver(self, name: str):
         return _import_file(self.find("drivers", name + ".py"))
 
+    def merged(self, key: str) -> list:
+        """The manifests' list `key` as one list, one entry per name: the
+        first root's entry, and for a metric that several roots declare its
+        `workloads` lists joined (no list at all if one of them has none).
+        So a root adds a cell to a metric's list by declaring the metric
+        again with that cell alone, whatever cells the list has by then."""
+        out = {}
+        for _, entry in self.entries(key):
+            first = out.get(entry["name"])
+            if first is None:
+                out[entry["name"]] = dict(entry)
+            elif "workloads" in first and "workloads" in entry:
+                first["workloads"] = list(dict.fromkeys(
+                    first["workloads"] + entry["workloads"]))
+            else:
+                first.pop("workloads", None)
+        return list(out.values())
+
     def cell(self, name: str) -> dict:
         """The cell with all its data: its entry, its configuration, its
-        traffic mix, and the per-layer metrics its configuration's kind
-        reports (those a manifest declares, with the declared unit)."""
+        traffic mix, and the metrics it reports. The one rule, for both
+        kinds of metric: a cell reports a metric when the manifest's entry
+        lists the cell, or has no `workloads` list (`reports`). A layer
+        metric is read besides only where its file names the kind of the
+        cell's configuration: the file says where its reader finds
+        something to read, the manifest says who reports it."""
         _, entry = self.entry("workloads", name)
         root, cfg_entry = self.entry("configs", entry["config"])
         config = _read_json(os.path.join(root, cfg_entry["file"]))
@@ -142,21 +178,19 @@ class Catalog:
             self.find("traffic", entry["traffic"] + ".json")
         )
         declared = {
-            key: {e["name"]: e for _, e in reversed(self.entries(key))}
+            key: {e["name"]: e for e in self.merged(key) if reports(e, name)}
             for key in ("end_to_end", "per_layer")
         }
         layer_metrics, seen = [], set()
-        for root in self.roots:
-            pattern = os.path.join(root, "benchmark", "layer_metrics", "*.json")
-            for path in sorted(glob.glob(pattern)):
-                metric = _read_json(path)
-                if (
-                    metric["name"] not in seen
-                    and config["kind"] in metric["kinds"]
-                    and metric["name"] in declared["per_layer"]
-                ):
-                    seen.add(metric["name"])
-                    layer_metrics.append(metric)
+        for path in self.files("layer_metrics", "*.json"):
+            metric = _read_json(path)
+            if (
+                metric["name"] not in seen
+                and config["kind"] in metric["kinds"]
+                and metric["name"] in declared["per_layer"]
+            ):
+                seen.add(metric["name"])
+                layer_metrics.append(metric)
         return {
             "name": name,
             "chips": entry["chips"],

@@ -6,18 +6,32 @@ An event is `(name, start, end)` on one clock, in any one unit (the trace's
 nanoseconds); `seconds_per_unit` converts at the end. The input of `reduce`:
 
     {"window": (lo, hi) or None,
-     "devices": {"/device:TPU:0": {"modules": [event, ...], "ops": [...]}},
+     "devices": {"/device:TPU:0": {"modules": [event, ...], "ops": [...],
+                                   "stacks": [event, ...]}},
      "spans": [event, ...],        # the benchmark's own TraceAnnotations
      "activities": [event, ...]}   # what the host runtime traced itself
+
+`stacks` (optional) holds the same device operations as `ops`, each named by
+the name stack the profile carries for it (`jit(f)/while/body/<scope>/.../
+<primitive>:`) in place of its HLO name, or by "" where it carries none.
 """
 
 from __future__ import annotations
 
 import bisect
+import re
 from collections import defaultdict
 
 #: gaps shorter than this are summed under one name instead of attributed
 SHORT_GAP_S = 20e-6
+
+
+#: segments of a name stack that are frames of the tracing machinery and no
+#: scope anyone named: a transformation with its argument (`jit(run_span)`,
+#: `vmap(...)`) and the control-flow frames
+_FRAME = re.compile(r"^(?:[A-Za-z_]\w*\(.*\)|while|body|cond|pjit|"
+                    r"body_fun|cond_fun|branch_\d+_fun|closed_call|"
+                    r"checkpoint|remat|custom_jvp_call|custom_vjp_call)$")
 
 
 def union(intervals):
@@ -34,6 +48,12 @@ def union(intervals):
 
 def clip(intervals, lo, hi):
     return [(max(s, lo), min(e, hi)) for s, e in intervals
+            if min(e, hi) > max(s, lo)]
+
+
+def clip_events(events, lo, hi):
+    """Named events cut to [lo, hi]; those outside it are dropped."""
+    return [(n, max(s, lo), min(e, hi)) for n, s, e in events
             if min(e, hi) > max(s, lo)]
 
 
@@ -77,6 +97,30 @@ def self_times(events):
             out[stack[-1][0]] -= min(end, stack[-1][1]) - start
         out[name] += end - start
         stack.append((name, end))
+    return dict(out)
+
+
+def scopes_of(stack: str) -> list:
+    """The named scopes of a name stack, outermost first: its segments less
+    the frames (`_FRAME`) and less the last one, which is the primitive the
+    operation was traced from (with the profile's `:<type>` after it) and
+    no scope. A scope that encloses itself counts once."""
+    segments = [seg for seg in stack.split(":")[0].split("/")[:-1]
+                if seg and not _FRAME.match(seg)]
+    return list(dict.fromkeys(segments))
+
+
+def scope_times(stacks):
+    """scope -> device time under it: the self time (`self_times`) of every
+    operation whose name stack holds the scope, at whatever depth. So the
+    key holds a TOTAL: an operation under `a/b` counts under `a` and under
+    `b`, and two scopes of which one encloses the other must not be added.
+    Keyed by the scope's own name, not its path, so that a scope keeps its
+    key when the code around it moves."""
+    out = defaultdict(float)
+    for stack, t in self_times(stacks).items():
+        for scope in scopes_of(stack):
+            out[scope] += t
     return dict(out)
 
 
@@ -141,7 +185,9 @@ def reduce(events, seconds_per_unit=1e-9):
     """The traced stretch as numbers: `window_s`; `busy_s`, the time in
     which an operation ran on the device, averaged over the devices;
     `modules`, name -> [executions, device seconds] summed over the
-    devices; and the two top-ten lists of the result line's `breakdown`."""
+    devices; `scopes`, name -> device seconds under that named scope
+    (`scope_times`), summed over the devices, every one of them; and the
+    two top-ten lists of the result line's `breakdown`."""
     devices = events["devices"]
     if not devices:
         return None
@@ -151,9 +197,9 @@ def reduce(events, seconds_per_unit=1e-9):
     )
     busy, modules = [], defaultdict(lambda: [0, 0.0])
     op_self, idle = defaultdict(float), defaultdict(float)
+    scopes = defaultdict(float)
     for dev in devices.values():
-        ops = [(n, max(s, lo), min(e, hi)) for n, s, e in dev["ops"]
-               if min(e, hi) > max(s, lo)]
+        ops = clip_events(dev["ops"], lo, hi)
         cover = union((s, e) for _, s, e in ops)
         busy.append(covered(cover))
         for name, start, end in dev["modules"]:
@@ -164,6 +210,9 @@ def reduce(events, seconds_per_unit=1e-9):
         named = [(f"{find(s)}/{n}", s, e) for n, s, e in ops]
         for name, t in self_times(named).items():
             op_self[name] += t * seconds_per_unit
+        for name, t in scope_times(
+                clip_events(dev.get("stacks", ()), lo, hi)).items():
+            scopes[name] += t * seconds_per_unit
         # a hole inside a running program is the program's, not the host's
         running = union(cover + clip(
             [(s, e) for _, s, e in dev["modules"]], lo, hi))
@@ -180,6 +229,7 @@ def reduce(events, seconds_per_unit=1e-9):
         "busy_s": sum(busy) / len(busy) * seconds_per_unit,
         "devices": len(busy),
         "modules": dict(modules),
+        "scopes": dict(scopes),
         "device_ops": top(op_self),
         "idle_gaps": top(idle),
     }

@@ -10,22 +10,22 @@ import types
 
 import pytest
 
-from rehearsal import MANIFEST, REPO, declared, rehearse
+from rehearsal import REPO, over, over_cells, rehearse
 
 import run as harness  # noqa: E402  (rehearsal puts benchmark/ on the path)
 
-NEW_METRICS = {
-    "spill_lock_wait_ms": ["g500-served.twohop"],
-    "spill_host_ms": ["g500-served.twohop"],
-    "executor_host_ms.served": ["g500-served.twohop"],
-    "executor_host_ms.olap": ["g500-olap.pagerank", "g500-olap.bfs"],
-    "server_host_ms": ["g500-served.twohop"],
-    "idle_unnamed_share.served": ["g500-served.twohop"],
-    "idle_unnamed_share.olap": ["g500-olap.pagerank", "g500-olap.bfs"],
-    "setup_compile_s": [w["name"] for w in MANIFEST["workloads"]],
-}
+#: the metrics this file is about; which cells report each is the
+#: manifest's to say
+PHASE_METRICS = [
+    "spill_lock_wait_ms", "spill_host_ms", "executor_host_ms.served",
+    "executor_host_ms.olap", "server_host_ms", "idle_unnamed_share.served",
+    "idle_unnamed_share.olap", "setup_compile_s",
+]
 #: those whose reader needs the device plane of a trace: silent on the CPU
 NEEDS_DEVICE = {"idle_unnamed_share.served", "idle_unnamed_share.olap"}
+#: by the kind of a cell's configuration, the one that reads its host loop
+EXECUTOR_HOST = {"olap-adopted": "executor_host_ms.olap",
+                 "served-store": "executor_host_ms.served"}
 
 
 def _readers():
@@ -46,22 +46,25 @@ def _timer(count, total_ms):
     return {"type": "timer", "count": count, "total_ms": total_ms}
 
 
-@pytest.mark.parametrize("name", sorted(NEW_METRICS))
-def test_metric_file_is_declared_and_names_a_registered_reader(name):
-    metric = json.load(open(os.path.join(
-        REPO, "benchmark", "layer_metrics", name + ".json")))
-    entry = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
+@pytest.mark.parametrize(
+    "view,name", over(lambda view: sorted(PHASE_METRICS)))
+def test_metric_file_is_declared_and_names_a_registered_reader(view, name):
+    catalog = view.catalog
+    metric = json.load(open(catalog.find("layer_metrics", name + ".json")))
+    entry = view.entry("per_layer", name)
     for key in ("name", "layer", "unit", "better", "source", "moves"):
         assert metric[key] == entry[key], key
-    assert metric["reader"] in _readers()
+    assert metric["reader"] in catalog.plugins("readers", "READERS")
     assert metric["better"] == "lower"
-    cells = entry.get("workloads", NEW_METRICS["setup_compile_s"])
-    assert cells == NEW_METRICS[name]
-    # the cells it lists are the cells whose configuration's kind it reads
-    catalog = harness.Catalog([REPO])
-    for cell in NEW_METRICS["setup_compile_s"]:
-        loaded = {m["name"] for m in catalog.cell(cell)["layer_metrics"]}
-        assert (name in loaded) == (cell in cells), cell
+    # the rule of `Catalog.cell`, for every cell of the manifest: a cell
+    # loads the metric when the entry lists it (or has no list); and no
+    # entry lists a cell whose configuration's kind the file does not read
+    for cell in view.cells:
+        loaded = {m["name"] for m in view.cell(cell)["layer_metrics"]}
+        assert (name in loaded) == harness.reports(entry, cell), cell
+        if harness.reports(entry, cell):
+            assert view.kind_of(cell) in metric["kinds"], cell
+    assert any(harness.reports(entry, cell) for cell in view.cells)
 
 
 def test_timers_per_sums_what_moved_in_the_window_and_notes_every_phase():
@@ -125,28 +128,44 @@ def test_idle_unnamed_share_on_a_synthetic_summary():
     assert read(_run(summary={"idle_gaps": []})) is None
 
 
-@pytest.mark.parametrize("cell", NEW_METRICS["setup_compile_s"])
-def test_traced_rehearsal_reports_the_phase_metrics(cell, tmp_path):
-    line, notes, _ = rehearse(cell, tmp_path, trace=1, seed=2**31 + 29)
+@pytest.mark.parametrize("view,cell", over_cells())
+def test_traced_rehearsal_reports_the_phase_metrics(view, cell, tmp_path):
+    line, notes, _ = rehearse(cell, tmp_path, trace=1, seed=2**31 + 29,
+                              view=view)
     assert line["correct"] is True
-    expected = {name for name, cells in NEW_METRICS.items()
-                if cell in cells} - NEEDS_DEVICE
-    assert expected <= declared("per_layer", cell)
+    expected = (set(PHASE_METRICS) & view.declared("per_layer", cell)
+                ) - NEEDS_DEVICE
     for name in expected:
         value = line["metrics"][name]["value"]
         assert math.isfinite(value) and value >= 0, name
     assert not NEEDS_DEVICE & set(line["metrics"])  # no device plane here
     assert line["metrics"]["setup_compile_s"]["value"] > 0
-    phases = notes["notes"]["phases"]
-    assert {"executor.setup", "executor.dispatch", "executor.fetch",
-            "executor.publish"} <= set(phases)
-    if cell == "g500-served.twohop":
-        assert line["metrics"]["executor_host_ms.served"]["value"] > 0
-        assert line["metrics"]["spill_host_ms"]["value"] > 0
-        assert line["metrics"]["server_host_ms"]["value"] > 0
-        requests = notes["counts"]["requests"]
-        assert phases["spill.lock_wait"]["count"] == requests
-        assert phases["executor.dispatch"]["count"] == 2 * requests
+    phases = notes["notes"].get("phases")
+    if phases is None:
+        # the table is the phase readers' note: a cell that no list of
+        # theirs names has none
+        assert expected <= {"setup_compile_s"}
+        return
+    kind, traffic = view.kind_of(cell), view.traffic_of(cell)
+    requests = notes["counts"]["requests"]
+    info = notes["notes"].get("run_info")
+    if info:  # some request of the run reached the executor
+        assert {"executor.setup", "executor.dispatch", "executor.fetch",
+                "executor.publish"} <= set(phases)
+        # a tier is chosen on the frontier engine's path alone
+        assert ("executor.tier" in phases) == (info["path"] == "frontier")
+        if EXECUTOR_HOST[kind] in expected:
+            assert line["metrics"][EXECUTOR_HOST[kind]]["value"] > 0
+    if kind == "served-store":
+        for name in {"spill_host_ms", "server_host_ms"} & expected:
+            assert line["metrics"][name]["value"] > 0
+        templates = traffic["templates"]
+        if all(t.get("promote") for t in templates):
+            # every request spilled, and waited for the planner's lock once
+            assert phases["spill.lock_wait"]["count"] == requests
+            if len(templates) == 1:  # one superstep a hop
+                hops = templates[0]["gremlin"].count(".out()")
+                assert phases["executor.dispatch"]["count"] == hops * requests
     else:
-        assert line["metrics"]["executor_host_ms.olap"]["value"] > 0
-        assert ("executor.tier" in phases) == (cell == "g500-olap.bfs")
+        # an analyst's submit is one run of the executor
+        assert phases["executor.publish"]["count"] == requests

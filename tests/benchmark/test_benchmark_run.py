@@ -8,7 +8,7 @@ import shutil
 
 import pytest
 
-from rehearsal import (MANIFEST, POINT_CELL, REPO, declared, rehearse,
+from rehearsal import (MANIFEST, POINT_CELL, REPO, over_cells, rehearse,
                        run_benchmark)
 
 
@@ -42,12 +42,12 @@ def test_no_result_in_a_directory_that_holds_only_the_benchmark(tmp_path):
     assert "janusgraph_tpu" in res.stderr and '"correct"' not in res.stdout
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_rehearsal_reports_the_cells_end_to_end_metrics(cell, tmp_path):
-    line, notes, _ = rehearse(cell, tmp_path)
+@pytest.mark.parametrize("view,cell", over_cells())
+def test_rehearsal_reports_the_cells_end_to_end_metrics(view, cell, tmp_path):
+    line, notes, _ = rehearse(cell, tmp_path, view=view)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
-    assert set(line["metrics"]) == declared("end_to_end", cell)
+    assert set(line["metrics"]) == view.declared("end_to_end", cell)
     assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["device"] == {"platform": "cpu", "kind": "cpu", "count": 1,
                               "memory_peak_bytes": 0}

@@ -1,6 +1,8 @@
 """The arithmetic from trace events to numbers, pinned on a synthetic list
 of events: busy and idle share, self times of nested operations, the
-attribution of idle gaps to what the host was doing, per-module totals."""
+attribution of idle gaps to what the host was doing, per-module totals,
+device time by named scope; and the reader of a profile's name stacks,
+pinned on a synthetic protobuf."""
 
 
 import pytest
@@ -119,15 +121,16 @@ def test_short_op_names(text, want):
 
 class _Run:
     def __init__(self, summary, counts=None, notes=None):
+        import run as bench
+
         self.trace_summary, self.counts = summary, counts or {}
         self.notes = notes or {}
         self.shapes = {"vertices": 1 << 20, "edges": 1 << 24}
+        self.catalog = bench.Catalog([REPO])
 
 
 def _readers():
-    import run as bench
-
-    return bench.Catalog([REPO]).plugins("readers", "READERS")
+    return _Run(None).catalog.plugins("readers", "READERS")
 
 
 def test_trace_readers_divide_device_time_by_the_units_they_are_given():
@@ -167,3 +170,189 @@ def test_roofline_reader_counts_the_algorithms_bytes_against_the_peak():
     assert readers["roofline"](run, **args) is None
     with pytest.raises(KeyError):
         device.peaks("TPU v9 imaginary")
+
+
+# ------------------------------------------------- device time by named scope
+
+def scoped_events():
+    """`events()` with a name stack on each operation: the program's first
+    fusion under `gather`, its second under `fold` inside `gather`, the
+    loop itself with none (as the profile has it), the superstep's fusion
+    under `fold` alone."""
+    ev = events()
+    body = "jit(run_span)/jit(main)/while/body/"
+    ev["devices"]["/device:TPU:0"]["stacks"] = [
+        ("", 0, 40 * MS),
+        (body + "gather/jit(_take)/gather:", 0, 25 * MS),
+        (body + "gather/fold/reduce_max:", 27 * MS, 40 * MS),
+        ("jit(superstep)/pjit/fold/cond/branch_1_fun/add:", 60 * MS, 70 * MS),
+    ]
+    return ev
+
+
+@pytest.mark.parametrize("stack,want", [
+    ("jit(run_span)/while/body/jit(_take)/gather:", []),
+    ("jit(run_span)/jit(main)/while/body/fold/reduce_max:", ["fold"]),
+    ("jit(f)/vmap(jit(g))/outer/pjit/inner/cond/branch_0_fun/mul:",
+     ["outer", "inner"]),
+    ("jit(f)/a/b/a/add:XlaOp", ["a", "b"]),   # a scope inside itself: once
+    ("jit(f)/gather/gather:", ["gather"]),    # the last is the primitive
+    ("jit(multiply)/mul:", []),
+    ("", []),
+])
+def test_scopes_of_a_name_stack_leave_out_frames_and_the_primitive(stack,
+                                                                   want):
+    assert tr.scopes_of(stack) == want
+
+
+def test_scope_times_are_totals_of_self_times():
+    """The key holds a total: the self time of every operation under the
+    scope at any depth. `fold` inside `gather` counts under both; the loop,
+    which has no name stack, keeps its own 2 ms out of every scope."""
+    out = tr.reduce(scoped_events())
+    assert out["scopes"] == {
+        "gather": pytest.approx(0.025 + 0.013),
+        "fold": pytest.approx(0.013 + 0.010),
+    }
+    # nothing else moved: the keys that were there read what they read
+    plain = tr.reduce(events())
+    assert plain["scopes"] == {}
+    for key in ("window_s", "busy_s", "modules", "device_ops", "idle_gaps"):
+        assert out[key] == plain[key], key
+
+
+def test_scope_times_clip_to_the_window_and_sum_over_devices():
+    ev = scoped_events()
+    ev["window"] = (20 * MS, 100 * MS)
+    ev["devices"]["/device:TPU:1"] = {
+        "modules": [], "ops": [("fusion.9 f32[8]", 30 * MS, 34 * MS)],
+        "stacks": [("jit(run_span)/while/body/fold/add:", 30 * MS, 34 * MS)],
+    }
+    out = tr.reduce(ev)
+    assert out["scopes"] == {
+        "gather": pytest.approx(0.005 + 0.013),        # 20..25 and 27..40
+        "fold": pytest.approx(0.013 + 0.010 + 0.004),  # both devices
+    }
+    # a device with operations and no name stacks adds nothing
+    del ev["devices"]["/device:TPU:1"]["stacks"]
+    assert tr.reduce(ev)["scopes"]["fold"] == pytest.approx(0.023)
+
+
+def test_trace_scope_reader_and_a_roofline_by_scope():
+    readers = _readers()
+    summary = tr.reduce(scoped_events())
+    run = _Run(summary, {"supersteps_traced": 20})
+    per = {"count": "supersteps_traced"}
+    assert readers["trace-scope"](run, scopes=["fold"], per=per) == (
+        pytest.approx(23.0 / 20))
+    assert readers["trace-scope"](
+        run, scopes=["fold", "nowhere"],
+        per={"executions_of": ["jit_superstep"]}) == pytest.approx(23.0)
+    # nothing to read: no summary, a summary from before the key, a scope
+    # the trace does not hold, nothing to divide by
+    assert readers["trace-scope"](_Run(None), scopes=["fold"], per=per) is None
+    old = {k: v for k, v in summary.items() if k != "scopes"}
+    assert readers["trace-scope"](
+        _Run(old, {"supersteps_traced": 20}), scopes=["fold"], per=per) is None
+    assert readers["trace-scope"](run, scopes=["nowhere"], per=per) is None
+    assert readers["trace-scope"](
+        _Run(summary), scopes=["fold"], per=per) is None
+
+    class _Dev:
+        platform, device_kind = "tpu", "TPU v5 lite"
+
+    run.devices = [_Dev()]
+    bytes_moved = 8 * (1 << 24) + 12 * (1 << 20)
+    want = 100.0 * (1000.0 * bytes_moved / 819e9) / (38.0 / 20)
+    assert readers["roofline"](
+        run, bytes_function="dense-superstep", scopes=["gather"], per=per
+    ) == pytest.approx(want)
+    assert readers["roofline"](
+        run, bytes_function="dense-superstep", scopes=["nowhere"], per=per
+    ) is None
+
+
+def test_a_bytes_function_comes_with_the_files_a_root_adds(tmp_path):
+    """A later PR names its scope and its bytes function in files of its
+    own: a `readers/*.py` under a root offers a `BYTES` table too."""
+    import run as bench
+
+    readers = tmp_path / "benchmark" / "readers"
+    readers.mkdir(parents=True)
+    (readers / "fold.py").write_text(
+        "BYTES = {'fold': lambda shapes: 4 * shapes['edges']}\n")
+    table = bench.Catalog([str(tmp_path), REPO]).plugins("readers", "BYTES")
+    assert table["fold"]({"edges": 10}) == 40
+    assert "dense-superstep" in table
+
+
+# ------------------------------------- the profile's name stacks, from the file
+
+def _varint(n):
+    out = bytearray()
+    while True:
+        out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+        n >>= 7
+        if not n:
+            return bytes(out)
+
+
+def _msg(*fields):
+    """A protobuf message of (number, int or bytes) fields."""
+    out = b""
+    for number, value in fields:
+        if isinstance(value, int):
+            out += _varint(number << 3) + _varint(value)
+        else:
+            out += _varint(number << 3 | 2) + _varint(len(value)) + value
+    return out
+
+
+def _plane(name, stat_names, metadata, lines):
+    """An XPlane: stat_names {id: name}; metadata {id: (name, [XStat])};
+    lines [(name, timestamp_ns, [(metadata id, offset_ps, duration_ps)])]."""
+    fields = [(1, 7), (2, name.encode())]
+    for line_name, at, evs in lines:
+        fields.append((3, _msg(
+            (1, 1), (2, line_name.encode()), (3, at),
+            *[(4, _msg((1, m), (2, off), (3, dur),
+                       (4, _msg((1, 99), (3, 5)))))  # an event's own stat
+              for m, off, dur in evs])))
+    for key, (md_name, stats) in metadata.items():
+        fields.append((4, _msg((1, key), (2, _msg(
+            (1, key), (2, md_name.encode()), (4, b"display"),
+            *[(5, stat) for stat in stats])))))
+    for key, stat_name in stat_names.items():
+        fields.append((5, _msg((1, key), (2, _msg(
+            (1, key), (2, stat_name.encode()))))))
+    return _msg(*fields)
+
+
+def test_name_stacks_are_read_from_the_metadata_of_each_device_operation(
+        tmp_path):
+    stat_names = {1: "flops", 2: device.STACK_STAT,
+                  3: "jit(f)/while/body/shared/mul:"}
+    fixed64 = _varint(2 << 3 | 1) + b"\0" * 8   # a double_value: stepped over
+    metadata = {
+        10: ("%fusion.1 = f32[8] fusion()", [
+            _msg((1, 1), (4, 1234)) + fixed64,
+            _msg((1, 2), (5, b"jit(f)/while/body/fold/add:"))]),
+        11: ("%while.1 = () while()", [_msg((1, 1), (4, 0))]),
+        12: ("%fusion.2 = f32[8] fusion()", [_msg((1, 2), (7, 3))]),
+    }
+    ops = [(11, 0, 9_000_000), (10, 1_000_000, 2_500_000), (12, 4_000_000, 500)]
+    space = _msg(
+        (1, _plane("/device:TPU:0", stat_names, metadata, [
+            ("XLA Modules", 1000, [(11, 0, 9_000_000)]),
+            ("XLA Ops", 1000, ops)])),
+        (1, _plane("/host:CPU", stat_names, metadata,
+                   [("XLA Ops", 0, ops)])),
+        (4, b"hostname"),
+    )
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(space)
+    assert device.name_stacks(str(path)) == {"/device:TPU:0": [
+        ("", 1000.0, 10000.0),
+        ("jit(f)/while/body/fold/add:", 2000.0, 4500.0),
+        ("jit(f)/while/body/shared/mul:", 5000.0, 5000.5),
+    ]}

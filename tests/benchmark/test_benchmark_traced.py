@@ -7,20 +7,22 @@ import os
 
 import pytest
 
-from rehearsal import MANIFEST, REPO, declared, rehearse
+from rehearsal import REPO, over_cells, rehearse
 
 
-@pytest.mark.parametrize("cell", [w["name"] for w in MANIFEST["workloads"]])
-def test_traced_rehearsal_reports_layer_metrics_only(cell, tmp_path):
-    line, notes, _ = rehearse(cell, tmp_path, trace=1)
+@pytest.mark.parametrize("view,cell", over_cells())
+def test_traced_rehearsal_reports_layer_metrics_only(view, cell, tmp_path):
+    line, notes, _ = rehearse(cell, tmp_path, trace=1, view=view)
     assert line["correct"] is True and line["failed"] == 0
     reported = set(line["metrics"])
     # the CPU's trace has no device plane: readers of the device trace
     # find nothing and their metrics are left out, not invented
-    assert reported and reported <= declared("per_layer", cell)
-    assert not reported & declared("end_to_end", cell)
-    assert not any(n.startswith(("superstep_", "device_idle"))
-                   for n in reported)
+    assert reported and reported <= view.declared("per_layer", cell)
+    assert not reported & view.declared("end_to_end", cell)
+    from_the_device_trace = {
+        m["name"] for m in view.manifest["per_layer"]
+        if m["source"] == "device_trace"}
+    assert not reported & from_the_device_trace
     assert "busy_s" not in line["device"] and "breakdown" not in line
     assert {"setup_snapshot_s", "setup_cache_misses"} <= reported
     assert notes["spans"]["traced"] > 0
