@@ -15,7 +15,7 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 from janusgraph_tpu.olap.csr import CSRGraph, load_csr
-from janusgraph_tpu.olap.vertex_program import VertexProgram
+from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
 
 
 @dataclass
@@ -86,12 +86,14 @@ class ComputerResult:
             path_index=self._path_index,
         )
 
-    def value(self, key: str, vertex_id: int) -> float:
-        return float(self.states[key][self.csr.index_of(vertex_id)])
+    def value(self, key: str, vertex_id: int):
+        """One vertex's value as a Python number: a float, or an int where
+        the state is an integer array (CDLP's labels)."""
+        return self.states[key][self.csr.index_of(vertex_id)].item()
 
     def by_vertex(self, key: str) -> Dict[int, float]:
-        arr = self.states[key]
-        return {int(v): float(arr[i]) for i, v in enumerate(self.csr.vertex_ids)}
+        arr = np.asarray(self.states[key]).tolist()
+        return {int(v): arr[i] for i, v in enumerate(self.csr.vertex_ids)}
 
     def write_back(self, keys: Optional[Sequence[str]] = None) -> None:
         from janusgraph_tpu.olap.tpu_executor import write_back
@@ -302,7 +304,10 @@ class GraphComputer:
                 ndev = len(jax.devices())
             except Exception:
                 ndev = 1
-            if ndev > 1 and getattr(
+            # the mesh's exchanges pre-combine partials: a MODE program
+            # stays on one device, like an sddmm one
+            whole_multiset = self._program.combiner == Combiner.MODE
+            if ndev > 1 and not whole_multiset and getattr(
                 self._program, "sharded_compatible", True
             ):
                 executor_kind = "sharded"
@@ -312,7 +317,9 @@ class GraphComputer:
                 }
             else:
                 routing["reason"] = (
-                    "single device" if ndev <= 1 else "sddmm program"
+                    "single device" if ndev <= 1
+                    else "mode combiner" if whole_multiset
+                    else "sddmm program"
                 )
         run_kwargs = {}
         if cfg is not None and executor_kind == "sharded":

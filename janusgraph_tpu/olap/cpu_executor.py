@@ -11,6 +11,7 @@ of the TPU executor, so agreement between the two is meaningful evidence
 
 from __future__ import annotations
 
+from collections import Counter
 from typing import Dict
 
 import numpy as np
@@ -25,11 +26,19 @@ from janusgraph_tpu.olap.vertex_program import (
 
 
 def _combine(op: str, a, b):
-    if op == Combiner.SUM:
-        return a + b
-    if op == Combiner.MIN:
-        return np.minimum(a, b)
-    return np.maximum(a, b)
+    return Combiner.monoid(
+        op, "the oracle's pairwise delivery", np.add, np.minimum, np.maximum
+    )(a, b)
+
+
+def _mode(labels) -> int:
+    """Combiner.MODE of one vertex's received labels, in plain Python: the
+    most frequent, the smallest on ties, NO_MESSAGE for none."""
+    if not labels:
+        return Combiner.NO_MESSAGE
+    counts = Counter(labels)
+    most = max(counts.values())
+    return min(label for label, c in counts.items() if c == most)
 
 
 class CPUExecutor:
@@ -190,6 +199,9 @@ class CPUExecutor:
                 program_delta_compatible,
             )
 
+            Combiner.require_foldable(
+                program.combiner, "the fused delta overlay"
+            )
             if not program_delta_compatible(program):
                 raise ValueError(
                     "delta-fused runs support default-edge-view "
@@ -234,11 +246,14 @@ class CPUExecutor:
             identity = Combiner.IDENTITY[op]
             ch_name = program.channel_for(step)
             use_pack = self.strategy != "scalar" and ch_name is None
+            mode = op == Combiner.MODE
             outgoing = np.asarray(
                 program.message(state, step, g, np),
                 # pack paths run float32 like the device executors (the
-                # bitwise-identity contract); the oracle loop keeps f64
-                dtype=np.float32 if use_pack else np.float64,
+                # bitwise-identity contract); the oracle loop keeps f64;
+                # MODE's labels stay the integers they are
+                dtype=np.int32 if mode
+                else np.float32 if use_pack else np.float64,
             )
             if use_pack:
                 # the device executors' exact aggregation arithmetic
@@ -277,10 +292,16 @@ class CPUExecutor:
             if not use_pack:
                 agg_shape = (n, outgoing.shape[1]) if vec else (n,)
                 aggregated = np.full(agg_shape, identity, dtype=np.float64)
+            #: MODE cannot combine on delivery: every label is kept until
+            #: the vertex has them all
+            received = [[] for _ in range(n)] if mode and not use_pack else None
 
             sddmm = getattr(program, "message_mode", None) == "sddmm"
 
             def deliver(dst: int, src: int, weight):
+                if received is not None:
+                    received[dst].append(int(outgoing[src]))
+                    return
                 if sddmm:
                     # dense-tier dot-attention oracle: the per-edge
                     # coefficient is <h_src, h_dst> (f64 here — the scalar
@@ -325,6 +346,10 @@ class CPUExecutor:
                             )
                             deliver(i, int(g.out_dst[e]), w)
 
+            if received is not None:
+                aggregated = np.asarray(
+                    [_mode(labels) for labels in received], dtype=np.int32
+                )
             memory_in = dict(memory.values)
             state, metrics = program.apply(
                 state, aggregated, step, memory_in, g, np
@@ -482,6 +507,9 @@ class CPUExecutor:
         tiers = profiler.attach_roofline(records, cost, peaks)
         info = {
             "path": "cpu",
+            "combiner": "+".join(dict.fromkeys(
+                r["combiner"] for r in records
+            )) or program.combiner,
             "supersteps": len(records),
             "wall_s": round(
                 sum(r["wall_ms"] for r in records) / 1000.0, 4

@@ -804,6 +804,7 @@ def fused_delta_aggregate(xp, lanes, meta, outgoing, base_agg, op):
     SUM contract's replay oracle."""
     from janusgraph_tpu.olap.kernels import _segment_combine
 
+    Combiner.require_foldable(op, "the fused delta overlay")
     identity = Combiner.IDENTITY[op]
     nb, npad = meta["n_base"], meta["n_pad"]
     tail = npad - base_agg.shape[0]
@@ -838,9 +839,9 @@ def fused_delta_aggregate(xp, lanes, meta, outgoing, base_agg, op):
     if base.ndim == 2:
         dirty = dirty[:, None]
     merged = xp.where(dirty > 0, live, base)
-    if op == Combiner.MIN:
-        return xp.minimum(merged, add)
-    return xp.maximum(merged, add)
+    return Combiner.monoid(
+        op, "the fused delta overlay", None, xp.minimum, xp.maximum
+    )(merged, add)
 
 
 def replay_fused_aggregate(lanes, meta, outgoing, base_agg, op):
@@ -1279,9 +1280,14 @@ def compact_result(view: OverlayView, states: Dict[str, np.ndarray]):
 def program_delta_compatible(program) -> bool:
     """Whether a vertex program can consume the overlay FUSED: default
     edge view only (typed channels aggregate over their own packs, which
-    the lanes do not patch), no sddmm (row-dst vectors are base-layout)."""
-    from janusgraph_tpu.olap.vertex_program import VertexProgram
+    the lanes do not patch), no sddmm (row-dst vectors are base-layout),
+    no MODE combiner (`fused_delta_aggregate` merges lane partials into
+    the base aggregate, and a mode cannot be folded from partials: the
+    overlay is materialized before such a program runs)."""
+    from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
 
+    if getattr(program, "combiner", None) == Combiner.MODE:
+        return False
     if getattr(program, "message_mode", None) == "sddmm":
         return False
     if getattr(program, "edge_channels", None):

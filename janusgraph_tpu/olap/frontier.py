@@ -40,6 +40,7 @@ from janusgraph_tpu.olap.device import await_arrays
 # the reached-ness tests below ("dist >= INF") are parity-equivalent to the
 # dense program only because both use the IDENTICAL constant
 from janusgraph_tpu.olap.programs.shortest_path import INF
+from janusgraph_tpu.olap.vertex_program import Combiner
 
 
 def _tier(need: int, lo: int, hi: int, growth: int = 4) -> int:
@@ -325,6 +326,8 @@ class FrontierEngine:
 
     def run(self, program) -> Dict[str, np.ndarray]:
         """SSSP/BFS through the shared hop loop."""
+        # the hop loop relaxes by scatter-min: partials merged in place
+        Combiner.require_foldable(program.combiner, "the frontier engine")
         jnp = self.jnp
         n = self.n
         weighted = program.weighted
@@ -366,6 +369,7 @@ class FrontierEngine:
         Per-step parity with the dense BSP path: an unchanged vertex's
         label was already absorbed by its neighbors when it last changed.
         Labels ride float32 (exact below 2^24 — eligibility-guarded)."""
+        Combiner.require_foldable(program.combiner, "the frontier engine")
         jnp = self.jnp
         with tracer.phase("executor.setup"):
             labels = jnp.asarray(np.arange(self.n, dtype=np.float32))

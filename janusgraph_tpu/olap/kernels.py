@@ -38,6 +38,12 @@ between the first two per graph and device (olap/autotune.decide):
    steps (zeroed on first touch). SUM monoid; used for PageRank-shaped
    programs.
 
+`Combiner.MODE` (the most frequent label, smallest on ties) is no monoid,
+so it rides the ELL and hybrid packs' ONE gather and then folds each
+destination's WHOLE multiset: a data-oblivious sort along a block's width,
+run lengths, and an arg-max by (count, then smaller label) — see
+`mode_along`, `hybrid_mode_fold`, `segment_mode`.
+
 All are built once per (graph, orientation) and reused across supersteps.
 The aggregation entry points take the array module (`jnp` or plain numpy)
 as their first argument, so the CPU oracle can run the identical pack
@@ -46,6 +52,7 @@ arithmetic for cross-executor bitwise checks.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 from typing import List, Optional, Tuple
 
@@ -259,6 +266,18 @@ def _is_jax(xp) -> bool:
     return "jax" in getattr(xp, "__name__", "")
 
 
+def superstep_scope(xp, name: str):
+    """`jax.named_scope("superstep.<name>")` around a stage of the compiled
+    superstep (message / gather / fold / apply, none enclosing another): it
+    names the stage's device operations in a profile and changes nothing
+    else; nothing at all on the numpy path."""
+    if _is_jax(xp):
+        import jax
+
+        return jax.named_scope("superstep." + name)
+    return contextlib.nullcontext()
+
+
 # graphlint: traced -- the fp-contraction fence of product-fed reductions
 def fp_fence(xp, a):
     """Add an optimizer-opaque zero to `a` — the fp-contraction fence.
@@ -313,14 +332,12 @@ def tree_reduce(xp, m, op: str, axis: int = 1):
     else:
         def every_other(m, start):
             return m[lead + (slice(start, None, 2),)]
+    pair = Combiner.monoid(
+        op, "tree_reduce (pack chunks folded pairwise)",
+        xp.add, xp.minimum, xp.maximum,
+    )
     while m.shape[axis] > 1:
-        a, b = every_other(m, 0), every_other(m, 1)
-        if op == Combiner.SUM:
-            m = a + b
-        elif op == Combiner.MIN:
-            m = xp.minimum(a, b)
-        else:
-            m = xp.maximum(a, b)
+        m = pair(every_other(m, 0), every_other(m, 1))
     return m[lead + (0,)]
 
 
@@ -332,11 +349,10 @@ def _segment_combine(xp, op: str, values, seg, num_segments: int):
     if _is_jax(xp):
         import jax
 
-        seg_fn = {
-            Combiner.SUM: jax.ops.segment_sum,
-            Combiner.MIN: jax.ops.segment_min,
-            Combiner.MAX: jax.ops.segment_max,
-        }[op]
+        seg_fn = Combiner.monoid(
+            op, "the fold of split-row partials",
+            jax.ops.segment_sum, jax.ops.segment_min, jax.ops.segment_max,
+        )
         return seg_fn(values, seg, num_segments=num_segments)
     return _segment_combine_host(xp, op, values, seg, num_segments)
 
@@ -347,10 +363,10 @@ def _segment_combine_host(xp, op: str, values, seg, num_segments: int):
         (num_segments,) + values.shape[1:], Combiner.IDENTITY[op],
         dtype=values.dtype,
     )
-    ufunc = {
-        Combiner.SUM: xp.add, Combiner.MIN: xp.minimum,
-        Combiner.MAX: xp.maximum,
-    }[op]
+    ufunc = Combiner.monoid(
+        op, "the fold of split-row partials",
+        xp.add, xp.minimum, xp.maximum,
+    )
     ufunc.at(out, seg, values)
     return out
 
@@ -372,6 +388,8 @@ def ell_aggregate(
     (see vertex_program.apply_edge_transform).
     """
     identity = Combiner.IDENTITY[op]
+    if op == Combiner.MODE:
+        _check_mode_messages(msgs, edge_transform, edge_transform_cols)
     if not pack.has_weight:
         # mirror the segment path: transforms only apply when weights exist
         edge_transform = EdgeTransform.NONE
@@ -383,40 +401,57 @@ def ell_aggregate(
     )
     parts = []
     for idx, w, valid, rowseg, num_slots in pack.buckets:
-        m = flat_take(jnp, msgs_ext, idx)
-        if w is not None:
-            # weighted pack: apply the transform, then force padded slots
-            # back to the identity (a transform can disturb it, e.g.
-            # identity*0 = nan for MIN's +inf)
-            valid_ = valid[:, :, None] if m.ndim == 3 else valid
-            if edge_transform_cols is not None:
-                m = apply_edge_transform(
-                    jnp, m, w, edge_transform, edge_transform_cols
-                )
+        with superstep_scope(jnp, "gather"):
+            m = flat_take(jnp, msgs_ext, idx)
+            # labels ride untransformed, and a weighted pack's padded
+            # slots index the sentinel like any other's: no mask for MODE
+            if w is not None and op != Combiner.MODE:
+                # weighted pack: apply the transform, then force padded
+                # slots back to the identity (a transform can disturb it,
+                # e.g. identity*0 = nan for MIN's +inf)
+                valid_ = valid[:, :, None] if m.ndim == 3 else valid
+                if edge_transform_cols is not None:
+                    m = apply_edge_transform(
+                        jnp, m, w, edge_transform, edge_transform_cols
+                    )
+                else:
+                    w_ = w[:, :, None] if m.ndim == 3 else w
+                    if edge_transform == EdgeTransform.MUL_WEIGHT:
+                        m = m * w_
+                    elif edge_transform == EdgeTransform.ADD_WEIGHT:
+                        m = m + w_
+                m = jnp.where(valid_ > 0, m, identity)
+                # fence the transformed leaves so no backend contracts the
+                # weight product into the reduction tree (and every layout
+                # normalizes -0.0 the same way)
+                m = fp_fence(jnp, m)
+            # unweighted pack: padded slots index the sentinel, which
+            # already reads the identity — no mask needed
+        with superstep_scope(jnp, "fold"):
+            if op == Combiner.MODE:
+                if rowseg is None:
+                    r = mode_along(jnp, m, 1)
+                else:
+                    # supernode rows: the owner's WHOLE multiset, never
+                    # row partials — a segmented sort by (owner, label)
+                    owners = jnp.broadcast_to(rowseg[:, None], m.shape)
+                    r = segment_mode(
+                        jnp, m.reshape(-1), owners.reshape(-1), num_slots
+                    )
             else:
-                w_ = w[:, :, None] if m.ndim == 3 else w
-                if edge_transform == EdgeTransform.MUL_WEIGHT:
-                    m = m * w_
-                elif edge_transform == EdgeTransform.ADD_WEIGHT:
-                    m = m + w_
-            m = jnp.where(valid_ > 0, m, identity)
-            # fence the transformed leaves so no backend contracts the
-            # weight product into the reduction tree (and every layout
-            # normalizes -0.0 the same way)
-            m = fp_fence(jnp, m)
-        # unweighted pack: padded slots index the sentinel, which already
-        # reads the identity — no mask needed
-        r = tree_reduce(jnp, m, op)
-        if rowseg is not None:
-            # fold supernode row partials into one slot per destination —
-            # a rows-sized reduction, negligible next to the edge gather
-            r = _segment_combine(jnp, op, r, rowseg, num_slots)
+                r = tree_reduce(jnp, m, op)
+                if rowseg is not None:
+                    # fold supernode row partials into one slot per
+                    # destination — a rows-sized reduction, negligible
+                    # next to the edge gather
+                    r = _segment_combine(jnp, op, r, rowseg, num_slots)
         parts.append(r)
     if not parts:
         out_shape = msgs.shape
         return jnp.full(out_shape, identity, dtype=msgs.dtype)
-    stacked = jnp.concatenate(parts, axis=0)
-    return stacked[pack.unpermute]
+    with superstep_scope(jnp, "fold"):
+        stacked = jnp.concatenate(parts, axis=0)
+        return stacked[pack.unpermute]
 
 
 # --------------------------------------------------------------------------
@@ -538,6 +573,7 @@ class HybridPack:
         #: static (tree cap, partials per row, rows, slots) per tail bucket
         self.tail_meta: List[Tuple[int, int, int, int]] = []
         ch_starts, ch_degs, slots = [], [], []
+        own_vertex, own_chunks = [], []  # per hub: id, chunks (all its rows)
         rowseg = None
         table_rows = 0
         hub = deg > self.hub_cutoff
@@ -574,8 +610,28 @@ class HybridPack:
                 table_rows += rows * ppr
                 self.tail_meta.append((c, ppr, rows, len(members)))
                 vertex_order_parts.append(members)
+                own_vertex.append(members)
+                own_chunks.append(np.bincount(
+                    np.arange(rows) if rows == len(members) else rowseg,
+                    weights=nch, minlength=len(members),
+                ).astype(np.int64))
         self.tail_chunks = sum(len(s) for s in ch_starts)
         self.table_rows = table_rows
+        self._mode_tables = {}  # array module -> tables (mode_tables)
+        self._mode_owners = (
+            np.concatenate(own_vertex) if own_vertex
+            else np.zeros(0, dtype=np.int64),
+            np.concatenate(own_chunks) if own_chunks
+            else np.zeros(0, dtype=np.int64),
+        )
+        #: static (chunks per hub padded to a power of two, hubs) per
+        #: whole-row bucket of the MODE fold (`mode_tables`)
+        self.mode_tail_meta: List[Tuple[int, int]] = [
+            (int(k), int(r)) for k, r in zip(*np.unique(
+                [_next_pow2(int(c)) for c in self._mode_owners[1]],
+                return_counts=True,
+            ))
+        ]
 
         tail_idx = np.full((self.tail_chunks, T), self.sentinel, dtype=np.int32)
         if self.has_weight:
@@ -622,6 +678,52 @@ class HybridPack:
         self.slots = int(self.arrays["idx"].size)
         self.pad_ratio = self.slots / max(1, self.num_edges)
 
+    def mode_tables(self, xp=np) -> dict:
+        """Index tables of the MODE fold as `xp` arrays (built, and moved
+        to the device, on first use). The tail's hubs are folded WHOLE, so
+        each hub becomes one row of a block whose width is its chunk count
+        padded to a power of two — every chunk of every row the pack split
+        it into, never partials.
+        "mode_pieces": per `mode_tail_meta` bucket a (hubs, K) matrix of
+        chunk numbers, flattened and laid end to end, holes pointing at
+        chunk number `tail_chunks` (an all-sentinel chunk the fold
+        appends); "mode_unpermute": `unpermute` with the hubs in the order
+        of those buckets."""
+        if not self._mode_tables:
+            vertex, chunks = self._mode_owners
+            first = np.cumsum(chunks) - chunks  # hubs' chunks lie end to end
+            width = np.asarray(
+                [_next_pow2(int(c)) for c in chunks], dtype=np.int64
+            )
+            pieces, order = [], []
+            for k, _hubs in self.mode_tail_meta:
+                sel = np.nonzero(width == k)[0]
+                j = np.arange(k, dtype=np.int64)[None, :]
+                pieces.append(np.where(
+                    j < chunks[sel, None], first[sel, None] + j,
+                    self.tail_chunks,
+                ).reshape(-1))
+                order.append(vertex[sel])
+            unpermute = np.array(self.arrays["unpermute"], dtype=np.int32)
+            if order:
+                order = np.concatenate(order)
+                unpermute[order] = (
+                    self.num_vertices - len(order)
+                    + np.arange(len(order), dtype=np.int32)
+                )
+            self._mode_tables[np] = {
+                "mode_pieces": (
+                    np.concatenate(pieces) if pieces
+                    else np.zeros(0, dtype=np.int64)
+                ).astype(np.int32),
+                "mode_unpermute": unpermute,
+            }
+        if xp not in self._mode_tables:
+            self._mode_tables[xp] = {
+                k: xp.asarray(v) for k, v in self._mode_tables[np].items()
+            }
+        return self._mode_tables[xp]
+
     def row_first_slots(self) -> np.ndarray:
         """Position in the flat index vector of each row's first slot:
         torso rows bucket after bucket, then the tail's chunks."""
@@ -665,6 +767,7 @@ class HybridPackView:
     _STATIC = (
         "torso_meta", "torso_slots", "tail_meta", "tail_chunks",
         "tail_chunk", "table_rows", "num_zero", "has_weight", "slots",
+        "mode_tail_meta",
     )
     __slots__ = ("arrays",) + _STATIC
 
@@ -765,27 +868,214 @@ def hybrid_aggregate(
     per-destination monoid fold — and bitwise-identical results to it
     (both reduce through tree_reduce's fixed adjacent-pair tree)."""
     identity = Combiner.IDENTITY[op]
+    if op == Combiner.MODE:
+        _check_mode_messages(msgs, edge_transform, edge_transform_cols)
     pad_shape = (1,) + tuple(msgs.shape[1:])
-    msgs_ext = xp.concatenate(
-        [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
-    )
-    m = flat_take(xp, msgs_ext, pack.arrays["idx"])  # (slots[, k])
-    if pack.has_weight:
-        # mirrors the ELL weighted path slot-for-slot: transform first,
-        # then force the tail's padded slots back to the identity (a
-        # transform can disturb it, e.g. identity*0 = nan for MIN's +inf)
-        m = apply_edge_transform(
-            xp, m, pack.arrays["w"], edge_transform, edge_transform_cols
+    with superstep_scope(xp, "gather"):
+        msgs_ext = xp.concatenate(
+            [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
         )
-        ts = pack.torso_slots
-        valid = pack.arrays["valid"]
-        valid_ = valid[:, None] if m.ndim == 2 else valid
-        # same fence as the ELL weighted branch: the torso's unmasked
-        # weight product would otherwise contract into the tree
-        m = fp_fence(xp, xp.concatenate(
-            [m[:ts], xp.where(valid_ > 0, m[ts:], identity)], axis=0
-        ))
-    return hybrid_fold(xp, pack, m, op, msgs.shape, msgs.dtype)
+        m = flat_take(xp, msgs_ext, pack.arrays["idx"])  # (slots[, k])
+        # labels ride untransformed, and a weighted pack's padded slots
+        # index the sentinel like any other's: no mask for MODE
+        if pack.has_weight and op != Combiner.MODE:
+            # mirrors the ELL weighted path slot-for-slot: transform first,
+            # then force the tail's padded slots back to the identity (a
+            # transform can disturb it, e.g. identity*0 = nan for MIN's
+            # +inf)
+            m = apply_edge_transform(
+                xp, m, pack.arrays["w"], edge_transform, edge_transform_cols
+            )
+            ts = pack.torso_slots
+            valid = pack.arrays["valid"]
+            valid_ = valid[:, None] if m.ndim == 2 else valid
+            # same fence as the ELL weighted branch: the torso's unmasked
+            # weight product would otherwise contract into the tree
+            m = fp_fence(xp, xp.concatenate(
+                [m[:ts], xp.where(valid_ > 0, m[ts:], identity)], axis=0
+            ))
+    with superstep_scope(xp, "fold"):
+        if op == Combiner.MODE:
+            return hybrid_mode_fold(xp, pack, m)
+        return hybrid_fold(xp, pack, m, op, msgs.shape, msgs.dtype)
+
+
+# --------------------------------------------------------------------------
+# Combiner.MODE: whole-multiset folds (sort, run lengths, arg-max)
+# --------------------------------------------------------------------------
+
+def _check_mode_messages(msgs, edge_transform, edge_transform_cols) -> None:
+    if msgs.ndim != 1 or msgs.dtype.kind not in "iu":
+        raise ValueError(
+            "Combiner.MODE folds one integer label per vertex: messages "
+            f"must be a 1-D integer array, got {msgs.dtype}{msgs.shape}"
+        )
+    if edge_transform != EdgeTransform.NONE or edge_transform_cols:
+        raise ValueError(
+            "Combiner.MODE carries labels, which no edge transform applies to"
+        )
+
+
+# graphlint: traced -- called from the compiled MODE folds
+def _shifted(xp, a, k: int, axis: int, fill):
+    """`a` moved `k` places along `axis`: position i reads a[i - k], the
+    first k read `fill`."""
+    keep = [slice(None)] * a.ndim
+    keep[axis] = slice(0, a.shape[axis] - k)
+    head = list(a.shape)
+    head[axis] = k
+    return xp.concatenate(
+        [xp.full(head, fill, dtype=a.dtype), a[tuple(keep)]], axis=axis
+    )
+
+
+# graphlint: traced -- called from the compiled MODE folds
+def _run_lengths(xp, keys, axis: int, max_run: int = None):
+    """For arrays sorted along `axis` (lexicographically by `keys`, all
+    non-negative), each position's place in its run of equal keys, from 1:
+    a run's last position holds the run's length. By doubling: after the
+    round that looks `k` places back a position holds min(place, 2k), so
+    ceil(log2(width)) rounds of a shifted compare and an add, whatever the
+    values are (`max_run`, where the caller knows no run is longer, ends
+    the rounds earlier)."""
+    width = keys[0].shape[axis]
+    place = xp.ones(keys[0].shape, dtype=np.int32)
+    k = 1
+    while k < min(width, max_run or width):
+        same = None
+        for a in keys:
+            eq = _shifted(xp, a, k, axis, -1) == a  # -1 is no key
+            same = eq if same is None else same & eq
+        place = place + xp.where(same, _shifted(xp, place, k, axis, 0), 0)
+        k *= 2
+    return place
+
+
+# graphlint: traced -- the MODE fold of one block of whole rows
+def mode_along(xp, block, axis: int):
+    """Most frequent label along `axis` of an int32 block, the smallest on
+    ties; `Combiner.NO_MESSAGE` entries are padding and never win (a row
+    of nothing else gives NO_MESSAGE). A sort along the axis (XLA's), run
+    lengths by doubling, and the arg-max by (count, then smaller label):
+    no step's work depends on the labels' values (on the v5e the sort's
+    time does not either: PERF.md section 6, PR 28)."""
+    no = Combiner.NO_MESSAGE
+    if _is_jax(xp):
+        import jax
+
+        s = jax.lax.sort(block, dimension=axis, is_stable=False)
+    else:
+        s = xp.sort(block, axis=axis)
+    count = xp.where(s == no, 0, _run_lengths(xp, (s,), axis))
+    best = count.max(axis=axis, keepdims=True)
+    return xp.where(count == best, s, no).min(axis=axis)
+
+
+# graphlint: traced -- the flat MODE fold (segment path, ELL split rows)
+def segment_mode(xp, labels, owners, num_owners: int, max_run: int = None):
+    """MODE of `labels` grouped by `owners` (any order, both 1-D int32):
+    one sort of the (owner, label) pairs, run lengths over the sorted
+    pairs, and per owner the arg-max by (count, then smaller label) — each
+    owner's whole multiset at once. An owner without labels, or with
+    NO_MESSAGE padding only, reads NO_MESSAGE."""
+    no = Combiner.NO_MESSAGE
+    if _is_jax(xp):
+        import jax
+
+        o, s = jax.lax.sort((owners, labels), num_keys=2, is_stable=False)
+        seg_max, seg_min = jax.ops.segment_max, jax.ops.segment_min
+    else:
+        o, s, seg_max, seg_min = _segment_mode_host(labels, owners)
+    count = xp.where(s == no, 0, _run_lengths(xp, (o, s), 0, max_run))
+    best = seg_max(count, o, num_segments=num_owners)
+    return seg_min(
+        xp.where(count == best[o], s, no), o, num_segments=num_owners
+    )
+
+
+# graphlint: host -- numpy-only branch, unreachable from traced code
+def _segment_mode_host(labels, owners):
+    """segment_mode's numpy side: the pairs sorted, and the two per-owner
+    folds with `jax.ops.segment_*`'s signature and empty-segment values."""
+    def fold(ufunc, empty):
+        def run(values, seg, num_segments):
+            out = np.full(num_segments, empty, dtype=values.dtype)
+            ufunc.at(out, seg, values)
+            return out
+        return run
+
+    order = np.lexsort((labels, owners))
+    return (
+        owners[order], labels[order],
+        fold(np.maximum, 0), fold(np.minimum, Combiner.NO_MESSAGE),
+    )
+
+
+# graphlint: traced -- the hybrid pack's MODE fold
+def hybrid_mode_fold(xp, pack, leaves):
+    """MODE over a HybridPack's gathered int32 `leaves` (slots,), padding
+    slots holding NO_MESSAGE. Torso: the buckets of one pow2 width are
+    sentinel-padded up to it in-kernel and laid side by side, as in
+    `hybrid_fold`, and each (width, rows) block is folded by `mode_along`.
+    Tail: a hub's chunks — of every row the pack split it into — are
+    brought together as ONE row (`HybridPack.mode_tables`: a gather of
+    whole 1-KB chunks by a static table) and folded whole; no chunk or row
+    partial is ever merged."""
+    no = Combiner.NO_MESSAGE
+    tables = (
+        pack.arrays if "mode_pieces" in pack.arrays else pack.mode_tables()
+    )
+    parts = []
+    off = 0
+    for cap, group in itertools.groupby(pack.torso_meta, key=lambda t: t[1]):
+        blocks = []
+        for d, _cap, rows in group:
+            m = leaves[off:off + d * rows].reshape((d, rows))
+            off += d * rows
+            if cap > d:
+                m = xp.pad(m, [(0, cap - d), (0, 0)], constant_values=no)
+            blocks.append(m)
+        m = blocks[0] if len(blocks) == 1 else xp.concatenate(blocks, axis=1)
+        parts.append(mode_along(xp, m, 0))
+    if pack.num_zero:
+        parts.append(xp.full((pack.num_zero,), no, dtype=leaves.dtype))
+    if pack.tail_chunks:
+        T = pack.tail_chunk
+        chunks = leaves[off:off + T * pack.tail_chunks].reshape(
+            (T, pack.tail_chunks)
+        ).T
+        chunks = xp.concatenate(
+            [chunks, xp.full((1, T), no, dtype=leaves.dtype)], axis=0
+        )
+        poff = 0
+        for k, hubs in pack.mode_tail_meta:
+            piece = tables["mode_pieces"][poff:poff + k * hubs]
+            poff += k * hubs
+            rows = xp.take(chunks, piece, axis=0).reshape((hubs, k * T))
+            parts.append(mode_along(xp, rows, 1))
+    if not parts:
+        return xp.full((0,), no, dtype=leaves.dtype)
+    stacked = xp.concatenate(parts, axis=0)
+    return stacked[tables["mode_unpermute"]]
+
+
+def mode_fold_sizes(pack) -> dict:
+    """What a MODE run folds, as the pack knows it (static numbers for the
+    run record): slots folded block by block, slots of destinations that
+    are brought together first, and how many such destinations."""
+    if hasattr(pack, "torso_slots"):  # HybridPack
+        return {
+            "torso_slots": int(pack.torso_slots),
+            "tail_slots": int(pack.tail_chunks * pack.tail_chunk),
+            "rows_folded_whole": int(sum(h for _k, h in pack.mode_tail_meta)),
+        }
+    split = [b for b in pack.buckets if b[3] is not None]
+    tail = sum(int(np.prod(b[0].shape)) for b in split)
+    return {
+        "torso_slots": int(pack.slots) - tail,
+        "tail_slots": tail,
+        "rows_folded_whole": int(sum(b[4] for b in split)),
+    }
 
 
 # --------------------------------------------------------------------------

@@ -23,6 +23,7 @@ import numpy as np
 from janusgraph_tpu.observability import registry, tracer
 from janusgraph_tpu.olap.csr import CSRGraph
 from janusgraph_tpu.olap.device import await_arrays
+from janusgraph_tpu.olap.kernels import superstep_scope
 from janusgraph_tpu.olap.vertex_program import (
     Combiner,
     EdgeTransform,
@@ -202,11 +203,11 @@ def _pytree_nbytes(tree) -> int:
 def _segment_reduce(jnp, op: str, data, segment_ids, num_segments: int):
     import jax
 
-    if op == Combiner.SUM:
-        return jax.ops.segment_sum(data, segment_ids, num_segments=num_segments)
-    if op == Combiner.MIN:
-        return jax.ops.segment_min(data, segment_ids, num_segments=num_segments)
-    return jax.ops.segment_max(data, segment_ids, num_segments=num_segments)
+    seg_fn = Combiner.monoid(
+        op, "the flat segment-reduce",
+        jax.ops.segment_sum, jax.ops.segment_min, jax.ops.segment_max,
+    )
+    return seg_fn(data, segment_ids, num_segments=num_segments)
 
 
 class TPUExecutor:
@@ -215,12 +216,12 @@ class TPUExecutor:
 
     `strategy` selects the aggregation kernel (janusgraph_tpu/olap/kernels.py):
       - "ell"     degree-bucketed ELLPACK gather + dense reduce
-                  (scatter-free, all monoids)
+                  (scatter-free, every combiner)
       - "hybrid"  exact-width ELL torso + chunked CSR tail for hubs
                   (bitwise-equal to "ell", pad ratio ~1)
-      - "segment" XLA gather + segment-reduce
-      - "pallas"  Pallas sorted-segment-sum kernel (SUM monoid; other
-                  monoids fall back to "ell")
+      - "segment" XLA gather + segment-reduce (MODE: one segmented sort)
+      - "pallas"  Pallas sorted-segment-sum kernel (SUM monoid; MIN and
+                  MAX fall back to "ell", MODE is refused)
       - "auto"    (default) the profiler-driven autotuner picks among
                   ell/hybrid/segment from the degree histogram + device
                   roofline (olap/autotune.py; decision recorded in
@@ -718,6 +719,10 @@ class TPUExecutor:
         SUM-only, everything else falls back to ELL."""
         base = self._base_strategy(undirected)
         if base == "pallas" and op != Combiner.SUM:
+            # the kernel accumulates block partials into its output tile
+            Combiner.require_foldable(
+                op, "the Pallas sorted-segment-sum strategy"
+            )
             return "ell"
         return base
 
@@ -773,7 +778,7 @@ class TPUExecutor:
             args["ell"] = self._pack_args(pack)
             args["unpermute"] = pack.unpermute
         elif strategy == "hybrid":
-            args["hyb"] = dict(pack.arrays)
+            args["hyb"] = self._hybrid_args(pack, op)
         elif strategy == "pallas":
             args["pallas"] = self._pallas_args(program)
         if getattr(program, "message_mode", None) == "sddmm" and strategy in (
@@ -812,6 +817,15 @@ class TPUExecutor:
         self._viewkeys[key] = used
         return used
 
+    def _hybrid_args(self, pack, op: str) -> dict:
+        """The hybrid pack's device arrays as jit arguments; a MODE run
+        also gets the pack's whole-row tables (kernels.HybridPack
+        .mode_tables), shipped once and to MODE programs only, so a monoid
+        program's executable keeps its signature."""
+        if op != Combiner.MODE:
+            return dict(pack.arrays)
+        return {**pack.arrays, **pack.mode_tables(self.jnp)}
+
     @staticmethod
     def _pack_args(pack):
         buckets = []
@@ -846,7 +860,7 @@ class TPUExecutor:
             args["ell"] = self._pack_args(pack)
             args["unpermute"] = pack.unpermute
         elif strategy == "hybrid":
-            args["hyb"] = dict(pack.arrays)
+            args["hyb"] = self._hybrid_args(pack, op)
         elif strategy == "pallas":
             args["pallas"] = self._pallas_args(program)
         if getattr(program, "message_mode", None) == "sddmm" and strategy in (
@@ -912,6 +926,12 @@ class TPUExecutor:
                 delta.lanes(bool(program.undirected))["_meta"]
             )
         strategy, pack_meta = self._resolve_pack(program, op, channel)
+        if op == Combiner.MODE and strategy not in ("ell", "hybrid"):
+            # flat path: no destination has more messages than this
+            deg = np.diff(self.csr.in_indptr)
+            if program.undirected:
+                deg = deg + np.diff(self.csr.out_indptr)
+            longest_run = int(deg.max()) if len(deg) else 1
         if strategy == "pallas":
             plans = [("in", self._segsum_plan("in"))]
             if program.undirected:
@@ -923,11 +943,26 @@ class TPUExecutor:
         # metadata only (bucket widths/rows); arrays arrive via gargs
 
         def aggregate(outgoing, src_idx, dst_seg, weight):
-            msgs = apply_edge_transform(
-                jnp, outgoing[src_idx], weight,
-                program.edge_transform, program.edge_transform_cols,
-            )
-            return _segment_reduce(jnp, op, msgs, dst_seg, nb)
+            with superstep_scope(jnp, "gather"):
+                msgs = apply_edge_transform(
+                    jnp, outgoing[src_idx], weight,
+                    program.edge_transform, program.edge_transform_cols,
+                )
+            with superstep_scope(jnp, "fold"):
+                return _segment_reduce(jnp, op, msgs, dst_seg, nb)
+
+        def mode_aggregate(outgoing, gv):
+            """The flat path's MODE: the labels along both orientations
+            laid end to end, and one segmented sort over all of them."""
+            from janusgraph_tpu.olap.kernels import segment_mode
+
+            with superstep_scope(jnp, "gather"):
+                labels, owners = outgoing[gv.in_src], gv.in_dst_seg
+                if program.undirected:
+                    labels = jnp.concatenate([labels, outgoing[gv.out_dst]])
+                    owners = jnp.concatenate([owners, gv.out_src_seg])
+            with superstep_scope(jnp, "fold"):
+                return segment_mode(jnp, labels, owners, nb, longest_run)
 
         def pallas_aggregate(outgoing, gv, plan_args):
             from janusgraph_tpu.olap.kernels import pallas_sorted_segment_sum
@@ -937,26 +972,35 @@ class TPUExecutor:
                     src_idx, weight = gv.in_src, gv.in_edge_weight
                 else:
                     src_idx, weight = gv.out_dst, gv.out_edge_weight
-                msgs = outgoing[src_idx]
-                if program.edge_transform == EdgeTransform.MUL_WEIGHT and weight is not None:
-                    msgs = msgs * weight
-                elif program.edge_transform == EdgeTransform.ADD_WEIGHT and weight is not None:
-                    msgs = msgs + weight
-                return pallas_sorted_segment_sum(
-                    msgs, plan, plan_args[orientation],
-                    interpret=self._interpret,
-                )
+                with superstep_scope(jnp, "gather"):
+                    msgs = outgoing[src_idx]
+                    if program.edge_transform == EdgeTransform.MUL_WEIGHT and weight is not None:
+                        msgs = msgs * weight
+                    elif program.edge_transform == EdgeTransform.ADD_WEIGHT and weight is not None:
+                        msgs = msgs + weight
+                with superstep_scope(jnp, "fold"):
+                    return pallas_sorted_segment_sum(
+                        msgs, plan, plan_args[orientation],
+                        interpret=self._interpret,
+                    )
 
             total = one(*plans[0])
             for orientation, plan in plans[1:]:
-                total = total + one(orientation, plan)
+                other = one(orientation, plan)
+                with superstep_scope(jnp, "fold"):
+                    total = total + other
             return total
 
         def superstep(state, superstep_idx, memory_in, gargs):
             gv = _TracedView(tmpl, gargs["view"], self._view_record)
             from janusgraph_tpu.olap.kernels import ell_aggregate
 
-            full_out = program.message(state, superstep_idx, gv, jnp)
+            # the four stages are named for the device profile (none
+            # encloses another; the packs' aggregations name their own
+            # gather and fold, the fused sddmm kernels interleave the two
+            # and name neither)
+            with superstep_scope(jnp, "message"):
+                full_out = program.message(state, superstep_idx, gv, jnp)
             # base aggregation consumes the base-row slice: the packs'
             # sentinel (index n_base) must keep reading the identity
             outgoing = full_out if delta is None else full_out[:nb]
@@ -1010,6 +1054,8 @@ class TPUExecutor:
                 )
             elif strategy == "pallas" and outgoing.ndim == 1:
                 agg = pallas_aggregate(outgoing, gv, gargs["pallas"])
+            elif op == Combiner.MODE:
+                agg = mode_aggregate(outgoing, gv)
             else:
                 agg = aggregate(
                     outgoing, gv.in_src, gv.in_dst_seg, gv.in_edge_weight
@@ -1018,12 +1064,11 @@ class TPUExecutor:
                     rev = aggregate(
                         outgoing, gv.out_dst, gv.out_src_seg, gv.out_edge_weight
                     )
-                    if op == Combiner.SUM:
-                        agg = agg + rev
-                    elif op == Combiner.MIN:
-                        agg = jnp.minimum(agg, rev)
-                    else:
-                        agg = jnp.maximum(agg, rev)
+                    with superstep_scope(jnp, "fold"):
+                        agg = Combiner.monoid(
+                            op, "the flat path's merge of two orientations",
+                            jnp.add, jnp.minimum, jnp.maximum,
+                        )(agg, rev)
             if delta is not None:
                 # fuse the overlay lanes over the base aggregate (SUM:
                 # add - tombstone subtraction; MIN/MAX: dirty rows
@@ -1032,14 +1077,16 @@ class TPUExecutor:
                     fused_delta_aggregate,
                 )
 
-                agg = fused_delta_aggregate(
-                    jnp, gargs["delta"], dmeta, full_out, agg, op
-                )
+                with superstep_scope(jnp, "fold"):
+                    agg = fused_delta_aggregate(
+                        jnp, gargs["delta"], dmeta, full_out, agg, op
+                    )
             # vertices with no in-edges hold the identity, matching the CPU
             # oracle's "no message received" semantics
-            new_state, metrics = program.apply(
-                state, agg, superstep_idx, memory_in, gv, jnp
-            )
+            with superstep_scope(jnp, "apply"):
+                new_state, metrics = program.apply(
+                    state, agg, superstep_idx, memory_in, gv, jnp
+                )
             self._metric_ops[(program.cache_key(), op)] = {
                 k: o for k, (o, _v) in metrics.items()
             }
@@ -1215,6 +1262,10 @@ class TPUExecutor:
                 program_delta_compatible,
             )
 
+            # the overlay merges lane partials into the base aggregate
+            Combiner.require_foldable(
+                program.combiner, "the fused delta overlay"
+            )
             if not program_delta_compatible(program):
                 raise ValueError(
                     "delta-fused runs support default-edge-view programs "
@@ -1397,6 +1448,21 @@ class TPUExecutor:
             )
         if strategy_resolved is not None:
             info["strategy_resolved"] = strategy_resolved
+        # the combiner(s) the run folded with; a MODE run also says what
+        # its fold was given, as the pack knows it (static numbers)
+        info["combiner"] = "+".join(dict.fromkeys(
+            r.get("combiner", program.combiner)
+            for r in info.get("superstep_records") or [{}]
+        ))
+        if program.combiner == Combiner.MODE:
+            from janusgraph_tpu.olap.kernels import mode_fold_sizes
+
+            edges = self.csr.num_edges * (2 if undirected else 1)
+            info["mode_fold"] = (
+                mode_fold_sizes(hyb or pack) if (hyb or pack) is not None
+                else {"torso_slots": 0, "tail_slots": edges,
+                      "rows_folded_whole": self.csr.num_vertices}
+            )
         # the tuner's decision travels with every run record (bench +
         # /telemetry read it from here); explicit strategies still record
         # a source="config" decision for provenance
@@ -1964,7 +2030,9 @@ def write_back(graph, csr: CSRGraph, result: Dict[str, np.ndarray], keys=None, b
     vertex, batched mutate_many per chunk, bulk relation-id spans — which is
     the batch-loading semantics the reference reserves for its bulk mode).
     Indexed or non-SINGLE keys fall back to the transactional path so index
-    maintenance stays correct.
+    maintenance stays correct, and so does an integer state (CDLP's int32
+    labels): its key is made with data type int and every value is written
+    as a Python int, never through a float.
     """
     from janusgraph_tpu.core.codecs import Cardinality
 
@@ -1972,7 +2040,8 @@ def write_back(graph, csr: CSRGraph, result: Dict[str, np.ndarray], keys=None, b
     names = list(result.keys() if keys is None else keys)
     for name in names:
         if graph.schema_cache.get_by_name(name) is None:
-            mgmt.make_property_key(name, float)
+            integral = np.issubdtype(np.asarray(result[name]).dtype, np.integer)
+            mgmt.make_property_key(name, int if integral else float)
     vids = csr.vertex_ids
     for name in names:
         pk = graph.schema_cache.get_by_name(name)
@@ -1987,13 +2056,16 @@ def write_back(graph, csr: CSRGraph, result: Dict[str, np.ndarray], keys=None, b
 
 
 def _write_back_tx(graph, vids, name, values, batch: int) -> None:
-    values = np.asarray(values, dtype=np.float64)
+    values = np.asarray(values)
+    if not np.issubdtype(values.dtype, np.integer):
+        values = values.astype(np.float64)
+    values = values.tolist()  # Python ints or floats, as the dtype says
     for lo in range(0, len(vids), batch):
         tx = graph.new_transaction(read_only=False)  # write-back writes
         for i in range(lo, min(lo + batch, len(vids))):
             v = tx.get_vertex(int(vids[i]))
             if v is not None:
-                v.property(name, float(values[i]))
+                v.property(name, values[i])
         tx.commit()
 
 

@@ -9,11 +9,14 @@ global aggregators), re-designed as an **array-BSP** model: a superstep is
     aggregated[i] = combine({ transform(message(src), w_e) for e=(src, i) })
     state', metrics = apply(state, aggregated, superstep, memory)
 
-with `combine` a segment-reduction monoid and per-vertex state a dict of
-dense arrays. This restriction (fixed-width numeric messages with monoid
-combiners — SURVEY.md §7 hard part (b)) makes message passing one
-segment-reduce / SpMV instead of the reference's NonBlockingHashMapLong
-churn; every BASELINE workload fits it.
+with `combine` a `Combiner` and per-vertex state a dict of dense arrays.
+SUM, MIN and MAX are segment-reduction monoids: an executor may fold a
+destination's messages in any grouping (pack chunks, split rows, shards)
+and combine the partial aggregates. MODE is not: it needs a destination's
+WHOLE multiset at once (see `Combiner`). This restriction (fixed-width
+numeric messages — SURVEY.md §7 hard part (b)) makes message passing one
+segment-reduce / SpMV, or one sort per destination, instead of the
+reference's NonBlockingHashMapLong churn; every BASELINE workload fits it.
 
 jit/psum-compatible by construction:
 - programs never mutate host state inside the superstep; global aggregators
@@ -39,13 +42,60 @@ from typing import Dict, Mapping, Optional, Tuple
 
 
 class Combiner:
-    """Message combination monoids (reference: MessageCombiner)."""
+    """How the messages a vertex receives in one superstep become its
+    aggregate (reference: MessageCombiner).
+
+    SUM / MIN / MAX are monoids over float messages: partial aggregates
+    combine, and `IDENTITY[op]` is what a vertex without messages reads.
+
+    MODE is the most frequent label among ALL the messages a destination
+    receives in the superstep, the smallest such label on ties. Labels are
+    int32 in [0, NO_MESSAGE) end to end (no float cast anywhere); a vertex
+    that receives nothing reads `NO_MESSAGE`, which also pads packs (a
+    padded slot carries it and never wins). It is the one combiner that
+    CANNOT be folded from partial aggregates: two partial modes do not
+    give the mode of the union. Every executor path either folds each
+    destination's whole multiset or raises through `monoid` /
+    `require_foldable`, naming the combiner and the path."""
 
     SUM = "sum"
     MIN = "min"
     MAX = "max"
+    MODE = "mode"
 
-    IDENTITY = {"sum": 0.0, "min": float("inf"), "max": float("-inf")}
+    #: MODE's "no message" and padding value: the largest int32
+    NO_MESSAGE = 2**31 - 1
+
+    IDENTITY = {
+        "sum": 0.0, "min": float("inf"), "max": float("-inf"),
+        "mode": NO_MESSAGE,
+    }
+
+    @staticmethod
+    def monoid(op: str, path: str, sum_, min_, max_):
+        """Pick a monoid's implementation by name — the one place an
+        `if SUM / if MIN / else` chain ends: an op that is none of the
+        three raises instead of being computed as a maximum."""
+        if op == Combiner.SUM:
+            return sum_
+        if op == Combiner.MIN:
+            return min_
+        if op == Combiner.MAX:
+            return max_
+        Combiner.require_foldable(op, path)
+        raise ValueError(f"unknown combiner {op!r} in {path}")
+
+    @staticmethod
+    def require_foldable(op: str, path: str) -> None:
+        """Raise for a combiner that `path` would fold from partials."""
+        if op == Combiner.MODE:
+            raise ValueError(
+                f"Combiner.MODE ({op!r}) needs each destination's whole "
+                f"multiset of messages and cannot be folded from partial "
+                f"aggregates: {path} combines partials — run it on the "
+                "single-device executor (executor='tpu' or 'cpu', strategy "
+                "auto/hybrid/ell/segment)"
+            )
 
 
 class EdgeTransform:
@@ -181,7 +231,7 @@ class VertexProgram:
 
     Class attributes:
       compute_keys    — state entries that write-back persists as properties
-      combiner        — Combiner monoid (or override combiner_for per phase)
+      combiner        — Combiner (or override combiner_for per phase)
       edge_transform  — EdgeTransform applied to messages in flight
       edge_transform_cols — per-COLUMN EdgeTransforms for 2-D messages
                         (overrides edge_transform; the substrate for

@@ -496,16 +496,14 @@ def build_ell(plan: BlockedPlan, has_weight: bool) -> None:
 
 
 def _seg_reduce_np(op: str, data, seg, n: int):
-    tail = data.shape[1:]
-    if op == Combiner.SUM:
-        acc = np.zeros((n,) + tail, dtype=data.dtype)
-        np.add.at(acc, seg, data)
-    elif op == Combiner.MIN:
-        acc = np.full((n,) + tail, np.inf, dtype=data.dtype)
-        np.minimum.at(acc, seg, data)
-    else:
-        acc = np.full((n,) + tail, -np.inf, dtype=data.dtype)
-        np.maximum.at(acc, seg, data)
+    ufunc = Combiner.monoid(
+        op, "the halo exchange (it pre-combines partials per shard)",
+        np.add, np.minimum, np.maximum,
+    )
+    acc = np.full(
+        (n,) + data.shape[1:], Combiner.IDENTITY[op], dtype=data.dtype
+    )
+    ufunc.at(acc, seg, data)
     return acc
 
 
@@ -525,6 +523,9 @@ def replay_superstep(
     np.minimum.at match XLA CPU scatter reductions bitwise and
     tree_reduce is xp-generic, which makes this the blocked path's CPU
     oracle for BOTH aggregation formats."""
+    Combiner.require_foldable(
+        op, "the halo exchange (it pre-combines partials per shard)"
+    )
     S, Np, Eq, Hc = (
         plan.num_shards, plan.shard_size, plan.edges_per_owner,
         plan.halo_cap,
@@ -612,12 +613,10 @@ def replay_superstep(
         remote = _seg_reduce_np(
             op, recv, plan.recv_dst[rbase : rbase + S * Hc], Np + 1
         )[:Np]
-        if op == Combiner.SUM:
-            out[s * Np : (s + 1) * Np] = local_parts[s] + remote
-        elif op == Combiner.MIN:
-            out[s * Np : (s + 1) * Np] = np.minimum(local_parts[s], remote)
-        else:
-            out[s * Np : (s + 1) * Np] = np.maximum(local_parts[s], remote)
+        out[s * Np : (s + 1) * Np] = Combiner.monoid(
+            op, "the halo exchange (it pre-combines partials per shard)",
+            np.add, np.minimum, np.maximum,
+        )(local_parts[s], remote)
     return out
 
 

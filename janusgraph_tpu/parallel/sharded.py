@@ -940,22 +940,24 @@ class ShardedExecutor:
         B = sc.boundary_width if exchange == "a2a" else 0
         Hc = sc.halo_cap if exchange == "blocked" else 0
 
+        # every fold below merges partial aggregates (edge blocks, halo
+        # bins, shards): a monoid's, picked once; MODE is refused here
+        path = "the sharded executor"
+        seg_fn = Combiner.monoid(
+            op, path,
+            jax.ops.segment_sum, jax.ops.segment_min, jax.ops.segment_max,
+        )
+        reduce_fn = Combiner.monoid(op, path, jnp.sum, jnp.min, jnp.max)
+        merge = Combiner.monoid(op, path, jnp.add, jnp.minimum, jnp.maximum)
+
         def seg_reduce_n(data, seg, n):
-            if op == Combiner.SUM:
-                return jax.ops.segment_sum(data, seg, num_segments=n)
-            if op == Combiner.MIN:
-                return jax.ops.segment_min(data, seg, num_segments=n)
-            return jax.ops.segment_max(data, seg, num_segments=n)
+            return seg_fn(data, seg, num_segments=n)
 
         def seg_reduce(data, seg):
             return seg_reduce_n(data, seg, Np)
 
         def reduce_cols(m, axis_):
-            if op == Combiner.SUM:
-                return m.sum(axis=axis_)
-            if op == Combiner.MIN:
-                return m.min(axis=axis_)
-            return m.max(axis=axis_)
+            return reduce_fn(m, axis=axis_)
 
         if exchange == "ring":
             sc.ensure_ring()
@@ -989,12 +991,7 @@ class ShardedExecutor:
                 )
                 mask = valid[:, None] if msgs.ndim == 2 else valid
                 msgs = jnp.where(mask > 0, msgs, identity)
-                part = seg_reduce(msgs, dst)
-                if op == Combiner.SUM:
-                    return acc + part
-                if op == Combiner.MIN:
-                    return jnp.minimum(acc, part)
-                return jnp.maximum(acc, part)
+                return merge(acc, seg_reduce(msgs, dst))
 
             # own block folds before any hop, so only S-1 ppermutes fire —
             # the final rotation (returning blocks home) would be dead comm
@@ -1103,12 +1100,7 @@ class ShardedExecutor:
                 remote = seg_reduce_n(
                     recv.reshape((S * Hc,) + tail), g["recv_dst"], Np + 1
                 )[:Np]
-                if op == Combiner.SUM:
-                    agg_v = local_part + remote
-                elif op == Combiner.MIN:
-                    agg_v = jnp.minimum(local_part, remote)
-                else:
-                    agg_v = jnp.maximum(local_part, remote)
+                agg_v = merge(local_part, remote)
                 return _apply_and_reduce(state, agg_v, step, memory_in, view)
 
             # ---- exchange: build the message table this shard reads from
@@ -1176,12 +1168,10 @@ class ShardedExecutor:
             # barrier: global aggregator reduction over the mesh
             reduced = {}
             for k, (mop, v) in metrics.items():
-                if mop == Combiner.SUM:
-                    reduced[k] = jax.lax.psum(v, axis)
-                elif mop == Combiner.MIN:
-                    reduced[k] = jax.lax.pmin(v, axis)
-                else:
-                    reduced[k] = jax.lax.pmax(v, axis)
+                reduced[k] = Combiner.monoid(
+                    mop, "the mesh barrier of global aggregators",
+                    jax.lax.psum, jax.lax.pmin, jax.lax.pmax,
+                )(v, axis)
             return new_state, reduced
 
         return body
@@ -1696,6 +1686,8 @@ class ShardedExecutor:
         )
 
         check_weighted_transforms(program, self.csr)
+        # every exchange pre-combines partial aggregates across shards
+        Combiner.require_foldable(program.combiner, "the sharded executor")
         if frontier not in ("auto", "off", "always"):
             raise ValueError(f"unknown frontier mode: {frontier!r}")
         if not getattr(program, "sharded_compatible", True):
