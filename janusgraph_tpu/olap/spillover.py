@@ -33,7 +33,10 @@ recurring expensive shapes onto the OLAP executor:
   (counter ``olap.spillover.stale`` — the bounded-staleness groundwork
   for the streaming delta-CSR item). ``computer.sharded-auto`` routes
   multi-device processes to the sharded executor exactly like
-  ``graph.compute()``.
+  ``graph.compute()``. A chain from explicit ``V(ids)`` takes its first
+  hop on the host (:func:`host_seed_hop`: the seeds' CSR rows, summed
+  by neighbour) and starts the device program at hop 1 with the arrival
+  counts as its seed mask; any other start keeps the dense hop 0.
 
 - **Tx-overlay reconciliation** (read-your-writes): the transaction's
   uncommitted adds/deletes — the existence-cell machinery already sees
@@ -340,6 +343,59 @@ def patched_csr(csr, overlay):
     return patched
 
 
+# ------------------------------------------------------------ the seed hop
+#: the seeds' rows may hold this share of the view's edges and hop 0 still run on the host: numpy walks them at 11-22 ns an edge, the device gathers ALL the view's edges at 6.6-7.5 ns a slot
+_SEED_HOP_HOST_MAX_SHARE = 0.125
+
+
+def host_seed_hop(csr, idx, mult, step):
+    """Hop 0 of a chain from explicit seeds, read off the seeds' CSR rows:
+    ``(targets, weights, edges walked)``, or None where the device's dense
+    hop 0 stays (per-edge types missing for a labelled hop, rows that
+    cover the view, or counts a float32 could not hold exactly).
+
+    ``idx`` are the seeds' vertex indices and ``mult`` their
+    multiplicities; every neighbour over the step's (direction, labels)
+    view receives its seed's multiplicity, one entry of ``targets`` /
+    ``weights`` per edge, so their sum by target is the integer vector the
+    dense superstep computes from the same rows: ``out`` walks the
+    out-rows, ``in`` the in-rows, ``both`` the two (a self loop twice, a
+    parallel edge once per copy). Nothing here is as long as the vertex
+    set: in the served process a fresh page costs 3 us."""
+    import numpy as np
+
+    sides = []
+    if step.direction in ("out", "both"):
+        sides.append((csr.out_indptr, csr.out_dst, csr.out_edge_type))
+    if step.direction in ("in", "both"):
+        sides.append((csr.in_indptr, csr.in_src, csr.in_edge_type))
+    if step.labels is not None and any(t is None for _, _, t in sides):
+        return None
+    rows = [
+        (indptr[idx], indptr[idx + 1] - indptr[idx]) for indptr, _, _ in sides
+    ]
+    walked = int(sum(degs.sum() for _, degs in rows))
+    if walked >= _SEED_HOP_HOST_MAX_SHARE * len(sides) * csr.num_edges:
+        return None
+    if sum(float(mult @ degs) for _, degs in rows) >= float(1 << 24):
+        return None
+    targets, weights = [], []
+    for (_, nbr, types), (starts, degs) in zip(sides, rows):
+        # the rows' slots, one after the other: each row's start, repeated,
+        # plus the offset inside the row
+        before = np.cumsum(degs) - degs
+        pos = np.repeat(starts - before, degs) + np.arange(degs.sum())
+        weight = np.repeat(mult, degs)
+        if step.labels is not None:
+            keep = np.isin(
+                types[pos], np.asarray(step.labels, dtype=types.dtype)
+            )
+            pos, weight = pos[keep], weight[keep]
+        targets.append(nbr[pos])
+        weights.append(weight)
+    return np.concatenate(targets), np.concatenate(weights), walked
+
+
 # ----------------------------------------------------------------- planner
 class SpilloverPlanner:
     """Per-graph spillover state: cached snapshot + epoch, promotion set,
@@ -571,7 +627,7 @@ class SpilloverPlanner:
             if overlay["size"] > self.max_overlay:
                 raise _SpillRefused("overlay-overflow")
             csr = patched_csr(base, overlay)
-            program = self._compile(plan, csr, overlay)
+            program, seed_hop_edges = self._compile(plan, csr, overlay)
             _deadline.check("spillover run")
             with tracer.span(
                 "olap.spillover", digest=plan.digest, hops=len(plan.hops),
@@ -592,12 +648,14 @@ class SpilloverPlanner:
         wall_ms = (time.perf_counter() - t0) * 1000.0
         with tracer.phase("spill.publish"):
             self._publish(
-                plan, terminal, overlay, packed_epoch, wall_ms, total
+                plan, terminal, overlay, packed_epoch, wall_ms, total,
+                seed_hop_edges,
             )
         return result
 
     def _publish(
-        self, plan, terminal, overlay, packed_epoch, wall_ms, total
+        self, plan, terminal, overlay, packed_epoch, wall_ms, total,
+        seed_hop_edges,
     ) -> None:
         """The spilled execution still feeds the digest table (the
         shape's new, cheap reality) and the ambient span, like the row
@@ -619,10 +677,14 @@ class SpilloverPlanner:
         registry.counter("olap.spillover.spilled").inc()
         # graphlint: disable=JG110 -- digest is bounded by the top-K-evicted price book (metrics.digest-top-k) that feeds promotion
         registry.counter(f"olap.spillover.spilled.{plan.digest}").inc()
+        if seed_hop_edges is not None:
+            registry.counter("olap.spillover.seed_hop_host").inc()
         block = {
             "digest": plan.digest,
             "shape": plan.shape,
             "hops": len(plan.hops),
+            "seed_hop": "device" if seed_hop_edges is None else "host",
+            "seed_hop_edges": seed_hop_edges or 0,
             "reducer": self._reducer_name(plan, terminal),
             "overlay": {
                 "added": len(overlay["added"]),
@@ -659,6 +721,10 @@ class SpilloverPlanner:
         return ">".join(parts) if parts else "vertices"
 
     def _compile(self, plan: SpilloverPlan, csr, overlay):
+        """(program, edges the host walked for hop 0 or None): a chain of
+        two hops or more from explicit ids has hop 0 expanded here
+        (:func:`host_seed_hop`) and hands the device the hops that
+        remain."""
         import numpy as np
 
         from janusgraph_tpu.olap.programs.olap_traversal import (
@@ -675,6 +741,7 @@ class SpilloverPlanner:
             raise _SpillRefused("unknown-edge-label")
         n = csr.num_vertices
         seed_mask = None
+        seed_rows = []
         if plan.seed_ids is not None:
             seed_mask = np.zeros(n, dtype=np.float32)
             for vid in plan.seed_ids:
@@ -685,6 +752,7 @@ class SpilloverPlanner:
                     # V(1, 1) seeds two traversers: the mask carries
                     # MULTIPLICITY, not membership
                     seed_mask[i] += 1.0
+                    seed_rows.append(i)
         if plan.seed_labels:
             lm = self._label_mask(csr, plan.seed_labels)
             seed_mask = lm if seed_mask is None else seed_mask * lm
@@ -704,9 +772,26 @@ class SpilloverPlanner:
                 for _, _, vlabels in plan.hops
             ]
             step_masks = np.stack(cols, axis=1)
-        return OLAPTraversalProgram(
+        seed_hop_edges = None
+        if plan.seed_ids is not None and len(steps) > 1:
+            # a seed the label mask or the overlay zeroed walks its row
+            # with weight 0
+            idx = np.asarray(sorted(set(seed_rows)), dtype=np.int64)
+            hop = host_seed_hop(csr, idx, seed_mask[idx], steps[0])
+            if hop is not None:
+                targets, weights, seed_hop_edges = hop
+                # the seeds' vector becomes the arrivals' in place (every
+                # partial sum an integer below 2^24: exact in float32)
+                seed_mask[idx] = 0.0
+                np.add.at(seed_mask, targets, weights)
+                steps = steps[1:]
+                if step_masks is not None:
+                    seed_mask *= step_masks[:, 0]
+                    step_masks = step_masks[:, 1:]
+        program = OLAPTraversalProgram(
             steps, seed_mask=seed_mask, step_masks=step_masks
         )
+        return program, seed_hop_edges
 
     def _label_mask(self, csr, label_groups):
         """AND over has_label() groups: each group is an OR of vertex

@@ -30,18 +30,18 @@ SPILL_CFG = {
 }
 
 
-def _social_graph(extra_cfg=None):
+def _social_graph(extra_cfg=None, n_people=12, n_places=3):
     g = open_graph({**SPILL_CFG, **(extra_cfg or {})})
     tx = g.new_transaction()
-    people = [tx.add_vertex("person", name=f"p{i}") for i in range(12)]
-    places = [tx.add_vertex("place", name=f"c{i}") for i in range(3)]
+    people = [tx.add_vertex("person", name=f"p{i}") for i in range(n_people)]
+    places = [tx.add_vertex("place", name=f"c{i}") for i in range(n_places)]
     import random
 
     rng = random.Random(11)
     for i, v in enumerate(people):
-        for j in rng.sample(range(12), 4):
+        for j in rng.sample(range(n_people), 4):
             tx.add_edge(v, "knows", people[j])
-        tx.add_edge(v, "lives", places[i % 3])
+        tx.add_edge(v, "lives", places[i % n_places])
     # a self-loop and a parallel edge: multiplicity edge cases the count
     # vector must reproduce exactly
     tx.add_edge(people[0], "knows", people[0])
@@ -51,10 +51,8 @@ def _social_graph(extra_cfg=None):
     return g, [v.id for v in people], [v.id for v in places]
 
 
-def _spill_count():
-    return registry.snapshot().get(
-        "olap.spillover.spilled", {}
-    ).get("count", 0)
+def _spill_count(counter="olap.spillover.spilled"):
+    return registry.snapshot().get(counter, {}).get("count", 0)
 
 
 def _ab(g, build, as_count=False):
@@ -577,5 +575,179 @@ def test_spillover_disabled_config():
         for _ in range(3):
             g.traversal().V().out("knows").out("knows").count()
         assert _spill_count() == base
+    finally:
+        g.close()
+
+
+# ------------------------------------------------------ the seed hop on the host
+def _seed_hop_graph():
+    """48 people (p0 on a self loop, p1 -> p2 a parallel edge): a seed's
+    rows stay well under the share of the edges at which hop 0 stays
+    dense."""
+    g, people, _places = _social_graph(n_people=48, n_places=4)
+    return g, people
+
+
+def _seed_hop_host_count():
+    return _spill_count("olap.spillover.seed_hop_host")
+
+
+def _hop(t, direction, labels):
+    return {"out": t.out, "in": t.in_, "both": t.both}[direction](*labels)
+
+
+def _device_counts(planner, plan, tx, host_hop):
+    """The count vector of the plan's program on the executor, over the
+    tx-patched snapshot: with hop 0 on the host, or (`host_hop` False)
+    OLAPTraversalProgram with ALL hops from the same seed mask."""
+    import numpy as np
+    from unittest import mock
+
+    from janusgraph_tpu.olap.tpu_executor import TPUExecutor
+
+    with planner._lock:
+        base = planner._snapshot()
+    overlay = sp.tx_overlay(tx)
+    csr = sp.patched_csr(base, overlay)
+    if host_hop:
+        program, edges = planner._compile(plan, csr, overlay)
+        assert edges is not None and edges > 0
+        assert len(program.steps) == len(plan.hops) - 1
+    else:
+        with mock.patch.object(sp, "host_seed_hop", lambda *a: None):
+            program, edges = planner._compile(plan, csr, overlay)
+        assert edges is None and len(program.steps) == len(plan.hops)
+    return np.asarray(TPUExecutor(csr).run(program)["count"])
+
+
+#: (name, seeds as indices into the people, what the tx does before the
+#: read, hasLabel after hop 0, hops)
+_SEED_HOP_CASES = [
+    ("seed-twice", (5, 5), None, False, 2),
+    ("two-seeds", (5, 9), None, False, 2),
+    ("self-loop", (0,), None, False, 2),
+    ("parallel-edges", (1,), None, False, 2),
+    ("seed-removed", (5, 9), "remove-seed", False, 2),
+    ("edge-added-on-row", (5,), "add-edge", False, 2),
+    ("has-label-after-hop0", (5, 9), None, True, 2),
+    ("three-hops", (5,), None, False, 3),
+]
+
+
+@pytest.mark.parametrize("labels", [(), ("knows",)], ids=["any", "knows"])
+@pytest.mark.parametrize("direction", ["out", "in", "both"])
+@pytest.mark.parametrize(
+    "case", _SEED_HOP_CASES, ids=[c[0] for c in _SEED_HOP_CASES]
+)
+def test_seed_hop_on_the_host(case, direction, labels):
+    import numpy as np
+
+    from janusgraph_tpu.core.traversal import GraphTraversalSource
+
+    _name, seeds, mutate, has_label, n_hops = case
+    g, people = _seed_hop_graph()
+    try:
+        tx = g.new_transaction()
+        if mutate == "remove-seed":
+            tx.remove_vertex(tx.get_vertex(people[seeds[0]]))
+        elif mutate == "add-edge":
+            # both ends of the new edge are read: it lies on the seed's
+            # out-row and on its in-row
+            v5, v7 = tx.get_vertex(people[5]), tx.get_vertex(people[7])
+            tx.add_edge(v5, "knows", v7)
+            tx.add_edge(v7, "knows", v5)
+
+        def build():
+            t = GraphTraversalSource(g, tx).V(*[people[i] for i in seeds])
+            t = _hop(t, direction, labels)
+            if has_label:
+                t = t.has_label("person")
+            for _ in range(n_hops - 1):
+                t = _hop(t, direction, labels)
+            return t.id_()
+
+        planner = g.spillover_planner
+        build().to_list()  # teach
+        spilled_before, host_before = _spill_count(), _seed_hop_host_count()
+        spilled = sorted(build().to_list())
+        assert _spill_count() == spilled_before + 1, "did not spill"
+        assert _seed_hop_host_count() == host_before + 1
+        info = registry.last_run("olap.spillover")
+        block = info["spillover"]
+        assert block["fallback"] is None
+        assert block["seed_hop"] == "host" and block["seed_hop_edges"] > 0
+        assert block["hops"] == n_hops
+        assert info["supersteps"] == n_hops - 1
+        assert info["executor"] == "host-loop"
+        planner.enabled = False
+        try:
+            row = sorted(build().to_list())
+        finally:
+            planner.enabled = True
+        assert spilled == row
+        # the whole count vector, against every hop on the executor
+        plan, _reason = sp.recognize(build())
+        host = _device_counts(planner, plan, tx, host_hop=True)
+        dense = _device_counts(planner, plan, tx, host_hop=False)
+        assert np.array_equal(host, dense)
+        tx.rollback()
+    finally:
+        g.close()
+
+
+@pytest.mark.parametrize("start", [
+    "all-vertices", "has-label-only", "ids-that-cover-the-graph",
+    "labelled-hop-without-edge-types",
+])
+def test_seed_hop_stays_on_the_device(start):
+    import dataclasses
+
+    g, people = _seed_hop_graph()
+    try:
+        def build():
+            t = g.traversal()
+            if start == "has-label-only":
+                return t.V().has_label("person").out().out().id_()
+            if start == "ids-that-cover-the-graph":
+                return t.V(*people).out().out().id_()
+            if start == "labelled-hop-without-edge-types":
+                return t.V(people[5]).out("knows").out().id_()
+            return t.V().out().out().id_()
+
+        planner = g.spillover_planner
+        build().to_list()  # teach
+        if start == "labelled-hop-without-edge-types":
+            sorted(build().to_list())  # packs the snapshot
+            with planner._lock:
+                planner._csr = dataclasses.replace(
+                    planner._csr, out_edge_type=None, in_edge_type=None
+                )
+                planner._tpu_ex = None
+                plan, _reason = sp.recognize(build())
+                program, edges = planner._compile(
+                    plan, planner._csr, sp.tx_overlay(g.new_transaction())
+                )
+            # the choice is the device's, whose pack then refuses labels
+            # without types as it always did: the row path answers
+            assert edges is None and len(program.steps) == 2
+        spilled_before, host_before = _spill_count(), _seed_hop_host_count()
+        got = sorted(build().to_list())
+        assert _seed_hop_host_count() == host_before
+        if start == "labelled-hop-without-edge-types":
+            assert _spill_count() == spilled_before
+            assert registry.last_run("olap.spillover")["spillover"][
+                "fallback"
+            ].startswith("error:ValueError")
+        else:
+            assert _spill_count() == spilled_before + 1
+            info = registry.last_run("olap.spillover")
+            assert info["spillover"]["seed_hop"] == "device"
+            assert info["spillover"]["seed_hop_edges"] == 0
+            assert info["supersteps"] == 2
+        planner.enabled = False
+        try:
+            assert got == sorted(build().to_list())
+        finally:
+            planner.enabled = True
     finally:
         g.close()
