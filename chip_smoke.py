@@ -17,7 +17,8 @@ Data is generated from --seed; nothing is read from a cache of graphs.
            as a warm snapshot (the way a fleet replica is warmed), then
            PageRank / 4-hop BFS / connected components through
            graph.compute() with the default configuration
-  phase 3  the Pallas sorted-segment-sum kernel, compiled, against ELL
+  phase 3  the executor's pack on the device against the CPU oracle's
+           replay of the ELL tree, bitwise
 
 The first phase that fails ends the run with a non-zero exit code; no
 failure is caught and carried past. Without a TPU the script fails in
@@ -41,9 +42,6 @@ import time
 # at least (1 - damping) / n, so a purely relative bound checks them all
 # (an absolute term near 1/n would wave the small ones through).
 RANK_RTOL = 1e-4
-# Pallas vs ELL: both float32, differing only in the order of the sums.
-# 25x under what a bfloat16-rounded dot shows (2.8e-3 on a v5e, PR 21).
-PALLAS_RTOL = 1e-4
 DAMPING = 0.85
 PR_ITERS = 20
 BFS_HOPS = 4
@@ -478,40 +476,67 @@ def phase_analytics(dev: dict, scale: int, seed: int, devices) -> None:
 def phase_kernels(dev: dict, scale: int, seed: int) -> None:
     import numpy as np
 
+    from janusgraph_tpu.olap.cpu_executor import CPUExecutor
     from janusgraph_tpu.olap.generators import rmat_csr
+    from janusgraph_tpu.olap.kernels import ELLPack, ell_aggregate
     from janusgraph_tpu.olap.programs import PageRankProgram
     from janusgraph_tpu.olap.tpu_executor import TPUExecutor
+    from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
 
-    say(f"phase 3: Pallas sorted-segment-sum at scale {scale}")
+    say(f"phase 3: the executor's pack against the ELL replay at scale "
+        f"{scale}")
     csr = rmat_csr(scale, 16, seed)
+    n = csr.num_vertices
+
+    class Echo(VertexProgram):
+        """One superstep: every vertex sends x and keeps what it folded."""
+
+        max_iterations = 1
+
+        def __init__(self, x):
+            self.x = x
+
+        def setup(self, graph, xp):
+            return {"x": xp.asarray(self.x)}, {}
+
+        def message(self, state, superstep, graph, xp):
+            return state["x"]
+
+        def apply(self, state, aggregated, superstep, memory_in, graph, xp):
+            return {"x": aggregated}, {}
+
+        def terminate(self, memory):
+            return False
+
+    x = np.random.default_rng(seed).uniform(-1.0, 1.0, n).astype(np.float32)
+    ex = TPUExecutor(csr)
+    t = time.perf_counter()
+    got = np.asarray(ex.run(Echo(x))["x"])
+    say(f"  observed: one superstep, first run (pack + compile) "
+        f"{time.perf_counter() - t:.2f}s")
+    info = ex.last_run_info
+    check(info["strategy_resolved"] == "hybrid"
+          and info["platform"] == dev["platform"],
+          f"the dense superstep ran on the {info['strategy_resolved']!r} "
+          f"pack ({info['pad_ratio']} slots an edge) on {info['platform']!r}")
+    dst = np.repeat(np.arange(n, dtype=np.int64), np.diff(csr.in_indptr))
+    want = ell_aggregate(
+        np, ELLPack(csr.in_src.astype(np.int64), dst, None, n), x,
+        Combiner.SUM,
+    )
+    check(np.array_equal(got, want),
+          "the device's float32 sums bitwise-equal to the numpy replay of "
+          "the ELL tree (the same reduction tree)")
+
     program = PageRankProgram(max_iterations=PR_ITERS, tol=0.0)
-
-    def run(strategy):
-        ex = TPUExecutor(csr, strategy=strategy)
-        t = time.perf_counter()
-        out = ex.run(program)
-        say(f"  observed {strategy}: first run (pack + compile) "
-            f"{time.perf_counter() - t:.2f}s")
-        info = ex.last_run_info
-        check(info["strategy_resolved"] == strategy
-              and info["platform"] == dev["platform"],
-              f"{strategy} ran as {info['strategy_resolved']!r} on "
-              f"{info['platform']!r}")
-        return np.asarray(out["rank"], np.float64), info
-
-    ell, _ = run("ell")
-    pallas, info = run("pallas")
-    check(info["pallas_interpret"] == (dev["platform"] != "tpu"),
-          "Pallas kernel "
-          + ("interpreted (not a TPU)" if info["pallas_interpret"]
-             else "compiled by Mosaic, not interpreted"))
-    rel = float(np.max(np.abs(pallas - ell) / ell))
-    check(rel <= PALLAS_RTOL,
-          f"Pallas vs ELL max relative difference {rel:.2e} <= "
-          f"{PALLAS_RTOL:g} (float32 sums in another order)")
-    got, _ = run("hybrid")
-    check(np.array_equal(got, ell),
-          "hybrid pack bitwise-equal to ELL (the same reduction tree)")
+    got = np.asarray(ex.run(program)["rank"], np.float64)
+    ell = np.asarray(
+        CPUExecutor(csr, strategy="ell").run(program)["rank"], np.float64)
+    rel = float(np.max(np.abs(got - ell) / ell))
+    check(rel <= RANK_RTOL,
+          f"PageRank against the CPU oracle's ELL replay: max relative "
+          f"difference {rel:.2e} <= {RANK_RTOL:g} (the oracle keeps its "
+          "state in float64)")
 
 
 # --------------------------------------------------------------------- main

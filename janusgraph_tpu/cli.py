@@ -7,7 +7,6 @@ janusgraph.sh — combined lifecycle):
 
   python -m janusgraph_tpu server  --config graph.json [--port 8182] [--auth]
   python -m janusgraph_tpu console [--config graph.json | --remote host:port]
-  python -m janusgraph_tpu bench   [--scale N]
 """
 
 from __future__ import annotations
@@ -353,20 +352,6 @@ def cmd_console(args) -> int:
         })
     code.interact(banner=banner, local=ns)
     return 0
-
-
-def cmd_bench(args) -> int:
-    import os
-
-    if args.scale:
-        os.environ["BENCH_SCALE"] = str(args.scale)
-    root = os.path.join(os.path.dirname(__file__), "..")
-    sys.path.insert(0, root)
-    import bench
-
-    # the bench supervisor stays off JAX (its worker holds the chip and
-    # places the compile cache itself), and its exit code is the result
-    return bench.main()
 
 
 def cmd_storage_server(args) -> int:
@@ -1093,10 +1078,6 @@ def main(argv=None) -> int:
     pc.add_argument("--remote", help="host:port of a running server")
     pc.add_argument("--load-gods", action="store_true")
     pc.set_defaults(fn=cmd_console)
-
-    pb = sub.add_parser("bench", help="run the benchmark")
-    pb.add_argument("--scale", type=int)
-    pb.set_defaults(fn=cmd_bench)
 
     pss = sub.add_parser(
         "storage-server", help="serve a storage backend over TCP"

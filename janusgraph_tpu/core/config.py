@@ -369,27 +369,11 @@ COMPUTER_NS.option(
     Mutability.MASKABLE, lambda v: v in ("memory", "persist"),
 )
 COMPUTER_NS.option(
-    "strategy", str,
-    "device aggregation kernel ('auto'|'ell'|'hybrid'|'segment'|'pallas'); "
-    "'auto' consults the profiler-driven autotuner (olap/autotune.py, "
-    "gated by computer.autotune)", "auto",
-    Mutability.MASKABLE,
-    lambda v: v in ("auto", "ell", "hybrid", "segment", "pallas"),
-)
-COMPUTER_NS.option(
-    "autotune", bool,
-    "profiler-driven autotuning behind computer.strategy='auto': choose "
-    "ell/hybrid/segment, the hybrid hub cutoff, and the frontier tier "
-    "schedules from the degree histogram + device roofline peaks "
-    "(olap/autotune.decide; decision recorded in run_info['autotune']). "
-    "False falls back to the legacy ELL footprint-budget heuristic", True,
-    Mutability.MASKABLE,
-)
-COMPUTER_NS.option(
     "autotune-hub-cutoff", int,
-    "hybrid-format degree cutoff between the exact-width ELL torso and "
-    "the chunked CSR tail (0 = let the tuner search the pow2 candidates; "
-    "read in TPUExecutor._autotune/_hybrid_pack)", 0,
+    "hybrid-pack degree cutoff between the exact-width ELL torso and "
+    "the chunked CSR tail (0 = let olap/autotune.decide search the pow2 "
+    "candidates against the degree histogram and the device's price "
+    "column; the decision is recorded in run_info['autotune'])", 0,
     Mutability.MASKABLE, lambda v: v >= 0,
 )
 COMPUTER_NS.option(
@@ -398,13 +382,6 @@ COMPUTER_NS.option(
     "in chunks of this many slots, so per-hub padding is bounded by one "
     "chunk (olap/kernels.py HybridPack)", 256,
     Mutability.MASKABLE, lambda v: v > 0 and (v & (v - 1)) == 0,
-)
-COMPUTER_NS.option(
-    "autotune-min-gain", float,
-    "fractional modeled superstep-time gain the hybrid layout must show "
-    "over pure ELL before the tuner picks it (hysteresis against churning "
-    "packs for marginal wins; olap/autotune.decide)", 0.05,
-    Mutability.MASKABLE, lambda v: 0.0 <= v < 1.0,
 )
 COMPUTER_NS.option(
     "autotune-max-tiers", int,
@@ -1135,19 +1112,8 @@ COMPUTER_NS.option(
     "auto", Mutability.MASKABLE, lambda v: v in ("auto", "off", "always"),
 )
 COMPUTER_NS.option(
-    "ell-auto-budget-bytes", int,
-    "HBM budget the auto strategy lets the ELL pack use before falling "
-    "back to segment reduction (TPUExecutor._auto_strategy)",
-    6 << 30, Mutability.MASKABLE, lambda v: v > 0,
-)
-COMPUTER_NS.option(
-    "ell-auto-pad", float,
-    "padding-ratio ceiling for the auto ELL strategy", 3.0,
-    Mutability.MASKABLE, lambda v: v >= 1.0,
-)
-COMPUTER_NS.option(
     "channel-cache-size", int,
-    "typed edge-channel ELL views kept device-resident (LRU)", 8,
+    "typed edge-channel packs kept device-resident (LRU)", 8,
     Mutability.MASKABLE, lambda v: v > 0,
 )
 SERVER_NS.option(

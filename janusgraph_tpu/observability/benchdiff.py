@@ -1,6 +1,10 @@
 """Bench regression sentinel: compare bench artifacts, verdict deltas.
 
-The bench trajectory (BENCH_r01.., MULTICHIP_r01.., SATURATE_r01..) has
+(The staged bench that wrote these artifacts, and the artifacts, were
+deleted in PR 30; commit 40a31da has them. The driver's PERF_LEDGER.jsonl
+is the comparison now; ROADMAP D14 names this module as a debt.)
+
+The bench trajectory (BENCH_r01.., MULTICHIP_r01.., SATURATE_r01..) had
 been eyeballed JSON so far. This module makes regressions a computed,
 CI-gateable verdict:
 
@@ -31,8 +35,8 @@ CI-gateable verdict:
   compares against the best it has ever demonstrated, not just the last
   round, so a slow round followed by another slow round still flags.
 
-``bench.py`` attaches a ``regression`` block to every emitted stage by
-default (no-op note when no prior artifact matches the cell);
+``bench.py`` attached a ``regression`` block to every emitted stage
+(no-op note when no prior artifact matched the cell);
 ``janusgraph_tpu benchdiff <old> <new> [--fail-on-regress]`` is the CI
 entry point and ``bin/benchdiff.sh`` wraps it.
 """

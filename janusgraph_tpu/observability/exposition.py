@@ -8,7 +8,7 @@ raw, gauges as-is). Served at ``GET /metrics`` by the query server and by
 
 ``json_snapshot`` bundles the metric snapshot, recent span trees, the
 slow-op log and the structured run records — the ``GET /telemetry``
-payload and what ``bench.py`` attaches to its artifacts.
+payload.
 """
 
 from __future__ import annotations

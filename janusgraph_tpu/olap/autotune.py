@@ -1,4 +1,4 @@
-"""Profiler-driven autotuner: close the measurement -> kernel-choice loop.
+"""Profiler-driven autotuner: close the measurement -> pack-shape loop.
 
 PR 5 made superstep cost visible (XLA ``cost_analysis`` flops/bytes,
 %-roofline per E_cap tier, pad ratios in every run record); this module
@@ -6,12 +6,10 @@ CONSUMES it. Given a graph's degree statistics, the device kind's roofline
 peaks (observability/profiler.py), the ``computer.autotune-*`` config
 overrides, and optionally a prior run's measurements, it decides:
 
-  * the aggregation **strategy** — ``ell`` (pow2 degree buckets),
-    ``hybrid`` (exact-width torso + chunked CSR tail, olap/kernels.py
-    HybridPack), or ``segment`` (flat gather + segment reduce when any
-    packed layout blows the HBM budget);
-  * the hybrid **hub cutoff** and **tail chunk** (searched over pow2
-    candidates against a bytes/peak_bw + flops/peak_flops time model);
+  * the hybrid pack's **hub cutoff** and **tail chunk** (olap/kernels.py
+    HybridPack, the single-device executor's one aggregation structure;
+    searched over pow2 candidates against a bytes/peak_bw +
+    flops/peak_flops time model);
   * the frontier **tier schedules** (F_cap/E_cap ladders) for the
     ShortestPath/CC special case — sized from the degree histogram and a
     tier-count budget instead of today's fixed power-of-two growth.
@@ -19,7 +17,8 @@ overrides, and optionally a prior run's measurements, it decides:
 Decisions are DETERMINISTIC: ``decide()`` is a pure function of
 (GraphStats, device_kind, overrides, measured) — same inputs, same
 AutotuneDecision, asserted by tests. The executor records the decision in
-``run_info["autotune"]`` and the bench artifact carries it per stage.
+``run_info["autotune"]``. The mesh's exchange and aggregation are
+``decide_sharded``'s, below.
 
 The graph-kernel literature motivates both levers (PAPERS.md):
 arXiv:2011.08451 (propagation blocking) shows format/preprocessing choice
@@ -71,7 +70,10 @@ class GraphStats:
     def from_degrees(
         cls, deg: np.ndarray, num_edges: int, weighted: bool,
         max_capacity: int = 1 << 14, tail_chunk: int = 256,
+        hub_cutoff: int = None,
     ) -> "GraphStats":
+        """`hub_cutoff`: a forced cutoff (computer.autotune-hub-cutoff)
+        to price beside the pow2 candidates."""
         deg = np.asarray(deg, dtype=np.int64)
         n = len(deg)
         maxd = int(deg.max()) if n else 0
@@ -88,7 +90,10 @@ class GraphStats:
             k = np.ceil(np.log2(np.maximum(deg, 1))).astype(np.int64)
             np.add.at(hist_bins, np.minimum(k, 35), 1)
         hyb = []
-        for cutoff in CUTOFF_CANDIDATES:
+        cutoffs = CUTOFF_CANDIDATES
+        if hub_cutoff and hub_cutoff not in cutoffs:
+            cutoffs += (int(hub_cutoff),)
+        for cutoff in cutoffs:
             torso = (deg >= 1) & (deg <= cutoff)
             hub = deg > cutoff
             t = min(tail_chunk, _next_pow2(cutoff + 1), max_capacity)
@@ -124,16 +129,17 @@ class GraphStats:
 @dataclass(frozen=True)
 class AutotuneDecision:
     """One deterministic tuning decision. ``as_dict()`` is the record shape
-    stored in ``run_info["autotune"]`` and bench artifacts."""
+    stored in ``run_info["autotune"]``."""
 
-    strategy: str                     # ell | hybrid | segment
-    hub_cutoff: Optional[int]         # hybrid only
-    tail_chunk: Optional[int]         # hybrid only
-    pad_ratio_est: float              # chosen layout's modeled pad ratio
+    hub_cutoff: int                   # the hybrid pack's two sizes
+    tail_chunk: int
+    pad_ratio_est: float              # the pack's modeled slots an edge
     f_schedule: Tuple[int, ...]       # frontier F_cap ladder (pow2, asc)
     e_schedule: Tuple[int, ...]       # frontier E_cap ladder (pow2, asc)
     device_kind: str
-    source: str                       # model | config | measured+model
+    source: str                       # model | measured+model
+    #: a superstep on the pack as sized ("hybrid") and, as a comparison
+    #: that decides nothing, on the pow2-bucketed ELL pack ("ell")
     modeled_ms: Dict[str, float] = field(default_factory=dict)
     #: dense-feature tier input: the program's logical/padded feature dim
     #: (0/None for scalar-message programs)
@@ -142,7 +148,6 @@ class AutotuneDecision:
 
     def as_dict(self) -> dict:
         return {
-            "strategy": self.strategy,
             "hub_cutoff": self.hub_cutoff,
             "tail_chunk": self.tail_chunk,
             "pad_ratio_est": round(self.pad_ratio_est, 4),
@@ -164,8 +169,9 @@ def _bytes_per_slot(weighted: bool) -> int:
 
 
 #: The packed layouts' price list, one column per device kind; `decide`
-#: prices every strategy from the SAME column. cpu: host XLA, the round-6
-#: s18 sweep (bench_artifacts/r6_hybrid_autotune_cpu.jsonl). tpu: one v5e,
+#: prices every pack from the SAME column. cpu: host XLA, the round-6 s18
+#: sweep (CPU, before the ledger; bench_artifacts/r6_hybrid_autotune_cpu
+#: .jsonl at commit 40a31da). tpu: one v5e,
 #: PageRank at Graph500 s17 and s20, ELL against the single-gather hybrid
 #: pack at eight (cutoff, chunk) settings (PERF.md section 6, PR 26).
 
@@ -190,14 +196,14 @@ _TAIL_CHUNK_COST_S = {"cpu": 7.5e-8, "tpu": 8e-9}
 _GATHER_COST_S = {"cpu": 3.3e-9, "tpu": 7.6e-9}
 
 #: scatter (segment-reduce) effective-bandwidth derating vs the packed
-#: gather paths — the reason ELL exists at all (serialized scatter-add
-#: lowering on TPU; cache-hostile on CPU)
+#: gather paths, in `decide_sharded`'s flat aggregations (serialized
+#: scatter-add lowering on TPU; cache-hostile on CPU)
 _SEGMENT_PENALTY = {"tpu": 8.0, "cpu": 2.5}
 
 
 def _modeled_seconds(
     slots: int, n: int, weighted: bool, buckets: int, peaks: dict,
-    kind: str, penalty: float = 1.0, eff_bw: Optional[float] = None,
+    kind: str, eff_bw: Optional[float] = None,
     chunk_rows: int = 0, cols: int = 1,
 ) -> float:
     """Roofline time model for one superstep of a packed aggregation: the
@@ -212,16 +218,13 @@ def _modeled_seconds(
     byts = slots * _bytes_per_slot(weighted) + 4.0 * slots * cols + (
         8.0 * n * cols
     )
-    t = max(
-        penalty * byts / max(bw, 1.0),
-        penalty * slots * _GATHER_COST_S[kind],
-    )
+    t = max(byts / max(bw, 1.0), slots * _GATHER_COST_S[kind])
     t += slots * cols / max(peaks["peak_flops"], 1.0)
     t += buckets * _BUCKET_OVERHEAD_S[kind]
     # the tail's partial-table scatter moves a cols-wide row per chunk, so
-    # its cost scales with the message width (measured r7: s16 d=32 GCN,
-    # hybrid 276.8 ms vs ELL 190.9 ms per superstep — the scatter term is
-    # what flips the winner for dense-feature runs)
+    # its cost scales with the message width (CPU, before the ledger, and
+    # on the tail PR 26 replaced: s16 d=32 GCN, hybrid 276.8 ms vs ELL
+    # 190.9 ms per superstep; the dense tier has no chip reading)
     t += chunk_rows * cols * _TAIL_CHUNK_COST_S[kind]
     return t
 
@@ -233,29 +236,24 @@ def decide(
     measured: Optional[dict] = None,
     feature_dim: int = 0,
 ) -> AutotuneDecision:
-    """Pick (strategy, hub cutoff, tail chunk, tier schedules) for one
-    graph + device. Pure function of its arguments — identical inputs give
-    an identical decision (tested), so a recorded decision is reproducible
-    from its recorded inputs.
+    """Size the hybrid pack (hub cutoff, tail chunk) and the frontier tier
+    schedules for one graph + device. Pure function of its arguments —
+    identical inputs give an identical decision (tested), so a recorded
+    decision is reproducible from its recorded inputs.
 
-    overrides (the ``computer.autotune-*`` / legacy budget keys):
-      strategy          force the strategy outright (source="config")
-      hub_cutoff        force the hybrid cutoff (0/None = search)
-      tail_chunk        tail chunk width (default 128)
-      min_gain          fractional modeled-time gain hybrid must show over
-                        ELL before it is chosen (default 0.05)
-      budget_bytes      HBM budget for packed layouts (default 6 GiB)
-      max_pad           pad-ratio ceiling for packed layouts (default 3.0)
+    overrides (the ``computer.autotune-*`` / ``frontier-*`` keys):
+      hub_cutoff        force the hub cutoff (0/None = search)
+      tail_chunk        tail chunk width (default 256)
       f_min/e_min       smallest frontier tier capacities
       max_tiers         frontier ladder length budget (default 8)
       tier_growth       max ladder growth factor (pow2, default 16)
 
     measured (a prior run's record — ``registry.last_run("olap")`` shape):
-      ``pad_ratio`` + ``superstep_ms`` of a run with ``strategy`` calibrate
-      the model's effective bandwidth (achieved bytes/s replaces the peak
-      table), folding real measurements into the next decision;
-      ``roofline_by_tier`` utilizations refine the frontier ladder (tiers
-      that measured near-zero utilization are pruned from the schedule).
+      ``pad_ratio`` + ``superstep_ms`` calibrate the model's effective
+      bandwidth (achieved bytes/s replaces the peak table), folding real
+      measurements into the next decision; ``roofline_by_tier``
+      utilizations refine the frontier ladder (tiers that measured
+      near-zero utilization are pruned from the schedule).
 
     feature_dim (the dense tier's input, 0 for scalar programs): the
       padded lane tier (features/kernels.pick_feature_tier, or the
@@ -268,10 +266,6 @@ def decide(
 
     peaks = profiler.device_peaks(device_kind)
     kind = "tpu" if "tpu" in (device_kind or "").lower() else "cpu"
-    budget = int(ov.get("budget_bytes") or (6 << 30))
-    max_pad = float(ov.get("max_pad") or 3.0)
-    min_gain = float(ov.get("min_gain") if ov.get("min_gain") is not None
-                     else 0.05)
     tail_chunk = int(ov.get("tail_chunk") or 256)
     feature_dim = int(feature_dim or 0)
     feature_tier = None
@@ -298,19 +292,6 @@ def decide(
         eff_bw = meas_bytes / (float(measured["superstep_ms"]) / 1e3)
         source = "measured+model"
 
-    # candidate models ----------------------------------------------------
-    modeled: Dict[str, float] = {}
-    modeled["segment"] = _modeled_seconds(
-        m, n, stats.weighted, 1, peaks, kind,
-        penalty=_SEGMENT_PENALTY[kind], eff_bw=eff_bw, cols=cols,
-    )
-    ell_buckets = max(1, len(stats.degree_hist))
-    ell_pad = stats.ell_slots / max(1, m)
-    modeled["ell"] = _modeled_seconds(
-        stats.ell_slots, n, stats.weighted, ell_buckets, peaks, kind,
-        eff_bw=eff_bw, cols=cols,
-    )
-
     forced_cutoff = int(ov.get("hub_cutoff") or 0) or None
     best = None  # (modeled_s, cutoff, slots)
     for cutoff, slots, hubs, torso_buckets, chunk_rows in (
@@ -325,49 +306,30 @@ def decide(
         )
         if best is None or t < best[0]:
             best = (t, cutoff, slots)
-    if best is not None:
-        modeled["hybrid"] = best[0]
-        hyb_cutoff, hyb_slots = best[1], best[2]
-        hyb_pad = hyb_slots / max(1, m)
-    else:
-        hyb_cutoff, hyb_slots, hyb_pad = None, stats.ell_slots, ell_pad
-
-    # strategy choice -----------------------------------------------------
-    forced = ov.get("strategy")
-    if forced and forced not in ("auto",):
-        strategy, source = forced, "config"
-    else:
-        strategy = "ell"
-        if "hybrid" in modeled and modeled["hybrid"] < modeled["ell"] * (
-            1.0 - min_gain
-        ):
-            strategy = "hybrid"
-        chosen_slots = hyb_slots if strategy == "hybrid" else stats.ell_slots
-        chosen_pad = hyb_pad if strategy == "hybrid" else ell_pad
-        if chosen_slots * bps > budget or chosen_pad > max_pad:
-            strategy = "segment"
-
-    pad_est = {
-        "ell": ell_pad, "hybrid": hyb_pad, "segment": 1.0, "pallas": 1.0,
-    }.get(strategy, ell_pad)
+    if best is None:
+        raise ValueError(
+            f"hub cutoff {forced_cutoff} is not among the statistics' "
+            "candidates: build GraphStats with hub_cutoff set to it"
+        )
+    hyb_s, hyb_cutoff, hyb_slots = best
+    ell_s = _modeled_seconds(
+        stats.ell_slots, n, stats.weighted,
+        max(1, len(stats.degree_hist)), peaks, kind, eff_bw=eff_bw,
+        cols=cols,
+    )
 
     f_sched, e_sched = decide_tiers(stats, ov, measured)
     return AutotuneDecision(
-        strategy=strategy,
-        hub_cutoff=hyb_cutoff if strategy == "hybrid" else None,
-        tail_chunk=(
-            min(tail_chunk, _next_pow2((hyb_cutoff or 0) + 1))
-            if strategy == "hybrid" and hyb_cutoff
-            else (tail_chunk if strategy == "hybrid" else None)
-        ),
-        pad_ratio_est=float(pad_est),
+        hub_cutoff=hyb_cutoff,
+        tail_chunk=min(tail_chunk, _next_pow2(hyb_cutoff + 1)),
+        pad_ratio_est=hyb_slots / max(1, m),
         f_schedule=f_sched,
         e_schedule=e_sched,
         device_kind=device_kind or "cpu",
         source=source,
         feature_dim=feature_dim,
         feature_tier=feature_tier,
-        modeled_ms={k: v * 1e3 for k, v in modeled.items()},
+        modeled_ms={"ell": ell_s * 1e3, "hybrid": hyb_s * 1e3},
     )
 
 

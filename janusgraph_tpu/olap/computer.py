@@ -105,7 +105,7 @@ class ComputerResult:
 
 class GraphComputer:
     """graph.compute() builder (reference: JanusGraphComputer). Executor
-    kind, aggregation strategy, sync cadence and checkpointing default to
+    kind, sync cadence and checkpointing default to
     the graph's registered config (computer.* options)."""
 
     def __init__(self, graph, executor: str = None):
@@ -347,14 +347,11 @@ class GraphComputer:
             }
         if cfg is not None and executor_kind == "tpu":
             run_kwargs = {
-                "strategy": cfg.get("computer.strategy"),
                 "ell_max_capacity": cfg.get("computer.ell-max-capacity"),
                 "sync_every": cfg.get("computer.sync-every"),
                 "checkpoint_every": cfg.get("computer.checkpoint-every"),
                 "checkpoint_path": cfg.get("computer.checkpoint-path") or None,
                 "frontier": cfg.get("computer.frontier"),
-                "ell_auto_bytes": cfg.get("computer.ell-auto-budget-bytes"),
-                "ell_auto_pad": cfg.get("computer.ell-auto-pad"),
                 "channel_cache_size": cfg.get("computer.channel-cache-size"),
                 "frontier_cc_min_edges": cfg.get(
                     "computer.frontier-cc-min-edges"
@@ -364,10 +361,8 @@ class GraphComputer:
                 "frontier_tier_growth": cfg.get(
                     "computer.frontier-tier-growth"
                 ),
-                "autotune": cfg.get("computer.autotune"),
                 "hub_cutoff": cfg.get("computer.autotune-hub-cutoff"),
                 "tail_chunk": cfg.get("computer.autotune-tail-chunk"),
-                "autotune_min_gain": cfg.get("computer.autotune-min-gain"),
                 "autotune_max_tiers": cfg.get("computer.autotune-max-tiers"),
                 "autotune_persist": cfg.get("computer.autotune-persist"),
                 "features_dim_tier": cfg.get("computer.features-dim-tier"),
@@ -517,14 +512,11 @@ def run_on(
     csr: CSRGraph,
     program: VertexProgram,
     executor: str = "tpu",
-    strategy: str = "auto",
     ell_max_capacity: int = None,
     sync_every: int = 1,
     checkpoint_every: int = 0,
     checkpoint_path: str = None,
     frontier: str = "auto",
-    ell_auto_bytes: int = None,
-    ell_auto_pad: float = None,
     channel_cache_size: int = None,
     frontier_cc_min_edges: int = None,
     frontier_f_min: int = None,
@@ -535,10 +527,8 @@ def run_on(
     shard_measure: bool = None,
     fault_hook=None,
     resume_attempts: int = 3,
-    autotune: bool = None,
     hub_cutoff: int = None,
     tail_chunk: int = None,
-    autotune_min_gain: float = None,
     autotune_max_tiers: int = None,
     autotune_persist: bool = None,
     features_dim_tier: int = None,
@@ -607,20 +597,15 @@ def run_on(
         from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 
         ctor_kwargs = dict(
-            strategy=strategy,
             ell_max_capacity=ell_max_capacity,
             frontier=frontier,
-            ell_auto_bytes=ell_auto_bytes,
-            ell_auto_pad=ell_auto_pad,
             channel_cache_size=channel_cache_size,
             frontier_cc_min_edges=frontier_cc_min_edges,
             frontier_f_min=frontier_f_min,
             frontier_e_min=frontier_e_min,
             frontier_tier_growth=frontier_tier_growth,
-            autotune=autotune,
             hub_cutoff=hub_cutoff,
             tail_chunk=tail_chunk,
-            autotune_min_gain=autotune_min_gain,
             autotune_max_tiers=autotune_max_tiers,
             autotune_persist=autotune_persist,
             features_dim_tier=features_dim_tier,

@@ -11,7 +11,6 @@ from janusgraph_tpu.olap.features.kernels import (  # noqa: F401
     pick_feature_tier,
     sddmm_ell_aggregate,
     sddmm_hybrid_aggregate,
-    sddmm_segment_aggregate,
     tree_dot,
     tree_matmul,
 )

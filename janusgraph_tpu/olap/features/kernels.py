@@ -308,30 +308,6 @@ def sddmm_hybrid_aggregate(xp, pack, row_dsts, msgs, op: str = Combiner.SUM):
     )
 
 
-# graphlint: traced -- the flat-gather SDDMM fallback (segment strategy)
-def sddmm_segment_aggregate(xp, msgs, src_idx, dst_idx, num_vertices: int):
-    """Flat SDDMM+SpMM: per-edge coefficient from the edge list, then a
-    segment sum. The fallback when neither packed layout fits the budget;
-    outside the pack-vs-pack bitwise contract (scatter-add ordering)."""
-    _check_sddmm(Combiner.SUM, msgs)
-    hs = msgs[src_idx]
-    hd = msgs[dst_idx]
-    alpha = tree_dot(xp, hs, hd)
-    vals = fp_fence(xp, hs * alpha[:, None])
-    if _is_jax(xp):
-        import jax
-
-        return jax.ops.segment_sum(vals, dst_idx, num_segments=num_vertices)
-    return _segment_sum_host(vals, dst_idx, num_vertices)
-
-
-# graphlint: host -- numpy-only branch, unreachable from traced code
-def _segment_sum_host(vals, dst_idx, num_vertices: int):
-    out = np.zeros((num_vertices, vals.shape[1]), dtype=vals.dtype)
-    np.add.at(out, np.asarray(dst_idx), np.asarray(vals))
-    return out
-
-
 def sddmm_flops(num_edges: int, d_pad: int) -> float:
     """MXU-attributable flops of one SDDMM pass: a length-d dot (2d ops)
     plus the coefficient multiply (d ops) per edge."""

@@ -126,9 +126,7 @@ class FrontierEngine:
         decision = getattr(executor, "_autotune_decisions", {}).get(
             (False, 0)
         )
-        if decision is not None and getattr(
-            executor, "_autotune_enabled", False
-        ):
+        if decision is not None:
             self.f_schedule = decision.f_schedule
             self.e_schedule = decision.e_schedule
         csr = executor.csr

@@ -4,16 +4,19 @@ The superstep's hot op is `combine({msg(src) for (src,dst) edges}) by dst` —
 the reference runs it as NonBlockingHashMapLong insert-with-combiner per
 message (reference: FulgoraVertexMemory.java:91-99); the straightforward XLA
 translation is gather + `segment_sum`, whose scatter-add lowering serializes
-poorly on TPU. Three alternatives here; `computer.strategy=auto` chooses
-between the first two per graph and device (olap/autotune.decide):
+poorly on TPU. Two packed layouts here:
 
 1. **Degree-bucketed ELL** (`ELLPack` / `ell_aggregate`): in-edges are packed
    per destination into power-of-two-capacity row buckets (ELLPACK layout).
    Aggregation becomes gather + dense axis-1 reduction — no scatter at all,
    every monoid (sum/min/max) supported, padding overhead < 2× by the
-   power-of-two bucketing. One gather per bucket.
+   power-of-two bucketing. One gather per bucket. The mesh's shards
+   (parallel/) aggregate over it, and the CPU oracle replays it as the
+   reference the hybrid pack must equal.
 
-2. **Degree-bucketed HYBRID** (`HybridPack` / `hybrid_aggregate`): the
+2. **Degree-bucketed HYBRID** (`HybridPack` / `hybrid_aggregate`), the
+   single-device executor's one aggregation structure, sized by
+   olap/autotune.decide: the
    ELL pack's power-of-two bucket rounding gathers 1.40-1.47 slots an edge
    on Graph500 R-MAT graphs, and a v5e pays 7.3-8.1 ns for a slot, padding
    or not (PERF.md section 6, PR 26: 180.2 ms a PageRank superstep at
@@ -29,22 +32,14 @@ between the first two per graph and device (olap/autotune.decide):
    the sentinel slots leaf-for-leaf. The whole pack is ONE index vector, so
    an aggregation is one gather whatever the number of exact widths.
 
-3. **Pallas sorted-segment-sum** (`pallas_sorted_segment_sum`): edges are
-   already destination-sorted (CSR); host-side alignment pads each output
-   tile's edge range to whole blocks, so each edge block accumulates into
-   exactly one output tile. The kernel one-hot-expands local segment ids and
-   reduces on the MXU (values split into three bfloat16 pieces, so the sum
-   is float32-accurate), revisiting the same output block across grid
-   steps (zeroed on first touch). SUM monoid; used for PageRank-shaped
-   programs.
-
 `Combiner.MODE` (the most frequent label, smallest on ties) is no monoid,
 so it rides the ELL and hybrid packs' ONE gather and then folds each
 destination's WHOLE multiset: a data-oblivious sort along a block's width,
 run lengths, and an arg-max by (count, then smaller label) — see
-`mode_along`, `hybrid_mode_fold`, `segment_mode`.
+`mode_along`, `hybrid_mode_fold`, and `segment_mode` (the ELL replay's
+split rows, numpy only).
 
-All are built once per (graph, orientation) and reused across supersteps.
+Both are built once per (graph, orientation) and reused across supersteps.
 The aggregation entry points take the array module (`jnp` or plain numpy)
 as their first argument, so the CPU oracle can run the identical pack
 arithmetic for cross-executor bitwise checks.
@@ -434,9 +429,10 @@ def ell_aggregate(
                 else:
                     # supernode rows: the owner's WHOLE multiset, never
                     # row partials — a segmented sort by (owner, label)
+                    # (the CPU oracle's replay only: numpy)
                     owners = jnp.broadcast_to(rowseg[:, None], m.shape)
                     r = segment_mode(
-                        jnp, m.reshape(-1), owners.reshape(-1), num_slots
+                        m.reshape(-1), owners.reshape(-1), num_slots
                     )
             else:
                 r = tree_reduce(jnp, m, op)
@@ -930,18 +926,17 @@ def _shifted(xp, a, k: int, axis: int, fill):
 
 
 # graphlint: traced -- called from the compiled MODE folds
-def _run_lengths(xp, keys, axis: int, max_run: int = None):
+def _run_lengths(xp, keys, axis: int):
     """For arrays sorted along `axis` (lexicographically by `keys`, all
     non-negative), each position's place in its run of equal keys, from 1:
     a run's last position holds the run's length. By doubling: after the
     round that looks `k` places back a position holds min(place, 2k), so
     ceil(log2(width)) rounds of a shifted compare and an add, whatever the
-    values are (`max_run`, where the caller knows no run is longer, ends
-    the rounds earlier)."""
+    values are."""
     width = keys[0].shape[axis]
     place = xp.ones(keys[0].shape, dtype=np.int32)
     k = 1
-    while k < min(width, max_run or width):
+    while k < width:
         same = None
         for a in keys:
             eq = _shifted(xp, a, k, axis, -1) == a  # -1 is no key
@@ -971,44 +966,24 @@ def mode_along(xp, block, axis: int):
     return xp.where(count == best, s, no).min(axis=axis)
 
 
-# graphlint: traced -- the flat MODE fold (segment path, ELL split rows)
-def segment_mode(xp, labels, owners, num_owners: int, max_run: int = None):
-    """MODE of `labels` grouped by `owners` (any order, both 1-D int32):
-    one sort of the (owner, label) pairs, run lengths over the sorted
-    pairs, and per owner the arg-max by (count, then smaller label) — each
-    owner's whole multiset at once. An owner without labels, or with
-    NO_MESSAGE padding only, reads NO_MESSAGE."""
+# graphlint: host -- numpy only, unreachable from traced code
+def segment_mode(labels, owners, num_owners: int):
+    """MODE of `labels` grouped by `owners` (any order, both 1-D int32
+    numpy arrays): one sort of the (owner, label) pairs, run lengths over
+    the sorted pairs, and per owner the arg-max by (count, then smaller
+    label) — each owner's whole multiset at once. An owner without labels,
+    or with NO_MESSAGE padding only, reads NO_MESSAGE. The ELL replay's
+    supernode rows fold through it (the CPU oracle; no device path splits
+    a MODE row)."""
     no = Combiner.NO_MESSAGE
-    if _is_jax(xp):
-        import jax
-
-        o, s = jax.lax.sort((owners, labels), num_keys=2, is_stable=False)
-        seg_max, seg_min = jax.ops.segment_max, jax.ops.segment_min
-    else:
-        o, s, seg_max, seg_min = _segment_mode_host(labels, owners)
-    count = xp.where(s == no, 0, _run_lengths(xp, (o, s), 0, max_run))
-    best = seg_max(count, o, num_segments=num_owners)
-    return seg_min(
-        xp.where(count == best[o], s, no), o, num_segments=num_owners
-    )
-
-
-# graphlint: host -- numpy-only branch, unreachable from traced code
-def _segment_mode_host(labels, owners):
-    """segment_mode's numpy side: the pairs sorted, and the two per-owner
-    folds with `jax.ops.segment_*`'s signature and empty-segment values."""
-    def fold(ufunc, empty):
-        def run(values, seg, num_segments):
-            out = np.full(num_segments, empty, dtype=values.dtype)
-            ufunc.at(out, seg, values)
-            return out
-        return run
-
     order = np.lexsort((labels, owners))
-    return (
-        owners[order], labels[order],
-        fold(np.maximum, 0), fold(np.minimum, Combiner.NO_MESSAGE),
-    )
+    o, s = owners[order], labels[order]
+    count = np.where(s == no, 0, _run_lengths(np, (o, s), 0))
+    best = np.zeros(num_owners, dtype=count.dtype)
+    np.maximum.at(best, o, count)
+    out = np.full(num_owners, no, dtype=s.dtype)
+    np.minimum.at(out, o, np.where(count == best[o], s, no))
+    return out
 
 
 # graphlint: traced -- the hybrid pack's MODE fold
@@ -1059,189 +1034,12 @@ def hybrid_mode_fold(xp, pack, leaves):
     return stacked[tables["mode_unpermute"]]
 
 
-def mode_fold_sizes(pack) -> dict:
+def mode_fold_sizes(pack: HybridPack) -> dict:
     """What a MODE run folds, as the pack knows it (static numbers for the
     run record): slots folded block by block, slots of destinations that
     are brought together first, and how many such destinations."""
-    if hasattr(pack, "torso_slots"):  # HybridPack
-        return {
-            "torso_slots": int(pack.torso_slots),
-            "tail_slots": int(pack.tail_chunks * pack.tail_chunk),
-            "rows_folded_whole": int(sum(h for _k, h in pack.mode_tail_meta)),
-        }
-    split = [b for b in pack.buckets if b[3] is not None]
-    tail = sum(int(np.prod(b[0].shape)) for b in split)
     return {
-        "torso_slots": int(pack.slots) - tail,
-        "tail_slots": tail,
-        "rows_folded_whole": int(sum(b[4] for b in split)),
+        "torso_slots": int(pack.torso_slots),
+        "tail_slots": int(pack.tail_chunks * pack.tail_chunk),
+        "rows_folded_whole": int(sum(h for _k, h in pack.mode_tail_meta)),
     }
-
-
-# --------------------------------------------------------------------------
-# Pallas sorted-segment-sum
-# --------------------------------------------------------------------------
-
-class _SegSumPlan:
-    """Static host-side plan: tile-aligned edge blocks for the kernel.
-
-    Edges (sorted by destination segment) are re-laid-out so each output
-    tile's edge range occupies whole blocks; a block therefore writes into
-    exactly one output tile, enabling the revisit-accumulate output pattern.
-    """
-
-    def __init__(
-        self,
-        seg: np.ndarray,
-        num_segments: int,
-        block: int = 1024,
-        tile: int = 1024,
-    ):
-        self.block = block
-        self.tile = tile
-        self.num_segments = num_segments
-        self.padded_segments = -(-max(num_segments, 1) // tile) * tile
-        num_tiles = self.padded_segments // tile
-
-        seg = np.asarray(seg, dtype=np.int64)
-        m = len(seg)
-        # edges per output tile (seg already sorted ascending)
-        tile_of = seg // tile
-        counts = np.bincount(tile_of, minlength=num_tiles)
-        blocks_per_tile = np.maximum(1, -(-counts // block))
-        total_blocks = int(blocks_per_tile.sum())
-        padded_m = total_blocks * block
-
-        gather_idx = np.zeros(padded_m, dtype=np.int32)
-        pad_mask = np.zeros(padded_m, dtype=np.float32)
-        seg_local = np.zeros(padded_m, dtype=np.int32)
-        out_tile = np.zeros(total_blocks, dtype=np.int32)
-
-        edge_starts = np.zeros(num_tiles + 1, dtype=np.int64)
-        np.cumsum(counts, out=edge_starts[1:])
-        b = 0
-        w = 0
-        for t in range(num_tiles):
-            lo, hi = edge_starts[t], edge_starts[t + 1]
-            k = hi - lo
-            gather_idx[w : w + k] = np.arange(lo, hi, dtype=np.int32)
-            pad_mask[w : w + k] = 1.0
-            seg_local[w : w + k] = (seg[lo:hi] - t * tile).astype(np.int32)
-            nb = int(blocks_per_tile[t])
-            out_tile[b : b + nb] = t
-            b += nb
-            w += nb * block
-        self.gather_idx = gather_idx
-        self.pad_mask = pad_mask
-        self.seg_local = seg_local
-        self.out_tile = out_tile
-        self.num_blocks = total_blocks
-        self._device_args = None
-
-    def device_args(self, jnp) -> dict:
-        """The plan's arrays on the device, shipped once and handed to the
-        compiled superstep as ARGUMENTS (closed over, they would be
-        constant-folded into the module; see TPUExecutor._graph_args).
-        ``seg_local`` is shaped (blocks, 1, B) so a kernel block is a
-        (1, B) row whose last two dims equal the array's."""
-        if self._device_args is None:
-            self._device_args = {
-                "gather_idx": jnp.asarray(self.gather_idx),
-                "pad_mask": jnp.asarray(self.pad_mask),
-                "seg_local": jnp.asarray(
-                    self.seg_local.reshape(self.num_blocks, 1, self.block)
-                ),
-                "out_tile": jnp.asarray(self.out_tile),
-            }
-        return self._device_args
-
-
-def make_segsum_plan(
-    seg: np.ndarray, num_segments: int, block: int = 1024, tile: int = 1024
-) -> _SegSumPlan:
-    return _SegSumPlan(seg, num_segments, block=block, tile=tile)
-
-
-def pallas_sorted_segment_sum(
-    data,
-    plan: _SegSumPlan,
-    args: Optional[dict] = None,
-    interpret: bool = False,
-):
-    """Segment-sum of `data` (per-edge values, original edge order) using a
-    Pallas TPU kernel over the precomputed tile-aligned plan. `args` is
-    ``plan.device_args(jnp)``, passed through a jit boundary by callers
-    that trace this function; left out, the arrays are taken from the plan.
-
-    Returns (num_segments,) float32 sums, accurate to float32: the MXU
-    multiplies in bfloat16, so each value is split into three bfloat16
-    pieces that sum to it exactly, the one-hot is exact in bfloat16, and
-    the products accumulate in float32. (A plain float32 dot at default
-    precision rounds every value to 8 bits: 2.4e-3 relative error measured
-    on a TPU v5e, against 1.2e-7 for this form at the same speed.)
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    B, T = plan.block, plan.tile
-    nb = plan.num_blocks
-    if args is None:
-        args = plan.device_args(jnp)
-
-    # align + pad on device (monotone gather, cheap)
-    data_p = (data[args["gather_idx"]] * args["pad_mask"]).astype(jnp.float32)
-
-    # Every block is 3-D with a squeezed leading dim, so what the kernel
-    # sees is a (1, B) or (1, T) row: Mosaic wants a block's last two dims
-    # to be tile-aligned or equal to the array's, and 1-D blocks leave the
-    # layout to reshapes inside the kernel.
-    def kernel(out_tile_ref, data_ref, seg_ref, out_ref):
-        b = pl.program_id(0)
-        prev = out_tile_ref[jnp.maximum(b - 1, 0)]
-
-        # first block of an output tile (blocks of one tile are contiguous)
-        @pl.when(jnp.logical_or(b == 0, out_tile_ref[b] != prev))
-        def _():
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-        # transposed one-hot (T, B): segment ids stay a lane-major row
-        rows = jax.lax.broadcasted_iota(jnp.int32, (T, B), 0)
-        onehot_t = (rows == seg_ref[...]).astype(jnp.bfloat16)
-        d = data_ref[...]                                   # (1, B) f32
-        hi = d.astype(jnp.bfloat16).astype(jnp.float32)
-        rest = d - hi
-        mid = rest.astype(jnp.bfloat16).astype(jnp.float32)
-        lo = rest - mid
-        # pieces as rows 0..2 of one (16, B) bf16 operand: a native bf16
-        # tile, and one MXU pass for all three
-        piece = jax.lax.broadcasted_iota(jnp.int32, (16, B), 0)
-        lhs = jnp.where(
-            piece == 0, hi,
-            jnp.where(piece == 1, mid, jnp.where(piece == 2, lo, 0.0)),
-        ).astype(jnp.bfloat16)
-        part = jax.lax.dot_general(
-            lhs, onehot_t, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                                   # (16, T)
-        out_ref[...] += jnp.sum(part, axis=0, keepdims=True)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((None, 1, B), lambda b, ot: (b, 0, 0)),
-            pl.BlockSpec((None, 1, B), lambda b, ot: (b, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, 1, T), lambda b, ot: (ot[b], 0, 0)),
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(
-            (plan.padded_segments // T, 1, T), jnp.float32
-        ),
-        interpret=interpret,
-    )(args["out_tile"], data_p.reshape(nb, 1, B), args["seg_local"])
-    return out.reshape(plan.padded_segments)[: plan.num_segments]

@@ -26,10 +26,8 @@ from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.kernels import superstep_scope
 from janusgraph_tpu.olap.vertex_program import (
     Combiner,
-    EdgeTransform,
     Memory,
     VertexProgram,
-    apply_edge_transform,
 )
 
 
@@ -38,10 +36,10 @@ class _DeviceGraph:
     programs use (num_vertices / local_num_vertices / out_degree / ...).
 
     Array fields are LAZY: each transfers to device on first access and is
-    cached. The O(E) per-edge arrays are 2.1GB at scale 23, and an
-    ELL-strategy PageRank touches none of them (the ELL pack is the
-    aggregation structure), so an eager transfer of the full view would
-    ship and hold device memory nothing reads."""
+    cached. The O(E) per-edge arrays are 2.1GB at scale 23, and a
+    PageRank touches none of them (the pack is the aggregation
+    structure), so an eager transfer of the full view would ship and hold
+    device memory nothing reads."""
 
     _LAZY = {
         "active": lambda csr, jnp: jnp.ones(csr.num_vertices),
@@ -159,28 +157,8 @@ class _TracedView:
             self._rec.add(key)
         # absent key: weights are legitimately None on unweighted graphs;
         # any other miss means discovery and execution disagree on the
-        # access set, which _PackView-style drift checks should surface
+        # access set
         return self._arrs.get(key)
-
-
-class _PackView:
-    """ELLPack-shaped facade over traced bucket arrays (duck-typed for
-    ell_aggregate: .buckets / .unpermute / .has_weight)."""
-
-    __slots__ = ("buckets", "unpermute", "has_weight")
-
-    def __init__(self, bucket_args, bucket_slots, unpermute, has_weight):
-        if len(bucket_args) != len(bucket_slots):
-            raise ValueError(
-                f"graph-args bucket count {len(bucket_args)} != compiled "
-                f"bucket metadata {len(bucket_slots)} (pack drift)"
-            )
-        self.buckets = [
-            (b["idx"], b.get("w"), b.get("valid"), b.get("rowseg"), ns)
-            for b, ns in zip(bucket_args, bucket_slots)
-        ]
-        self.unpermute = unpermute
-        self.has_weight = has_weight
 
 
 def _segment_ids(indptr: np.ndarray, m: int) -> np.ndarray:
@@ -200,52 +178,31 @@ def _pytree_nbytes(tree) -> int:
     return int(getattr(tree, "nbytes", 0) or 0)
 
 
-def _segment_reduce(jnp, op: str, data, segment_ids, num_segments: int):
-    import jax
-
-    seg_fn = Combiner.monoid(
-        op, "the flat segment-reduce",
-        jax.ops.segment_sum, jax.ops.segment_min, jax.ops.segment_max,
-    )
-    return seg_fn(data, segment_ids, num_segments=num_segments)
-
-
 class TPUExecutor:
     """Single-device executor. The sharded (mesh) executor lives in
     janusgraph_tpu/parallel/.
 
-    `strategy` selects the aggregation kernel (janusgraph_tpu/olap/kernels.py):
-      - "ell"     degree-bucketed ELLPACK gather + dense reduce
-                  (scatter-free, every combiner)
-      - "hybrid"  exact-width ELL torso + chunked CSR tail for hubs
-                  (bitwise-equal to "ell", pad ratio ~1)
-      - "segment" XLA gather + segment-reduce (MODE: one segmented sort)
-      - "pallas"  Pallas sorted-segment-sum kernel (SUM monoid; MIN and
-                  MAX fall back to "ell", MODE is refused)
-      - "auto"    (default) the profiler-driven autotuner picks among
-                  ell/hybrid/segment from the degree histogram + device
-                  roofline (olap/autotune.py; decision recorded in
-                  run_info["autotune"])
+    Every program, edge view and typed edge channel aggregates over ONE
+    structure, the hybrid pack (olap/kernels.HybridPack: exact-width
+    torso + chunked tail for hubs, one index vector, one gather; bitwise
+    equal to the ELL replay the CPU oracle keeps). olap/autotune.decide
+    sizes its hub cutoff and tail chunk from the degree histogram and the
+    device's price column; the decision is recorded in
+    run_info["autotune"].
     """
 
     def __init__(
         self,
         csr: CSRGraph,
-        use_pallas: bool = False,
-        strategy: str = "auto",
         ell_max_capacity: int = None,
         frontier: str = "auto",
-        ell_auto_bytes: int = None,
-        ell_auto_pad: float = None,
         channel_cache_size: int = None,
         frontier_cc_min_edges: int = None,
         frontier_f_min: int = None,
         frontier_e_min: int = None,
         frontier_tier_growth: int = None,
-        autotune: bool = None,
         hub_cutoff: int = None,
         tail_chunk: int = None,
-        autotune_min_gain: float = None,
         autotune_max_tiers: int = None,
         autotune_persist: bool = None,
         features_dim_tier: int = None,
@@ -257,7 +214,8 @@ class TPUExecutor:
         self.jax = jax
         self.jnp = jnp
         self.csr = csr
-        self.ell_max_capacity = ell_max_capacity  # computer.ell-max-capacity
+        # computer.ell-max-capacity: rows above it are split
+        self.ell_max_capacity = ell_max_capacity or (1 << 14)
         # delta-CSR overlay (olap/delta.OverlayView): supersteps consume
         # the pending write overlay FUSED with the base pack — base
         # aggregation over the untouched device-resident pack, delta
@@ -279,17 +237,10 @@ class TPUExecutor:
         # cached executor returning to a clean snapshot reuses the already
         # shipped base arrays instead of re-uploading them
         self._base_g = self.g if self._delta is None else None
-        if strategy == "auto" and use_pallas:
-            strategy = "pallas"
-        if strategy not in ("auto", "ell", "hybrid", "segment", "pallas"):
-            raise ValueError(f"unknown aggregation strategy: {strategy!r}")
-        # computer.autotune-* — the profiler-driven tuner behind "auto"
-        # (olap/autotune.py); explicit strategies bypass it but are still
-        # recorded as a source="config" decision
-        self._autotune_enabled = True if autotune is None else bool(autotune)
+        # computer.autotune-hub-cutoff / -tail-chunk force the pack's two
+        # sizes; unset, olap/autotune.decide searches them
         self._hub_cutoff_cfg = hub_cutoff or None
         self._tail_chunk_cfg = tail_chunk or None
-        self._autotune_min_gain = autotune_min_gain
         self._autotune_max_tiers = autotune_max_tiers
         # computer.autotune-persist: serialize the last measured record
         # next to the checkpoint path and feed it back into decide() on
@@ -312,12 +263,7 @@ class TPUExecutor:
         # special-case, mirroring FulgoraGraphComputer.java:249-253
         self._frontier_cfg = frontier
         self._frontier_engine = None
-        # computer.ell-auto-budget-bytes / ell-auto-pad /
-        # channel-cache-size overrides (class attrs remain the defaults)
-        if ell_auto_bytes is not None:
-            self.ELL_AUTO_BYTES = ell_auto_bytes
-        if ell_auto_pad is not None:
-            self.ELL_AUTO_PAD = ell_auto_pad
+        # computer.channel-cache-size (the class attr is the default)
         if channel_cache_size is not None:
             self.CHANNEL_CACHE_SIZE = channel_cache_size
         # computer.frontier-cc-min-edges / frontier-f-min / frontier-e-min
@@ -327,11 +273,6 @@ class TPUExecutor:
         self._frontier_e_min = frontier_e_min
         # computer.frontier-tier-growth — tier ladder growth factor
         self._frontier_tier_growth = frontier_tier_growth
-        # "auto" resolves lazily per edge view: an undirected program packs
-        # in+out edges (~2x footprint), so the budget check must see the
-        # view it will actually ship
-        self._strategy_cfg = strategy
-        self._auto_cache: Dict[Tuple, str] = {}
         # the device every array of this executor lands on (jnp.asarray
         # places on this process's first device); run records carry it
         from janusgraph_tpu.olap.device import (
@@ -342,9 +283,6 @@ class TPUExecutor:
         count_compiles()
         self._device = jax.local_devices()[0]
         self._device_info = describe_devices([self._device])
-        # Pallas kernels are compiled by Mosaic on a TPU and interpreted
-        # everywhere else (tests); run_info["pallas_interpret"] says which
-        self._interpret = self._device.platform != "tpu"
         from collections import OrderedDict
 
         #: per-run execution record ({"path", "supersteps", "wall_s", ...});
@@ -353,7 +291,7 @@ class TPUExecutor:
         #: `registry.last_run("olap")` (observability/metrics_core.py)
         self.last_run_info: Dict[str, object] = {}
         #: bytes of the graph-argument pytree shipped to the last compiled
-        #: dispatch (view fields + ELL buckets) — host-side arithmetic on
+        #: dispatch (view fields + the pack) — host-side arithmetic on
         #: static shapes, no device sync
         self._last_arg_bytes = 0
         self._compiled: Dict[str, object] = {}
@@ -371,19 +309,18 @@ class TPUExecutor:
         # aggregator's monoid inline; the fused path needs the full pytree
         # + identities BEFORE the first compiled dispatch)
         self._metric_ops: Dict[Tuple, Dict[str, str]] = {}
-        self._ell_packs: Dict[bool, object] = {}
+        # the pack of each edge view, keyed by `undirected`
         self._hybrid_packs: Dict[bool, object] = {}
-        # per-(strategy, orientation) row-destination vectors for the
-        # dense tier's fused SDDMM pass (features/kernels row-dst builders)
-        self._sddmm_rows_cache: Dict[Tuple, object] = {}
+        # per-orientation row-destination vectors for the dense tier's
+        # fused SDDMM pass (features/kernels.hybrid_row_dsts)
+        self._sddmm_rows_cache: Dict[bool, object] = {}
         self._channel_packs: "OrderedDict" = OrderedDict()
-        self._segsum_plans: Dict[str, object] = {}
 
     def set_delta(self, delta) -> None:
         """Swap the pending-overlay view WITHOUT rebuilding the executor —
         the warm-submit executor-cache path (olap/computer.py): the base
-        CSR, ELL/hybrid packs, compiled executables, and autotune
-        decisions all survive across submits. A new overlay with the same
+        CSR, packs, compiled executables, and autotune decisions all
+        survive across submits. A new overlay with the same
         lane signature reuses the compiled fused executable outright (the
         lanes ship as jit ARGUMENTS); a different signature compiles its
         own variant under the sig-keyed executable cache. ``None`` (or an
@@ -418,54 +355,15 @@ class TPUExecutor:
             self.csr, self.jnp, host_view=FusedHostView(delta)
         )
 
-    @staticmethod
-    def ell_footprint(
-        csr: CSRGraph, max_capacity: int = 1 << 14, undirected: bool = False
-    ):
-        """Estimate the ELL pack's device footprint WITHOUT building it:
-        per-vertex slot count = next-pow2(degree) (capped, supernodes
-        row-split at ~1x). Unweighted graphs ship idx (i32) only — padded
-        slots read the identity through the sentinel; weighted graphs add
-        weight + valid f32 matrices. Undirected programs pack BOTH
-        orientations, so their estimate uses in+out degree. Computed from
-        the degree histogram in one numpy pass."""
-        deg = np.diff(csr.in_indptr).astype(np.int64)
-        edges = csr.num_edges
-        if undirected:
-            deg = deg + np.diff(csr.out_indptr).astype(np.int64)
-            edges *= 2
-        caps = np.maximum(1, 1 << np.ceil(
-            np.log2(np.maximum(deg, 1))
-        ).astype(np.int64))
-        slots = int(np.minimum(caps, max_capacity).sum())
-        # row-split remainder of supernodes keeps ~1 slot per edge
-        over = deg > max_capacity
-        if over.any():
-            slots += int((deg[over] - max_capacity).sum())
-        per_slot = 12 if csr.in_edge_weight is not None else 4
-        return {
-            "slots": slots,
-            "bytes": slots * per_slot,
-            "pad_ratio": slots / max(1, edges),
-        }
-
-    #: HBM budget the auto strategy lets the ELL pack use (v5e lite has
-    #: 16GB; leave room for state/messages/output + XLA scratch)
-    ELL_AUTO_BYTES = 6 << 30
-    ELL_AUTO_PAD = 3.0
-
     def _device_kind(self) -> str:
         return self._device.device_kind
 
     def _autotune_overrides(self) -> dict:
-        """The computer.autotune-* / legacy-budget knobs, in the tuner's
+        """The computer.autotune-* / frontier-* knobs, in the tuner's
         override vocabulary (None entries mean 'search')."""
         return {
             "hub_cutoff": self._hub_cutoff_cfg,
             "tail_chunk": self._tail_chunk_cfg,
-            "min_gain": self._autotune_min_gain,
-            "budget_bytes": self.ELL_AUTO_BYTES,
-            "max_pad": self.ELL_AUTO_PAD,
             "f_min": self._frontier_f_min,
             "e_min": self._frontier_e_min,
             "max_tiers": self._autotune_max_tiers,
@@ -497,8 +395,9 @@ class TPUExecutor:
 
     def _stats_kwargs(self) -> dict:
         return {
-            "max_capacity": self.ell_max_capacity or (1 << 14),
+            "max_capacity": self.ell_max_capacity,
             "tail_chunk": self._tail_chunk_cfg or 256,
+            "hub_cutoff": self._hub_cutoff_cfg,
         }
 
     def _decide(self, stats, measured: dict = None):
@@ -507,46 +406,12 @@ class TPUExecutor:
         from janusgraph_tpu.olap import autotune
 
         ov = self._autotune_overrides()
-        if self._strategy_cfg != "auto":
-            ov["strategy"] = self._strategy_cfg
         if self._features_dim_tier:
             ov["feature_dim_tier"] = self._features_dim_tier
         return autotune.decide(
             stats, self._device_kind(), overrides=ov, measured=measured,
             feature_dim=self._feature_dim_run,
         )
-
-    def _auto_strategy(self, undirected: bool) -> str:
-        """'auto' resolution. With the tuner enabled (the default) this is
-        the autotune decision — strategy chosen against the device roofline
-        from the degree histogram (ISSUE 6 closes the PR 5 loop); the
-        legacy footprint-budget heuristic remains as the fallback when
-        computer.autotune=false (VERDICT r2 shape: ELL within budget,
-        segment otherwise)."""
-        if self._autotune_enabled:
-            return self._autotune(undirected).strategy
-        fp = self.ell_footprint(
-            self.csr, self.ell_max_capacity or (1 << 14), undirected
-        )
-        if fp["bytes"] > self.ELL_AUTO_BYTES or fp["pad_ratio"] > self.ELL_AUTO_PAD:
-            return "segment"
-        return "ell"
-
-    @property
-    def strategy(self) -> str:
-        """The configured strategy; 'auto' reports the directed-view
-        resolution (display/back-compat)."""
-        return self._base_strategy(False)
-
-    def _base_strategy(self, undirected: bool) -> str:
-        base = self._strategy_cfg
-        if base == "auto":
-            key = (undirected, self._feature_dim_run)
-            base = self._auto_cache.get(key)
-            if base is None:
-                base = self._auto_strategy(undirected)
-                self._auto_cache[key] = base
-        return base
 
     def _edge_view(self, undirected: bool):
         """(src, dst, w) edge arrays for one orientation view — the single
@@ -569,58 +434,27 @@ class TPUExecutor:
             )
         return src, dst, w
 
-    def _ell_pack(self, undirected: bool):
-        from janusgraph_tpu.olap.kernels import ELLPack
-
-        pack = self._ell_packs.get(undirected)
-        if pack is None:
-            src, dst, w = self._edge_view(undirected)
-            pack = ELLPack(
-                src, dst, w, self.csr.num_vertices, **self._ell_kwargs()
-            )
-            pack.device_put(self.jnp)
-            self._ell_packs[undirected] = pack
-        return pack
-
-    def _sddmm_rows(self, strategy: str, undirected: bool):
-        """Row-destination vectors for the fused SDDMM pass, aligned with
-        the strategy's pack layout (features/kernels builders); built once
-        per (strategy, orientation) and kept device-resident."""
+    def _sddmm_rows(self, undirected: bool):
+        """Row-destination vector for the fused SDDMM pass, aligned with
+        the pack's layout (features/kernels.hybrid_row_dsts); built once
+        per orientation and kept device-resident."""
         from janusgraph_tpu.olap.features import kernels as fkernels
 
-        key = (strategy, undirected)
-        rows = self._sddmm_rows_cache.get(key)
-        if rows is not None:
-            return rows
-        src, dst, _w = self._edge_view(undirected)
-        cap = self.ell_max_capacity or (1 << 14)
-        if strategy == "ell":
-            host = fkernels.ell_row_dsts(
-                src, dst, self.csr.num_vertices, max_capacity=cap
-            )
-            rows = [self.jnp.asarray(r) for r in host]
-        else:
+        rows = self._sddmm_rows_cache.get(undirected)
+        if rows is None:
+            src, dst, _w = self._edge_view(undirected)
             pack = self._hybrid_pack(undirected)
-            host = fkernels.hybrid_row_dsts(
+            rows = self.jnp.asarray(fkernels.hybrid_row_dsts(
                 src, dst, self.csr.num_vertices,
                 hub_cutoff=pack.hub_cutoff, tail_chunk=pack.tail_chunk,
-                max_capacity=cap,
-            )
-            rows = self.jnp.asarray(host)
-        self._sddmm_rows_cache[key] = rows
+                max_capacity=self.ell_max_capacity,
+            ))
+            self._sddmm_rows_cache[undirected] = rows
         return rows
-
-    def _ell_kwargs(self):
-        return (
-            {"max_capacity": self.ell_max_capacity}
-            if self.ell_max_capacity
-            else {}
-        )
 
     def _hybrid_pack(self, undirected: bool):
         """HybridPack for one edge view, with the tuner's (or configured)
-        hub cutoff + tail chunk. Built and device-put once, like the ELL
-        pack."""
+        hub cutoff + tail chunk. Built and device-put once."""
         pack = self._hybrid_packs.get(undirected)
         if pack is None:
             pack = self._build_hybrid(
@@ -635,9 +469,9 @@ class TPUExecutor:
         src, dst, w = edges
         pack = HybridPack(
             src, dst, w, self.csr.num_vertices,
-            hub_cutoff=self._hub_cutoff_cfg or decision.hub_cutoff or 512,
-            tail_chunk=self._tail_chunk_cfg or decision.tail_chunk or 256,
-            **self._ell_kwargs(),
+            hub_cutoff=decision.hub_cutoff,
+            tail_chunk=decision.tail_chunk,
+            max_capacity=self.ell_max_capacity,
         )
         return pack.device_put(self.jnp)
 
@@ -647,17 +481,14 @@ class TPUExecutor:
     CHANNEL_CACHE_SIZE = 8
 
     def _channel_pack(self, program: VertexProgram, name: str):
-        """(strategy, pack, decision) for one named EdgeChannel (typed edge
-        view): the ELL or the hybrid pack of the channel's filtered edge
-        list, as the tuner decides from THAT list's degrees (a configured
-        'ell' or 'hybrid' holds here too; whatever else resolves to a
-        flat path packs ELL). Cached per channel VALUE (frozen
+        """(pack, decision) for one named EdgeChannel (typed edge view):
+        the hybrid pack of the channel's filtered edge list, sized by the
+        tuner from THAT list's degrees. Cached per channel VALUE (frozen
         dataclass) — names like 's0' recur across different programs on a
         reused executor and must not alias each other's packs. LRU-bounded;
         eviction also drops compiled supersteps that close over the pack."""
         from janusgraph_tpu.olap import autotune
         from janusgraph_tpu.olap.csr import channel_edges
-        from janusgraph_tpu.olap.kernels import ELLPack
 
         channel = program.edge_channels[name]
         entry = self._channel_packs.get(channel)
@@ -665,81 +496,34 @@ class TPUExecutor:
             self._channel_packs.move_to_end(channel)
             return entry
         src, dst, w = channel_edges(self.csr, channel)
-        n = self.csr.num_vertices
-        decision = None
-        if self._strategy_cfg == "hybrid" or (
-            self._strategy_cfg == "auto" and self._autotune_enabled
-        ):
-            decision = self._decide(autotune.GraphStats.from_degrees(
-                np.bincount(dst, minlength=n), len(src), w is not None,
-                **self._stats_kwargs(),
-            ))
-        if decision is not None and decision.strategy == "hybrid":
-            strategy = "hybrid"
-            pack = self._build_hybrid((src, dst, w), decision)
-        else:
-            strategy = "ell"
-            pack = ELLPack(src, dst, w, n, **self._ell_kwargs())
-            pack.device_put(self.jnp)
-        entry = self._channel_packs[channel] = (strategy, pack, decision)
+        decision = self._decide(autotune.GraphStats.from_degrees(
+            np.bincount(dst, minlength=self.csr.num_vertices), len(src),
+            w is not None, **self._stats_kwargs(),
+        ))
+        entry = self._channel_packs[channel] = (
+            self._build_hybrid((src, dst, w), decision), decision
+        )
         while len(self._channel_packs) > self.CHANNEL_CACHE_SIZE:
             evicted, _ = self._channel_packs.popitem(last=False)
             self._compiled = {
                 k: v for k, v in self._compiled.items()
-                if not (len(k) >= 5 and k[4] == evicted)
+                if not (k[0] == "step" and k[3] == evicted)
             }
         return entry
 
-    def _segsum_plan(self, orientation: str):
-        from janusgraph_tpu.olap.kernels import make_segsum_plan
-
-        plan = self._segsum_plans.get(orientation)
-        if plan is None:
-            csr = self.csr
-            if orientation == "in":
-                seg = _segment_ids(csr.in_indptr, csr.num_edges)
-            else:
-                seg = _segment_ids(csr.out_indptr, csr.num_edges)
-            plan = make_segsum_plan(seg, csr.num_vertices)
-            self._segsum_plans[orientation] = plan
-        return plan
-
-    def _pallas_args(self, program: VertexProgram) -> dict:
-        """{orientation: plan arrays on device} for the Pallas strategy —
-        jit arguments like the ELL buckets, not closed-over constants."""
-        orientations = ("in", "out") if program.undirected else ("in",)
-        return {
-            o: self._segsum_plan(o).device_args(self.jnp)
-            for o in orientations
-        }
-
-    def _resolve_strategy(self, op: str, undirected: bool = False) -> str:
-        """The strategy actually used for a combiner monoid and edge view:
-        auto resolves against the view's footprint; the pallas kernel is
-        SUM-only, everything else falls back to ELL."""
-        base = self._base_strategy(undirected)
-        if base == "pallas" and op != Combiner.SUM:
-            # the kernel accumulates block partials into its output tile
-            Combiner.require_foldable(
-                op, "the Pallas sorted-segment-sum strategy"
-            )
-            return "ell"
-        return base
+    def _resolve_pack(self, program: VertexProgram, channel: str = None):
+        """The pack one superstep variant aggregates over: the named
+        channel's, or the program's edge view's — the single answer shared
+        by `_pack_args` (which ships the pack's arrays) and
+        `_superstep_body` (which captures its static metadata)."""
+        if channel is not None:
+            return self._channel_pack(program, channel)[0]
+        return self._hybrid_pack(program.undirected)
 
     def prewarm(self, program: VertexProgram) -> None:
-        """Build + device-put the aggregation structures a program will use,
-        so transfer cost is paid (and measurable) before the first run."""
-        strategy = self._resolve_strategy(
-            program.combiner, program.undirected
-        )
-        if strategy == "ell":
-            self._ell_pack(program.undirected)
-        elif strategy == "hybrid":
-            self._hybrid_pack(program.undirected)
-        elif strategy == "pallas":
-            self._segsum_plan("in")
-            if program.undirected:
-                self._segsum_plan("out")
+        """Build + device-put the pack a program will use, so transfer cost
+        is paid (and measurable) before the first run."""
+        self._hybrid_pack(program.undirected)
 
     # ------------------------------------------------------------ superstep
     def _used_view_keys(
@@ -755,10 +539,7 @@ class TPUExecutor:
         so the fused path needs no second discovery pass."""
         jnp = self.jnp
         ch_val = program.edge_channels[channel] if channel is not None else None
-        key = (
-            program.cache_key(), op, self._strategy_cfg, ch_val,
-            self._delta_sig(program),
-        )
+        key = (program.cache_key(), op, ch_val, self._delta_sig(program))
         used = self._viewkeys.get(key)
         if used is not None:
             return used
@@ -772,23 +553,7 @@ class TPUExecutor:
             view["in_w"] = g.spec("in_w")
         if self.csr.out_edge_weight is not None:
             view["out_w"] = g.spec("out_w")
-        args = {"view": view}
-        strategy, pack = self._resolve_pack(program, op, channel)
-        if strategy == "ell":
-            args["ell"] = self._pack_args(pack)
-            args["unpermute"] = pack.unpermute
-        elif strategy == "hybrid":
-            args["hyb"] = self._hybrid_args(pack, op)
-        elif strategy == "pallas":
-            args["pallas"] = self._pallas_args(program)
-        if getattr(program, "message_mode", None) == "sddmm" and strategy in (
-            "ell", "hybrid"
-        ):
-            args["sddmm"] = self._sddmm_rows(strategy, program.undirected)
-        if self._delta is not None:
-            args["delta"] = self._delta.device_args(
-                jnp, bool(program.undirected)
-            )
+        args = {"view": view, **self._pack_args(program, op, channel)}
         if state is None:
             # cold discovery (direct _graph_args call before any run):
             # setup just to learn the state/metric pytree shapes
@@ -817,28 +582,25 @@ class TPUExecutor:
         self._viewkeys[key] = used
         return used
 
-    def _hybrid_args(self, pack, op: str) -> dict:
-        """The hybrid pack's device arrays as jit arguments; a MODE run
-        also gets the pack's whole-row tables (kernels.HybridPack
-        .mode_tables), shipped once and to MODE programs only, so a monoid
-        program's executable keeps its signature."""
-        if op != Combiner.MODE:
-            return dict(pack.arrays)
-        return {**pack.arrays, **pack.mode_tables(self.jnp)}
-
-    @staticmethod
-    def _pack_args(pack):
-        buckets = []
-        for idx, w, valid, rowseg, _ns in pack.buckets:
-            b = {"idx": idx}
-            if w is not None:
-                b["w"] = w
-            if valid is not None:
-                b["valid"] = valid
-            if rowseg is not None:
-                b["rowseg"] = rowseg
-            buckets.append(b)
-        return buckets
+    def _pack_args(self, program: VertexProgram, op: str, channel) -> dict:
+        """The jit arguments beside the view: the pack's device arrays
+        (`hyb`), the dense tier's row destinations (`sddmm`) and the
+        overlay's lanes (`delta`). A MODE run also gets the pack's
+        whole-row tables (kernels.HybridPack.mode_tables), shipped once
+        and to MODE programs only, so a monoid program's executable keeps
+        its signature."""
+        pack = self._resolve_pack(program, channel)
+        hyb = dict(pack.arrays)
+        if op == Combiner.MODE:
+            hyb.update(pack.mode_tables(self.jnp))
+        args = {"hyb": hyb}
+        if getattr(program, "message_mode", None) == "sddmm":
+            args["sddmm"] = self._sddmm_rows(program.undirected)
+        if self._delta is not None:
+            args["delta"] = self._delta.device_args(
+                self.jnp, bool(program.undirected)
+            )
+        return args
 
     def _graph_args(self, program: VertexProgram, op: str, channel: str = None):
         """The device-array pytree a compiled superstep consumes as an
@@ -854,23 +616,7 @@ class TPUExecutor:
             val = getattr(g, attr_of.get(key, key))
             if val is not None:
                 view[key] = val
-        args = {"view": view}
-        strategy, pack = self._resolve_pack(program, op, channel)
-        if strategy == "ell":
-            args["ell"] = self._pack_args(pack)
-            args["unpermute"] = pack.unpermute
-        elif strategy == "hybrid":
-            args["hyb"] = self._hybrid_args(pack, op)
-        elif strategy == "pallas":
-            args["pallas"] = self._pallas_args(program)
-        if getattr(program, "message_mode", None) == "sddmm" and strategy in (
-            "ell", "hybrid"
-        ):
-            args["sddmm"] = self._sddmm_rows(strategy, program.undirected)
-        if self._delta is not None:
-            args["delta"] = self._delta.device_args(
-                self.jnp, bool(program.undirected)
-            )
+        args = {"view": view, **self._pack_args(program, op, channel)}
         self._last_arg_bytes = _pytree_nbytes(args)
         return args
 
@@ -890,21 +636,6 @@ class TPUExecutor:
             )
         return sig
 
-    def _resolve_pack(self, program: VertexProgram, op: str, channel: str = None):
-        """(strategy, pack-or-None) for one combiner monoid + edge view —
-        the single source of truth shared by `_graph_args` (which ships the
-        pack's arrays) and `_superstep_body` (which captures its static
-        bucket metadata), so the two can never disagree on bucket count."""
-        strategy = self._resolve_strategy(op, program.undirected)
-        pack = None
-        if channel is not None:
-            strategy, pack, _decision = self._channel_pack(program, channel)
-        elif strategy == "ell":
-            pack = self._ell_pack(program.undirected)
-        elif strategy == "hybrid":
-            pack = self._hybrid_pack(program.undirected)
-        return strategy, pack
-
     def _superstep_body(self, program: VertexProgram, op: str, channel: str = None):
         """Build the (un-jitted) superstep function for one combiner monoid
         (and, for channel-switching programs, one named edge channel —
@@ -915,7 +646,6 @@ class TPUExecutor:
         jnp = self.jnp
         n = self.g.local_num_vertices
         tmpl = self.g
-        identity = Combiner.IDENTITY[op]
         # delta overlay: base aggregation runs over the base rows only
         # (the pack's sentinel is index n_base); the lanes merge after
         delta = self._delta
@@ -925,150 +655,43 @@ class TPUExecutor:
             dmeta = dict(
                 delta.lanes(bool(program.undirected))["_meta"]
             )
-        strategy, pack_meta = self._resolve_pack(program, op, channel)
-        if op == Combiner.MODE and strategy not in ("ell", "hybrid"):
-            # flat path: no destination has more messages than this
-            deg = np.diff(self.csr.in_indptr)
-            if program.undirected:
-                deg = deg + np.diff(self.csr.out_indptr)
-            longest_run = int(deg.max()) if len(deg) else 1
-        if strategy == "pallas":
-            plans = [("in", self._segsum_plan("in"))]
-            if program.undirected:
-                plans.append(("out", self._segsum_plan("out")))
-        elif strategy == "ell":
-            bucket_slots = [b[4] for b in pack_meta.buckets]
-            has_weight = pack_meta.has_weight
-        # "hybrid": pack_meta (the HybridPack) is captured for its STATIC
-        # metadata only (bucket widths/rows); arrays arrive via gargs
-
-        def aggregate(outgoing, src_idx, dst_seg, weight):
-            with superstep_scope(jnp, "gather"):
-                msgs = apply_edge_transform(
-                    jnp, outgoing[src_idx], weight,
-                    program.edge_transform, program.edge_transform_cols,
-                )
-            with superstep_scope(jnp, "fold"):
-                return _segment_reduce(jnp, op, msgs, dst_seg, nb)
-
-        def mode_aggregate(outgoing, gv):
-            """The flat path's MODE: the labels along both orientations
-            laid end to end, and one segmented sort over all of them."""
-            from janusgraph_tpu.olap.kernels import segment_mode
-
-            with superstep_scope(jnp, "gather"):
-                labels, owners = outgoing[gv.in_src], gv.in_dst_seg
-                if program.undirected:
-                    labels = jnp.concatenate([labels, outgoing[gv.out_dst]])
-                    owners = jnp.concatenate([owners, gv.out_src_seg])
-            with superstep_scope(jnp, "fold"):
-                return segment_mode(jnp, labels, owners, nb, longest_run)
-
-        def pallas_aggregate(outgoing, gv, plan_args):
-            from janusgraph_tpu.olap.kernels import pallas_sorted_segment_sum
-
-            def one(orientation, plan):
-                if orientation == "in":
-                    src_idx, weight = gv.in_src, gv.in_edge_weight
-                else:
-                    src_idx, weight = gv.out_dst, gv.out_edge_weight
-                with superstep_scope(jnp, "gather"):
-                    msgs = outgoing[src_idx]
-                    if program.edge_transform == EdgeTransform.MUL_WEIGHT and weight is not None:
-                        msgs = msgs * weight
-                    elif program.edge_transform == EdgeTransform.ADD_WEIGHT and weight is not None:
-                        msgs = msgs + weight
-                with superstep_scope(jnp, "fold"):
-                    return pallas_sorted_segment_sum(
-                        msgs, plan, plan_args[orientation],
-                        interpret=self._interpret,
-                    )
-
-            total = one(*plans[0])
-            for orientation, plan in plans[1:]:
-                other = one(orientation, plan)
-                with superstep_scope(jnp, "fold"):
-                    total = total + other
-            return total
+        # the pack is captured for its STATIC metadata only (bucket
+        # widths/rows); its arrays arrive via gargs
+        pack_meta = self._resolve_pack(program, channel)
 
         def superstep(state, superstep_idx, memory_in, gargs):
             gv = _TracedView(tmpl, gargs["view"], self._view_record)
-            from janusgraph_tpu.olap.kernels import ell_aggregate
+            from janusgraph_tpu.olap.kernels import (
+                HybridPackView,
+                hybrid_aggregate,
+            )
 
             # the four stages are named for the device profile (none
-            # encloses another; the packs' aggregations name their own
-            # gather and fold, the fused sddmm kernels interleave the two
-            # and name neither)
+            # encloses another; the pack's aggregation names its own
+            # gather and fold, the fused sddmm kernel interleaves the two
+            # and names neither)
             with superstep_scope(jnp, "message"):
                 full_out = program.message(state, superstep_idx, gv, jnp)
-            # base aggregation consumes the base-row slice: the packs'
+            # base aggregation consumes the base-row slice: the pack's
             # sentinel (index n_base) must keep reading the identity
             outgoing = full_out if delta is None else full_out[:nb]
-            mode = getattr(program, "message_mode", None)
-            if mode == "sddmm":
+            hv = HybridPackView(gargs["hyb"], pack_meta)
+            if getattr(program, "message_mode", None) == "sddmm":
                 # dense tier: fused SDDMM+SpMM — per-edge dot-attention
                 # coefficients computed in the same gather pass
                 from janusgraph_tpu.olap.features.kernels import (
-                    sddmm_ell_aggregate,
                     sddmm_hybrid_aggregate,
-                    sddmm_segment_aggregate,
                 )
 
-                if strategy == "ell":
-                    pv = _PackView(
-                        gargs["ell"], bucket_slots, gargs["unpermute"],
-                        has_weight,
-                    )
-                    agg = sddmm_ell_aggregate(
-                        jnp, pv, gargs["sddmm"], outgoing, op
-                    )
-                elif strategy == "hybrid":
-                    from janusgraph_tpu.olap.kernels import HybridPackView
-
-                    hv = HybridPackView(gargs["hyb"], pack_meta)
-                    agg = sddmm_hybrid_aggregate(
-                        jnp, hv, gargs["sddmm"], outgoing, op
-                    )
-                else:
-                    agg = sddmm_segment_aggregate(
-                        jnp, outgoing, gv.in_src, gv.in_dst_seg, n
-                    )
-            elif strategy == "ell":
-                pv = _PackView(
-                    gargs["ell"], bucket_slots, gargs["unpermute"], has_weight
+                agg = sddmm_hybrid_aggregate(
+                    jnp, hv, gargs["sddmm"], outgoing, op
                 )
-                agg = ell_aggregate(
-                    jnp, pv, outgoing, op, program.edge_transform,
-                    program.edge_transform_cols,
-                )
-            elif strategy == "hybrid":
-                from janusgraph_tpu.olap.kernels import (
-                    HybridPackView,
-                    hybrid_aggregate,
-                )
-
-                hv = HybridPackView(gargs["hyb"], pack_meta)
+            else:
+                # a MODE run's fold is inside hybrid_aggregate too
                 agg = hybrid_aggregate(
                     jnp, hv, outgoing, op, program.edge_transform,
                     program.edge_transform_cols,
                 )
-            elif strategy == "pallas" and outgoing.ndim == 1:
-                agg = pallas_aggregate(outgoing, gv, gargs["pallas"])
-            elif op == Combiner.MODE:
-                agg = mode_aggregate(outgoing, gv)
-            else:
-                agg = aggregate(
-                    outgoing, gv.in_src, gv.in_dst_seg, gv.in_edge_weight
-                )
-                if program.undirected:
-                    rev = aggregate(
-                        outgoing, gv.out_dst, gv.out_src_seg, gv.out_edge_weight
-                    )
-                    with superstep_scope(jnp, "fold"):
-                        agg = Combiner.monoid(
-                            op, "the flat path's merge of two orientations",
-                            jnp.add, jnp.minimum, jnp.maximum,
-                        )(agg, rev)
             if delta is not None:
                 # fuse the overlay lanes over the base aggregate (SUM:
                 # add - tombstone subtraction; MIN/MAX: dirty rows
@@ -1097,7 +720,7 @@ class TPUExecutor:
     def _superstep_fn(self, program: VertexProgram, op: str, channel: str = None):
         """Jitted single superstep (host-loop path)."""
         ch_val = program.edge_channels[channel] if channel is not None else None
-        key = ("step", program.cache_key(), op, self._strategy_cfg, ch_val,
+        key = ("step", program.cache_key(), op, ch_val,
                self._delta_sig(program))
         if key not in self._compiled:
             self._compiled[key] = self.jax.jit(
@@ -1115,7 +738,7 @@ class TPUExecutor:
         from janusgraph_tpu.observability import profiler
 
         ch_val = program.edge_channels[channel] if channel is not None else None
-        key = ("cost", program.cache_key(), op, self._strategy_cfg, ch_val,
+        key = ("cost", program.cache_key(), op, ch_val,
                self._delta_sig(program))
         cost = self._kernel_costs.get(key)
         if cost is not None:
@@ -1150,7 +773,7 @@ class TPUExecutor:
         scalars, so the same executable serves the full run and any
         checkpoint-bounded chunk of it. No per-superstep host round trips:
         compiler-visible control flow instead of a host loop."""
-        key = ("fused", program.cache_key(), op, self._strategy_cfg, None,
+        key = ("fused", program.cache_key(), op, None,
                self._delta_sig(program))
         if key in self._compiled:
             return self._compiled[key]
@@ -1321,7 +944,6 @@ class TPUExecutor:
             "olap.run",
             program=type(program).__name__,
             executor="tpu",
-            strategy=self._strategy_cfg,
         ) as sp:
             from janusgraph_tpu.exceptions import SuperstepPreempted
 
@@ -1402,7 +1024,6 @@ class TPUExecutor:
         graphlint JG106 keeps it that way."""
         info = self.last_run_info
         info.update(self._device_info)
-        info["pallas_interpret"] = self._interpret
         info["wall_s"] = round(wall_s, 4)
         info["retraces"] = new_execs
         info["h2d_arg_bytes"] = int(self._last_arg_bytes)
@@ -1411,7 +1032,6 @@ class TPUExecutor:
         )
         undirected = bool(getattr(program, "undirected", False))
         pad_ratio = None
-        strategy_resolved = None
         # a channel-switching program's packs are its channels' own, the
         # largest first; any other program's is its edge view's
         channel_packs = sorted(
@@ -1420,34 +1040,24 @@ class TPUExecutor:
                 for c in set(program.edge_channels.values())
                 if c in self._channel_packs
             ),
-            key=lambda entry: -entry[1].slots,
+            key=lambda entry: -entry[0].slots,
         )
         hyb = self._hybrid_packs.get(undirected)
-        pack = self._ell_packs.get(undirected)
         if channel_packs:
             pad_ratio = round(
-                sum(p.slots for _s, p, _d in channel_packs)
-                / max(1, sum(p.num_edges for _s, p, _d in channel_packs)),
+                sum(p.slots for p, _d in channel_packs)
+                / max(1, sum(p.num_edges for p, _d in channel_packs)),
                 4,
             )
-            strategy_resolved = channel_packs[0][0]
         elif hyb is not None:
             pad_ratio = round(hyb.pad_ratio, 4)
-            strategy_resolved = "hybrid"
-        elif pack is not None:
-            pad_ratio = round(pack.pad_ratio, 4)
-            strategy_resolved = "ell"
-        # active pack's pad (legacy key name kept — every BENCH round since
-        # r01 tracks it); `pad_ratio` is the strategy-neutral alias
+        # `ell_pad_ratio` is the older name of `pad_ratio`: the mesh's
+        # record and observability/benchdiff.py carry it too
         info["ell_pad_ratio"] = pad_ratio
         info["pad_ratio"] = pad_ratio
-        if strategy_resolved is None and info.get("path") != "frontier":
-            # no pack: segment, or the Pallas kernel (SUM programs)
-            strategy_resolved = self._resolve_strategy(
-                program.combiner, undirected
-            )
-        if strategy_resolved is not None:
-            info["strategy_resolved"] = strategy_resolved
+        if info.get("path") != "frontier":
+            # every dense superstep aggregates over the hybrid pack
+            info["strategy_resolved"] = "hybrid"
         # the combiner(s) the run folded with; a MODE run also says what
         # its fold was given, as the pack knows it (static numbers)
         info["combiner"] = "+".join(dict.fromkeys(
@@ -1457,28 +1067,17 @@ class TPUExecutor:
         if program.combiner == Combiner.MODE:
             from janusgraph_tpu.olap.kernels import mode_fold_sizes
 
-            edges = self.csr.num_edges * (2 if undirected else 1)
-            info["mode_fold"] = (
-                mode_fold_sizes(hyb or pack) if (hyb or pack) is not None
-                else {"torso_slots": 0, "tail_slots": edges,
-                      "rows_folded_whole": self.csr.num_vertices}
+            info["mode_fold"] = mode_fold_sizes(
+                channel_packs[0][0] if channel_packs
+                else self._hybrid_pack(undirected)
             )
-        # the tuner's decision travels with every run record (bench +
-        # /telemetry read it from here); explicit strategies still record
-        # a source="config" decision for provenance
+        # the tuner's decision travels with every run record (/telemetry
+        # reads it from here)
         decision = (
-            channel_packs[0][2] if channel_packs
-            else self._autotune_decisions.get(
-                (undirected, self._feature_dim_run)
-            )
+            channel_packs[0][1] if channel_packs
+            else self._autotune(undirected)
         )
-        if decision is None and self._autotune_enabled:
-            try:
-                decision = self._autotune(undirected)
-            except Exception:  # noqa: BLE001 - recording must not fail a run
-                decision = None
-        if decision is not None:
-            info["autotune"] = decision.as_dict()
+        info["autotune"] = decision.as_dict()
 
         records = info.get("superstep_records")
         if records is None:
@@ -1648,7 +1247,7 @@ class TPUExecutor:
             # run records under its own mesh size — the layouts must not
             # clobber each other's calibration)
             _at.save_measured(self._measured_path, {
-                "strategy": strategy_resolved,
+                "strategy": info.get("strategy_resolved"),
                 "pad_ratio": pad_ratio,
                 "superstep_ms": walls[len(walls) // 2],
                 "roofline_by_tier": info.get("roofline_by_tier"),
@@ -1740,10 +1339,9 @@ class TPUExecutor:
         )
 
         if self._frontier_engine is None:
-            if self._autotune_enabled:
-                # the tier-schedule half of the decision: computed before
-                # the engine snapshots it (aggregation half unused here)
-                self._autotune(False)
+            # the tier-schedule half of the decision: computed before the
+            # engine snapshots it (aggregation half unused here)
+            self._autotune(False)
             self._frontier_engine = FrontierEngine(self)
         t0 = time.perf_counter()
         if type(program) is ConnectedComponentsProgram:
@@ -1822,8 +1420,8 @@ class TPUExecutor:
                 }
                 steps_done = 0
 
-            fused_key = ("fused", program.cache_key(), op, self._strategy_cfg,
-                         None, self._delta_sig(program))
+            fused_key = ("fused", program.cache_key(), op, None,
+                         self._delta_sig(program))
             cold = fused_key not in self._compiled
             fn = self._fused_fn(program, op)
             gargs = self._graph_args(program, op)

@@ -4,15 +4,20 @@ Three contracts:
 
 1. **Determinism** — `autotune.decide` is a pure function: identical
    (GraphStats, device_kind, overrides, measured) give an identical
-   AutotuneDecision, and stats built twice from the same CSR are equal.
-2. **Bitwise identity** — the hybrid strategy's results are bit-for-bit
-   equal to the pure-ELL path (PageRank/BFS/CC oracles, weighted and
-   unweighted, supernode row-split, 2-D messages), on the device executor
-   AND the CPU executor's numpy replay of the same pack arithmetic.
+   AutotuneDecision, and stats built twice from the same CSR are equal;
+   on the benchmark's graphs it decides what the commit before the
+   strategy switch went (40a31da) decided.
+2. **Bitwise identity** — the hybrid pack's results are bit-for-bit
+   equal to the pure-ELL replay (BFS/CC against the CPU oracle's, the
+   aggregations themselves weighted and unweighted, supernode row-split,
+   2-D messages); tests/test_pack_contract.py holds the device executor's
+   own pack to the same replay.
 3. **Wiring** — the decision lands in `run_info["autotune"]`, the
    `computer.autotune-*` keys override it, and the frontier engine prices
    hops against the tuner's tier schedule.
 """
+
+import functools
 
 import numpy as np
 import pytest
@@ -63,7 +68,7 @@ def test_decision_deterministic():
     assert isinstance(d1, AutotuneDecision)
     # overrides and measurements are part of the function's inputs: same
     # inputs, same decision — and they do change it
-    ov = {"hub_cutoff": 32, "min_gain": 0.0}
+    ov = {"hub_cutoff": 32}
     assert decide(s1, "cpu", overrides=ov) == decide(s2, "cpu", overrides=ov)
     meas = {"superstep_ms": 12.5, "pad_ratio": 1.47}
     dm1 = decide(s1, "cpu", measured=meas)
@@ -97,17 +102,38 @@ def test_stats_shape():
     assert und.num_edges == 2 * csr.num_edges
 
 
-def test_config_overrides_force_choice():
-    s = GraphStats.from_csr(skewed_graph())
-    forced = decide(s, "cpu", overrides={"strategy": "segment"})
-    assert forced.strategy == "segment" and forced.source == "config"
-    cut = decide(
-        s, "cpu", overrides={"strategy": "hybrid", "hub_cutoff": 64}
+def test_config_overrides_force_the_packs_sizes():
+    csr = skewed_graph()
+    s = GraphStats.from_csr(csr)
+    cut = decide(s, "cpu", overrides={"hub_cutoff": 64})
+    assert cut.hub_cutoff == 64 and cut.tail_chunk == 128
+    assert cut.source == "model"
+    # the chunk never outgrows the narrowest hub's tree
+    assert decide(s, "cpu", overrides={"tail_chunk": 16}).tail_chunk == 16
+    assert decide(
+        s, "cpu", overrides={"hub_cutoff": 8, "tail_chunk": 256}
+    ).tail_chunk == 16
+    # a cutoff that is no pow2 candidate is priced when the statistics
+    # were built for it (the executor's), and refused when they were not
+    with pytest.raises(ValueError, match="hub cutoff 48"):
+        decide(s, "cpu", overrides={"hub_cutoff": 48})
+    s48 = GraphStats.from_csr(csr, hub_cutoff=48)
+    odd = decide(s48, "cpu", overrides={"hub_cutoff": 48})
+    assert odd.hub_cutoff == 48 and odd.tail_chunk == 64
+    # (the closed form leaves out the few slots that make the index
+    # vector's length a prime)
+    assert odd.pad_ratio_est == pytest.approx(
+        HybridPack(*in_edges(csr), None, csr.num_vertices,
+                   hub_cutoff=48, tail_chunk=64).pad_ratio, abs=2e-3)
+    # and the candidate it adds changes no other decision
+    assert decide(s48, "cpu") == decide(s, "cpu")
+
+
+def in_edges(csr):
+    dst = np.repeat(
+        np.arange(csr.num_vertices, dtype=np.int64), np.diff(csr.in_indptr)
     )
-    assert cut.strategy == "hybrid" and cut.hub_cutoff == 64
-    # a tiny budget pushes the auto choice off the packed layouts
-    tiny = decide(s, "cpu", overrides={"budget_bytes": 1024})
-    assert tiny.strategy == "segment"
+    return csr.in_src.astype(np.int64), dst
 
 
 def test_tier_schedules_pow2_and_bounded():
@@ -134,9 +160,10 @@ def test_tier_schedules_pow2_and_bounded():
 
 
 # ------------------------------------------------------- one price list
-def _graph500_stats(scale):
-    """Degree statistics of the benchmark's own graph (benchmark/data.py:
-    Graph500 R-MAT .57/.19/.19/.05, edge factor 16, structure seed 500)."""
+@functools.lru_cache(maxsize=None)
+def graph500(scale):
+    """The benchmark's own graph (benchmark/data.py: Graph500 R-MAT
+    .57/.19/.19/.05, edge factor 16, structure seed 500; --seed 1)."""
     import importlib.util
     import os
 
@@ -147,19 +174,18 @@ def _graph500_stats(scale):
     spec = importlib.util.spec_from_file_location("benchmark_data", path)
     data = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(data)
-    n, _src, dst, _perm = data.rmat_edges(scale, 16, 500, 7)
-    return GraphStats.from_degrees(
-        np.bincount(dst, minlength=n), len(dst), weighted=False
-    )
+    n, src, dst, _perm = data.rmat_edges(scale, 16, 500, 1)
+    return csr_from_edges(n, src, dst)
 
 
 @pytest.mark.parametrize("device_kind", ["cpu", "TPU v5 lite"])
 def test_every_pack_is_priced_from_one_device_column(device_kind):
-    """`decide` prices ell and hybrid with the SAME device's constants:
-    less the per-bucket and per-chunk terms, their modeled times are in
-    the ratio of their slot counts, on a tpu kind and on a cpu kind. (The
-    ELL pack was once priced at the cpu's gather cost on every device, so
-    `auto` on a TPU could never pick the pack that gathers less.)"""
+    """`decide` prices the pack it sizes and the ELL pack it reports
+    beside it with the SAME device's constants: less the per-bucket and
+    per-chunk terms, their modeled times are in the ratio of their slot
+    counts, on a tpu kind and on a cpu kind. (The ELL pack was once priced
+    at the cpu's gather cost on every device, so a TPU never got the pack
+    that gathers less.)"""
     from janusgraph_tpu.olap import autotune
 
     kind = "tpu" if "TPU" in device_kind else "cpu"
@@ -180,34 +206,86 @@ def test_every_pack_is_priced_from_one_device_column(device_kind):
     )
 
 
-#: what the parent commit decided on a cpu kind for the benchmark's graph
-PARENT_CPU_DECISIONS = {
-    12: ("hybrid", 1024, 256, 1.0008,
-         {"ell": 0.3091, "hybrid": 0.2421, "segment": 0.541}),
-    13: ("hybrid", 512, 256, 1.0109,
-         {"ell": 0.6138, "hybrid": 0.4727, "segment": 1.0818}),
-    14: ("hybrid", 1024, 256, 1.0058,
-         {"ell": 1.2634, "hybrid": 0.9206, "segment": 2.1634}),
+#: what commit 40a31da (the last with the strategy switch; it chose
+#: "hybrid" in every row) decided for the benchmark's graph, written down
+#: from a run of that commit: (scale, undirected view) -> hub cutoff, tail
+#: chunk, F and E schedules, slots an edge, modeled ms by device kind, and
+#: the first 16 hex digits of the sha256 of the index vector of the pack
+#: its executor built
+PARENT_DECISIONS = {
+    (12, False): (1024, 256, [1024, 2048, 4096], [16384, 32768, 65536],
+                  1.0008, {"TPU v5 lite": 0.4986, "cpu": 0.2421},
+                  65599, "318676701183da76"),
+    (12, True): (1024, 256, [1024, 2048, 4096], [32768, 65536, 131072],
+                 1.0113, {"TPU v5 lite": 1.0082, "cpu": 0.4778},
+                 132589, "a2ffb7d7861dfd45"),
+    (13, False): (512, 256, [1024, 2048, 4096, 8192],
+                  [16384, 32768, 65536, 131072],
+                  1.0109, {"TPU v5 lite": 1.0077, "cpu": 0.4727},
+                  132511, "4a180c6167a63b44"),
+    (13, True): (1024, 256, [1024, 2048, 4096, 8192],
+                 [32768, 65536, 131072, 262144],
+                 1.0101, {"TPU v5 lite": 2.0137, "cpu": 0.9279},
+                 264811, "dfd137a66c48137e"),
+    (14, False): (1024, 256, [1024, 2048, 4096, 8192, 16384],
+                  [16384, 32768, 65536, 131072, 262144],
+                  1.0058, {"TPU v5 lite": 2.0048, "cpu": 0.9206},
+                  263657, "22e402b62fb3441a"),
+    (14, True): (512, 256, [1024, 2048, 4096, 8192, 16384],
+                 [32768, 65536, 131072, 262144, 524288],
+                 1.0304, {"TPU v5 lite": 4.1114, "cpu": 1.8754},
+                 540251, "cc4c7375ba6b8a65"),
 }
 
 
-@pytest.mark.parametrize("scale", sorted(PARENT_CPU_DECISIONS))
-def test_auto_picks_the_zero_padding_pack_on_the_chip(scale):
-    """On the benchmark's degree distribution `auto` on a v5e resolves to
-    the hybrid pack, at most 1.15 slots an edge, with no option set; and a
-    cpu kind decides what it decided before the price lists were joined
-    (the default column WAS the cpu's)."""
-    s = _graph500_stats(scale)
-    tpu = decide(s, "TPU v5 lite")
-    assert tpu.strategy == "hybrid" and tpu.source == "model"
-    assert tpu.pad_ratio_est <= 1.15
-    assert tpu.modeled_ms["hybrid"] < 0.95 * tpu.modeled_ms["ell"]
-    cpu = decide(s, "cpu").as_dict()
-    strategy, cutoff, chunk, pad, modeled = PARENT_CPU_DECISIONS[scale]
+@pytest.mark.parametrize("device_kind", ["TPU v5 lite", "cpu"])
+@pytest.mark.parametrize(
+    "scale,undirected", sorted(PARENT_DECISIONS),
+    ids=[f"s{s}-{'closure' if u else 'directed'}"
+         for s, u in sorted(PARENT_DECISIONS)],
+)
+def test_decide_sizes_the_pack_as_the_parent_did(
+    scale, undirected, device_kind
+):
+    """On the benchmark's degree distribution, with no option set, the
+    pack has the sizes and the frontier engine the tiers they had while
+    `auto` still chose among strategies: at most 1.15 slots an edge, and
+    under the ELL pack's modeled time by more than the old hysteresis."""
+    cutoff, chunk, f_sched, e_sched, pad, modeled, _slots, _sha = (
+        PARENT_DECISIONS[(scale, undirected)]
+    )
+    stats = GraphStats.from_csr(graph500(scale), undirected=undirected)
+    d = decide(stats, device_kind)
+    rec = d.as_dict()
+    assert "strategy" not in rec and d.source == "model"
     assert (
-        cpu["strategy"], cpu["hub_cutoff"], cpu["tail_chunk"],
-        cpu["pad_ratio_est"], cpu["modeled_ms"],
-    ) == (strategy, cutoff, chunk, pad, modeled)
+        rec["hub_cutoff"], rec["tail_chunk"], rec["f_schedule"],
+        rec["e_schedule"], rec["pad_ratio_est"], rec["modeled_ms"]["hybrid"],
+    ) == (cutoff, chunk, f_sched, e_sched, pad, modeled[device_kind])
+    assert d.pad_ratio_est <= 1.15
+    assert d.modeled_ms["hybrid"] < 0.95 * d.modeled_ms["ell"]
+
+
+@pytest.mark.parametrize(
+    "scale,undirected", sorted(PARENT_DECISIONS),
+    ids=[f"s{s}-{'closure' if u else 'directed'}"
+         for s, u in sorted(PARENT_DECISIONS)],
+)
+def test_executor_builds_the_parents_pack(scale, undirected):
+    """The index vector the executor ships is the parent's to the byte, so
+    the lowered superstep is the parent's and a compile cache it filled
+    serves this code."""
+    import hashlib
+
+    cutoff, chunk, _f, _e, _pad, _ms, slots, sha = (
+        PARENT_DECISIONS[(scale, undirected)]
+    )
+    pack = TPUExecutor(graph500(scale))._hybrid_pack(undirected)
+    idx = np.asarray(pack.arrays["idx"])
+    assert (pack.hub_cutoff, pack.tail_chunk, pack.slots, len(idx)) == (
+        cutoff, chunk, slots, slots)
+    assert sorted(pack.arrays) == ["idx", "slot", "unpermute"]
+    assert hashlib.sha256(idx.tobytes()).hexdigest()[:16] == sha
 
 
 # ------------------------------------------------- bitwise result identity
@@ -222,19 +300,23 @@ BITWISE_PROGRAMS = [
 
 @pytest.mark.parametrize("weights", [False, True], ids=["unweighted", "w"])
 @pytest.mark.parametrize(
-    "name,make,key", BITWISE_PROGRAMS, ids=[p[0] for p in BITWISE_PROGRAMS]
+    "name,make,key", BITWISE_PROGRAMS[1:],
+    ids=[p[0] for p in BITWISE_PROGRAMS[1:]],
 )
-def test_hybrid_bitwise_equals_ell_device(name, make, key, weights):
-    """The tentpole contract: hybrid and pure-ELL runs are bit-for-bit
-    identical on the device executor (frontier off so the dense BSP path
-    is what's compared)."""
+def test_device_run_bitwise_equals_the_ell_replay(name, make, key, weights):
+    """The device executor's dense run (frontier off) against the CPU
+    oracle's replay of the ELL tree, for the programs whose apply is exact
+    in any float width (the oracle keeps its state in float64, so
+    PageRank's ranks agree to rounding only; its aggregation is held
+    bitwise in tests/test_pack_contract.py)."""
     g = skewed_graph(weights=weights)
-    ell = TPUExecutor(g, strategy="ell").run(make(), frontier="off")
-    hyb = TPUExecutor(g, strategy="hybrid").run(make(), frontier="off")
-    assert set(ell) == set(hyb)
+    ell = CPUExecutor(g, strategy="ell").run(make())
+    dev = TPUExecutor(g).run(make(), frontier="off")
+    assert set(ell) == set(dev)
     for k in ell:
         np.testing.assert_array_equal(
-            np.asarray(hyb[k]), np.asarray(ell[k]),
+            np.asarray(dev[k], dtype=np.float32),
+            np.asarray(ell[k], dtype=np.float32),
             err_msg=f"device:{name}:{k}",
         )
 
@@ -288,14 +370,13 @@ def test_hybrid_pad_ratio_beats_ell():
     """The point of the format: on a heavy-tailed graph the hybrid pack
     moves <1.15x the edge count where pow2 ELL moves ~1.5x."""
     g = skewed_graph(n=2000, m=40000)
-    fp = TPUExecutor.ell_footprint(g)
-    dst = np.repeat(
-        np.arange(g.num_vertices, dtype=np.int64), np.diff(g.in_indptr)
-    )
-    hyb = HybridPack(g.in_src.astype(np.int64), dst, None, g.num_vertices)
-    assert fp["pad_ratio"] > 1.3
+    src, dst = in_edges(g)
+    ell = ELLPack(src, dst, None, g.num_vertices)
+    hyb = HybridPack(src, dst, None, g.num_vertices)
+    assert ell.pad_ratio > 1.3
     assert hyb.pad_ratio < 1.15
-    assert hyb.pad_ratio < fp["pad_ratio"]
+    # and the statistics count the ELL pack's slots without building it
+    assert GraphStats.from_csr(g).ell_slots == ell.slots
 
 
 def test_tree_reduce_fixed_tree():
@@ -324,15 +405,23 @@ def test_run_info_records_decision():
     ex.run(PageRankProgram(max_iterations=4, tol=0.0))
     rec = ex.last_run_info.get("autotune")
     assert rec is not None
-    assert rec["strategy"] in ("ell", "hybrid", "segment")
-    assert rec["source"] in ("model", "config", "measured+model")
+    assert "strategy" not in rec
+    assert rec["source"] in ("model", "measured+model")
     assert rec["e_schedule"] == sorted(rec["e_schedule"])
-    assert ex.last_run_info["pad_ratio"] == ex.last_run_info["ell_pad_ratio"]
-    # explicit strategies still record provenance
-    ex2 = TPUExecutor(g, strategy="ell")
+    info = ex.last_run_info
+    assert info["pad_ratio"] == info["ell_pad_ratio"]
+    assert info["strategy_resolved"] == "hybrid"
+    # the record's sizes are the pack's
+    pack = ex._hybrid_pack(False)
+    assert (rec["hub_cutoff"], rec["tail_chunk"]) == (
+        pack.hub_cutoff, pack.tail_chunk)
+    assert info["pad_ratio"] == round(pack.pad_ratio, 4)
+    # configured sizes hold, a cutoff that is no pow2 candidate included
+    ex2 = TPUExecutor(g, hub_cutoff=48, tail_chunk=32)
     ex2.run(PageRankProgram(max_iterations=4, tol=0.0))
-    assert ex2.last_run_info["autotune"]["source"] == "config"
-    assert ex2.last_run_info["strategy_resolved"] == "ell"
+    rec2 = ex2.last_run_info["autotune"]
+    assert (rec2["hub_cutoff"], rec2["tail_chunk"]) == (48, 32)
+    assert ex2._hybrid_pack(False).hub_cutoff == 48
 
 
 def test_frontier_uses_tuned_schedule():
@@ -345,12 +434,6 @@ def test_frontier_uses_tuned_schedule():
     for tier in info["tiers"]:
         assert tier["tier_source"] == "autotune"
         assert tier["E_cap"] in sched or tier["E_cap"] == g.num_edges
-    # tuner off -> legacy ladder
-    ex2 = TPUExecutor(g, autotune=False)
-    ex2.run(ShortestPathProgram(seed_index=0, max_iterations=4))
-    assert all(
-        t["tier_source"] == "static" for t in ex2.last_run_info["tiers"]
-    )
 
 
 def test_computer_config_keys_flow_through():
@@ -361,7 +444,7 @@ def test_computer_config_keys_flow_through():
         "storage.backend": "inmemory",
         "computer.autotune-hub-cutoff": 16,
         "computer.autotune-tail-chunk": 32,
-        "computer.strategy": "hybrid",
+        "computer.sharded-auto": False,  # conftest shows eight devices
     })
     tx = g.new_transaction()
     prev = None
@@ -377,6 +460,8 @@ def test_computer_config_keys_flow_through():
         .submit()
     )
     assert len(res.states["rank"]) == 12
+    assert res.run_info["autotune"]["hub_cutoff"] == 16
+    assert res.run_info["autotune"]["tail_chunk"] == 32
     g.close()
 
 

@@ -131,17 +131,17 @@ def test_ldbc_directed_rule_on_a_hand_written_graph():
 # ------------------------------------------- reference = cpu = tpu, exactly
 PINNED = {
     "cpu-scalar": {"computer.executor": "cpu"},
-    "auto": {},
-    "ell": {"computer.strategy": "ell"},
-    "hybrid": {"computer.strategy": "hybrid"},
-    "segment": {"computer.strategy": "segment"},
+    "default": {},
+    # every destination in the exact-width torso: no tail at all
+    "torso-only": {"computer.autotune-hub-cutoff": 1024},
     # hubs above the cutoff (a chunked tail) and rows above the capacity
     # (split rows), both configured small
-    "hybrid-tail-and-split": {
-        "computer.strategy": "hybrid", "computer.autotune-hub-cutoff": 8,
+    "tail-and-split": {
+        "computer.autotune-hub-cutoff": 8,
         "computer.autotune-tail-chunk": 4, "computer.ell-max-capacity": 32},
-    "ell-split": {"computer.strategy": "ell",
-                  "computer.ell-max-capacity": 16},
+    # one chunk a row of the capacity's width: every hub is split rows
+    "split": {"computer.autotune-hub-cutoff": 8,
+              "computer.ell-max-capacity": 16},
 }
 
 
@@ -162,8 +162,7 @@ def test_submit_equals_the_plain_reference(pinned, seed):
         return
     assert info["path"] == "fused" and info["supersteps"] == 4
     assert info["routing"]["routed"] == "tpu"
-    if pinned != "auto":
-        assert info["strategy_resolved"] == pinned.split("-")[0]
+    assert info["strategy_resolved"] == "hybrid"
     sizes = info["mode_fold"]
     assert sizes["torso_slots"] + sizes["tail_slots"] >= 2 * len(src)
     if "split" in pinned:
@@ -187,8 +186,7 @@ def test_rmat_ten_rounds(scale):
 
     n, src, dst = rmat_edges(scale, 8, seed=scale)
     want = reference_cdlp(n, src, dst, 10)
-    for options in ({}, {"computer.strategy": "hybrid",
-                         "computer.autotune-hub-cutoff": 16,
+    for options in ({}, {"computer.autotune-hub-cutoff": 16,
                          "computer.autotune-tail-chunk": 8,
                          "computer.ell-max-capacity": 64}):
         result = submit(n, src, dst, 10, **options)
@@ -227,20 +225,15 @@ def test_labels_above_2_pow_24_stay_exact():
     label = state["label"]
     assert label.dtype == np.int32 and metrics == {}
     assert label[top] == top and label[1 << 24] == 1 << 24
-    # the fold over those labels, through the segment path's function and
-    # the packs' (both orientations: every edge delivers twice)
+    # the fold over those labels, through the ELL replay's split-row
+    # function (both orientations: every edge delivers twice)
     receiver = np.concatenate([dst, src]).astype(np.int32)
     sent = label[np.concatenate([src, dst])]
     want = np.full(n, NO, dtype=np.int64)
     rounds = reference_cdlp_sparse(src, dst)
     for v, lb in rounds.items():
         want[v] = lb
-    got = kernels.segment_mode(np, sent, receiver, n)
-    np.testing.assert_array_equal(got, want)
-    import jax.numpy as jnp
-
-    got = np.asarray(kernels.segment_mode(
-        jnp, jnp.asarray(sent), jnp.asarray(receiver), n))
+    got = kernels.segment_mode(sent, receiver, n)
     np.testing.assert_array_equal(got, want)
     new, _ = program.apply(state, got.astype(np.int32), 0, {}, graph, np)
     assert new["label"].dtype == np.int32
@@ -291,9 +284,6 @@ def test_mode_along_ties_padding_and_empty_rows(axis):
 def test_run_lengths_count_from_the_run_start():
     s = np.asarray([1, 1, 1, 2, 3, 3, 3, 3, 3, 9], dtype=np.int32)
     assert kernels._run_lengths(np, (s,), 0).tolist() == [
-        1, 2, 3, 1, 1, 2, 3, 4, 5, 1]
-    # a bound on the longest run ends the doubling early, same answer
-    assert kernels._run_lengths(np, (s,), 0, max_run=5).tolist() == [
         1, 2, 3, 1, 1, 2, 3, 4, 5, 1]
 
 
@@ -372,16 +362,6 @@ def test_halo_exchange_refuses_mode():
         halo.replay_superstep(None, np.ones(4, np.float32), Combiner.MODE)
 
 
-def test_pallas_strategy_refuses_mode():
-    n, src, dst = every_case_graph(1)
-    ex = TPUExecutor(csr_from_edges(n, src, dst), strategy="pallas")
-    with pytest.raises(ValueError, match="MODE.*Pallas"):
-        ex.run(CDLPProgram(2))
-    # MIN and MAX still fall back to the ELL pack, as before
-    assert ex._resolve_strategy(Combiner.MIN) == "ell"
-    assert ex._resolve_strategy(Combiner.SUM) == "pallas"
-
-
 def test_frontier_engine_refuses_mode():
     from janusgraph_tpu.olap.frontier import FrontierEngine
 
@@ -445,9 +425,8 @@ def test_delta_overlay_is_materialized_before_a_mode_program_runs():
 
 
 # --------------------------------------------------- scopes and write-back
-@pytest.mark.parametrize("strategy", ["ell", "hybrid", "segment"])
 @pytest.mark.parametrize("program", ["cdlp", "pagerank"])
-def test_the_superstep_names_its_four_stages(program, strategy):
+def test_the_superstep_names_its_four_stages(program):
     """`jax.named_scope` in the shared superstep body: every dense
     program's gather and fold carry the names the benchmark's trace-scope
     reader sums, and none encloses another."""
@@ -455,7 +434,7 @@ def test_the_superstep_names_its_four_stages(program, strategy):
     import jax.numpy as jnp
 
     n, src, dst = every_case_graph(2)
-    ex = TPUExecutor(csr_from_edges(n, src, dst), strategy=strategy)
+    ex = TPUExecutor(csr_from_edges(n, src, dst))
     prog = CDLPProgram(2) if program == "cdlp" else PageRankProgram(
         max_iterations=2)
     op = prog.combiner

@@ -146,19 +146,18 @@ def test_computer_options_flow_to_executor():
 
     g = open_graph({
         "computer.executor": "cpu",
-        "computer.strategy": "segment",
         "computer.ell-max-capacity": 64,
     })
     comp = g.compute()
     assert comp.executor_kind == "cpu"
-    # strategy/capacity flow through run_on for tpu executors
+    # the capacity flows through run_on for tpu executors
     from janusgraph_tpu.olap.computer import run_on
     from janusgraph_tpu.olap import csr_from_edges
     from janusgraph_tpu.olap.programs import PageRankProgram
 
     csr = csr_from_edges(6, [0, 1, 2], [1, 2, 3])
     out = run_on(csr, PageRankProgram(max_iterations=3),
-                 executor="tpu", strategy="segment", ell_max_capacity=64)
+                 executor="tpu", ell_max_capacity=64)
     assert "rank" in out
     g.close()
 

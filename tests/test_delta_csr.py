@@ -161,8 +161,12 @@ def test_capture_overflow_serves_none(g):
 
 
 # ----------------------------------------------------- fused bitwise matrix
-@pytest.mark.parametrize("strategy", ["ell", "hybrid"])
-@pytest.mark.parametrize("executor", ["tpu", "cpu"])
+#: the device executor on its one pack, and the CPU oracle's two replays
+FUSED_EXECUTORS = [("tpu", None), ("cpu", "ell"), ("cpu", "hybrid")]
+FUSED_IDS = ["tpu", "cpu-ell", "cpu-hybrid"]
+
+
+@pytest.mark.parametrize("executor,strategy", FUSED_EXECUTORS, ids=FUSED_IDS)
 def test_base_plus_delta_bitwise_min_family(g, executor, strategy):
     """CC (undirected) and SSSP (directed) fused base+delta results are
     BITWISE-identical to runs over the freshly repacked CSR — min is
@@ -177,7 +181,7 @@ def test_base_plus_delta_bitwise_min_family(g, executor, strategy):
 
     def run(graph, delta, program):
         if executor == "tpu":
-            ex = TPUExecutor(graph, strategy=strategy, delta=delta)
+            ex = TPUExecutor(graph, delta=delta)
         else:
             ex = CPUExecutor(graph, strategy=strategy, delta=delta)
         return ex.run(program)
@@ -195,8 +199,7 @@ def test_base_plus_delta_bitwise_min_family(g, executor, strategy):
     np.testing.assert_array_equal(f["distance"], r["distance"])
 
 
-@pytest.mark.parametrize("strategy", ["ell", "hybrid"])
-@pytest.mark.parametrize("executor", ["tpu", "cpu"])
+@pytest.mark.parametrize("executor,strategy", FUSED_EXECUTORS, ids=FUSED_IDS)
 def test_base_plus_delta_sum_close_to_repack(g, executor, strategy):
     vs = seed_random(g)
     csr, epoch = load_csr_snapshot(g)
@@ -205,10 +208,10 @@ def test_base_plus_delta_sum_close_to_repack(g, executor, strategy):
     view = D.OverlayView(csr, ov)
     repack = load_csr(g)
     if executor == "tpu":
-        f = TPUExecutor(csr, strategy=strategy, delta=view).run(
+        f = TPUExecutor(csr, delta=view).run(
             PageRankProgram(max_iterations=10)
         )
-        r = TPUExecutor(repack, strategy=strategy).run(
+        r = TPUExecutor(repack).run(
             PageRankProgram(max_iterations=10)
         )
     else:

@@ -37,7 +37,6 @@ from janusgraph_tpu.olap.features.kernels import (
     pick_feature_tier,
     sddmm_ell_aggregate,
     sddmm_hybrid_aggregate,
-    sddmm_segment_aggregate,
     tree_dot,
     tree_matmul,
 )
@@ -165,16 +164,8 @@ def test_sddmm_aggregate_layouts_bitwise_and_vs_dense():
         ref[d] += m64[s] * float(np.dot(m64[s], m64[d]))
     np.testing.assert_allclose(a, ref, rtol=1e-3, atol=1e-4)
 
-    seg = sddmm_segment_aggregate(np, msgs, src, dst, n)
-    np.testing.assert_allclose(seg, ref, rtol=1e-3, atol=1e-4)
-
 
 def test_sddmm_rejects_bad_shapes():
-    msgs = np.ones((4, 12), dtype=np.float32)  # 12 not a lane tier
-    with pytest.raises(ValueError):
-        sddmm_segment_aggregate(
-            np, msgs, np.zeros(2, np.int64), np.zeros(2, np.int64), 4
-        )
     g = skewed_graph(n=32, m=100)
     n = g.num_vertices
     src = g.in_src.astype(np.int64)
@@ -182,6 +173,9 @@ def test_sddmm_rejects_bad_shapes():
     ell = ELLPack(src, dst, None, n)
     rows = ell_row_dsts(src, dst, n)
     ok = np.ones((n, 16), dtype=np.float32)
+    with pytest.raises(ValueError):
+        sddmm_ell_aggregate(  # 12 is not a lane tier
+            np, ell, rows, np.ones((n, 12), dtype=np.float32))
     with pytest.raises(ValueError):
         sddmm_ell_aggregate(np, ell, rows, ok, op="min")  # SUM-only
     with pytest.raises(ValueError):
@@ -214,7 +208,7 @@ def test_sddmm_undirected_rejected_on_both_executors():
     p = EmbeddingUpdateProgram(feature_dim=8, max_iterations=1, mode="sddmm")
     p.undirected = True
     with pytest.raises(ValueError, match="in-CSR"):
-        TPUExecutor(g, strategy="ell").run(p)
+        TPUExecutor(g).run(p)
     with pytest.raises(ValueError, match="in-CSR"):
         CPUExecutor(g, strategy="ell").run(p)
 
@@ -234,12 +228,13 @@ EMB_MODES = [
 
 def _run_matrix(make, key, weights):
     g = skewed_graph(weights=weights)
-    ref = np.asarray(TPUExecutor(g, strategy="ell").run(make())[key])
+    # the reference: the CPU oracle's replay of the ELL tree
+    ref = np.asarray(CPUExecutor(g, strategy="ell").run(make())[key])
     runs = {
-        "tpu-hybrid": TPUExecutor(
-            g, strategy="hybrid", hub_cutoff=16, tail_chunk=16
+        "tpu": TPUExecutor(g).run(make())[key],
+        "tpu-tail": TPUExecutor(
+            g, hub_cutoff=16, tail_chunk=16
         ).run(make())[key],
-        "cpu-ell": CPUExecutor(g, strategy="ell").run(make())[key],
         "cpu-hybrid": CPUExecutor(g, strategy="hybrid").run(make())[key],
     }
     assert ref.dtype == np.float32
@@ -294,7 +289,7 @@ def test_gcn_explicit_weights_and_activation():
     )
     assert p.d_pad == 8
     np.testing.assert_array_equal(p._w_stack[0, :6, :6], ws[0])
-    out = TPUExecutor(g, strategy="ell").run(p)["h"]
+    out = TPUExecutor(g).run(p)["h"]
     assert np.isfinite(np.asarray(out)).all()
     with pytest.raises(ValueError):
         GCNForwardProgram(weights=[np.ones((3, 3))] * 2, feature_dim=6)
@@ -356,7 +351,7 @@ def test_executor_keys_decisions_by_feature_tier():
     """A dense run's decision is cached separately from scalar runs (the
     tier changes modeled bytes), and run_info records the feature tier."""
     g = skewed_graph()
-    ex = TPUExecutor(g, strategy="auto")
+    ex = TPUExecutor(g)
     p = GCNForwardProgram(feature_dim=12, hidden_dim=12, out_dim=8,
                           num_layers=2)
     ex.run(p)
@@ -373,7 +368,7 @@ def test_executor_keys_decisions_by_feature_tier():
 def test_forced_dim_tier_flows_from_executor():
     g = skewed_graph(n=64, m=500)
     p = GCNForwardProgram(feature_dim=12, hidden_dim=12, out_dim=8)
-    ex = TPUExecutor(g, strategy="ell", features_dim_tier=32)
+    ex = TPUExecutor(g, features_dim_tier=32)
     out = ex.run(p)
     assert p.d_pad == 32
     assert np.asarray(out["h"]).shape == (64, 32)
@@ -406,20 +401,20 @@ def test_autotune_persists_across_executor_lifetimes(tmp_path):
 
     g = skewed_graph()
     ck = str(tmp_path / "pr.npz")
-    ex1 = TPUExecutor(g, strategy="auto")
+    ex1 = TPUExecutor(g)
     ex1.run(PageRankProgram(max_iterations=3), checkpoint_path=ck,
             checkpoint_every=2)
     rec = load_measured(ck + ".autotune.json")
     assert rec is not None and rec["superstep_ms"] > 0
 
-    ex2 = TPUExecutor(g, strategy="auto")
+    ex2 = TPUExecutor(g)
     ex2.run(PageRankProgram(max_iterations=2), checkpoint_path=ck,
             checkpoint_every=2)
     assert ex2.last_run_info["autotune"]["source"] == "measured+model"
 
     # config off: no record is written
     ck2 = str(tmp_path / "pr2.npz")
-    ex3 = TPUExecutor(g, strategy="auto", autotune_persist=False)
+    ex3 = TPUExecutor(g, autotune_persist=False)
     ex3.run(PageRankProgram(max_iterations=2), checkpoint_path=ck2,
             checkpoint_every=2)
     assert load_measured(ck2 + ".autotune.json") is None
@@ -432,7 +427,7 @@ def test_mxu_fields_in_run_info_both_executors():
         feature_dim=12, hidden_dim=12, out_dim=8, num_layers=2
     )
     for ex, info in (
-        (TPUExecutor(g, strategy="ell"), None),
+        (TPUExecutor(g), None),
         (CPUExecutor(g, strategy="ell"), None),
     ):
         ex.run(mk())
@@ -448,7 +443,7 @@ def test_mxu_fields_in_run_info_both_executors():
     # scalar programs carry no mxu block
     from janusgraph_tpu.olap.programs.pagerank import PageRankProgram
 
-    ex = TPUExecutor(g, strategy="ell")
+    ex = TPUExecutor(g)
     ex.run(PageRankProgram(max_iterations=2))
     assert "mxu" not in ex.last_run_info
 

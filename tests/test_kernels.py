@@ -11,8 +11,6 @@ from janusgraph_tpu.olap.kernels import (
     HybridPack,
     ell_aggregate,
     hybrid_aggregate,
-    make_segsum_plan,
-    pallas_sorted_segment_sum,
 )
 from janusgraph_tpu.olap.programs import (
     ConnectedComponentsProgram,
@@ -139,17 +137,15 @@ def test_single_gather_hybrid_bitwise_equals_ell(op, cols, weights):
     np.testing.assert_array_equal(np.asarray(got), want)
 
 
-@pytest.mark.parametrize("strategy,gathers", [("hybrid", 1), ("ell", None)])
-def test_dense_superstep_gathers_once_per_aggregation(strategy, gathers):
-    """The lowered module of one dense superstep on the hybrid pack holds
-    the message gather and the un-permutation of the result and no other
-    gather, whatever the number of exact widths; the ELL pack's holds one
-    per bucket and more (a count of the module's ops, not a timing)."""
+def test_dense_superstep_gathers_once_per_aggregation():
+    """The lowered module of one dense superstep holds the message gather
+    and the un-permutation of the result and no other gather, whatever the
+    number of exact widths (a count of the module's ops, not a timing)."""
     import jax.numpy as jnp
 
     n, src, dst, _ = supernode_graph(False)
     g = csr_from_edges(n, src.astype(np.int32), dst.astype(np.int32), None)
-    ex = TPUExecutor(g, strategy=strategy, hub_cutoff=64, tail_chunk=16)
+    ex = TPUExecutor(g, hub_cutoff=64, tail_chunk=16)
     program = PageRankProgram(max_iterations=3, tol=0.0)
     op = program.combiner
     step = ex._superstep_body(program, op)
@@ -159,43 +155,8 @@ def test_dense_superstep_gathers_once_per_aggregation(strategy, gathers):
         state, jnp.int32(0), memory, ex._graph_args(program, op)
     ).as_text()
     count = text.count('"stablehlo.gather"') + text.count(" stablehlo.gather ")
-    if gathers is None:
-        assert count > 2 + len(ex._ell_pack(False).buckets)
-    else:
-        assert len(ex._hybrid_pack(False).torso_meta) > 20
-        assert count == gathers + 1  # + the result's `stacked[unpermute]`
-
-
-def test_pallas_sorted_segment_sum_matches():
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(9)
-    num_segments = 2500  # > one output tile, exercises multi-tile grid
-    m = 9000
-    seg = np.sort(rng.integers(0, num_segments, m))
-    data = rng.uniform(-1, 1, m).astype(np.float32)
-
-    plan = make_segsum_plan(seg, num_segments)
-    got = np.asarray(
-        pallas_sorted_segment_sum(jnp.asarray(data), plan, interpret=True)
-    )
-    want = np.bincount(seg, weights=data.astype(np.float64), minlength=num_segments)
-    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
-
-
-def test_pallas_segment_sum_empty_segments_tail():
-    """Segments with no edges (including whole empty tiles) read zero."""
-    import jax.numpy as jnp
-
-    seg = np.array([0, 0, 5, 1030], dtype=np.int64)  # tile 0 and tile 1
-    data = np.array([1.0, 2.0, 3.0, 4.0], dtype=np.float32)
-    plan = make_segsum_plan(seg, 4000)
-    got = np.asarray(
-        pallas_sorted_segment_sum(jnp.asarray(data), plan, interpret=True)
-    )
-    assert got[0] == 3.0 and got[5] == 3.0 and got[1030] == 4.0
-    assert got.sum() == 10.0
-    assert got.shape == (4000,)
+    assert len(ex._hybrid_pack(False).torso_meta) > 20
+    assert count == 2  # the messages, and the result's `stacked[unpermute]`
 
 
 # ------------------------------------------------------------- program parity
@@ -207,14 +168,13 @@ STRATEGY_PROGRAMS = [
 ]
 
 
-@pytest.mark.parametrize("strategy", ["ell", "hybrid", "pallas"])
 @pytest.mark.parametrize(
     "name,make", STRATEGY_PROGRAMS, ids=[p[0] for p in STRATEGY_PROGRAMS]
 )
-def test_strategy_parity_vs_cpu_oracle(strategy, name, make):
+def test_executor_parity_vs_cpu_oracle(name, make):
     g = random_graph(weights=True)
     cpu = run_on(g, make(), "cpu")
-    ex = TPUExecutor(g, strategy=strategy)
+    ex = TPUExecutor(g)
     got = ex.run(make())
     assert set(cpu) == set(got)
     for k in cpu:
@@ -223,7 +183,7 @@ def test_strategy_parity_vs_cpu_oracle(strategy, name, make):
             cpu[k],
             rtol=1e-4,
             atol=1e-5,
-            err_msg=f"{strategy}:{name}:{k}",
+            err_msg=f"{name}:{k}",
         )
 
 
@@ -233,7 +193,7 @@ def test_strategy_parity_vs_cpu_oracle(strategy, name, make):
 )
 def test_fused_whole_run_matches_host_loop(name, make):
     g = random_graph(seed=21, weights=True)
-    ex = TPUExecutor(g, strategy="ell")
+    ex = TPUExecutor(g)
     host = ex.run(make(), fused=False)
     fused = ex.run(make(), fused=True)
     for k in host:
@@ -249,7 +209,7 @@ def test_fused_early_termination_device():
     src = np.array([0, 1, 2, 3], dtype=np.int32)
     dst = np.array([1, 2, 3, 4], dtype=np.int32)
     g = csr_from_edges(6, src, dst, None)
-    ex = TPUExecutor(g, strategy="ell")
+    ex = TPUExecutor(g)
     res = ex.run(ConnectedComponentsProgram(max_iterations=100), fused=True)
     comp = np.asarray(res["component"])
     assert (comp[:5] == comp[0]).all() and comp[5] != comp[0]

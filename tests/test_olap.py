@@ -208,31 +208,23 @@ def test_compute_api_and_write_back(gods_graph):
     ranks = result.by_vertex("rank")
 
 
-def test_ell_auto_strategy_budget():
-    """auto resolution: the tuner picks a packed layout whose padding is
-    actually bounded (ELL on a uniform chain; HYBRID when ELL's empty-row
-    slots blow the pad up — zero-degree vertices cost hybrid nothing);
-    computer.autotune=false falls back to the legacy budget heuristic
-    (ELL within budget, segment past it)."""
+def test_the_packs_padding_is_bounded_where_ells_is_not():
+    """A uniform chain packs at one slot an edge; so does a graph of
+    nearly nothing but empty rows, where the ELL pack pays a slot for
+    every vertex — zero-degree vertices cost the hybrid pack nothing."""
     from janusgraph_tpu.olap import csr_from_edges
+    from janusgraph_tpu.olap.autotune import GraphStats
     from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 
     dense = csr_from_edges(100, np.arange(99), np.arange(1, 100))
-    fp = TPUExecutor.ell_footprint(dense)
-    assert fp["pad_ratio"] <= 2.0
-    assert TPUExecutor(dense).strategy in ("ell", "hybrid")
+    assert TPUExecutor(dense)._autotune(False).pad_ratio_est == 1.0
 
     sparse = csr_from_edges(50_000, [0, 1], [1, 2])
-    fp = TPUExecutor.ell_footprint(sparse)
-    assert fp["pad_ratio"] > 3.0
+    stats = GraphStats.from_csr(sparse)
+    assert stats.ell_slots / stats.num_edges > 3.0
     ex = TPUExecutor(sparse)
-    assert ex.strategy == "hybrid"
     assert ex._autotune(False).pad_ratio_est < 1.5
-    # the legacy heuristic (no tuner) keeps its old segment fallback
-    assert TPUExecutor(sparse, autotune=False).strategy == "segment"
-    assert TPUExecutor(dense, autotune=False).strategy == "ell"
-    # explicit strategy always wins over either heuristic
-    assert TPUExecutor(sparse, strategy="ell").strategy == "ell"
+    assert ex._hybrid_pack(False).pad_ratio < 1.5
 
 
 def test_degree_count_parity():

@@ -71,7 +71,7 @@ def test_cluster_count_map_reduce():
         np.array([1, 2, 4, 5], dtype=np.int32),
         None,
     )
-    ex = TPUExecutor(csr, strategy="ell")
+    ex = TPUExecutor(csr)
     states = ex.run(ConnectedComponentsProgram(max_iterations=20))
     out = run_map_reduce(ClusterCountMapReduce("component"), states, csr)
     assert out["count"] == 2
@@ -100,11 +100,11 @@ def test_checkpoint_resume_matches_uninterrupted(tmp_path):
     path = str(tmp_path / "ck.npz")
     prog = lambda: PageRankProgram(max_iterations=24, tol=0.0)
 
-    direct = TPUExecutor(csr, strategy="ell").run(prog())
+    direct = TPUExecutor(csr).run(prog())
 
     # run with checkpoints every 5 steps, "crash" after the first chunk by
     # reloading from the checkpoint and resuming with a fresh executor
-    ex1 = TPUExecutor(csr, strategy="ell")
+    ex1 = TPUExecutor(csr)
     ex1.run(prog(), checkpoint_path=path, checkpoint_every=5)
     st, mem, steps = load_checkpoint(path)
     assert steps == 24 and "rank" in st
@@ -112,14 +112,14 @@ def test_checkpoint_resume_matches_uninterrupted(tmp_path):
     # simulate interruption: rewind by saving a mid-run checkpoint
     from janusgraph_tpu.olap.checkpoint import save_checkpoint
 
-    ex2 = TPUExecutor(csr, strategy="ell")
+    ex2 = TPUExecutor(csr)
     # produce a genuine mid-run state: run 2 chunks of 5 then stop
     p = PageRankProgram(max_iterations=10, tol=0.0)
     mid = ex2.run(p, checkpoint_path=path, checkpoint_every=5)
     st, mem, steps = load_checkpoint(path)
     assert steps == 10
 
-    resumed = TPUExecutor(csr, strategy="ell").run(
+    resumed = TPUExecutor(csr).run(
         prog(), checkpoint_path=path, checkpoint_every=5, resume=True
     )
     np.testing.assert_allclose(
@@ -156,7 +156,7 @@ def test_checkpoint_early_termination_preserved(tmp_path):
     dst = np.array([1, 2, 3], dtype=np.int32)
     csr = csr_from_edges(5, src, dst, None)
     path = str(tmp_path / "cc.npz")
-    ex = TPUExecutor(csr, strategy="ell")
+    ex = TPUExecutor(csr)
     res = ex.run(
         ConnectedComponentsProgram(max_iterations=50),
         checkpoint_path=path, checkpoint_every=10,
@@ -173,17 +173,17 @@ def test_checkpoint_resume_host_loop_path(tmp_path):
 
     csr = random_graph(seed=41)
     path = str(tmp_path / "pp.npz")
-    direct = TPUExecutor(csr, strategy="ell").run(
+    direct = TPUExecutor(csr).run(
         PeerPressureProgram(num_buckets=128, rounds=6)
     )
-    ex = TPUExecutor(csr, strategy="ell")
+    ex = TPUExecutor(csr)
     ex.run(
         PeerPressureProgram(num_buckets=128, rounds=3),
         checkpoint_path=path, checkpoint_every=2,
     )
     _st, _mem, steps = load_checkpoint(path)
     assert steps > 0
-    resumed = TPUExecutor(csr, strategy="ell").run(
+    resumed = TPUExecutor(csr).run(
         PeerPressureProgram(num_buckets=128, rounds=6),
         checkpoint_path=path, checkpoint_every=2, resume=True,
     )

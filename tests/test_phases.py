@@ -392,7 +392,7 @@ def _run_frontier(ex):
 def test_every_executor_path_moves_its_phases(path, drive):
     from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 
-    ex = TPUExecutor(_random_csr(), strategy="segment")
+    ex = TPUExecutor(_random_csr())
     before = registry.snapshot()
     expected = {"executor.setup": 1, "executor.fetch": 1,
                 "executor.publish": 1, **drive(ex)}
