@@ -250,15 +250,34 @@ class OLAPTraversalProgram(VertexProgram):
 
     def setup(self, graph, xp):
         n = graph.local_num_vertices
-        if self.seed_indices is None:
-            # `active` masks SPMD padding slots on sharded views (all graph
-            # views define it)
-            count = xp.ones(n) * graph.active
+        mask = self._seed_mask
+        if (
+            self.seed_indices is None
+            and mask is not None
+            and getattr(graph, "all_active", False)
+            and graph.global_offset == 0
+            and len(mask) == n
+        ):
+            # the start is a host-resident vector, and the view says that
+            # its `active` is all ones and its local slice the whole: the
+            # product below is then the mask itself, so it reaches the
+            # device in one copy and no device op (a spilled request's
+            # whole set-up)
+            import numpy as np
+
+            count = xp.asarray(np.asarray(mask, dtype=xp.result_type(float)))
         else:
-            idx = xp.arange(n) + graph.global_offset
-            count = xp.isin(idx, xp.asarray(self.seed_indices)).astype(float)
-        if self._seed_mask is not None:
-            count = count * self._slice_local(self._seed_mask, graph, xp)
+            if self.seed_indices is None:
+                # `active` masks SPMD padding slots on sharded views (all
+                # graph views define it)
+                count = xp.ones(n) * graph.active
+            else:
+                idx = xp.arange(n) + graph.global_offset
+                count = xp.isin(
+                    idx, xp.asarray(self.seed_indices)
+                ).astype(float)
+            if mask is not None:
+                count = count * self._slice_local(mask, graph, xp)
         state = {"count": count}
         if self.sack is not None:
             state["sack"] = count * self.sack_init

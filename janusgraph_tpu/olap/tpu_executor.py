@@ -89,6 +89,9 @@ class _DeviceGraph:
             self.local_num_vertices = csr.num_vertices
             self.num_edges = csr.num_edges
         self.global_offset = 0
+        #: every row of `active` is 1: the base view has no padding and no
+        #: removed rows (a delta-fused view's host view zeroes both)
+        self.all_active = host_view is None
 
     def __getattr__(self, name):
         # only reached when `name` is not an instance attribute yet
@@ -304,6 +307,12 @@ class TPUExecutor:
         # None record = not discovering
         self._viewkeys: Dict[Tuple, frozenset] = {}
         self._view_record = None
+        # host-loop steps prepared once per variant (`_variant_key`):
+        # (fn, gargs, cost, arg bytes).
+        # The entry HOLDS device arrays (the pack's, the view's, the
+        # overlay's lanes), so it goes wherever they go stale: with its
+        # channel's pack (`_channel_pack`) and at every `set_delta`
+        self._prepared: Dict[Tuple, Tuple] = {}
         # (cache_key, op) -> {metric_key: combiner_op}, recorded as a side
         # effect of tracing the superstep body (apply declares each
         # aggregator's monoid inline; the fused path needs the full pytree
@@ -329,6 +338,7 @@ class TPUExecutor:
         if delta is None:
             if self._delta is None:
                 return
+            self._prepared.clear()
             self._delta = None
             if self._base_g is None:
                 self._base_g = _DeviceGraph(self.csr, self.jnp)
@@ -350,6 +360,9 @@ class TPUExecutor:
             self._base_g = self.g
         from janusgraph_tpu.olap.delta import FusedHostView
 
+        # a new overlay's lanes ride gargs["delta"] under an equal
+        # signature: no step prepared for the old one may serve it
+        self._prepared.clear()
         self._delta = delta
         self.g = _DeviceGraph(
             self.csr, self.jnp, host_view=FusedHostView(delta)
@@ -486,7 +499,8 @@ class TPUExecutor:
         tuner from THAT list's degrees. Cached per channel VALUE (frozen
         dataclass) — names like 's0' recur across different programs on a
         reused executor and must not alias each other's packs. LRU-bounded;
-        eviction also drops compiled supersteps that close over the pack."""
+        eviction also drops the compiled supersteps that close over the
+        pack and the prepared steps that hold its arrays."""
         from janusgraph_tpu.olap import autotune
         from janusgraph_tpu.olap.csr import channel_edges
 
@@ -509,6 +523,9 @@ class TPUExecutor:
                 k: v for k, v in self._compiled.items()
                 if not (k[0] == "step" and k[3] == evicted)
             }
+            self._prepared = {
+                k: v for k, v in self._prepared.items() if k[2] != evicted
+            }
         return entry
 
     def _resolve_pack(self, program: VertexProgram, channel: str = None):
@@ -526,6 +543,13 @@ class TPUExecutor:
         self._hybrid_pack(program.undirected)
 
     # ------------------------------------------------------------ superstep
+    def _variant_key(self, program: VertexProgram, op: str, channel=None):
+        """What one compiled superstep variant is keyed by: the program's
+        traced parameters, the combiner, the channel's VALUE (names like
+        's0' recur across programs) and the overlay's static signature."""
+        ch_val = program.edge_channels[channel] if channel is not None else None
+        return (program.cache_key(), op, ch_val, self._delta_sig(program))
+
     def _used_view_keys(
         self, program: VertexProgram, op: str, channel=None,
         state=None, mem0=None,
@@ -538,8 +562,7 @@ class TPUExecutor:
         The same trace records each metric's combiner op (`_metric_ops`),
         so the fused path needs no second discovery pass."""
         jnp = self.jnp
-        ch_val = program.edge_channels[channel] if channel is not None else None
-        key = (program.cache_key(), op, ch_val, self._delta_sig(program))
+        key = self._variant_key(program, op, channel)
         used = self._viewkeys.get(key)
         if used is not None:
             return used
@@ -719,9 +742,7 @@ class TPUExecutor:
 
     def _superstep_fn(self, program: VertexProgram, op: str, channel: str = None):
         """Jitted single superstep (host-loop path)."""
-        ch_val = program.edge_channels[channel] if channel is not None else None
-        key = ("step", program.cache_key(), op, ch_val,
-               self._delta_sig(program))
+        key = ("step",) + self._variant_key(program, op, channel)
         if key not in self._compiled:
             self._compiled[key] = self.jax.jit(
                 self._superstep_body(program, op, channel)
@@ -737,9 +758,7 @@ class TPUExecutor:
         only — lowering traces the body, it never dispatches or compiles."""
         from janusgraph_tpu.observability import profiler
 
-        ch_val = program.edge_channels[channel] if channel is not None else None
-        key = ("cost", program.cache_key(), op, ch_val,
-               self._delta_sig(program))
+        key = ("cost",) + self._variant_key(program, op, channel)
         cost = self._kernel_costs.get(key)
         if cost is not None:
             return cost
@@ -765,6 +784,30 @@ class TPUExecutor:
             )
         self._kernel_costs[key] = cost
         return cost
+
+    def _prepared_step(
+        self, program: VertexProgram, op: str, channel, state, mem
+    ):
+        """(jitted fn, gargs, cost) of one host-loop superstep. All three
+        are constant per variant, so they are resolved on its first
+        dispatch (view-usage discovery seeded with the run's live pytrees,
+        the jit wrapper, the argument pytree, the lower-once cost harvest)
+        and every later dispatch pays one key and one lookup."""
+        key = self._variant_key(program, op, channel)
+        step = self._prepared.get(key)
+        if step is None:
+            self._used_view_keys(program, op, channel, state=state, mem0=mem)
+            fn = self._superstep_fn(program, op, channel)
+            gargs = self._graph_args(program, op, channel)
+            cost = self._superstep_cost(program, op, channel, state, mem, gargs)
+            self._prepared[key] = (fn, gargs, cost, self._last_arg_bytes)
+            return fn, gargs, cost
+        registry.counter("olap.executor.prepared_step").inc()
+        if key[2] is not None:
+            # the recency `_channel_pack` would have refreshed
+            self._channel_packs.move_to_end(key[2])
+        fn, gargs, cost, self._last_arg_bytes = step
+        return fn, gargs, cost
 
     def _fused_fn(self, program: VertexProgram, op: str):
         """A span of the BSP iteration as one compiled dispatch: a
@@ -1542,23 +1585,12 @@ class TPUExecutor:
                 ch = program.channel_for(step)
                 s0 = time.perf_counter()
                 compiled_before = len(self._compiled)
-                # seed view-usage discovery with this run's live pytrees so the
-                # cache-miss path never re-runs program.setup
-                self._used_view_keys(
-                    program, op, ch, state=state, mem0=device_memory
+                fn, gargs, cost = self._prepared_step(
+                    program, op, ch, state, device_memory
                 )
-                fn = self._superstep_fn(program, op, ch)
-                gargs = self._graph_args(program, op, ch)
-                # lower-once cost harvest (memoized per compiled variant):
-                # flops + bytes accessed feed the per-superstep roofline
-                cost = self._superstep_cost(
-                    program, op, ch, state, device_memory, gargs
-                )
+                # the step index rides the dispatch as a host scalar
                 state, metrics = fn(
-                    state,
-                    jnp.asarray(step, dtype=jnp.int32),
-                    device_memory,
-                    gargs,
+                    state, np.int32(step), device_memory, gargs
                 )
                 device_memory = {
                     k: metrics.get(k, device_memory.get(k)) for k in
