@@ -185,6 +185,7 @@ class CPUExecutor:
         )
 
         check_weighted_transforms(program, self.graph)
+        program.require_dense_capable("the CPU executor")
         if getattr(program, "message_mode", None) == "sddmm" and (
             program.undirected
         ):

@@ -36,6 +36,7 @@ import numpy as np
 
 from janusgraph_tpu.observability import tracer
 from janusgraph_tpu.olap.device import await_arrays
+from janusgraph_tpu.olap.kernels import frontier_scope
 
 # the reached-ness tests below ("dist >= INF") are parity-equivalent to the
 # dense program only because both use the IDENTICAL constant
@@ -208,6 +209,14 @@ class FrontierEngine:
         return capped_expand(self.jnp, idx, indptr, dst, E_cap, self.n)
 
     def _step_fn(self, F_cap, E_cap, weighted, track_paths, undirected):
+        """One hop at one tier. Its device time is named by four scopes,
+        none inside another (`frontier_scope`): `frontier.expand` (the
+        frontier's compaction and `capped_expand`), `frontier.relax` (the
+        sender's value gathered to each slot, the weight added),
+        `frontier.scatter` (the scatter-min and the compare that makes the
+        next mask); `frontier.parent` is `_parent_fn`'s. With `weighted`
+        and `track_paths` the `pred` slot carries, per vertex, the round
+        of its last strict improvement: what `_parent_fn` breaks ties by."""
         key = ("frontier-step", F_cap, E_cap, weighted, track_paths, undirected)
         cache = self.ex._compiled
         if key in cache:
@@ -216,29 +225,35 @@ class FrontierEngine:
         n = self.n
 
         def one_orientation(tmp, dist, idx, indptr, dst, w):
-            own, pos, nbr, valid = self._expand(idx, indptr, dst, E_cap)
-            if weighted:
-                # message = sender distance (+ edge weight when present);
-                # invalid slots target the sentinel row, but mask the value
-                # anyway so a clamped gather can never leak a finite number
-                dist_f = dist[jnp.clip(idx, 0, n - 1)]
-                msg = dist_f[own]
-                if w is not None:
-                    msg = msg + w[pos]
-            elif track_paths:
-                # message = sender's (global) vertex index; MIN-combining
-                # yields the smallest-index frontier predecessor — the same
-                # encoding the dense program uses (programs/shortest_path.py)
-                msg = idx.astype(jnp.float32)[own]
-            else:
-                # unweighted: any finite marker means "reached this hop"
-                msg = jnp.zeros((E_cap,), jnp.float32)
-            msg = jnp.where(valid, msg, INF)
-            return tmp.at[nbr].min(msg)
+            with frontier_scope("expand"):
+                own, pos, nbr, valid = self._expand(idx, indptr, dst, E_cap)
+            with frontier_scope("relax"):
+                if weighted:
+                    # message = sender distance (+ edge weight when
+                    # present); invalid slots target the sentinel row, but
+                    # mask the value anyway so a clamped gather can never
+                    # leak a finite number
+                    dist_f = dist[jnp.clip(idx, 0, n - 1)]
+                    msg = dist_f[own]
+                    if w is not None:
+                        msg = msg + w[pos]
+                elif track_paths:
+                    # message = sender's (global) vertex index;
+                    # MIN-combining yields the smallest-index frontier
+                    # predecessor — the same encoding the dense program
+                    # uses (programs/shortest_path.py)
+                    msg = idx.astype(jnp.float32)[own]
+                else:
+                    # unweighted: any finite marker means "reached this hop"
+                    msg = jnp.zeros((E_cap,), jnp.float32)
+                msg = jnp.where(valid, msg, INF)
+            with frontier_scope("scatter"):
+                return tmp.at[nbr].min(msg)
 
         def step(dist, pred, mask, t, fargs):
-            idx = jnp.nonzero(mask, size=F_cap, fill_value=n)[0]
-            idx = idx.astype(jnp.int32)
+            with frontier_scope("expand"):
+                idx = jnp.nonzero(mask, size=F_cap, fill_value=n)[0]
+                idx = idx.astype(jnp.int32)
             tmp = jnp.full((n + 1,), INF, jnp.float32)
             tmp = one_orientation(
                 tmp, dist, idx, fargs["out_ip"], fargs["out_dst"],
@@ -249,18 +264,81 @@ class FrontierEngine:
                     tmp, dist, idx, fargs["in_ip"], fargs["in_src"],
                     fargs.get("in_w") if weighted else None,
                 )
-            tmp = tmp[:n]
-            if weighted:
-                new = jnp.minimum(dist, tmp)
-                changed = new < dist
-                return new, pred, changed, jnp.sum(changed.astype(jnp.int32))
-            newly = (dist >= INF) & (tmp < INF)
-            new = jnp.where(newly, t + 1.0, dist)
-            if track_paths:
-                pred = jnp.where(newly, tmp, pred)
-            return new, pred, newly, jnp.sum(newly.astype(jnp.int32))
+            with frontier_scope("scatter"):
+                tmp = tmp[:n]
+                if weighted:
+                    new = jnp.minimum(dist, tmp)
+                    changed = new < dist
+                    if track_paths:
+                        pred = jnp.where(changed, t + 1.0, pred)
+                    return (
+                        new, pred, changed,
+                        jnp.sum(changed.astype(jnp.int32)),
+                    )
+                newly = (dist >= INF) & (tmp < INF)
+                new = jnp.where(newly, t + 1.0, dist)
+                if track_paths:
+                    pred = jnp.where(newly, tmp, pred)
+                return new, pred, newly, jnp.sum(newly.astype(jnp.int32))
 
         fn = self.jax.jit(step)
+        cache[key] = fn
+        return fn
+
+    def _parent_fn(self, undirected):
+        """(dist, when, fargs) -> parent array of a converged WEIGHTED
+        search, in one full-width pass over the out-orientation (every
+        edge once; read from both ends where `undirected`).
+
+        `p` may be `v`'s parent if an edge between them of weight `w`
+        explains `v`'s distance, `fl32(dist[p] + w) == dist[v]`, and `p`
+        comes before `v` in the order (dist, when): `when` is the round of
+        a vertex's last strict improvement, as the weighted step recorded
+        it. Every reached vertex but the root has such a `p`: the sender
+        of its last improvement, whose distance was final by then if it is
+        equal (a weight of 0, or one the addition absorbs) and is smaller
+        otherwise. The order strictly falls along parent pointers, so they
+        form a tree that ends at the root, the one vertex with `when` 0.
+        Among a vertex's candidates the smallest index is returned."""
+        key = ("frontier-parent", undirected)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        jnp = self.jnp
+        n, m = self.n, self.m
+
+        def parent_pass(dist, when, fargs):
+            with frontier_scope("parent"):
+                dst, w = fargs["out_dst"], fargs["out_w"]
+                # the sender of slot s: +1 at each row's first slot
+                # (empty rows accumulate on the next one), as capped_expand
+                src = jnp.cumsum(
+                    jnp.zeros((m,), jnp.int32)
+                    .at[fargs["out_ip"][1:n]].add(1, mode="drop")
+                )
+                d_src, d_dst = dist[src], dist[dst]
+                w_src, w_dst = when[src], when[dst]
+                best = jnp.full((n + 1,), INF, jnp.float32)
+
+                def offer(best, d_p, when_p, p, d_v, when_v, v):
+                    ok = (
+                        (d_p + w == d_v) & (d_v < INF)
+                        & ((d_p < d_v) | (when_p < when_v))
+                    )
+                    return best.at[jnp.where(ok, v, n)].min(
+                        p.astype(jnp.float32)
+                    )
+
+                best = offer(best, d_src, w_src, src, d_dst, w_dst, dst)
+                if undirected:
+                    best = offer(best, d_dst, w_dst, dst, d_src, w_src, src)
+                best = best[:n]
+                pred = jnp.where(best < INF, best, -1.0)
+                return jnp.where(
+                    when == 0.0, jnp.arange(n, dtype=jnp.float32), pred
+                )
+
+        fn = self.jax.jit(parent_pass)
         cache[key] = fn
         return fn
 
@@ -302,8 +380,12 @@ class FrontierEngine:
                     )
                 trace.append(
                     {"hop": t, "frontier": count,
-                     "edges": max(tot_out, tot_in), "F_cap": f_cap,
-                     "E_cap": e_cap,
+                     "edges": max(tot_out, tot_in),
+                     # slots the hop relaxes and slots its tier holds,
+                     # over the orientations it runs (tot_in is 0 directed)
+                     "relaxed_slots": tot_out + tot_in,
+                     "tier_slots": e_cap * (2 if und else 1),
+                     "F_cap": f_cap, "E_cap": e_cap,
                      "tier_source": (
                          "autotune" if self.e_schedule else "static"
                      )}
@@ -338,10 +420,13 @@ class FrontierEngine:
             )
             pred = None
             if track:
+                # the root's own index; in a weighted run its round, 0
+                # (`_step_fn`: the slot carries rounds until `_parent_fn`)
                 pred = jnp.asarray(
                     np.where(
                         idx0 == program.seed_index,
-                        float(program.seed_index), -1.0,
+                        0.0 if weighted else float(program.seed_index),
+                        -1.0,
                     ),
                     jnp.float32,
                 )
@@ -351,6 +436,13 @@ class FrontierEngine:
             dist, pred, mask, weighted, track, und, fargs,
             program.max_iterations,
         )
+        if weighted and track:
+            # the loop carried rounds, not parents: read the parents off
+            # the converged distances in one full-width pass
+            with tracer.phase("executor.dispatch"):
+                pred = self._parent_fn(und)(dist, pred, fargs)
+            with tracer.phase("executor.sync"):
+                await_arrays((pred,))
         with tracer.phase("executor.fetch"):
             out = {"distance": np.asarray(dist)}
             if track:

@@ -273,6 +273,16 @@ def superstep_scope(xp, name: str):
     return contextlib.nullcontext()
 
 
+def frontier_scope(name: str):
+    """`jax.named_scope("frontier.<name>")` around a stage of the frontier
+    engine's compiled hop (expand / relax / scatter / parent, none
+    enclosing another): `superstep_scope`'s sibling for the engine that
+    has no numpy path."""
+    import jax
+
+    return jax.named_scope("frontier." + name)
+
+
 # graphlint: traced -- the fp-contraction fence of product-fed reductions
 def fp_fence(xp, a):
     """Add an optimizer-opaque zero to `a` — the fp-contraction fence.

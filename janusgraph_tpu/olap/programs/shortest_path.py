@@ -26,11 +26,38 @@ class ShortestPathProgram(VertexProgram):
     ShortestPathVertexProgram materializes paths, special-cased at
     FulgoraGraphComputer.java:249-253; the TPU-native form is a predecessor
     index per vertex + host chain-walk, not per-traverser path objects).
-    Unweighted only: at superstep t the frontier is exactly {dist == t}, so
+    Unweighted: at superstep t the frontier is exactly {dist == t}, so
     the message is the sender's own (global) index where it is on the
     frontier and +inf elsewhere; MIN-combining yields, at each newly reached
     vertex, the smallest-index frontier neighbor as its predecessor —
     float32-exact (indices < 2^24), no wide encodings needed.
+
+    The modes, and who runs them:
+
+    - ``weighted=False`` (BFS hop counts), with or without ``track_paths``
+      and ``undirected``: every executor.
+    - ``weighted=True`` (distances only), directed or ``undirected``: every
+      executor. ``distance`` is the greatest fixpoint below the start
+      vector of ``d[v] = min(d[v], fl32(d[u] + w(u, v)))`` over every edge
+      (from both ends where ``undirected``; a parallel edge once per copy):
+      float32, one add a relaxation, defined bit for bit whatever the
+      order of relaxations.
+    - ``weighted=True, track_paths=True`` (Graph500 kernel 3's result with
+      ``undirected=True``): the single-device frontier engine
+      (``olap/frontier.py``, what ``frontier="auto"`` picks). It returns
+      ``distance`` as above and ``predecessor``: for a reached vertex ``v``
+      other than the root a vertex ``p`` joined to ``v`` by an edge of some
+      weight ``w`` with ``fl32(distance[p] + w) == distance[v]``; the root
+      is its own predecessor; unreached is -1; following predecessors from
+      any reached vertex ends at the root, also over an edge of weight 0
+      or one the addition absorbs (``FrontierEngine._parent_fn`` has the
+      rule). Which of several valid parents comes back is the engine's
+      choice (the smallest index). A weighted parent is an arg-min, which
+      no per-column monoid fold gives, so the dense superstep path
+      (``frontier="off"``, checkpointed or delta-fused runs), ``CPUExecutor``
+      and the sharded executor refuse the combination by name
+      (``require_dense_capable``); ``weighted_predecessors`` derives a
+      parent array on the host from distances any of them computed.
     """
 
     compute_keys = ("distance",)
@@ -45,13 +72,6 @@ class ShortestPathProgram(VertexProgram):
         max_iterations: int = 100,
         track_paths: bool = False,
     ):
-        if track_paths and weighted:
-            raise ValueError(
-                "track_paths requires unweighted BFS (frontier-index "
-                "predecessor encoding); for weighted paths run distances "
-                "to fixpoint and derive predecessors with "
-                "weighted_predecessors(csr, result, seed)"
-            )
         self.seed_index = seed_index
         self.weighted = weighted
         self.track_paths = track_paths
@@ -62,6 +82,19 @@ class ShortestPathProgram(VertexProgram):
         self.max_iterations = max_iterations
         if track_paths:
             self.compute_keys = ("distance", "predecessor")
+
+    def require_dense_capable(self, path: str) -> None:
+        if self.weighted and self.track_paths:
+            raise ValueError(
+                "ShortestPathProgram(weighted=True, track_paths=True) "
+                "returns a weighted parent array, an arg-min over a "
+                f"vertex's relaxations that no monoid fold gives: {path} "
+                "folds messages with a monoid — run it on the single-"
+                "device frontier engine (executor='tpu', frontier='auto' "
+                "or 'always', no checkpoint, no pending delta overlay), or "
+                "run distances alone and derive parents with "
+                "weighted_predecessors(csr, result, seed)"
+            )
 
     def setup(self, graph, xp):
         idx = xp.arange(graph.local_num_vertices) + graph.global_offset
@@ -138,10 +171,13 @@ def weighted_predecessors(csr, result, seed_index: int):
     """Predecessor array for a WEIGHTED run, derived host-side from the
     converged distances in one vectorized O(E) pass: v's predecessor is
     any in-neighbor u with dist[u] + w(u,v) == dist[v] (ties broken by
-    first slot). The device program cannot carry predecessors in weighted
-    mode (its frontier-index encoding is hop-count-based), but at a
+    first slot). The dense device program cannot carry predecessors in
+    weighted mode (its frontier-index encoding is hop-count-based), but at a
     FIXPOINT the relaxation equation identifies them exactly — so paths
-    come from distances, not from extra device state. Returns an array
+    come from distances, not from extra device state. (The single-device
+    frontier engine now returns weighted parents itself, exact and on the
+    device: `ShortestPathProgram(weighted=True, track_paths=True)`; this
+    pass stays for distances from any other executor.) Returns an array
     shaped like the unweighted tracker: pred[seed] = seed, -1 where
     unreached, ready for reconstruct_path (reference capability:
     TinkerPop ShortestPathVertexProgram with the distance modulator).

@@ -970,6 +970,10 @@ class TPUExecutor:
                     "exactness needs |V| < 2^24, int32 expansion needs "
                     "|E| < 2^30) — use frontier='auto' or 'off'"
                 )
+        if not use_frontier:
+            program.require_dense_capable(
+                "the dense superstep path of the single-device executor"
+            )
         if fused is None:
             fused = program.fused_eligible()
         use_fused = (
@@ -1273,6 +1277,14 @@ class TPUExecutor:
         registry.set_gauge("olap.transfer.d2h_bytes", float(info["d2h_bytes"]))
         if pad_ratio is not None:
             registry.set_gauge("olap.ell.pad_ratio", pad_ratio)
+        if info.get("path") == "frontier":
+            registry.counter("olap.frontier.rounds").inc(info["rounds"])
+            registry.counter("olap.frontier.relaxed_slots").inc(
+                info["relaxed_slots"]
+            )
+            registry.counter("olap.frontier.tier_slots").inc(
+                info["tier_slots"]
+            )
         if records:
             registry.set_gauge(
                 "olap.frontier.last", float(records[-1].get("frontier", n))
@@ -1397,6 +1409,11 @@ class TPUExecutor:
             "supersteps": len(trace),
             "wall_s": round(time.perf_counter() - t0, 4),
             "tiers": trace,
+            # totals a layer metric can read: hops executed, slots they
+            # relaxed, slots their tiers held (padding = the difference)
+            "rounds": len(trace),
+            "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
+            "tier_slots": sum(t["tier_slots"] for t in trace),
         }
         return out
 

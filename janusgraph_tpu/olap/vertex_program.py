@@ -317,6 +317,11 @@ class VertexProgram:
             )),
         )
 
+    def require_dense_capable(self, path: str) -> None:
+        """Raise, naming `path`, if this program's result cannot come from
+        message / fold / apply supersteps. Every executor whose run is such
+        supersteps calls it at run() entry; the default refuses nothing."""
+
     def fused_eligible(self) -> bool:
         """Whether run() may compile the whole iteration into one on-device
         while_loop: requires a constant combiner monoid, a constant edge

@@ -1688,6 +1688,8 @@ class ShardedExecutor:
         check_weighted_transforms(program, self.csr)
         # every exchange pre-combines partial aggregates across shards
         Combiner.require_foldable(program.combiner, "the sharded executor")
+        # its frontier engine scatter-mins across shards like the dense path
+        program.require_dense_capable("the sharded executor")
         if frontier not in ("auto", "off", "always"):
             raise ValueError(f"unknown frontier mode: {frontier!r}")
         if not getattr(program, "sharded_compatible", True):

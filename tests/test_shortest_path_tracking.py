@@ -95,9 +95,37 @@ def test_undirected_paths(mesh8):
             assert (a, b) in edges
 
 
-def test_track_paths_rejects_weighted():
-    with pytest.raises(ValueError, match="unweighted"):
-        ShortestPathProgram(seed_index=0, weighted=True, track_paths=True)
+def test_track_paths_weighted_walks_cheapest_paths():
+    """Weighted + track_paths is a mode now (it raised ValueError): the
+    frontier engine returns parents along cheapest paths, and the paths
+    they reconstruct weigh what networkx's dijkstra says."""
+    import networkx as nx
+
+    rng = np.random.default_rng(21)
+    n, m = 90, 360
+    src = rng.integers(0, n, m).astype(np.int32)
+    dst = rng.integers(0, n, m).astype(np.int32)
+    wts = rng.uniform(0.25, 2.0, m).astype(np.float32)
+    csr = csr_from_edges(n, src, dst, weights=wts)
+    res = TPUExecutor(csr).run(ShortestPathProgram(
+        seed_index=0, weighted=True, track_paths=True, max_iterations=200))
+    G = nx.DiGraph()
+    G.add_nodes_from(range(n))
+    cheapest = {}
+    for s, d, w in zip(src.tolist(), dst.tolist(), wts.tolist()):
+        cheapest[(s, d)] = min(cheapest.get((s, d), np.inf), w)
+    G.add_weighted_edges_from((s, d, w) for (s, d), w in cheapest.items())
+    nx_dist = nx.single_source_dijkstra_path_length(G, 0)
+    assert len(nx_dist) > 50
+    for v in range(n):
+        path = reconstruct_path(res, v)
+        if v not in nx_dist:
+            assert path is None and res["predecessor"][v] == -1
+            continue
+        assert path[0] == 0 and path[-1] == v
+        total = sum(cheapest[(a, b)] for a, b in zip(path, path[1:]))
+        assert abs(total - nx_dist[v]) < 1e-4
+        assert abs(float(res["distance"][v]) - nx_dist[v]) < 1e-4
 
 
 def test_plain_distance_mode_unchanged(mesh8):
