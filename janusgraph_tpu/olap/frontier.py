@@ -16,12 +16,20 @@ scale 23 even when the BFS frontier is a handful of vertices. Here each hop:
   3. gathers only the frontier's out-neighbors (E_frontier elements, not E),
   4. scatter-mins the relaxed distances into the state.
 
-Tiers: one executable per (F_cap, E_cap) pair, caps growing in powers of 4
-up to (n, m) — the top tier IS the dense fallback, so a saturated frontier
-costs one full-edge pass and nothing is ever dropped. Per-step results are
-bit-identical to the dense BSP path: relaxing a non-frontier edge is a
-no-op (its source's distance has not changed since it was last relaxed), so
-skipping it cannot change any superstep's output, weighted or not.
+Tiers: one executable per (F_cap, E_cap) pair, caps growing up a ladder
+whose top rung is m. A hop that picks the top rung (`E_cap == m`: it would
+hold every edge of each orientation it reads) is a WIDE ROUND instead, the
+dense superstep's aggregation on the executor's pack (`_wide_fn`): the
+frontier's values as an n-vector, INF elsewhere, read through every slot of
+the pack with ONE gather and folded with a min tree (`kernels.
+hybrid_gather` / `hybrid_fold`), with no expansion and no scatter, at a
+fifth of the narrow step's price of a slot. So a saturated frontier costs
+one full-edge pass and nothing is ever dropped. Per-step results are
+bit-identical to the dense BSP path, wide or narrow: relaxing a
+non-frontier edge is a no-op (its source's distance has not changed since
+it was last relaxed), so skipping it cannot change any superstep's output,
+weighted or not; a message is one float32 add and `min` is exact in any
+order.
 
 Int32 throughout (the telescoping cumsum trick needs diff headroom, hence
 the ``m < 2**30`` eligibility guard — beyond that the executor keeps the
@@ -36,12 +44,17 @@ import numpy as np
 
 from janusgraph_tpu.observability import tracer
 from janusgraph_tpu.olap.device import await_arrays
-from janusgraph_tpu.olap.kernels import frontier_scope
+from janusgraph_tpu.olap.kernels import (
+    HybridPackView,
+    frontier_scope,
+    hybrid_fold,
+    hybrid_gather,
+)
 
 # the reached-ness tests below ("dist >= INF") are parity-equivalent to the
 # dense program only because both use the IDENTICAL constant
 from janusgraph_tpu.olap.programs.shortest_path import INF
-from janusgraph_tpu.olap.vertex_program import Combiner
+from janusgraph_tpu.olap.vertex_program import Combiner, EdgeTransform
 
 
 def _tier(need: int, lo: int, hi: int, growth: int = 4) -> int:
@@ -265,21 +278,76 @@ class FrontierEngine:
                     fargs.get("in_w") if weighted else None,
                 )
             with frontier_scope("scatter"):
-                tmp = tmp[:n]
+                return self._settle(
+                    dist, pred, tmp[:n], t, weighted, track_paths
+                )
+
+        fn = self.jax.jit(step)
+        cache[key] = fn
+        return fn
+
+    def _settle(self, dist, pred, tmp, t, weighted, track_paths):
+        """The tail of a hop, narrow or wide: from the least message each
+        vertex received (`tmp`, INF or more where none came from the
+        frontier) to (new value, pred, next mask, its count)."""
+        jnp = self.jnp
+        if weighted:
+            new = jnp.minimum(dist, tmp)
+            changed = new < dist
+            if track_paths:
+                pred = jnp.where(changed, t + 1.0, pred)
+            return (
+                new, pred, changed,
+                jnp.sum(changed.astype(jnp.int32)),
+            )
+        newly = (dist >= INF) & (tmp < INF)
+        new = jnp.where(newly, t + 1.0, dist)
+        if track_paths:
+            pred = jnp.where(newly, tmp, pred)
+        return new, pred, newly, jnp.sum(newly.astype(jnp.int32))
+
+    def _wide_fn(self, weighted, track_paths, undirected, add_weight):
+        """A hop at the ladder's top rung, as a wide round on the
+        executor's pack of the view the hop reads: the frontier's messages
+        as an n-vector (INF off the frontier, so those slots relax
+        nothing), ONE gather over every slot with the weight added in
+        flight where the narrow step adds it (`add_weight`; never for
+        labels), a min tree, and the narrow step's tail. The call is the
+        narrow step's with the pack's arrays in place of `fargs`,
+        `fn(dist, pred, mask, t, pack.arrays)`: arguments, not constants
+        (`TPUExecutor._graph_args`' reason). The function is named `step`
+        like the narrow one, so a profile holds both under `jit_step`; the
+        gather and the weight lie under `frontier.relax`, the fold and the
+        tail under `frontier.scatter`."""
+        key = ("frontier-wide", weighted, track_paths, undirected, add_weight)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        pack = self.ex._hybrid_pack(undirected)  # for its static sizes
+        jnp = self.jnp
+        n = self.n
+        transform = (
+            EdgeTransform.ADD_WEIGHT if add_weight else EdgeTransform.NONE
+        )
+
+        def step(dist, pred, mask, t, hyb):
+            view = HybridPackView(hyb, pack)
+            with frontier_scope("relax"):
                 if weighted:
-                    new = jnp.minimum(dist, tmp)
-                    changed = new < dist
-                    if track_paths:
-                        pred = jnp.where(changed, t + 1.0, pred)
-                    return (
-                        new, pred, changed,
-                        jnp.sum(changed.astype(jnp.int32)),
-                    )
-                newly = (dist >= INF) & (tmp < INF)
-                new = jnp.where(newly, t + 1.0, dist)
-                if track_paths:
-                    pred = jnp.where(newly, tmp, pred)
-                return new, pred, newly, jnp.sum(newly.astype(jnp.int32))
+                    value = dist
+                elif track_paths:
+                    value = jnp.arange(n, dtype=jnp.float32)
+                else:
+                    value = jnp.zeros((n,), jnp.float32)
+                msgs = jnp.where(mask, value, INF)
+                leaves = hybrid_gather(
+                    jnp, view, msgs, Combiner.MIN, transform
+                )
+            with frontier_scope("scatter"):
+                tmp = hybrid_fold(
+                    jnp, view, leaves, Combiner.MIN, msgs.shape, msgs.dtype
+                )
+                return self._settle(dist, pred, tmp, t, weighted, track_paths)
 
         fn = self.jax.jit(step)
         cache[key] = fn
@@ -347,7 +415,8 @@ class FrontierEngine:
         self, value, pred, mask, weighted, track, und, fargs, max_iterations
     ):
         """The shared host-driven loop: plan (3 scalars) -> pick tier ->
-        one compiled step. Two device round trips per hop; per-step output
+        one compiled step, the wide round (`_wide_fn`) where the tier is
+        the ladder's top. Two device round trips per hop; per-step output
         is identical to the dense BSP path's. The host's time in it is
         tiled by phases: per hop `executor.tier` (the plan's dispatch and
         the tier choice; the wait for its three scalars is the
@@ -378,22 +447,37 @@ class FrontierEngine:
                         max(tot_out, tot_in, 1), self.E_MIN, self.m,
                         self.GROWTH,
                     )
+                # the rule is the rung: a hop that would hold every edge
+                # is a wide round on the pack of the view it reads (built
+                # on first use, the dense path's own)
+                wide = e_cap == self.m
+                pack = self.ex._hybrid_pack(und) if wide else None
                 trace.append(
                     {"hop": t, "frontier": count,
                      "edges": max(tot_out, tot_in),
                      # slots the hop relaxes and slots its tier holds,
-                     # over the orientations it runs (tot_in is 0 directed)
+                     # over the orientations it runs (tot_in is 0
+                     # directed); a wide hop holds what it gathers
                      "relaxed_slots": tot_out + tot_in,
-                     "tier_slots": e_cap * (2 if und else 1),
-                     "F_cap": f_cap, "E_cap": e_cap,
+                     "tier_slots": (
+                         pack.slots if wide else e_cap * (2 if und else 1)
+                     ),
+                     "F_cap": f_cap, "E_cap": e_cap, "wide": wide,
                      "tier_source": (
                          "autotune" if self.e_schedule else "static"
                      )}
                 )
             with tracer.phase("executor.dispatch"):
-                fn = self._step_fn(f_cap, e_cap, weighted, track, und)
+                if wide:
+                    fn = self._wide_fn(
+                        weighted, track, und, weighted and "out_w" in fargs
+                    )
+                    args = pack.arrays
+                else:
+                    fn = self._step_fn(f_cap, e_cap, weighted, track, und)
+                    args = fargs
                 value, pred, mask, _ = fn(
-                    value, pred, mask, jnp.asarray(t, jnp.float32), fargs
+                    value, pred, mask, jnp.asarray(t, jnp.float32), args
                 )
         with tracer.phase("executor.sync"):
             # the last step is still running: wait for it here, so that

@@ -858,6 +858,45 @@ def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
     return stacked[pack.arrays["unpermute"]]
 
 
+# graphlint: traced -- the one gather of the hybrid aggregation bodies
+def hybrid_gather(
+    xp,
+    pack,
+    msgs,
+    op: str,
+    edge_transform: str = EdgeTransform.NONE,
+    edge_transform_cols=None,
+):
+    """Every slot of a HybridPack (or view) read with ONE gather,
+    `flat_take(msgs_ext, idx)`, and transformed in flight: the (slots[, k])
+    leaves `hybrid_fold` reduces, in the flat index vector's order."""
+    identity = Combiner.IDENTITY[op]
+    pad_shape = (1,) + tuple(msgs.shape[1:])
+    msgs_ext = xp.concatenate(
+        [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
+    )
+    m = flat_take(xp, msgs_ext, pack.arrays["idx"])  # (slots[, k])
+    # labels ride untransformed, and a weighted pack's padded slots
+    # index the sentinel like any other's: no mask for MODE
+    if pack.has_weight and op != Combiner.MODE:
+        # mirrors the ELL weighted path slot-for-slot: transform first,
+        # then force the tail's padded slots back to the identity (a
+        # transform can disturb it, e.g. identity*0 = nan for MIN's
+        # +inf)
+        m = apply_edge_transform(
+            xp, m, pack.arrays["w"], edge_transform, edge_transform_cols
+        )
+        ts = pack.torso_slots
+        valid = pack.arrays["valid"]
+        valid_ = valid[:, None] if m.ndim == 2 else valid
+        # same fence as the ELL weighted branch: the torso's unmasked
+        # weight product would otherwise contract into the tree
+        m = fp_fence(xp, xp.concatenate(
+            [m[:ts], xp.where(valid_ > 0, m[ts:], identity)], axis=0
+        ))
+    return m
+
+
 # graphlint: traced -- the hybrid aggregation body of compiled supersteps
 def hybrid_aggregate(
     xp,
@@ -868,38 +907,17 @@ def hybrid_aggregate(
     edge_transform_cols=None,
 ):
     """Aggregate per-vertex messages over a HybridPack (or view) with ONE
-    gather: `flat_take(msgs_ext, idx)` reads every slot of the pack.
+    gather (`hybrid_gather`) and one fold of its leaves.
 
     Same contract as ell_aggregate — msgs (n,) or (n, k), returns the
     per-destination monoid fold — and bitwise-identical results to it
     (both reduce through tree_reduce's fixed adjacent-pair tree)."""
-    identity = Combiner.IDENTITY[op]
     if op == Combiner.MODE:
         _check_mode_messages(msgs, edge_transform, edge_transform_cols)
-    pad_shape = (1,) + tuple(msgs.shape[1:])
     with superstep_scope(xp, "gather"):
-        msgs_ext = xp.concatenate(
-            [msgs, xp.full(pad_shape, identity, dtype=msgs.dtype)], axis=0
+        m = hybrid_gather(
+            xp, pack, msgs, op, edge_transform, edge_transform_cols
         )
-        m = flat_take(xp, msgs_ext, pack.arrays["idx"])  # (slots[, k])
-        # labels ride untransformed, and a weighted pack's padded slots
-        # index the sentinel like any other's: no mask for MODE
-        if pack.has_weight and op != Combiner.MODE:
-            # mirrors the ELL weighted path slot-for-slot: transform first,
-            # then force the tail's padded slots back to the identity (a
-            # transform can disturb it, e.g. identity*0 = nan for MIN's
-            # +inf)
-            m = apply_edge_transform(
-                xp, m, pack.arrays["w"], edge_transform, edge_transform_cols
-            )
-            ts = pack.torso_slots
-            valid = pack.arrays["valid"]
-            valid_ = valid[:, None] if m.ndim == 2 else valid
-            # same fence as the ELL weighted branch: the torso's unmasked
-            # weight product would otherwise contract into the tree
-            m = fp_fence(xp, xp.concatenate(
-                [m[:ts], xp.where(valid_ > 0, m[ts:], identity)], axis=0
-            ))
     with superstep_scope(xp, "fold"):
         if op == Combiner.MODE:
             return hybrid_mode_fold(xp, pack, m)

@@ -1285,6 +1285,9 @@ class TPUExecutor:
             registry.counter("olap.frontier.tier_slots").inc(
                 info["tier_slots"]
             )
+            registry.counter("olap.frontier.wide_rounds").inc(
+                info["wide_rounds"]
+            )
         if records:
             registry.set_gauge(
                 "olap.frontier.last", float(records[-1].get("frontier", n))
@@ -1410,10 +1413,12 @@ class TPUExecutor:
             "wall_s": round(time.perf_counter() - t0, 4),
             "tiers": trace,
             # totals a layer metric can read: hops executed, slots they
-            # relaxed, slots their tiers held (padding = the difference)
+            # relaxed, slots their tiers held (padding = the difference),
+            # hops that ran as wide rounds on the pack (the top rung)
             "rounds": len(trace),
             "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
             "tier_slots": sum(t["tier_slots"] for t in trace),
+            "wide_rounds": sum(t["wide"] for t in trace),
         }
         return out
 

@@ -46,6 +46,13 @@ def _dist(res):
     return np.where(d >= 1e17, np.inf, d)
 
 
+#: rungs under the ladder's top. The default E floor (8192) lies above
+#: these graphs' edge counts, which makes the ladder the single rung (m,)
+#: and every hop a wide round on the pack: a test that means to drive the
+#: narrow step (compaction, `capped_expand`, the scatter-min) forces rungs.
+RUNGS = dict(frontier_e_min=64, frontier_f_min=16)
+
+
 CASES = [
     ("bfs", dict()),
     ("bfs_undirected", dict(undirected=True)),
@@ -61,9 +68,10 @@ def test_frontier_matches_cpu_and_dense(name, kw):
     prog = lambda: ShortestPathProgram(seed_index=0, **kw)  # noqa: E731
     cpu = CPUExecutor(csr).run(prog())
     dense = TPUExecutor(csr, frontier="off").run(prog())
-    ex = TPUExecutor(csr)
+    ex = TPUExecutor(csr, **RUNGS)
     assert ex._frontier_eligible(prog(), "auto")
     sparse = ex.run(prog())
+    assert not all(t["wide"] for t in ex.last_run_info["tiers"])
     np.testing.assert_allclose(_dist(sparse), _dist(cpu), rtol=1e-6)
     np.testing.assert_allclose(_dist(sparse), _dist(dense), rtol=1e-6)
     if "predecessor" in sparse:
@@ -76,7 +84,10 @@ def test_frontier_supernode_deg0():
     csr = supernode_graph()
     prog = lambda: ShortestPathProgram(seed_index=0)  # noqa: E731
     cpu = CPUExecutor(csr).run(prog())
-    sparse = TPUExecutor(csr).run(prog())
+    ex = TPUExecutor(csr, **RUNGS)
+    sparse = ex.run(prog())
+    # the hub's 199 out-edges on the rung of 256, then the sparse tail
+    assert not any(t["wide"] for t in ex.last_run_info["tiers"])
     np.testing.assert_allclose(_dist(sparse), _dist(cpu), rtol=1e-6)
 
 
@@ -96,7 +107,7 @@ def test_frontier_step_parity_at_cutoff(max_iter):
     csr = random_graph(n=120, m=500, seed=11)
     mk = lambda: ShortestPathProgram(seed_index=0, max_iterations=max_iter)  # noqa: E731
     dense = TPUExecutor(csr, frontier="off").run(mk())
-    sparse = TPUExecutor(csr).run(mk())
+    sparse = TPUExecutor(csr, **RUNGS).run(mk())
     np.testing.assert_allclose(_dist(sparse), _dist(dense), rtol=1e-6)
 
 
@@ -107,13 +118,13 @@ def test_frontier_weighted_cutoff_parity():
             seed_index=5, weighted=True, max_iterations=it
         )
         dense = TPUExecutor(csr, frontier="off").run(mk())
-        sparse = TPUExecutor(csr).run(mk())
+        sparse = TPUExecutor(csr, **RUNGS).run(mk())
         np.testing.assert_allclose(_dist(sparse), _dist(dense), rtol=1e-6)
 
 
 def test_frontier_path_reconstruction():
     csr = random_graph(n=150, m=700, seed=19)
-    res = TPUExecutor(csr).run(
+    res = TPUExecutor(csr, **RUNGS).run(
         ShortestPathProgram(seed_index=0, track_paths=True)
     )
     dist = _dist(res)
@@ -136,7 +147,9 @@ def test_frontier_line_graph_many_hops():
     src = np.arange(n - 1, dtype=np.int32)
     dst = np.arange(1, n, dtype=np.int32)
     csr = csr_from_edges(n, src, dst)
-    res = TPUExecutor(csr).run(ShortestPathProgram(seed_index=0))
+    ex = TPUExecutor(csr, frontier_e_min=8, frontier_f_min=4)
+    res = ex.run(ShortestPathProgram(seed_index=0))
+    assert not any(t["wide"] for t in ex.last_run_info["tiers"])
     np.testing.assert_allclose(_dist(res), np.arange(n, dtype=np.float32))
 
 
@@ -188,9 +201,10 @@ def test_frontier_cc_matches_cpu_and_dense():
     mk = lambda: ConnectedComponentsProgram(max_iterations=100)  # noqa: E731
     cpu = CPUExecutor(csr).run(mk())
     dense = TPUExecutor(csr, frontier="off").run(mk())
-    ex = TPUExecutor(csr, frontier="always")
+    ex = TPUExecutor(csr, frontier="always", **RUNGS)
     assert ex._frontier_eligible(mk(), "always")
     sparse = ex.run(mk())
+    assert not all(t["wide"] for t in ex.last_run_info["tiers"])
     np.testing.assert_array_equal(
         np.asarray(sparse["component"]), np.asarray(cpu["component"])
     )
@@ -206,7 +220,7 @@ def test_frontier_cc_step_cutoff_parity():
     for it in (1, 2, 3):
         mk = lambda: ConnectedComponentsProgram(max_iterations=it)  # noqa: E731
         dense = TPUExecutor(csr, frontier="off").run(mk())
-        sparse = TPUExecutor(csr, frontier="always").run(mk())
+        sparse = TPUExecutor(csr, frontier="always", **RUNGS).run(mk())
         np.testing.assert_array_equal(
             np.asarray(sparse["component"]), np.asarray(dense["component"])
         )
@@ -264,21 +278,171 @@ def test_frontier_fuzz_vs_dense():
             max_iterations=it,
         )
         dense = TPUExecutor(csr, frontier="off").run(mk())
-        sparse = TPUExecutor(csr, frontier="always").run(mk())
-        np.testing.assert_allclose(
-            _dist(sparse), _dist(dense), rtol=1e-6,
-            err_msg=f"trial={trial} n={n} m={m} w={weights} und={und} it={it}",
-        )
+        # every hop wide (the default ladder is (m,) here), then rungs
+        for rungs in ({}, RUNGS):
+            sparse = TPUExecutor(csr, frontier="always", **rungs).run(mk())
+            np.testing.assert_allclose(
+                _dist(sparse), _dist(dense), rtol=1e-6,
+                err_msg=f"trial={trial} n={n} m={m} w={weights} und={und} "
+                        f"it={it} rungs={rungs}",
+            )
         cc_d = TPUExecutor(csr, frontier="off").run(
             ConnectedComponentsProgram(max_iterations=64)
         )
-        cc_s = TPUExecutor(csr, frontier="always").run(
+        cc_s = TPUExecutor(csr, frontier="always", **RUNGS).run(
             ConnectedComponentsProgram(max_iterations=64)
         )
         np.testing.assert_array_equal(
             np.asarray(cc_s["component"]), np.asarray(cc_d["component"]),
             err_msg=f"cc trial={trial} n={n} m={m}",
         )
+
+
+# ------------------------------------------- the wide round (the top rung)
+def awkward_multigraph(seed, n=260, m=1100, island=30):
+    """A weighted multigraph with the cases a relaxation has to survive:
+    the last `island` vertices joined among themselves only, ten self
+    loops, sixty parallel edges under other weights, forty weights of 0
+    and forty of 2**-26 (absorbed by any distance of 2**-2 or more). 1100
+    edges, so that a rung of 1024 lies just under the ladder's top."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n - island, m).astype(np.int32)
+    dst = rng.integers(0, n - island, m).astype(np.int32)
+    src[:20] = rng.integers(n - island, n, 20)
+    dst[:20] = rng.integers(n - island, n, 20)
+    weight = rng.random(m, dtype=np.float32)
+    weight[rng.integers(20, m, 40)] = 0.0
+    weight[rng.integers(20, m, 40)] = np.float32(2.0 ** -26)
+    src[100:110] = dst[100:110]
+    src[200:260], dst[200:260] = src[300:360], dst[300:360]
+    return csr_from_edges(n, src, dst, weight)
+
+
+def _components(**kw):
+    from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
+
+    return ConnectedComponentsProgram(max_iterations=100, **kw)
+
+
+#: flavour -> (program factory, the oracle that folds in the same float)
+WIDE_FLAVOURS = {
+    "bfs": (lambda: ShortestPathProgram(seed_index=3), {}),
+    "bfs-paths": (
+        lambda: ShortestPathProgram(seed_index=3, track_paths=True), {}),
+    "weighted": (
+        lambda: ShortestPathProgram(
+            seed_index=3, weighted=True, max_iterations=1000),
+        {"strategy": "ell"}),
+    # labels on a WEIGHTED graph: a label never absorbs a weight
+    "cc-weighted-graph": (_components, {}),
+}
+
+#: ladder -> (executor options, which hops must be wide). The E ladder is
+#: pow2 rungs from the floor, then m = 1100.
+WIDE_LADDERS = {
+    # (1024, m): no hop of a search holds more than 1024 edges
+    "narrow": (dict(frontier_e_min=1024, frontier_f_min=16), "none"),
+    # (128, m): the hops in the middle of a search are wide
+    "mixed": (dict(frontier_e_min=64, frontier_f_min=16,
+                   autotune_max_tiers=2), "some"),
+    # the default floor lies above m: the ladder is (m,), every hop wide
+    "default": ({}, "all"),
+}
+
+_oracles = {}
+
+
+def _oracle(flavour, seed):
+    """(CPUExecutor's result, the dense path's), once a flavour and seed."""
+    if (flavour, seed) not in _oracles:
+        make, cpu_options = WIDE_FLAVOURS[flavour]
+        csr = awkward_multigraph(seed)
+        _oracles[flavour, seed] = (
+            CPUExecutor(csr, **cpu_options).run(make()),
+            TPUExecutor(csr, frontier="off").run(make()),
+        )
+    return _oracles[flavour, seed]
+
+
+def assert_hop_mix(info, num_edges, expect):
+    """The record says which hops were wide, by the rule (the rung), and
+    the run is the mix of wide and narrow hops the case means to drive.
+    CC starts from every vertex, so its first hop holds every edge and is
+    wide under any ladder."""
+    wide = [t["wide"] for t in info["tiers"]]
+    assert wide == [t["E_cap"] == num_edges for t in info["tiers"]]
+    assert info["wide_rounds"] == sum(wide)
+    if expect == "all":
+        assert all(wide)
+    elif expect == "some":
+        assert any(wide) and not all(wide)
+    elif info["tiers"][0]["frontier"] > 1:  # CC
+        assert wide[0] and not all(wide)
+    else:
+        assert not any(wide)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("ladder", list(WIDE_LADDERS))
+@pytest.mark.parametrize("flavour", list(WIDE_FLAVOURS))
+def test_wide_round_is_the_narrow_step_bit_for_bit(flavour, ladder, seed):
+    """The top rung runs on the pack; whatever mix of wide and narrow hops
+    a ladder gives, the result is CPUExecutor's and the dense path's, bit
+    for bit."""
+    make, _ = WIDE_FLAVOURS[flavour]
+    options, expect = WIDE_LADDERS[ladder]
+    csr = awkward_multigraph(seed)
+    ex = TPUExecutor(csr, frontier="always", **options)
+    got = ex.run(make())
+    assert ex.last_run_info["path"] == "frontier"
+    assert_hop_mix(ex.last_run_info, csr.num_edges, expect)
+    cpu, dense = _oracle(flavour, seed)
+    assert set(got) == set(dense)
+    for key in got:
+        np.testing.assert_array_equal(
+            np.asarray(got[key]).view(np.uint32),
+            np.asarray(dense[key]).view(np.uint32), err_msg=key)
+    state = "component" if "component" in got else "distance"
+    np.testing.assert_array_equal(
+        np.asarray(got[state]), np.asarray(cpu[state]).astype(np.float32))
+    # the island is never reached, and never joins the rest
+    if state == "distance":
+        assert np.all(np.asarray(got[state])[-30:] >= 1e17)
+    else:
+        assert np.all(np.asarray(got[state])[-30:] >= csr.num_vertices - 30)
+
+
+def test_wide_executable_is_one_per_flavour_and_top_rung_steps_are_gone():
+    """One wide executable serves every top-rung hop of a flavour; no
+    narrow step is built at `E_cap == m`; the pack is the executor's own
+    (built on the first wide hop, the dense path's afterwards)."""
+    csr = awkward_multigraph(2)
+    ex = TPUExecutor(csr, frontier_e_min=64, frontier_f_min=16,
+                     autotune_max_tiers=2)
+    assert ex._hybrid_packs == {}
+    for root in (3, 5, 9):
+        ex.run(ShortestPathProgram(seed_index=root))
+        assert ex.last_run_info["wide_rounds"] >= 1
+    keys = list(ex._compiled)
+    assert [k for k in keys if k[0] == "frontier-wide"] == [
+        ("frontier-wide", False, False, False, False)]
+    assert all(k[2] < csr.num_edges for k in keys if k[0] == "frontier-step")
+    assert list(ex._hybrid_packs) == [False]
+    pack = ex._hybrid_packs[False]
+    assert all(t["tier_slots"] == pack.slots
+               for t in ex.last_run_info["tiers"] if t["wide"])
+    # CC on the same (weighted) graph and weighted SSSP read the closure
+    # pack, with and without its weights: two executables, one pack
+    ex.run(_components(), frontier="always")
+    ex.run(ShortestPathProgram(seed_index=3, weighted=True, undirected=True,
+                               max_iterations=1000))
+    wide = sorted(k for k in ex._compiled if k[0] == "frontier-wide")
+    assert wide == [
+        ("frontier-wide", False, False, False, False),
+        ("frontier-wide", True, False, True, False),
+        ("frontier-wide", True, False, True, True),
+    ]
+    assert sorted(ex._hybrid_packs) == [False, True]
 
 
 def test_frontier_always_refuses_checkpointing(tmp_path):

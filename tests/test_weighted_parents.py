@@ -87,11 +87,13 @@ def _as_infinite(distance):
 def test_frontier_engine_returns_reference_distances_and_valid_parents(
         seed, undirected):
     data = multigraph(seed)
-    ex = TPUExecutor(_csr(data))
+    # rungs under the top: all but the widest hops run the narrow step
+    ex = TPUExecutor(_csr(data), frontier_e_min=64, frontier_f_min=16)
     for root in (0, 7):
         got = ex.run(_program(root, undirected, track_paths=True))
         info = ex.last_run_info
         assert info["path"] == "frontier"
+        assert info["wide_rounds"] < info["rounds"] / 2
         # ended by its fixpoint, and the record carries the totals
         assert info["rounds"] == info["supersteps"] < 1000
         assert info["relaxed_slots"] <= info["tier_slots"]
@@ -105,6 +107,58 @@ def test_frontier_engine_returns_reference_distances_and_valid_parents(
         reached = np.flatnonzero(np.asarray(got["distance"]) < INF)
         path = reconstruct_path(got, int(reached[-1]))
         assert path[0] == root and path[-1] == reached[-1]
+
+
+#: ladder -> (executor options, which hops must be wide), on 1100 edges:
+#: (1024, m) holds every hop of these searches under the top rung, (128, m)
+#: makes the hops in the middle wide rounds on the closure pack, and the
+#: default floor (8192) lies above m, so the ladder is (m,): all wide.
+LADDERS = {
+    "narrow": (dict(frontier_e_min=1024, frontier_f_min=16), "none"),
+    "mixed": (dict(frontier_e_min=64, frontier_f_min=16,
+                   autotune_max_tiers=2), "some"),
+    "default": ({}, "all"),
+}
+_narrow_runs = {}
+
+
+def _search(seed, ladder, undirected):
+    data = multigraph(seed, n=260, m=1100, island=30)
+    ex = TPUExecutor(_csr(data), **LADDERS[ladder][0])
+    got = ex.run(_program(3, undirected, track_paths=True))
+    return data, got, ex.last_run_info
+
+
+@pytest.mark.parametrize("undirected", [True, False],
+                         ids=["undirected", "directed"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("ladder", list(LADDERS))
+def test_wide_rounds_are_the_same_search_with_the_same_parents(
+        ladder, seed, undirected):
+    """Kernel 3's search with the top rung's hops run as wide rounds on
+    the weighted closure pack: the reference's distances bit for bit, a
+    valid parent array, and hop for hop the search the narrow step runs
+    (its rounds, its frontiers, its parents: the parent pass reads the
+    round of each vertex's last improvement)."""
+    data, got, info = _search(seed, ladder, undirected)
+    wide = [t["wide"] for t in info["tiers"]]
+    assert wide == [t["E_cap"] == len(data.src) for t in info["tiers"]]
+    assert info["wide_rounds"] == sum(wide)
+    expect = LADDERS[ladder][1]
+    assert {"none": not any(wide), "all": all(wide),
+            "some": any(wide) and not all(wide)}[expect]
+    want = sssp.KernelThree.expect(data, 3, undirected=undirected)
+    assert sssp.KernelThree.disagreements(got, want) == []
+    if (seed, undirected) not in _narrow_runs:
+        _narrow_runs[seed, undirected] = _search(
+            seed, "narrow", undirected)[1:]
+    narrow, narrow_info = _narrow_runs[seed, undirected]
+    np.testing.assert_array_equal(
+        np.asarray(got["distance"]).view(np.uint32),
+        np.asarray(narrow["distance"]).view(np.uint32))
+    np.testing.assert_array_equal(got["predecessor"], narrow["predecessor"])
+    assert [(t["frontier"], t["relaxed_slots"]) for t in info["tiers"]] == [
+        (t["frontier"], t["relaxed_slots"]) for t in narrow_info["tiers"]]
 
 
 @pytest.mark.parametrize("weights", [
@@ -155,24 +209,35 @@ def test_submit_runs_the_combination_on_the_frontier_engine():
 ], ids=["weighted-parents", "weighted", "bfs"])
 def test_frontier_run_record_totals_and_registry_counters(program,
                                                           orientations):
-    """`rounds`, `relaxed_slots`, `tier_slots`: sums over the hops of the
-    record's own `tiers`, and the registry's counters move by them."""
+    """`rounds`, `relaxed_slots`, `tier_slots`, `wide_rounds`: sums over
+    the hops of the record's own `tiers`, and the registry's counters move
+    by them. A hop holds `E_cap` slots an orientation, a wide one (the top
+    rung) the slots of the pack it gathers."""
     from janusgraph_tpu.observability import registry
 
     def counters():
         snap = registry.snapshot()
         return {k: snap.get("olap.frontier." + k, {}).get("count", 0)
-                for k in ("rounds", "relaxed_slots", "tier_slots")}
+                for k in ("rounds", "relaxed_slots", "tier_slots",
+                          "wide_rounds")}
 
-    ex = TPUExecutor(_csr(multigraph(3)))
+    # the ladder (128, m): every search here has wide and narrow hops
+    ex = TPUExecutor(_csr(multigraph(3)), frontier_e_min=64,
+                     frontier_f_min=16, autotune_max_tiers=2)
     before = counters()
     ex.run(program())
     info = registry.last_run("olap")
     assert info["path"] == "frontier" and info["rounds"] == len(info["tiers"])
     assert info["relaxed_slots"] == sum(
         t["relaxed_slots"] for t in info["tiers"])
-    assert info["tier_slots"] == orientations * sum(
-        t["E_cap"] for t in info["tiers"])
+    pack_slots = ex._hybrid_pack(orientations == 2).slots
+    for t in info["tiers"]:
+        assert t["wide"] == (t["E_cap"] == ex.csr.num_edges)
+        assert t["tier_slots"] == (
+            pack_slots if t["wide"] else orientations * t["E_cap"])
+    assert info["tier_slots"] == sum(t["tier_slots"] for t in info["tiers"])
+    assert 0 < info["wide_rounds"] < info["rounds"]
+    assert info["wide_rounds"] == sum(t["wide"] for t in info["tiers"])
     assert all(t["relaxed_slots"] <= t["tier_slots"] for t in info["tiers"])
     moved = {k: v - before[k] for k, v in counters().items()}
     assert moved == {k: info[k] for k in moved}
