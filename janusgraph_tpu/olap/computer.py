@@ -319,7 +319,9 @@ class GraphComputer:
                 routing["reason"] = (
                     "single device" if ndev <= 1
                     else "mode combiner" if whole_multiset
-                    else "sddmm program"
+                    else "sddmm program" if getattr(
+                        self._program, "message_mode", None) == "sddmm"
+                    else "single-device program"
                 )
         run_kwargs = {}
         if cfg is not None and executor_kind == "sharded":

@@ -1283,10 +1283,13 @@ def program_delta_compatible(program) -> bool:
     the lanes do not patch), no sddmm (row-dst vectors are base-layout),
     no MODE combiner (`fused_delta_aggregate` merges lane partials into
     the base aggregate, and a mode cannot be folded from partials: the
-    overlay is materialized before such a program runs)."""
+    overlay is materialized before such a program runs), no program that
+    runs no superstep at all (`LCCProgram`: `fuses_delta_overlay`)."""
     from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
 
     if getattr(program, "combiner", None) == Combiner.MODE:
+        return False
+    if not getattr(program, "fuses_delta_overlay", True):
         return False
     if getattr(program, "message_mode", None) == "sddmm":
         return False

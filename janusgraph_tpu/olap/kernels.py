@@ -283,6 +283,15 @@ def frontier_scope(name: str):
     return jax.named_scope("frontier." + name)
 
 
+def intersect_scope(name: str):
+    """`jax.named_scope("lcc.<name>")` around a stage of the intersection
+    engine's compiled pass (expand / intersect / credit, none enclosing
+    another): `frontier_scope`'s sibling."""
+    import jax
+
+    return jax.named_scope("lcc." + name)
+
+
 # graphlint: traced -- the fp-contraction fence of product-fed reductions
 def fp_fence(xp, a):
     """Add an optimizer-opaque zero to `a` — the fp-contraction fence.

@@ -266,6 +266,9 @@ class TPUExecutor:
         # special-case, mirroring FulgoraGraphComputer.java:249-253
         self._frontier_cfg = frontier
         self._frontier_engine = None
+        # neighbourhood intersection (olap/intersect.py): the snapshot's
+        # third view (its simple closure by rows) and the one pass over it
+        self._intersect_engine = None
         # computer.channel-cache-size (the class attr is the default)
         if channel_cache_size is not None:
             self.CHANNEL_CACHE_SIZE = channel_cache_size
@@ -932,6 +935,10 @@ class TPUExecutor:
             Combiner.require_foldable(
                 program.combiner, "the fused delta overlay"
             )
+            # and feeds them to dense supersteps alone
+            program.require_dense_capable(
+                "the fused delta overlay of the single-device executor"
+            )
             if not program_delta_compatible(program):
                 raise ValueError(
                     "delta-fused runs support default-edge-view programs "
@@ -970,7 +977,13 @@ class TPUExecutor:
                     "exactness needs |V| < 2^24, int32 expansion needs "
                     "|E| < 2^30) — use frontier='auto' or 'off'"
                 )
-        if not use_frontier:
+        # the intersection engine has no dense form to fall back on and
+        # no checkpoint: off or checkpointed, the program is refused below
+        use_intersect = (
+            self._intersect_family(program)
+            and mode != "off" and not checkpoint_path
+        )
+        if not (use_frontier or use_intersect):
             program.require_dense_capable(
                 "the dense superstep path of the single-device executor"
             )
@@ -978,6 +991,7 @@ class TPUExecutor:
             fused = program.fused_eligible()
         use_fused = (
             not use_frontier
+            and not use_intersect
             and fused
             and type(program).combiner_for is VertexProgram.combiner_for
         )
@@ -1000,6 +1014,8 @@ class TPUExecutor:
                 try:
                     if use_frontier:
                         out = self._run_frontier(program)
+                    elif use_intersect:
+                        out = self._run_intersect(program)
                     elif use_fused:
                         out = self._run_fused(
                             program, checkpoint_path, checkpoint_every,
@@ -1102,7 +1118,7 @@ class TPUExecutor:
         # record and observability/benchdiff.py carry it too
         info["ell_pad_ratio"] = pad_ratio
         info["pad_ratio"] = pad_ratio
-        if info.get("path") != "frontier":
+        if info.get("path") not in ("frontier", "intersect"):
             # every dense superstep aggregates over the hybrid pack
             info["strategy_resolved"] = "hybrid"
         # the combiner(s) the run folded with; a MODE run also says what
@@ -1120,11 +1136,14 @@ class TPUExecutor:
             )
         # the tuner's decision travels with every run record (/telemetry
         # reads it from here)
-        decision = (
-            channel_packs[0][1] if channel_packs
-            else self._autotune(undirected)
-        )
-        info["autotune"] = decision.as_dict()
+        # (the intersection engine sizes its own tables from the degrees:
+        # `info["intersect"]`; it reads no pack and no tier)
+        if info.get("path") != "intersect":
+            decision = (
+                channel_packs[0][1] if channel_packs
+                else self._autotune(undirected)
+            )
+            info["autotune"] = decision.as_dict()
 
         records = info.get("superstep_records")
         if records is None:
@@ -1277,6 +1296,14 @@ class TPUExecutor:
         registry.set_gauge("olap.transfer.d2h_bytes", float(info["d2h_bytes"]))
         if pad_ratio is not None:
             registry.set_gauge("olap.ell.pad_ratio", pad_ratio)
+        if info.get("path") == "intersect":
+            registry.counter("olap.intersect.runs").inc()
+            registry.counter("olap.intersect.candidates").inc(
+                info["candidates"]
+            )
+            registry.counter("olap.intersect.probe_slots").inc(
+                info["probe_slots"]
+            )
         if info.get("path") == "frontier":
             registry.counter("olap.frontier.rounds").inc(info["rounds"])
             registry.counter("olap.frontier.relaxed_slots").inc(
@@ -1419,6 +1446,48 @@ class TPUExecutor:
             "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
             "tier_slots": sum(t["tier_slots"] for t in trace),
             "wide_rounds": sum(t["wide"] for t in trace),
+        }
+        return out
+
+    @staticmethod
+    def _intersect_family(program: VertexProgram) -> bool:
+        from janusgraph_tpu.olap.programs.lcc import LCCProgram
+
+        return type(program) is LCCProgram
+
+    def _run_intersect(self, program: VertexProgram) -> Dict[str, np.ndarray]:
+        """`LCCProgram` on the intersection engine: the snapshot's tables
+        on first use (a vertex of degree 65,536 or more is refused there,
+        by name), then one compiled pass a submit."""
+        from janusgraph_tpu.olap.intersect import IntersectEngine
+
+        if self._intersect_engine is None:
+            with tracer.phase("executor.setup"):
+                self._intersect_engine = IntersectEngine(self)
+        t0 = time.perf_counter()
+        out = self._intersect_engine.run(program)
+        wall_s = time.perf_counter() - t0
+        view = self._intersect_engine.view
+        self.last_run_info = {
+            "path": "intersect",
+            "supersteps": 1,
+            "dispatches": 1,
+            "wall_s": round(wall_s, 4),
+            # totals a layer metric can read: the closure's edges, the
+            # pairs the pass decides (as a forward count lists them), the
+            # words and elements it gathers to decide them (padding
+            # included), the triangles of the graph
+            "simple_edges": view.simple_edges,
+            "candidates": view.candidates,
+            "probe_slots": view.probe_slots,
+            "triangles_total": int(
+                out["triangles"].sum(dtype=np.int64) // 3
+            ),
+            "intersect": dict(view.sizes),
+            "superstep_records": [{
+                "step": 0, "wall_ms": round(wall_s * 1000.0, 4),
+                "edges": view.simple_edges,
+            }],
         }
         return out
 
