@@ -101,11 +101,14 @@ HAND = {
 }
 
 
-@pytest.fixture(params=[None, (1, 2)], ids=["all-hubs", "with-a-tail"])
+NARROW = {"WIDTHS": (4, 8), "CLASS_FLOOR": 1}
+
+
+@pytest.fixture(params=[{}, {"WIDTHS": (1, 2)}, NARROW],
+                ids=["all-hubs", "with-a-tail", "with-classes"])
 def widths(request, monkeypatch):
-    if request.param:
-        monkeypatch.setattr(
-            intersect.IntersectEngine, "WIDTHS", request.param)
+    for name, value in request.param.items():
+        monkeypatch.setattr(intersect.IntersectEngine, name, value)
     return request.param
 
 
@@ -133,6 +136,8 @@ def test_submit_equals_the_plain_reference(name, widths):
     assert info["simple_edges"] == len(lo)
     if widths and name.startswith("rmat"):
         assert info["intersect"]["tail_candidates"] > 0
+    if widths is NARROW and name.startswith("rmat"):
+        assert len(info["intersect"]["classes"]) > 1
 
 
 def test_the_run_record_counts_what_the_pass_reads(widths):
@@ -150,9 +155,18 @@ def test_the_run_record_counts_what_the_pass_reads(widths):
     info, sizes = ex.last_run_info, ex.last_run_info["intersect"]
     assert registry.last_run("olap")["path"] == "intersect"
     assert info["dispatches"] == 1 and "autotune" not in info
+    classes = sizes["classes"]
     assert info["probe_slots"] == (
-        2 * sizes["edge_slots"] * sizes["words"]
+        sum(2 * c["edge_slots"] * c["words"] for c in classes)
         + sizes["tail_candidates"] * (sizes["search_steps"] + 1))
+    assert sizes["edge_slots"] == sum(c["edge_slots"] for c in classes)
+    assert classes[0]["words"] <= sizes["words"]
+    assert (len(classes) > 1) == (widths is NARROW)
+    shapes = _argument_shapes(ex)
+    for c in classes:  # each class in whole chunks of its own scan
+        assert shapes[f"edge_u.{c['words']}"] == shapes[
+            f"edge_v.{c['words']}"] == (
+            (c["edge_slots"] // c["chunk"], c["chunk"]), "int32")
     assert info["candidates"] >= sizes["tail_candidates"]
     moved = {k: v - before[k] for k, v in counters().items()}
     assert moved == {"runs": 1, "candidates": info["candidates"],
@@ -225,7 +239,73 @@ def test_a_pending_overlay_is_materialized_before_the_program_runs():
         g.close()
 
 
-# ------------------------------------------------- ids: answers and shapes
+# --------------------------------------------- the classes, on the tables
+def _view(data, widths, floor):
+    src, dst = data.src.astype(np.int64), data.dst.astype(np.int64)
+    engine = intersect.IntersectEngine
+    return intersect.IntersectView(
+        data.n, src, dst, widths, engine.WORD_NS, engine.CANDIDATE_NS,
+        engine.TABLE_BYTES_LIMIT, floor)
+
+
+@pytest.mark.parametrize("scale", [9, 10])
+@pytest.mark.parametrize("ladder", [(2, 4), (4, 8)])
+def test_an_edge_skips_only_words_that_are_zero(scale, ladder):
+    """The exactness argument, on the tables themselves (numpy alone): the
+    words of `bits[v]` below an edge's class start are all zero, so the AND
+    loses nothing; and each class holds only the edges whose suffix fits
+    its width and not the next narrower one."""
+    floor = 1
+    view = _view(rmat(scale), ladder, floor)
+    W, base = view.words, view.hub_base
+    # the table is kept once, in column blocks cut where the classes begin
+    assert [a for a, _ in view.blocks] == [
+        sum(w for _, w in view.blocks[:k]) for k in range(len(view.blocks))]
+    bits = np.concatenate(
+        [view.tables[f"bits.{a}"] for a, _ in view.blocks], axis=1)
+    assert bits.shape == (view.rows, W)
+    zero_row = int(view.tables["row_of"].max())
+    assert not bits[zero_row].any()
+    widths = [c["words"] for c in view.classes]
+    assert len(widths) > 1 and widths == sorted(widths, reverse=True)
+    # every class's suffix begins at a block's first word
+    assert {W - w for w in widths} <= {a for a, _ in view.blocks}
+    ends = {}
+    for c in view.classes:
+        # a chunk takes every n-th edge: read down the columns, the class
+        # lies in (u, v) order with its padding at the end
+        u = view.tables[f"edge_u.{c['words']}"].T.reshape(-1)
+        v = view.tables[f"edge_v.{c['words']}"].T.reshape(-1)
+        assert len(u) == c["edge_slots"] and len(u) % c["chunk"] == 0
+        real = u != zero_row
+        assert (v[~real] == zero_row).all()  # padding reads the zero row
+        assert len(u) - np.count_nonzero(real) < c["chunk"]
+        assert real[:np.count_nonzero(real)].all()
+        ends[c["words"]] = u[real].astype(np.int64), v[real].astype(np.int64)
+    assert sum(len(u) for u, _ in ends.values()) == view.simple_edges
+    # the rows rise by degree: the first row of each row's degree
+    all_u = np.concatenate([u for u, _ in ends.values()])
+    all_v = np.concatenate([v for _, v in ends.values()])
+    row_degree = np.bincount(all_u, minlength=zero_row) + np.bincount(
+        all_v, minlength=zero_row)
+    assert (np.diff(row_degree) >= 0).all()
+    first = np.searchsorted(row_degree, row_degree)
+    for width, (u, v) in ends.items():
+        assert (u < v).all()
+        assert (np.diff(u * view.rows + v) > 0).all()  # (u, v) order
+        # what the scan leaves out of the higher end's row is zero, and so
+        # nothing of the AND is lost
+        assert not bits[v, :W - width].any()
+        assert not (bits[u] & bits[v])[:, :W - width].any()
+        # the words the edge can need start at the first row of v's degree
+        # (a tail edge needs them all): they fit this class ...
+        need = np.where(v >= base, W - (first[v] - base) // 32, W)
+        assert (need <= width).all()
+        # ... and not the next narrower one
+        if width // 2 >= floor and width % 2 == 0:
+            assert (need > width // 2).all()
+
+
 def _argument_shapes(ex):
     """Shape and dtype of every argument of the compiled pass."""
     return {k: (tuple(v.shape), str(v.dtype))
