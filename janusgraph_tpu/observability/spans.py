@@ -30,7 +30,11 @@ Design:
 - a *phase* (``Tracer.phase``) says where a thread's time went: phases
   of one thread suspend each other, so each adds only its SELF time to
   the registry timer ``phase.<name>`` and the phases of a request tile
-  it; while it runs, a phase is a ``jax.profiler.TraceAnnotation`` too,
+  it; on a tracer that is given the thread's CPU clock it reads that
+  too, so ``phase_cpu.<name>`` says how much of that time the thread
+  ran and the difference how long it stood off the CPU (blocked, or
+  runnable while another thread held the interpreter lock); while it
+  runs, a phase is a ``jax.profiler.TraceAnnotation`` too,
   which puts the same segments on the device trace's clock (a profiler
   session labels each idle gap of the device by the innermost phase);
   and it joins the span tree as a timed child. Host-only like every
@@ -208,13 +212,14 @@ class Span:
 
 
 class _Phase:
-    """One entry of ``Tracer.phase``: the self-time clock and the profiler
-    annotation of a phase, suspended while an inner phase of the same
-    thread runs."""
+    """One entry of ``Tracer.phase``: the self-time clocks (wall, and the
+    thread's CPU) and the profiler annotation of a phase, suspended while
+    an inner phase of the same thread runs."""
 
     __slots__ = (
         "_tracer", "_name", "_wait", "_attrs", "_parent", "_outer",
-        "_wall_t", "_start_ns", "_since", "_self_ns", "_annotation",
+        "_wall_t", "_start_ns", "_end_ns", "_since", "_self_ns",
+        "_cpu_since", "_cpu_ns", "_annotation",
     )
 
     def __init__(self, tracer: "Tracer", name: str, wait: bool, attrs: dict):
@@ -223,18 +228,37 @@ class _Phase:
         self._wait = wait
         self._attrs = attrs
         self._self_ns = 0
+        #: None once a segment began or ended without a reading of the CPU
+        #: clock (the tracer keeps none, or it was switched meanwhile): the
+        #: phase then records no CPU time, never an estimate
+        self._cpu_ns = 0
         self._annotation = None
 
-    def _resume(self, now: int) -> None:
+    @property
+    def start_ns(self) -> int:
+        """The tracer's clock when the phase was entered."""
+        return self._start_ns
+
+    @property
+    def end_ns(self) -> int:
+        """The tracer's clock when the phase was left."""
+        return self._end_ns
+
+    def _resume(self, now: int, cpu: Optional[int]) -> None:
         self._since = now
+        self._cpu_since = cpu
         if not self._wait:
             open_event = self._tracer._annotation()
             if open_event is not None:
                 self._annotation = open_event(self._name)
                 self._annotation.__enter__()
 
-    def _suspend(self, now: int) -> None:
+    def _suspend(self, now: int, cpu: Optional[int]) -> None:
         self._self_ns += now - self._since
+        if cpu is None or self._cpu_since is None:
+            self._cpu_ns = None
+        elif self._cpu_ns is not None:
+            self._cpu_ns += cpu - self._cpu_since
         if self._annotation is not None:
             self._annotation.__exit__(None, None, None)
             self._annotation = None
@@ -243,26 +267,37 @@ class _Phase:
         tr = self._tracer
         stack = tr._phase_stack()
         # ONE clock read ends the outer segment and starts this one, so
-        # the phases of a thread tile its time with nothing counted twice
+        # the phases of a thread tile its time with nothing counted twice;
+        # likewise one read of the thread's CPU clock, where one is kept
         now = tr._clock()
+        cpu_clock = tr.cpu_clock  # read once: it may be switched meanwhile
+        cpu = cpu_clock() if cpu_clock is not None else None
         self._outer = stack[-1] if stack else None
         if self._outer is not None:
-            self._outer._suspend(now)
+            self._outer._suspend(now, cpu)
         stack.append(self)
         self._parent = _CURRENT.get()
         self._wall_t = time.time()
         self._start_ns = now
-        self._resume(now)
+        self._resume(now, cpu)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         tr = self._tracer
         now = tr._clock()
-        self._suspend(now)
+        cpu_clock = tr.cpu_clock  # read once: it may be switched meanwhile
+        cpu = cpu_clock() if cpu_clock is not None else None
+        self._end_ns = now
+        self._suspend(now, cpu)
         tr._phase_stack().pop()
+        timed_cpu = self._cpu_ns is not None
         if tr.registry is not None:
             # graphlint: disable=JG110 -- phase names are literals at the call sites (the table in docs/observability.md)
             tr.registry.timer("phase." + self._name).update(self._self_ns)
+            if timed_cpu:
+                # graphlint: disable=JG110 -- the same literals under their own prefix
+                tr.registry.timer("phase_cpu." + self._name).update(
+                    self._cpu_ns)
         parent = self._parent
         if parent is not None:
             # the phase joins the tree as a timed child of the span that
@@ -274,6 +309,8 @@ class _Phase:
             # timed and annotated, not kept
             s = Span(self._name, self._attrs)
             s.attrs["self_ms"] = round(self._self_ns / 1e6, 4)
+            if timed_cpu:
+                s.attrs["cpu_ms"] = round(self._cpu_ns / 1e6, 4)
             s.wall_t = self._wall_t
             s.start_ns = self._start_ns
             s.end_ns = now
@@ -282,7 +319,7 @@ class _Phase:
             parent.children.append(s)
             tr._finished(s, root=False)
         if self._outer is not None:
-            self._outer._resume(now)
+            self._outer._resume(now, cpu)
         return False
 
 
@@ -295,10 +332,18 @@ class Tracer:
         slow_threshold_ms: float = 100.0,
         slow_buffer: int = 128,
         clock=time.perf_counter_ns,
+        cpu_clock=None,
     ):
         self.slow_threshold_ms = slow_threshold_ms
         #: monotonic nanoseconds behind phase self times (tests inject one)
         self._clock = clock
+        #: nanoseconds the CALLING thread has run (``time.thread_time_ns``,
+        #: CLOCK_THREAD_CPUTIME_ID), read at every phase boundary beside
+        #: the wall clock; None (the default): phases keep no CPU time.
+        #: Switch it on where the host's thread clock is exact and cheap
+        #: (Linux: 0.3 us a read). Under gVisor a read is a 6-33 us system
+        #: call and the clock moves in steps of 10 ms (PERF.md, PR 36)
+        self.cpu_clock = cpu_clock
         self._phases = threading.local()
         #: where phases put their self time (timer ``phase.<name>``);
         #: observability/__init__.py wires the process registry
@@ -385,7 +430,12 @@ class Tracer:
         Entering an inner phase suspends the outer one of the same thread,
         so each phase adds its SELF time (its wall less the inner phases')
         to the registry timer ``phase.<name>``: the phases of a request
-        sum to its wall, nothing counted twice.
+        sum to its wall, nothing counted twice. On a tracer with a
+        ``cpu_clock`` the thread's CPU time over the same segments goes to
+        ``phase_cpu.<name>`` (and ``cpu_ms`` beside ``self_ms``): wall less
+        CPU is time the thread did not run, which for a compute phase
+        means it stood runnable while another thread held the interpreter
+        lock (or blocked inside the runtime), and for a wait is the wait.
 
         While it runs (not while suspended) the phase is also a profiler
         trace event named ``name``, on the device trace's clock; with no
@@ -399,6 +449,11 @@ class Tracer:
 
         Enter and exit on the same thread; host code only (JG106)."""
         return _Phase(self, name, wait, attrs)
+
+    def now_ns(self) -> int:
+        """The clock phases are timed on: a stamp taken here can be held
+        against a phase's ``start_ns`` / ``end_ns``."""
+        return self._clock()
 
     def _phase_stack(self) -> list:
         try:

@@ -60,6 +60,7 @@ terminal) via :func:`try_spill`; built per graph at open when
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -412,6 +413,12 @@ class SpilloverPlanner:
         self.max_overlay = int(cfg.get("computer.spillover-max-overlay"))
         self.max_staleness = int(cfg.get("computer.spillover-max-staleness"))
         self._lock = threading.RLock()
+        #: the lock's ledger (`_lock_taken`): arrival tickets, the arrival
+        #: stamp of every request that stands at the lock (at either take),
+        #: and the stamp the last holder left at its release
+        self._tickets = itertools.count()
+        self._waiting: Dict[int, int] = {}
+        self._released_ns: Optional[int] = None
         self._csr = None
         self._epoch = -1
         self._tpu_ex = None
@@ -573,9 +580,17 @@ class SpilloverPlanner:
         # stands only what stood there before phases: a few microseconds
         # more let the woken waiter in and move the latency's median by a
         # third (PERF.md, PR 25). As a wait it is timed, never a trace
-        # event.
+        # event. The lock's ledger keeps to the same rule: before the first
+        # take a ticket beside the stamp the phase has just read, which
+        # leaves the queue where the wait ends; the rest under the second
+        # take (`_lock_taken`) and just before its release; nothing between
+        # the takes and nothing while a request waits.
         with contextlib.ExitStack() as waiting:
-            waiting.enter_context(tracer.phase("spill.lock_wait", wait=True))
+            wait = waiting.enter_context(
+                tracer.phase("spill.lock_wait", wait=True))
+            ticket = next(self._tickets)
+            self._waiting[ticket] = wait.start_ns
+            waiting.callback(self._waiting.pop, ticket)
             with self._lock:
                 if not self._check_promotion(plan.digest, plan.shape):
                     return None
@@ -594,7 +609,12 @@ class SpilloverPlanner:
             try:
                 with self._lock:
                     waiting.close()  # the lock is held: the wait is over
-                    return self._execute_plan(traversal, plan, terminal)
+                    taken = self._lock_taken(ticket, wait)
+                    try:
+                        return self._execute_plan(
+                            traversal, plan, terminal, taken)
+                    finally:
+                        self._released_ns = tracer.now_ns()
             except _SpillRefused as e:
                 return self._fallback(plan.digest, e.reason)
             except (QueryError, DeadlineExceededError):
@@ -610,7 +630,37 @@ class SpilloverPlanner:
                     plan.digest, f"error:{type(e).__name__}: {e}"[:200]
                 )
 
-    def _execute_plan(self, traversal, plan: SpilloverPlan, terminal):
+    def _lock_taken(self, ticket: int, wait) -> dict:
+        """The ledger's entry for one hold of the lock, made under it once
+        the wait phase has closed: the run record's lock fields.
+
+        ``queue_depth`` is how many other requests stand at the lock (at
+        either take) and ``overtook`` how many of them arrived before this
+        one; `_publish` counts both once the request has spilled. Against
+        the stamp the previous holder left at its release, the stretch
+        from that release to this hold is a HAND-OFF when this request or
+        one it overtook had arrived by then (the lock was wanted and free,
+        the device idle), else FREE time (nobody was asking). Arrival and
+        hold are the wait phase's own reads of the tracer's clock: nothing
+        here reads a clock, and the ledger's times add up with the
+        phases'."""
+        from janusgraph_tpu.observability import registry
+
+        # a copy: arrivals write the dict whoever holds the lock
+        others = list(self._waiting.items())
+        earlier = [stamp for t, stamp in others if t < ticket]
+        if self._released_ns is not None:
+            # both looked up, so that a reader finds both names
+            handoff = registry.timer("spill.lock_handoff")
+            free = registry.timer("spill.lock_free")
+            wanted = min(earlier, default=wait.start_ns) < self._released_ns
+            (handoff if wanted else free).update(
+                wait.end_ns - self._released_ns)
+        return {"queue_depth": len(others), "overtook": len(earlier)}
+
+    def _execute_plan(
+        self, traversal, plan: SpilloverPlan, terminal, taken: dict,
+    ):
         import numpy as np
 
         from janusgraph_tpu.core import deadline as _deadline
@@ -649,13 +699,13 @@ class SpilloverPlanner:
         with tracer.phase("spill.publish"):
             self._publish(
                 plan, terminal, overlay, packed_epoch, wall_ms, total,
-                seed_hop_edges,
+                seed_hop_edges, taken,
             )
         return result
 
     def _publish(
         self, plan, terminal, overlay, packed_epoch, wall_ms, total,
-        seed_hop_edges,
+        seed_hop_edges, taken,
     ) -> None:
         """The spilled execution still feeds the digest table (the
         shape's new, cheap reality) and the ambient span, like the row
@@ -675,6 +725,10 @@ class SpilloverPlanner:
         if stats is not None:
             stats["spilled"] += 1
         registry.counter("olap.spillover.spilled").inc()
+        registry.counter("olap.spillover.lock.waiters_seen").inc(
+            taken["queue_depth"])
+        registry.counter("olap.spillover.lock.overtakes").inc(
+            int(taken["overtook"] > 0))
         # graphlint: disable=JG110 -- digest is bounded by the top-K-evicted price book (metrics.digest-top-k) that feeds promotion
         registry.counter(f"olap.spillover.spilled.{plan.digest}").inc()
         if seed_hop_edges is not None:
@@ -696,6 +750,7 @@ class SpilloverPlanner:
             "wall_ms": round(wall_ms, 3),
             "result_total": total,
             "fallback": None,
+            **taken,
         }
         olap_run = registry.last_run("olap") or {}
         run_info = {

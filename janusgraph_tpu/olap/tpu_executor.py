@@ -1206,14 +1206,6 @@ class TPUExecutor:
             mean_util = info["mxu"].get("mean_utilization")
             if mean_util is not None:
                 registry.set_gauge("olap.mxu.utilization", float(mean_util))
-        if records:
-            registry.set_gauge(
-                "olap.roofline.operational_intensity",
-                float(records[-1].get("operational_intensity") or 0.0),
-            )
-            util = records[-1].get("roofline_utilization")
-            if util is not None:
-                registry.set_gauge("olap.roofline.utilization", float(util))
 
         # run records and OLTP profile trees share one cost vocabulary:
         # the `resources` block, accrued into the ambient ledger too (an

@@ -197,6 +197,209 @@ def test_default_annotation_is_the_profilers():
     assert tracer.annotation is jax.profiler.TraceAnnotation
 
 
+# ------------------------------------------------------- the thread's CPU clock
+
+@pytest.fixture
+def cpu_rig():
+    """A tracer on two injected clocks: wall, and the thread's CPU."""
+    clock, cpu = FakeClock(), FakeClock()
+    tr = Tracer(clock=clock, cpu_clock=cpu)
+    tr.registry = TelemetryRegistry()
+    tr.annotation = Annotations()
+
+    def run(wall_ns, cpu_ns):
+        clock.tick(wall_ns)
+        cpu.tick(cpu_ns)
+
+    return tr, run
+
+
+def _cpu_ns(tr, name):
+    return tr.registry.timer("phase_cpu." + name).total_ns
+
+
+def test_self_cpu_time_tiles_like_self_wall_time(cpu_rig):
+    tr, run = cpu_rig
+    ms = 1_000_000
+    with tr.phase("outer"):
+        run(5 * ms, 4 * ms)
+        with tr.phase("inner"):
+            run(70 * ms, 10 * ms)  # 60 off the CPU: runnable, not running
+            with tr.phase("innermost", wait=True):
+                run(11 * ms, 0)  # a wait keeps CPU time too, and reads none
+            run(2 * ms, 2 * ms)
+        run(3 * ms, 1 * ms)
+        with tr.phase("inner"):
+            run(7 * ms, 7 * ms)
+    assert [_cpu_ns(tr, n) for n in ("outer", "inner", "innermost")] \
+        == [5 * ms, 19 * ms, 0]
+    assert [_total_ns(tr, n) for n in ("outer", "inner", "innermost")] \
+        == [8 * ms, 79 * ms, 11 * ms]  # the wall clock's side, as before
+    # one observation of CPU time for each of wall time
+    for name in ("outer", "inner", "innermost"):
+        assert (tr.registry.timer("phase_cpu." + name).count
+                == tr.registry.timer("phase." + name).count)
+    # the self CPU times sum to the CPU time of the outer one's stretch
+    assert sum(_cpu_ns(tr, n)
+               for n in ("outer", "inner", "innermost")) == 24 * ms
+
+
+def test_a_boundary_reads_the_cpu_clock_once_or_records_nothing():
+    """Every boundary of a tracer with a CPU clock reads it, once; a phase
+    with a boundary that had none to read (the clock was switched on or
+    off while it was open) records no CPU time at all, never an estimate."""
+    wall, cpu, reads = FakeClock(), FakeClock(), []
+
+    def cpu_clock():
+        reads.append(1)
+        return cpu.now
+
+    def run(wall_ns, cpu_ns):
+        wall.tick(wall_ns)
+        cpu.tick(cpu_ns)
+
+    tr = Tracer(clock=wall)
+    tr.registry = TelemetryRegistry()
+    tr.annotation = Annotations()
+    with tr.phase("outer"):                 # no clock yet: nothing read
+        run(10, 10)
+        tr.cpu_clock = cpu_clock
+        with tr.phase("inner"):             # enter and exit read
+            run(20, 5)
+            with tr.phase("innermost"):
+                run(30, 30)
+            run(5, 5)
+        assert len(reads) == 4
+        run(7, 7)
+    assert len(reads) == 5                  # outer's exit
+    with tr.phase("later"):
+        run(3, 2)
+        tr.cpu_clock = None                 # switched off: no reading to end on
+    assert len(reads) == 6
+    assert [_cpu_ns(tr, n) for n in ("inner", "innermost")] == [10, 30]
+    snapshot = tr.registry.snapshot()
+    assert "phase_cpu.outer" not in snapshot
+    assert "phase_cpu.later" not in snapshot
+    # the wall clock's side is whole
+    assert [_total_ns(tr, n) for n in ("outer", "inner", "innermost",
+                                        "later")] == [17, 25, 30, 3]
+
+
+def test_cpu_time_survives_an_exception_and_resumes_the_outer(cpu_rig):
+    tr, run = cpu_rig
+    with tr.phase("outer"):
+        with pytest.raises(ValueError):
+            with tr.phase("inner"):
+                run(6_000_000, 3_000_000)
+                raise ValueError("boom")
+        run(2_000_000, 2_000_000)
+    assert (_cpu_ns(tr, "inner"), _cpu_ns(tr, "outer")) \
+        == (3_000_000, 2_000_000)
+
+
+def test_cpu_time_is_the_calling_threads_own():
+    """The CPU clock is per thread (CLOCK_THREAD_CPUTIME_ID): each phase
+    reads the clock of the thread it runs on, and another thread's phase
+    suspends nothing here."""
+    wall = FakeClock()
+    cpus = {}  # thread name -> its CPU clock
+
+    def cpu_clock():
+        return cpus[threading.current_thread().name].now
+
+    tr = Tracer(clock=wall, cpu_clock=cpu_clock)
+    tr.registry = TelemetryRegistry()
+    tr.annotation = Annotations()
+    cpus[threading.current_thread().name] = main_cpu = FakeClock()
+    cpus["worker"] = worker_cpu = FakeClock()
+    worker_cpu.now = 5_000_000  # the clocks of two threads share no origin
+    inside, release = threading.Event(), threading.Event()
+
+    def worker():
+        with tr.phase("worker"):
+            inside.set()
+            assert release.wait(10)
+
+    th = threading.Thread(target=worker, name="worker")
+    with tr.phase("main"):
+        th.start()
+        assert inside.wait(10)
+        main_cpu.tick(5_000_000)
+        worker_cpu.tick(90_000_000)
+        wall.tick(100_000_000)
+        release.set()
+        th.join(10)
+    assert not th.is_alive()
+    assert (_cpu_ns(tr, "main"), _cpu_ns(tr, "worker")) \
+        == (5_000_000, 90_000_000)
+    assert (_total_ns(tr, "main"), _total_ns(tr, "worker")) \
+        == (100_000_000, 100_000_000)
+
+
+def test_phase_child_carries_cpu_ms_beside_self_ms(cpu_rig):
+    tr, run = cpu_rig
+    with tr.span("request") as root:
+        with tr.phase("evaluate"):
+            run(10_000_000, 2_500_000)
+    assert root.children[0].attrs == {"self_ms": 10.0, "cpu_ms": 2.5}
+
+
+def test_a_tracer_keeps_no_cpu_time_unless_it_is_given_the_clock(rig):
+    tr, clock, _ = rig
+    with tr.span("request") as root:
+        with tr.phase("evaluate"):
+            clock.tick(1_000_000)
+    assert "cpu_ms" not in root.children[0].attrs
+    assert "phase_cpu.evaluate" not in tr.registry.snapshot()
+    # nor does the process tracer: where the clock is a dear system call
+    # that moves in steps of 10 ms (gVisor), it would cost and say nothing
+    assert tracer.cpu_clock is None
+
+
+def test_a_busy_loop_reads_cpu_near_wall_and_a_sleep_near_none():
+    tr = Tracer(cpu_clock=time.thread_time_ns)
+    tr.registry = TelemetryRegistry()
+    busy_until = time.thread_time_ns() + 20_000_000
+    with tr.phase("busy"):
+        while time.thread_time_ns() < busy_until:
+            pass
+    with tr.phase("asleep", wait=True):
+        time.sleep(0.05)
+    busy_cpu, busy_wall = _cpu_ns(tr, "busy"), _total_ns(tr, "busy")
+    assert 19_000_000 <= busy_cpu <= busy_wall + 1_000_000
+    # asleep, the thread ran for the call and the wake-up alone
+    assert _total_ns(tr, "asleep") >= 45_000_000
+    assert _cpu_ns(tr, "asleep") < 5_000_000
+
+
+def test_phase_cpu_moves_once_per_phase_on_every_executor_path(monkeypatch):
+    from janusgraph_tpu.olap.tpu_executor import TPUExecutor
+
+    monkeypatch.setattr(tracer, "cpu_clock", time.thread_time_ns)
+    ex = TPUExecutor(_random_csr())
+    before = registry.snapshot()
+    _run_host_loop(ex)
+    after = registry.snapshot()
+    moved = {
+        name[len("phase."):]: entry["count"] - before.get(
+            name, {}).get("count", 0)
+        for name, entry in after.items() if name.startswith("phase.")
+    }
+    moved = {name: count for name, count in moved.items() if count}
+    assert {"executor.setup", "executor.dispatch", "executor.sync",
+            "executor.fetch", "executor.publish"} <= set(moved)
+    for name, count in moved.items():
+        cpu = "phase_cpu." + name
+        assert (after[cpu]["count"]
+                - before.get(cpu, {}).get("count", 0)) == count, name
+        # a thread cannot have run for longer than it was there
+        assert (after[cpu]["total_ms"] - before.get(cpu, {}).get(
+            "total_ms", 0.0)) <= _moved(before, name, "total_ms") + 1.0, name
+    run = tracer.recent("olap.run")[-1]
+    child = [c for c in run.children if c.name == "executor.setup"][0]
+    assert 0 <= child.attrs["cpu_ms"] <= child.attrs["self_ms"] + 1.0
+
+
 # ------------------------------------------------------------- the sites
 
 def _wait_for(predicate, what, seconds=10.0):
@@ -325,6 +528,82 @@ def test_no_phase_boundary_between_the_planners_two_lock_takes(monkeypatch):
         # lock_wait out, spill.plan in right after the second
         assert events[:events.index("take")].count("phase") >= 2
         assert events[plan_taken + 1:plan_taken + 3] == ["phase", "phase"]
+    finally:
+        g.close()
+
+
+def test_nothing_is_written_or_read_between_the_planners_two_lock_takes(
+        monkeypatch):
+    """The lock's ledger (tickets, queue depth, hand-off) and the phases'
+    CPU clock keep to the rule of the test above: between the release of
+    the first take and the second take no clock is read, wall or CPU, the
+    registry is not touched and the planner stores no attribute; all of
+    that happens before the first take or under the second."""
+    from test_spillover import _social_graph
+
+    g, people, _ = _social_graph()
+    try:
+        planner = g.spillover_planner
+
+        def build():
+            return g.traversal().V(people[0]).out("knows").out("knows")
+
+        build().count()  # teach the shape
+        build().count()  # ... and leave a release stamp to hold against
+        spilled = registry.snapshot()["olap.spillover.spilled"]["count"]
+        events = []
+
+        class Recording:
+            def __init__(self, lock):
+                self._lock = lock
+
+            def __enter__(self):
+                self._lock.acquire()
+                events.append("take")
+
+            def __exit__(self, *exc):
+                events.append("release")
+                self._lock.release()
+
+        class Watched(type(planner)):
+            def __setattr__(self, name, value):
+                events.append("store:" + name)
+                super().__setattr__(name, value)
+
+        def noting(what, fn):
+            def noted(*args, **kwargs):
+                events.append(what)
+                return fn(*args, **kwargs)
+            return noted
+
+        monkeypatch.setattr(planner, "_lock", Recording(planner._lock))
+        monkeypatch.setattr(tracer, "_clock", noting("clock", tracer._clock))
+        monkeypatch.setattr(
+            tracer, "cpu_clock", noting("cpu", time.thread_time_ns))
+        for accessor in ("counter", "timer", "histogram", "gauge",
+                         "set_gauge", "record_run"):
+            monkeypatch.setattr(registry, accessor, noting(
+                "registry:" + accessor, getattr(registry, accessor)))
+        planner.__class__ = Watched
+        try:
+            build().count()
+        finally:
+            planner.__class__ = Watched.__bases__[0]
+        assert (registry.snapshot()["olap.spillover.spilled"]["count"]
+                == spilled + 1)
+        check_done = events.index("release")
+        plan_taken = events.index("take", check_done)
+        assert events[:check_done].count("take") == 1
+        assert events[check_done + 1:plan_taken] == []
+        # under the second take: the wait phase closes on one read of each
+        # clock, the ledger writes its timers without reading a clock, then
+        # spill.plan opens
+        held = events[plan_taken + 1:events.index("release", plan_taken)]
+        assert held[:2] == ["clock", "cpu"]
+        # the wait's wall and CPU; hand-off and free time
+        assert held[2:held.index("clock", 2)] == ["registry:timer"] * 4
+        # the release stamp is the last thing under the lock
+        assert held[-2:] == ["clock", "store:_released_ns"]
     finally:
         g.close()
 

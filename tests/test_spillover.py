@@ -9,7 +9,11 @@ row-by-row walk). The digest table is process-global, so every test
 resets it and uses its own graph/planner.
 """
 
+import collections
 import os
+import queue
+import threading
+import time
 
 import pytest
 
@@ -749,5 +753,220 @@ def test_seed_hop_stays_on_the_device(start):
             assert got == sorted(build().to_list())
         finally:
             planner.enabled = True
+    finally:
+        g.close()
+
+
+# ------------------------------------------------------------ the lock's ledger
+class _Turnstile:
+    """Stands in for the planner's lock: a thread passes a take only when
+    the test lets it through, so the test decides who holds the lock in
+    what order, and exactly one staged thread moves at a time."""
+
+    def __init__(self, lock):
+        self._lock = lock
+        self._at_gate = queue.Queue()
+        self._standing = set()
+        self._go = collections.defaultdict(lambda: threading.Semaphore(0))
+        self.exits = 0  # takes that have been released
+
+    def __enter__(self):
+        name = threading.current_thread().name
+        self._at_gate.put(name)
+        assert self._go[name].acquire(timeout=60), f"{name} never let in"
+        self._lock.acquire()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        self.exits += 1
+
+    def arrives(self, name):
+        """Wait until thread `name` stands at a take, and leave it there."""
+        assert self._at_gate.get(timeout=60) == name
+        self._standing.add(name)
+
+    def admit(self, name, takes=1):
+        """Let `name` through its next `takes` takes, one after another."""
+        for _ in range(takes):
+            if name not in self._standing:
+                self.arrives(name)
+            self._standing.remove(name)
+            self._go[name].release()
+
+
+def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
+        monkeypatch):
+    """Four threads staged through the planner's lock on an injected
+    clock. `a` runs alone (FREE time: nobody was asking) while `b` and `c`
+    arrive; `d` arrives after a's release and is let in first, overtaking
+    both (a HAND-OFF all the same: the lock was wanted while it stood
+    free); then `c` before `b`; then `a` again with nobody waiting."""
+    from janusgraph_tpu.observability import tracer
+
+    g, people, _ = _social_graph()
+    try:
+        planner = g.spillover_planner
+
+        def count():
+            return g.traversal().V(people[0]).out("knows").out(
+                "knows").count()
+
+        want = count()  # teach the shape ...
+        assert count() == want  # ... spill it once: compiles, and releases
+        gate = _Turnstile(planner._lock)
+        monkeypatch.setattr(planner, "_lock", gate)
+
+        class Clock:  # moves only when the test says so
+            now = planner._released_ns  # the teaching run's release
+
+        monkeypatch.setattr(tracer, "_clock", lambda: Clock.now)
+        def gaps():  # (observations, ns) of the ledger's two timers
+            return {
+                name: (registry.timer(name).count,
+                       registry.timer(name).total_ns)
+                for name in ("spill.lock_handoff", "spill.lock_free")
+            }
+
+        before = gaps()
+        seen = _spill_count("olap.spillover.lock.waiters_seen")
+        overtakes = _spill_count("olap.spillover.lock.overtakes")
+        spilled = _spill_count()
+        answers, again = {}, threading.Event()
+
+        def client(name, twice=False):
+            answers[name] = [count()]
+            if twice:
+                assert again.wait(60)
+                answers[name].append(count())
+
+        threads = {
+            name: threading.Thread(
+                target=client, args=(name, name == "a"), name=name)
+            for name in "abcd"
+        }
+        at = dict(before)
+
+        def finished(n):
+            """Once run `n` has spilled and released the lock (two takes a
+            request): its record's lock fields, and what it added to the
+            ledger's timers ({timer: ns}, one of the two)."""
+            deadline = time.monotonic() + 60
+            while _spill_count() < spilled + n or gate.exits < 2 * n:
+                assert time.monotonic() < deadline, f"run {n} never ended"
+                time.sleep(0.002)
+            record = registry.last_run("olap.spillover")["spillover"]
+            now = gaps()
+            moved = {name[len("spill.lock_"):]: ns - at[name][1]
+                     for name, (seen_, ns) in now.items()
+                     if seen_ != at[name][0]}
+            at.update(now)
+            return record["queue_depth"], record["overtook"], moved
+
+        # a arrives 1,000 ns after the teaching run's release
+        Clock.now += 1_000
+        threads["a"].start()
+        gate.admit("a")          # the promotion check
+        gate.arrives("a")        # a stands at the second take
+        for name in "bc":        # b, then c, arrive and stand at the first
+            Clock.now += 10
+            threads[name].start()
+            gate.arrives(name)
+        Clock.now += 5
+        gate.admit("a")          # held 1,025 ns after that release
+        # it had not arrived by that release: free time
+        assert finished(1) == (2, 0, {"free": 1_025})
+        # a released at the instant it was held (the clock stood still);
+        # d arrives 40 us later and is let through both takes at once
+        Clock.now += 40_000
+        threads["d"].start()
+        gate.arrives("d")
+        Clock.now += 2_000_000
+        gate.admit("d", takes=2)
+        # d came after a's release, b and c before it: the lock was wanted
+        assert finished(2) == (2, 2, {"handoff": 2_040_000})
+        Clock.now += 3_000_000
+        gate.admit("c", takes=2)
+        assert finished(3) == (1, 1, {"handoff": 3_000_000})
+        Clock.now += 500_000
+        gate.admit("b", takes=2)
+        assert finished(4) == (0, 0, {"handoff": 500_000})
+        # nobody waits: a's second request arrives 700 ns after b's release
+        Clock.now += 700
+        again.set()
+        gate.admit("a", takes=2)
+        assert finished(5) == (0, 0, {"free": 700})
+        for th in threads.values():
+            th.join(60)
+            assert not th.is_alive()
+        assert answers == {"a": [want, want], "b": [want], "c": [want],
+                           "d": [want]}
+        assert planner._waiting == {}
+        assert not {"lock_wait_ms", "handoff_ms"} & set(
+            registry.last_run("olap.spillover")["spillover"])
+        assert {
+            name: (count - before[name][0], ns - before[name][1])
+            for name, (count, ns) in gaps().items()
+        } == {
+            "spill.lock_handoff": (3, 2_040_000 + 3_000_000 + 500_000),
+            "spill.lock_free": (2, 1_025 + 700),
+        }
+        assert _spill_count(
+            "olap.spillover.lock.waiters_seen") == seen + 2 + 2 + 1
+        assert _spill_count(
+            "olap.spillover.lock.overtakes") == overtakes + 2
+    finally:
+        g.close()
+
+
+def test_a_request_that_leaves_before_the_plan_leaves_the_queue():
+    """An unpromoted shape takes a ticket, passes the promotion check and
+    goes back to the row path: it stands in the queue no longer, and holds
+    no one's `queue_depth` up."""
+    g, people, _ = _social_graph({"computer.spillover-min-seen": 1000})
+    try:
+        planner = g.spillover_planner
+        before = _spill_count("olap.spillover.lock.waiters_seen")
+        for _ in range(3):
+            g.traversal().V(people[0]).out("knows").out("knows").count()
+        assert planner._waiting == {}
+        assert _spill_count() == 0 or planner._promoted == {}
+        assert _spill_count("olap.spillover.lock.waiters_seen") == before
+    finally:
+        g.close()
+
+
+def test_a_hold_that_is_refused_counts_in_no_share(monkeypatch):
+    """The lock's two counters are written where `olap.spillover.spilled`
+    is, so a hold whose plan is refused under the lock is in neither side
+    of `lock_queue_depth` and `lock_overtake_share`."""
+    from janusgraph_tpu.olap import spillover
+
+    g, people, _ = _social_graph()
+    try:
+        planner = g.spillover_planner
+
+        def count():
+            return g.traversal().V(people[0]).out("knows").out(
+                "knows").count()
+
+        want = count()  # teach the shape ...
+        spilled = _spill_count()
+        assert count() == want and _spill_count() == spilled + 1
+        names = ("olap.spillover.spilled", "olap.spillover.lock.waiters_seen",
+                 "olap.spillover.lock.overtakes")
+        before = [_spill_count(n) for n in names]
+        # someone stands at the lock, and has for long
+        planner._waiting[-1] = 0
+
+        def refuse(*args, **kwargs):
+            raise spillover._SpillRefused("staged")
+
+        monkeypatch.setattr(planner, "_snapshot", refuse)
+        assert count() == want  # the row path answers
+        assert [_spill_count(n) for n in names] == before
+        monkeypatch.undo()
+        assert count() == want
+        assert [_spill_count(n) for n in names] == [
+            before[0] + 1, before[1] + 1, before[2] + 1]
     finally:
         g.close()
