@@ -186,7 +186,14 @@ class OLAPTraversalProgram(VertexProgram):
         both prebuilt by `build_olap_traversal` from the steps' filters.
         Masks travel through STATE (not closures) so they ride the jit
         argument path like every other device array (_graph_args lesson:
-        big closure constants break remote compile)."""
+        big closure constants break remote compile).
+
+        An (n, K) `seed_mask` is K STARTS run as the columns of one
+        chain (the spillover planner's batch: K requests' arrival
+        vectors, one dispatch): `count` is (n, K) throughout, column j
+        bit for bit the (n,) run of column j (the pack's aggregation
+        folds each column through the same tree). Such a start carries no
+        sack, no step masks and no reach record."""
         self.steps = tuple(steps)
         if not self.steps:
             raise ValueError("at least one traversal step required")
@@ -203,6 +210,21 @@ class OLAPTraversalProgram(VertexProgram):
             if seed_indices is not None
             else None
         )
+        #: columns of an (n, K) start, 0 for the (n,) start: an int, so it
+        #: tells the two compiled steps apart (`cache_key`)
+        self.width = 0
+        if seed_mask is not None and getattr(seed_mask, "ndim", 1) == 2:
+            for given, name in (
+                (sack, "sack"), (step_masks, "step_masks"),
+                (record_reach, "record_reach"),
+                (seed_indices, "seed_indices"),
+            ):
+                if given is not None and given is not False:
+                    raise ValueError(
+                        f"an (n, K) seed_mask runs K plain chains: {name} "
+                        "is not supported with it"
+                    )
+            self.width = int(seed_mask.shape[1])
         self._seed_mask = seed_mask
         self._step_masks = step_masks
         self.has_step_masks = step_masks is not None
@@ -248,6 +270,42 @@ class OLAPTraversalProgram(VertexProgram):
     def channel_for(self, superstep: int) -> str:
         return f"s{min(superstep, len(self.steps) - 1)}"
 
+    def stackable(self) -> bool:
+        """Whether this chain can ride as ONE COLUMN of an (n, K) start:
+        a plain chain (no sack, step masks or reach record) from a host
+        vector of its own."""
+        return (
+            self._seed_mask is not None and not self.width
+            and self.seed_indices is None and not self.has_step_masks
+            and self.sack is None and not self.record_reach
+        )
+
+    @classmethod
+    def stacked(cls, programs, width: int, out=None) -> "OLAPTraversalProgram":
+        """Up to `width` stackable chains of the SAME steps as one chain
+        whose start is (n, width): column j is programs[j]'s start, the
+        columns past the last are zero (and stay zero). Given `out`, an
+        (n, width) float32 array, the columns are written there and it is
+        the start: a caller that stacks again and again keeps its pages,
+        and the columns past the last keep what they held (each column is
+        a chain of its own, so they disturb nobody: the caller reads the
+        columns it wrote)."""
+        import numpy as np
+
+        first = programs[0]
+        if len(programs) > width or not all(
+            p.stackable() and p.steps == first.steps for p in programs
+        ):
+            raise ValueError(
+                "stacked() takes at most `width` stackable chains of the "
+                "same steps"
+            )
+        start = out if out is not None else np.zeros(
+            (len(first._seed_mask), width), dtype=np.float32)
+        for j, p in enumerate(programs):
+            start[:, j] = p._seed_mask
+        return cls(first.steps, seed_mask=start)
+
     def setup(self, graph, xp):
         n = graph.local_num_vertices
         mask = self._seed_mask
@@ -277,7 +335,8 @@ class OLAPTraversalProgram(VertexProgram):
                     idx, xp.asarray(self.seed_indices)
                 ).astype(float)
             if mask is not None:
-                count = count * self._slice_local(mask, graph, xp)
+                local = self._slice_local(mask, graph, xp)
+                count = count[:, None] * local if self.width else count * local
         state = {"count": count}
         if self.sack is not None:
             state["sack"] = count * self.sack_init

@@ -38,6 +38,15 @@ recurring expensive shapes onto the OLAP executor:
   by neighbour) and starts the device program at hop 1 with the arrival
   counts as its seed mask; any other start keeps the dense hop 0.
 
+- **One dispatch for the requests that stand at the lock**: a request
+  plans in its own thread and then stands at the planner's lock; whoever
+  holds the lock takes the compatible requests that stand there along
+  (same fresh snapshot, no tx overlay, same remaining steps) and runs
+  them with itself, their arrival vectors the columns of one
+  ``(n, BATCH_WIDTH)`` start, ONE superstep and one fetch. Each member
+  folds its own column in its own thread; a request that finds nobody
+  runs alone as the ``(n,)`` program.
+
 - **Tx-overlay reconciliation** (read-your-writes): the transaction's
   uncommitted adds/deletes — the existence-cell machinery already sees
   every mutation — are merged into the snapshot BEFORE the run by
@@ -398,6 +407,58 @@ def host_seed_hop(csr, idx, mult, step):
 
 
 # ----------------------------------------------------------------- planner
+#: columns of the wide superstep, the ONE width a batch runs at (absent
+#: members' columns are zero): two executables a plan shape, narrow and
+#: wide. From the chip (TPU v5e, the served deployment's pack of 2,110,811
+#: slots, one whole superstep; PERF.md section 6, PR 37):
+#:   start      step ms   served + fetch + h2d of the start, ms
+#:   (n,)       15.46     16.69 + 0.69
+#:   (n, 2)     12.57     13.93 + 0.85
+#:   (n, 4)     13.93     15.55 + 0.97
+#:   (n, 8)     12.23     14.13 + 1.52    <- the cheapest, and carries most
+#:   (n, 128)   21.16     22.65 + 8.09    (4 live columns sliced on device)
+#:   (K, n)     compiles to the program of (n, K)
+#: A row of 2-8 words is gathered cheaper than an element; 2 / 4 separate
+#: (n,) gathers cost 30.5 / 64.9 ms.
+BATCH_WIDTH = 8
+
+
+@dataclass
+class _Request:
+    """One spilled request from its arrival to its answer: what it planned
+    in its own thread (the entry that stands at the lock), then what the
+    dispatch it rode left it."""
+
+    ticket: int
+    #: the tracer's clock when it first asked for the lock
+    arrived_ns: int
+    plan: SpilloverPlan
+    #: the snapshot it was planned against, that snapshot's epoch, the
+    #: transaction's overlay and the snapshot patched with it (`base`
+    #: itself for an empty overlay)
+    base: object = None
+    epoch: int = -1
+    overlay: Optional[dict] = None
+    csr: object = None
+    #: the (n,) program whose start is the arrival vector of hop 1 (or the
+    #: seeds', where hop 0 stays on the device)
+    program: object = None
+    seed_hop_edges: Optional[int] = None
+    #: ms of its own plan
+    own_ms: float = 0.0
+    #: its column, the dispatch's record (shared by the members), and
+    #: whether this request held the lock and ran it
+    counts: object = None
+    ride: Optional[dict] = None
+    led: bool = False
+    #: why the dispatch that took it along failed
+    refused: Optional[str] = None
+
+
+def _error_reason(e: BaseException) -> str:
+    return f"error:{type(e).__name__}: {e}"[:200]
+
+
 class SpilloverPlanner:
     """Per-graph spillover state: cached snapshot + epoch, promotion set,
     and the cached single-device executor (compiled step executables
@@ -412,13 +473,30 @@ class SpilloverPlanner:
         self.min_hops = int(cfg.get("computer.spillover-min-hops"))
         self.max_overlay = int(cfg.get("computer.spillover-max-overlay"))
         self.max_staleness = int(cfg.get("computer.spillover-max-staleness"))
+        #: the DEVICE's lock, held for one dispatch: the cached executor,
+        #: the run, the ledger's release stamp
         self._lock = threading.RLock()
+        #: the planner's short lock: the promoted set and the snapshot with
+        #: its epoch. Taken alone by an arriving request (which must not
+        #: wait behind a dispatch to plan), and inside the device's lock by
+        #: the holder's freshness check; never the other way round
+        self._state = threading.Lock()
         #: the lock's ledger (`_lock_taken`): arrival tickets, the arrival
         #: stamp of every request that stands at the lock (at either take),
         #: and the stamp the last holder left at its release
         self._tickets = itertools.count()
         self._waiting: Dict[int, int] = {}
         self._released_ns: Optional[int] = None
+        #: the planned requests that stand at the lock for a dispatch, by
+        #: ticket: whoever holds the lock takes the compatible ones along
+        self._pending: Dict[int, "_Request"] = {}
+        #: guards the per-digest tallies of `_promoted`, which requests
+        #: write from their own threads
+        self._tally = threading.Lock()
+        #: the step chains whose wide step the cached executor has run
+        self._wide_ready: set = set()
+        #: the host array a batch's start is stacked in
+        self._stage = None
         self._csr = None
         self._epoch = -1
         self._tpu_ex = None
@@ -427,7 +505,7 @@ class SpilloverPlanner:
     # ------------------------------------------------------------ promotion
     def _check_promotion(self, digest: str, shape: str) -> bool:
         """Sticky promotion against the digest table's measured means.
-        Call under the lock."""
+        Call under `_state`."""
         if digest in self._promoted:
             return True
         from janusgraph_tpu.observability import registry
@@ -462,7 +540,7 @@ class SpilloverPlanner:
         return True
 
     def promotion_snapshot(self) -> dict:
-        with self._lock:
+        with self._state, self._tally:
             return {d: dict(s) for d, s in self._promoted.items()}
 
     # ------------------------------------------------------------- snapshot
@@ -472,7 +550,9 @@ class SpilloverPlanner:
         olap/delta.py) while the pending overlay stays within the
         staleness bound, dropped for repack beyond it. Without a capture
         the PR 12 whole-row re-derivation (refresh_csr) remains the
-        fallback. Call under the lock."""
+        fallback. Call under `_state`. The cached executor is the
+        device lock's: the next dispatch replaces one built on a snapshot
+        that has gone (`_run_program`)."""
         from janusgraph_tpu.observability import registry
 
         backend = self.graph.backend
@@ -480,7 +560,6 @@ class SpilloverPlanner:
             from janusgraph_tpu.olap.csr import load_csr_snapshot
 
             self._csr, self._epoch = load_csr_snapshot(self.graph)
-            self._tpu_ex = None
             registry.counter("olap.spillover.packs").inc()
             registry.set_gauge("olap.spillover.staleness", 0.0)
             return self._csr
@@ -506,7 +585,6 @@ class SpilloverPlanner:
             # refresh; THIS query falls back, the next attempt repacks
             registry.counter("olap.spillover.stale").inc()
             self._csr = None
-            self._tpu_ex = None
             raise _SpillRefused("stale")
         if lag == 0:
             # property-only writes bumped the epoch but changed no
@@ -543,7 +621,6 @@ class SpilloverPlanner:
 
             refreshed = refresh_csr(self.graph, self._csr, self._epoch)
         self._csr, self._epoch = refreshed
-        self._tpu_ex = None
         registry.counter("olap.spillover.refreshes").inc()
         registry.set_gauge("olap.spillover.staleness", 0.0)
         return self._csr
@@ -552,7 +629,15 @@ class SpilloverPlanner:
     def maybe_execute(self, traversal, terminal=None):
         """The planner hook body: None = run the row path. For
         ``terminal="count"`` returns the int count; otherwise the final
-        traverser list."""
+        traverser list.
+
+        A spilled request does its OWN work in its own thread: the plan
+        (overlay, patch, the seed hop on the host) before it queues,
+        against the snapshot a short take of the lock gave it; the fold of
+        its own column and its records after. The lock is held per
+        DISPATCH: whoever holds it takes the compatible requests that
+        stand there and runs them with itself, their arrival vectors the
+        columns of one superstep (`_lead`)."""
         steps = traversal._steps
         n_hops = sum(
             1 for s in steps if getattr(s, "_expand_meta", None) is not None
@@ -567,149 +652,295 @@ class SpilloverPlanner:
                 # not compilable: only a PROMOTED shape's refusal is an
                 # event
                 shape, digest = traversal_digest(traversal)
-                with self._lock:
+                with self._state:
                     hot = digest in self._promoted
                 if hot:
                     return self._fallback(digest, f"unsupported:{reason}")
                 return None
-        # The planner's lock is taken twice: for the promotion check and,
-        # straight after it, to run the plan. With several clients the
-        # queue stands at the FIRST (whoever passes it usually takes the
-        # second before a woken waiter can), so ONE wait phase runs from
-        # before the first until the second is held, and between the two
-        # stands only what stood there before phases: a few microseconds
-        # more let the woken waiter in and move the latency's median by a
-        # third (PERF.md, PR 25). As a wait it is timed, never a trace
-        # event. The lock's ledger keeps to the same rule: before the first
-        # take a ticket beside the stamp the phase has just read, which
-        # leaves the queue where the wait ends; the rest under the second
-        # take (`_lock_taken`) and just before its release; nothing between
-        # the takes and nothing while a request waits.
+        from janusgraph_tpu.exceptions import (
+            DeadlineExceededError,
+            QueryError,
+            ServerOverloadedError,
+        )
+
+        ticket = next(self._tickets)
+        try:
+            request = self._ride(traversal, plan, ticket)
+            if request is None:
+                return None
+            return self._answer(traversal, request, terminal)
+        except ServerOverloadedError:
+            return self._fallback(plan.digest, "brownout")
+        except _SpillRefused as e:
+            return self._fallback(plan.digest, e.reason)
+        except (QueryError, DeadlineExceededError):
+            # semantic refusals (traverser budget, expired deadline)
+            # are the QUERY's errors, not planner defects — the row
+            # path would raise the same way, so surface them directly
+            raise
+        except Exception as e:  # noqa: BLE001 - fallback IS the
+            # contract: a planner defect must degrade to the row walk,
+            # never fail the query (the flight event + counter keep it
+            # visible)
+            return self._fallback(plan.digest, _error_reason(e))
+        finally:
+            self._waiting.pop(ticket, None)
+            self._pending.pop(ticket, None)
+
+    def _ride(self, traversal, plan: SpilloverPlan, ticket: int):
+        """From the request's arrival to its column: the `_Request` with
+        `counts` and `ride` set, or None for the row path.
+
+        First the planner's short lock, `_state`, which no dispatch holds
+        (promotion, rung-2 admission, the deadline, the snapshot with its
+        freshness check), then the plan against that snapshot in the
+        request's own thread, beside whatever dispatch is running, then
+        the stand at the device's lock until the request has its column:
+        from the dispatch of whoever held the lock meanwhile, or from its
+        own (`_lead`) once it holds the lock itself.
+
+        ONE wait phase runs from before the first take until the column
+        is there or the lock is held to dispatch (the plan's phase
+        suspends it): a member's wait for its column is `spill.lock_wait`
+        too. As a wait it is timed, never a trace event, and the lock's
+        ledger keeps to the same rule: a ticket beside the stamp the phase
+        has just read, the rest under the hold that dispatches
+        (`_lock_taken`) and just before its release, and nothing while a
+        request stands at the lock: a few microseconds between a release
+        and the next take decide who wakes into it (PERF.md section 6,
+        finding
+        1). No timer and no sleep: a holder takes who is there."""
+        from janusgraph_tpu.core import deadline as _deadline
+        from janusgraph_tpu.observability import tracer
+        from janusgraph_tpu.server.admission import check_olap_admission
+
         with contextlib.ExitStack() as waiting:
             wait = waiting.enter_context(
                 tracer.phase("spill.lock_wait", wait=True))
-            ticket = next(self._tickets)
             self._waiting[ticket] = wait.start_ns
-            waiting.callback(self._waiting.pop, ticket)
-            with self._lock:
+            with self._state:
                 if not self._check_promotion(plan.digest, plan.shape):
                     return None
-            from janusgraph_tpu.exceptions import ServerOverloadedError
-            from janusgraph_tpu.server.admission import check_olap_admission
-
-            try:
                 check_olap_admission()
-            except ServerOverloadedError:
-                return self._fallback(plan.digest, "brownout")
-            from janusgraph_tpu.exceptions import (
-                DeadlineExceededError,
-                QueryError,
-            )
-
-            try:
-                with self._lock:
+                _deadline.check("spillover compile")
+                base, epoch = self._snapshot(), self._epoch
+            request = _Request(ticket, wait.start_ns, plan)
+            self._plan(traversal, request, base, epoch)
+            self._pending[ticket] = request
+            with self._lock:
+                if request.ride is None and request.refused is None:
                     waiting.close()  # the lock is held: the wait is over
-                    taken = self._lock_taken(ticket, wait)
                     try:
-                        return self._execute_plan(
-                            traversal, plan, terminal, taken)
+                        self._lead(traversal, request, wait.end_ns)
                     finally:
                         self._released_ns = tracer.now_ns()
-            except _SpillRefused as e:
-                return self._fallback(plan.digest, e.reason)
-            except (QueryError, DeadlineExceededError):
-                # semantic refusals (traverser budget, expired deadline)
-                # are the QUERY's errors, not planner defects — the row
-                # path would raise the same way, so surface them directly
-                raise
-            except Exception as e:  # noqa: BLE001 - fallback IS the
-                # contract: a planner defect must degrade to the row walk,
-                # never fail the query (the flight event + counter keep it
-                # visible)
-                return self._fallback(
-                    plan.digest, f"error:{type(e).__name__}: {e}"[:200]
-                )
+                    return request
+        # a member of somebody's dispatch
+        _deadline.check("spillover run")
+        if request.refused is not None:
+            raise _SpillRefused(request.refused)
+        return request
 
-    def _lock_taken(self, ticket: int, wait) -> dict:
-        """The ledger's entry for one hold of the lock, made under it once
-        the wait phase has closed: the run record's lock fields.
+    def _plan(self, traversal, request: "_Request", base, epoch) -> None:
+        """What depends on the request alone, against the snapshot `base`:
+        the transaction's overlay, the patch, the program with hop 0 read
+        off the seeds' rows (`_compile`)."""
+        from janusgraph_tpu.observability import tracer
+
+        t0 = time.perf_counter()
+        with tracer.phase("spill.plan"):
+            overlay = tx_overlay(traversal.tx)
+            if overlay["size"] > self.max_overlay:
+                raise _SpillRefused("overlay-overflow")
+            request.base, request.epoch, request.overlay = base, epoch, overlay
+            request.csr = patched_csr(base, overlay)
+            request.program, request.seed_hop_edges = self._compile(
+                request.plan, request.csr, overlay)
+        request.own_ms += (time.perf_counter() - t0) * 1000.0
+
+    def _lead(self, traversal, request: "_Request", held_ns: int) -> None:
+        """One dispatch, under the lock: this request and the compatible
+        ones that stand at the lock, as the columns of one program.
+
+        Compatible is what the code can observe: built against the
+        snapshot the freshness check returns NOW (so every member's answer
+        is computed on a snapshot checked after its arrival), no
+        transaction overlay (a patched snapshot is one transaction's), the
+        same remaining steps, each a plain chain from its own arrival
+        vector (`OLAPTraversalProgram.stackable`). Anything else, and a
+        request that finds nobody, runs alone as the (n,) program. The
+        holder takes who is there: it waits for nobody."""
+        import numpy as np
+
+        from janusgraph_tpu.core import deadline as _deadline
+        from janusgraph_tpu.observability import registry, tracer
+        from janusgraph_tpu.olap.programs.olap_traversal import (
+            OLAPTraversalProgram,
+        )
+
+        _deadline.check("spillover run")
+        self._pending.pop(request.ticket, None)
+        t0 = time.perf_counter()
+        with tracer.phase("spill.plan"):
+            with self._state:
+                base, epoch = self._snapshot(), self._epoch
+            if request.base is not base:
+                # a commit was refreshed in since this request planned:
+                # its program is of an older snapshot than this check
+                # accepts
+                self._plan(traversal, request, base, epoch)
+            csr, program = request.csr, request.program
+            members = [request]
+            if csr is base and program.stackable():
+                # a copy: arrivals write the dict whoever holds the lock
+                members += [
+                    r for r in list(self._pending.values())
+                    if r.csr is base and r.program.stackable()
+                    and r.program.steps == program.steps
+                ][:BATCH_WIDTH - 1]
+            for r in members[1:]:
+                del self._pending[r.ticket]
+            ride = self._lock_taken(request, members, held_ns)
+            for r in members:
+                self._waiting.pop(r.ticket, None)
+            try:
+                if len(members) > 1:
+                    # stacked in an array kept between dispatches (this
+                    # dispatch is over, its start copied and its columns
+                    # fetched, before the next holder stacks): a fresh 4
+                    # MB array is a thousand page faults in the served
+                    # process, 0.5 ms a dispatch under the lock (PERF.md
+                    # section 6, PR 37). Columns nobody rides keep an old
+                    # start: nobody reads them
+                    if self._stage is None or len(self._stage) != (
+                        csr.num_vertices
+                    ):
+                        self._stage = np.zeros(
+                            (csr.num_vertices, BATCH_WIDTH), np.float32)
+                    program = OLAPTraversalProgram.stacked(
+                        [r.program for r in members], BATCH_WIDTH,
+                        out=self._stage,
+                    )
+                with tracer.span(
+                    "olap.spillover", digest=request.plan.digest,
+                    hops=len(request.plan.hops), batch=len(members),
+                ):
+                    states = self._run_program(
+                        csr, program, patched=csr is not base)
+                registry.counter("olap.spillover.dispatches").inc()
+                counts = states["count"]
+                olap_run = registry.last_run("olap") or {}
+                ride.update(
+                    executor=olap_run.get("path"),
+                    supersteps=olap_run.get("supersteps"),
+                )
+                ex, steps = self._tpu_ex, request.program.steps
+                if (
+                    ex is not None and ex.csr is csr
+                    and request.program.stackable()
+                    and steps not in self._wide_ready
+                ):
+                    # the first dispatch of its steps on this executor:
+                    # if it ran alone, the wide step is prepared beside
+                    # the narrow one now (a run, not a dispatch), so that
+                    # no batch compiles in front of a waiting client
+                    self._wide_ready.add(steps)
+                    if len(members) == 1:
+                        self._run_program(
+                            csr,
+                            OLAPTraversalProgram.stacked(
+                                [program], BATCH_WIDTH),
+                            patched=False,
+                        )
+            except BaseException as e:
+                # the dispatch failed for every member: each falls back,
+                # and is counted, in its own thread
+                for r in members[1:]:
+                    r.refused = _error_reason(e)
+                raise
+            ride["wall_ms"] = (time.perf_counter() - t0) * 1000.0
+            request.led = True
+            for j, r in enumerate(members):
+                r.counts = counts if len(members) == 1 else counts[:, j]
+                r.ride = ride
+
+    def _lock_taken(self, request, members, held_ns: int) -> dict:
+        """The ledger's entry for one hold of the lock that dispatches,
+        made under it once the wait phase has closed: the lock fields of
+        the run records of every request the dispatch carries.
 
         ``queue_depth`` is how many other requests stand at the lock (at
-        either take) and ``overtook`` how many of them arrived before this
-        one; `_publish` counts both once the request has spilled. Against
+        either take, the members this hold takes along included),
+        ``overtook`` how many of them arrived before the holder and are
+        LEFT standing, ``batch`` the requests of the dispatch. Against
         the stamp the previous holder left at its release, the stretch
         from that release to this hold is a HAND-OFF when this request or
-        one it overtook had arrived by then (the lock was wanted and free,
-        the device idle), else FREE time (nobody was asking). Arrival and
-        hold are the wait phase's own reads of the tracer's clock: nothing
-        here reads a clock, and the ledger's times add up with the
-        phases'."""
+        one that arrived before it had arrived by then (the lock was
+        wanted and free, the device idle), else FREE time (nobody was
+        asking). Arrival and hold are the wait phases' own reads of the
+        tracer's clock: nothing here reads a clock, and the ledger's times
+        add up with the phases'. A member that takes the lock only to find
+        its column is no hold: it leaves no stamp and no entry."""
         from janusgraph_tpu.observability import registry
 
+        ticket = request.ticket
+        riding = {r.ticket for r in members}
         # a copy: arrivals write the dict whoever holds the lock
-        others = list(self._waiting.items())
-        earlier = [stamp for t, stamp in others if t < ticket]
+        others = [
+            (t, stamp) for t, stamp in list(self._waiting.items())
+            if t != ticket
+        ]
+        earlier = [(t, stamp) for t, stamp in others if t < ticket]
         if self._released_ns is not None:
             # both looked up, so that a reader finds both names
             handoff = registry.timer("spill.lock_handoff")
             free = registry.timer("spill.lock_free")
-            wanted = min(earlier, default=wait.start_ns) < self._released_ns
+            wanted = min(
+                (stamp for _, stamp in earlier), default=request.arrived_ns
+            ) < self._released_ns
             (handoff if wanted else free).update(
-                wait.end_ns - self._released_ns)
-        return {"queue_depth": len(others), "overtook": len(earlier)}
+                held_ns - self._released_ns)
+        return {
+            "queue_depth": len(others),
+            "overtook": sum(1 for t, _ in earlier if t not in riding),
+            "batch": len(members),
+        }
 
-    def _execute_plan(
-        self, traversal, plan: SpilloverPlan, terminal, taken: dict,
-    ):
+    def _answer(self, traversal, request: "_Request", terminal):
+        """The request's own column folded into the chain's output, and
+        its records, in its own thread."""
         import numpy as np
 
-        from janusgraph_tpu.core import deadline as _deadline
         from janusgraph_tpu.observability import tracer
 
-        _deadline.check("spillover compile")
         t0 = time.perf_counter()
-        # self time: snapshot, overlay, patch, compile and the executor's
-        # choice; the run's executor.* phases suspend it
-        with tracer.phase("spill.plan"):
-            base = self._snapshot()
-            packed_epoch = self._epoch
-            overlay = tx_overlay(traversal.tx)
-            if overlay["size"] > self.max_overlay:
-                raise _SpillRefused("overlay-overflow")
-            csr = patched_csr(base, overlay)
-            program, seed_hop_edges = self._compile(plan, csr, overlay)
-            _deadline.check("spillover run")
-            with tracer.span(
-                "olap.spillover", digest=plan.digest, hops=len(plan.hops),
-            ):
-                states = self._run_program(
-                    csr, program, patched=csr is not base
-                )
+        plan = request.plan
         with tracer.phase("spill.reduce"):
-            counts = np.asarray(states["count"], dtype=np.float64)
+            counts = np.asarray(request.counts, dtype=np.float64)
             if counts.size and counts.max() >= float(1 << 24):
                 # per-vertex traverser counts ride float32 on device —
                 # exact only below 2^24; past it the row walk is the
                 # honest answer
                 raise _SpillRefused("count-overflow")
             result, total = self._reduce(
-                traversal, plan, csr, counts, terminal
+                traversal, plan, request.csr, counts, terminal
             )
-        wall_ms = (time.perf_counter() - t0) * 1000.0
+        # the request's service: its own plan and fold, and the whole
+        # dispatch it rode (no wait for the lock)
+        wall_ms = (
+            request.own_ms + request.ride["wall_ms"]
+            + (time.perf_counter() - t0) * 1000.0
+        )
         with tracer.phase("spill.publish"):
-            self._publish(
-                plan, terminal, overlay, packed_epoch, wall_ms, total,
-                seed_hop_edges, taken,
-            )
+            self._publish(request, terminal, wall_ms, total)
         return result
 
-    def _publish(
-        self, plan, terminal, overlay, packed_epoch, wall_ms, total,
-        seed_hop_edges, taken,
-    ) -> None:
+    def _publish(self, request, terminal, wall_ms, total) -> None:
         """The spilled execution still feeds the digest table (the
         shape's new, cheap reality) and the ambient span, like the row
-        path; then counters, the run record and the flight event."""
+        path; then counters, the run record and the flight event. The
+        lock's two counters are a DISPATCH's, written by the request that
+        led it."""
         from janusgraph_tpu.observability import (
             flight_recorder,
             registry,
@@ -717,18 +948,22 @@ class SpilloverPlanner:
         )
         from janusgraph_tpu.observability.profiler import digest_table
 
+        plan, overlay, ride = request.plan, request.overlay, request.ride
+        seed_hop_edges = request.seed_hop_edges
         digest_table.observe(plan.digest, plan.shape, wall_ms)
         cur = tracer.current()
         if cur is not None:
             cur.annotate(digest=plan.digest, spillover=True)
-        stats = self._promoted.get(plan.digest)
-        if stats is not None:
-            stats["spilled"] += 1
+        with self._tally:
+            stats = self._promoted.get(plan.digest)
+            if stats is not None:
+                stats["spilled"] += 1
         registry.counter("olap.spillover.spilled").inc()
-        registry.counter("olap.spillover.lock.waiters_seen").inc(
-            taken["queue_depth"])
-        registry.counter("olap.spillover.lock.overtakes").inc(
-            int(taken["overtook"] > 0))
+        if request.led:
+            registry.counter("olap.spillover.lock.waiters_seen").inc(
+                ride["queue_depth"])
+            registry.counter("olap.spillover.lock.overtakes").inc(
+                int(ride["overtook"] > 0))
         # graphlint: disable=JG110 -- digest is bounded by the top-K-evicted price book (metrics.digest-top-k) that feeds promotion
         registry.counter(f"olap.spillover.spilled.{plan.digest}").inc()
         if seed_hop_edges is not None:
@@ -746,17 +981,19 @@ class SpilloverPlanner:
                 "new_vertices": len(overlay["new_vertices"]),
                 "removed": len(overlay["removed"]),
             },
-            "snapshot_epoch": packed_epoch,
+            "snapshot_epoch": request.epoch,
             "wall_ms": round(wall_ms, 3),
             "result_total": total,
             "fallback": None,
-            **taken,
+            "queue_depth": ride["queue_depth"],
+            "overtook": ride["overtook"],
+            "batch": ride["batch"],
+            "led": request.led,
         }
-        olap_run = registry.last_run("olap") or {}
         run_info = {
             "spillover": block,
-            "executor": olap_run.get("path"),
-            "supersteps": olap_run.get("supersteps"),
+            "executor": ride["executor"],
+            "supersteps": ride["supersteps"],
         }
         registry.record_run("olap.spillover", run_info)
         flight_recorder.record(
@@ -896,6 +1133,7 @@ class SpilloverPlanner:
                 return TPUExecutor(csr).run(program)
             if self._tpu_ex is None or self._tpu_ex.csr is not csr:
                 self._tpu_ex = TPUExecutor(csr)
+                self._wide_ready = set()
             return self._tpu_ex.run(program)
         from janusgraph_tpu.olap.computer import run_on
 
@@ -965,7 +1203,7 @@ class SpilloverPlanner:
         head = reason.split(":", 1)[0]
         # graphlint: disable=JG110 -- head is the fixed refusal-reason vocabulary (unsupported/overlay/stale/brownout/overflow/error)
         registry.counter(f"olap.spillover.fallback.{head}").inc()
-        with self._lock:
+        with self._tally:
             stats = self._promoted.get(digest)
             if stats is not None:
                 stats["fallbacks"] += 1

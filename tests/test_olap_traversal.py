@@ -652,3 +652,101 @@ def test_sack_on_weightless_csr_refused_by_every_executor(g, mesh8):
     ):
         with pytest.raises(ValueError, match="no edge weights"):
             ex.run(prog)
+
+
+# ------------------------------------------------- an (n, K) start: K chains
+def _column_graph():
+    from janusgraph_tpu.olap import csr_from_edges
+    from janusgraph_tpu.olap.programs.olap_traversal import TraversalStep
+
+    rng = np.random.default_rng(37)
+    n, m = 300, 3000
+    src = rng.integers(0, n, m).astype(np.int32)
+    dst = rng.integers(0, n, m).astype(np.int32)
+    et = rng.integers(0, 2, m).astype(np.int32)
+    csr = csr_from_edges(n, src, dst, edge_types=et)
+    steps = [TraversalStep("out", (0,)), TraversalStep("both")]
+    # arrival vectors as the planner's: small integer counts, column 3
+    # empty (an absent member of a batch)
+    starts = rng.integers(0, 4, (n, 4)).astype(np.float32)
+    starts[:, 3] = 0.0
+    return csr, steps, starts
+
+
+@pytest.mark.parametrize("executor", ["numpy", "numpy-pack", "tpu"])
+def test_columns_of_a_wide_start_are_the_narrow_runs_bit_for_bit(executor):
+    """An (n, K) start gives, column for column and bit for bit, the (n,)
+    run of each column: on the numpy executor (the scalar loop and the
+    pack's replay) and on the `tpu` executor (under JAX_PLATFORMS=cpu
+    here), whose prepared steps tell the two widths apart."""
+    csr, steps, starts = _column_graph()
+    ex = {
+        "numpy": lambda: CPUExecutor(csr),
+        "numpy-pack": lambda: CPUExecutor(csr, strategy="hybrid"),
+        "tpu": lambda: TPUExecutor(csr),
+    }[executor]()
+    wide = np.asarray(
+        ex.run(OLAPTraversalProgram(steps, seed_mask=starts))["count"])
+    assert wide.shape == starts.shape
+    assert wide[:, :3].any() and not wide[:, 3].any()
+    for j in range(starts.shape[1]):
+        narrow = np.asarray(ex.run(OLAPTraversalProgram(
+            steps, seed_mask=starts[:, j].copy()))["count"])
+        assert narrow.shape == (csr.num_vertices,)
+        assert narrow.dtype == wide.dtype
+        assert narrow.tobytes() == wide[:, j].tobytes(), j
+    if executor == "tpu":
+        widths = sorted(dict(key[0][2])["width"] for key in ex._prepared)
+        assert widths == [0, 0, 4, 4]  # two steps, each at two widths
+
+
+def test_stacked_builds_the_wide_start_from_plain_chains():
+    csr, steps, starts = _column_graph()
+    plain = [
+        OLAPTraversalProgram(steps, seed_mask=starts[:, j].copy())
+        for j in range(3)
+    ]
+    assert all(p.stackable() and p.width == 0 for p in plain)
+    wide = OLAPTraversalProgram.stacked(plain, 4)
+    assert wide.width == 4 and not wide.stackable()
+    assert wide.cache_key() != plain[0].cache_key()
+    got = np.asarray(CPUExecutor(csr).run(wide)["count"])
+    want = np.asarray(CPUExecutor(csr).run(
+        OLAPTraversalProgram(steps, seed_mask=starts))["count"])
+    assert got.tobytes() == want.tobytes()
+    # stacked into the caller's own array: the columns it wrote are the
+    # chains', what the others held disturbs nobody
+    kept = np.full(starts.shape, 7.0, np.float32)
+    again = np.asarray(CPUExecutor(csr).run(
+        OLAPTraversalProgram.stacked(plain[:2], 4, out=kept))["count"])
+    assert again[:, :2].tobytes() == want[:, :2].tobytes()
+    assert (kept[:, 2:] == 7.0).all() and kept[:, 0].tobytes() == (
+        starts[:, 0].tobytes())
+    with pytest.raises(ValueError, match="at most `width` stackable"):
+        OLAPTraversalProgram.stacked(plain, 2)
+    other = OLAPTraversalProgram(steps[:1], seed_mask=starts[:, 0].copy())
+    with pytest.raises(ValueError, match="same steps"):
+        OLAPTraversalProgram.stacked([plain[0], other], 4)
+    for unfit in (
+        OLAPTraversalProgram(steps),  # starts everywhere: no vector
+        OLAPTraversalProgram(steps, seed_indices=[1, 2]),
+        OLAPTraversalProgram(
+            steps, seed_mask=starts[:, 0].copy(),
+            step_masks=np.ones((csr.num_vertices, 2), np.float32)),
+        OLAPTraversalProgram(
+            steps, seed_mask=starts[:, 0].copy(), record_reach=True),
+        OLAPTraversalProgram(steps, seed_mask=starts[:, 0].copy(), sack="sum"),
+    ):
+        assert not unfit.stackable()
+
+
+@pytest.mark.parametrize("extra,name", [
+    ({"sack": "sum"}, "sack"),
+    ({"step_masks": np.ones((300, 2), np.float32)}, "step_masks"),
+    ({"record_reach": True}, "record_reach"),
+    ({"seed_indices": [0]}, "seed_indices"),
+])
+def test_a_wide_start_refuses_what_it_does_not_carry_by_name(extra, name):
+    _, steps, starts = _column_graph()
+    with pytest.raises(ValueError, match=f"K plain chains: {name}"):
+        OLAPTraversalProgram(steps, seed_mask=starts, **extra)

@@ -455,6 +455,7 @@ def test_spilled_request_moves_every_served_phase_once():
         expected = {name: 1 for name in SERVER_PHASES + SPILL_PHASES
                     + EXECUTOR_PHASES}
         expected["server.serialize"] = 2  # the result, then the response
+        expected["spill.plan"] = 2  # its own plan, then the dispatch it led
         expected["executor.dispatch"] = supersteps
         expected["executor.sync"] = supersteps + 1  # ... and the result
         for name, count in expected.items():
@@ -481,12 +482,15 @@ def test_spilled_request_moves_every_served_phase_once():
         g.close()
 
 
-def test_no_phase_boundary_between_the_planners_two_lock_takes(monkeypatch):
-    """The planner takes its lock for the promotion check and again, at
-    once, for the plan; which waiter gets it in between decides the served
-    median (PERF.md, PR 25: +35% from a few microseconds there). So the
-    wait phase opens before the first take and closes under the second,
-    and no phase begins or ends between them."""
+def test_only_the_plan_stands_between_the_planners_two_lock_takes(monkeypatch):
+    """A request takes the planner's short lock for its check and then the
+    device's lock to dispatch; which waiter gets that one decides the
+    served median (PERF.md, PR 25: +35% from a few microseconds there).
+    The ONE wait phase opens before the first take and closes under the
+    second; between the takes runs the
+    request's own plan (one phase, which suspends the wait) and no other
+    boundary, and none between the plan's end and the second take, where
+    the request stands for a dispatch."""
     from test_spillover import _social_graph
 
     g, people, _ = _social_graph()
@@ -501,44 +505,53 @@ def test_no_phase_boundary_between_the_planners_two_lock_takes(monkeypatch):
         events = []
 
         class Recording:
-            def __init__(self, lock):
-                self._lock = lock
+            def __init__(self, lock, which=""):
+                self._lock, self._which = lock, which
 
             def __enter__(self):
                 self._lock.acquire()
-                events.append("take")
+                events.append("take" + self._which)
 
             def __exit__(self, *exc):
-                events.append("release")
+                events.append("release" + self._which)
                 self._lock.release()
 
         clock = tracer._clock
+        waits = registry.snapshot()["phase.spill.lock_wait"]["count"]
         monkeypatch.setattr(planner, "_lock", Recording(planner._lock))
+        monkeypatch.setattr(
+            planner, "_state", Recording(planner._state, ":state"))
         # every phase boundary reads the tracer's clock exactly once
         monkeypatch.setattr(
             tracer, "_clock", lambda: (events.append("phase"), clock())[1])
         build().count()
         assert (registry.snapshot()["olap.spillover.spilled"]["count"]
                 == spilled + 1)
-        check_done = events.index("release")
-        plan_taken = events.index("take", check_done)
-        assert events[:check_done].count("take") == 1
-        assert "phase" not in events[check_done:plan_taken]
+        check_done = events.index("release:state")
+        plan_taken = events.index("take")
+        assert events[:check_done].count("take:state") == 1
+        # spill.plan in and out, in the request's own thread
+        assert events[check_done + 1:plan_taken] == ["phase", "phase"]
         # spill.recognize out and spill.lock_wait in before the first take;
-        # lock_wait out, spill.plan in right after the second
-        assert events[:events.index("take")].count("phase") >= 2
+        # lock_wait out, the holder's spill.plan in right after the second
+        assert events[:events.index("take:state")].count("phase") >= 2
         assert events[plan_taken + 1:plan_taken + 3] == ["phase", "phase"]
+        # one wait phase a request, whatever it waited for
+        assert registry.snapshot()["phase.spill.lock_wait"]["count"] == (
+            waits + 1)
     finally:
         g.close()
 
 
-def test_nothing_is_written_or_read_between_the_planners_two_lock_takes(
+def test_nothing_is_written_or_read_where_a_request_stands_at_the_lock(
         monkeypatch):
     """The lock's ledger (tickets, queue depth, hand-off) and the phases'
-    CPU clock keep to the rule of the test above: between the release of
-    the first take and the second take no clock is read, wall or CPU, the
-    registry is not touched and the planner stores no attribute; all of
-    that happens before the first take or under the second."""
+    CPU clock keep to the rule of the test above: from the end of the
+    request's own plan to the second take no clock is read, wall or CPU,
+    the registry is not touched and the planner stores no attribute (the
+    request enters `_pending`, an item of a dict, and stands); the ledger
+    is written under the take that dispatches, without a clock of its
+    own, and the release stamp is the last thing under the lock."""
     from test_spillover import _social_graph
 
     g, people, _ = _social_graph()
@@ -554,15 +567,15 @@ def test_nothing_is_written_or_read_between_the_planners_two_lock_takes(
         events = []
 
         class Recording:
-            def __init__(self, lock):
-                self._lock = lock
+            def __init__(self, lock, which=""):
+                self._lock, self._which = lock, which
 
             def __enter__(self):
                 self._lock.acquire()
-                events.append("take")
+                events.append("take" + self._which)
 
             def __exit__(self, *exc):
-                events.append("release")
+                events.append("release" + self._which)
                 self._lock.release()
 
         class Watched(type(planner)):
@@ -577,6 +590,8 @@ def test_nothing_is_written_or_read_between_the_planners_two_lock_takes(
             return noted
 
         monkeypatch.setattr(planner, "_lock", Recording(planner._lock))
+        monkeypatch.setattr(
+            planner, "_state", Recording(planner._state, ":state"))
         monkeypatch.setattr(tracer, "_clock", noting("clock", tracer._clock))
         monkeypatch.setattr(
             tracer, "cpu_clock", noting("cpu", time.thread_time_ns))
@@ -591,17 +606,27 @@ def test_nothing_is_written_or_read_between_the_planners_two_lock_takes(
             planner.__class__ = Watched.__bases__[0]
         assert (registry.snapshot()["olap.spillover.spilled"]["count"]
                 == spilled + 1)
-        check_done = events.index("release")
-        plan_taken = events.index("take", check_done)
-        assert events[:check_done].count("take") == 1
-        assert events[check_done + 1:plan_taken] == []
+        check_done = events.index("release:state")
+        plan_taken = events.index("take")
+        assert events[:check_done].count("take:state") == 1
+        # between the takes, the request's own plan: its phase opens on one
+        # read of each clock and closes on one, writing its two timers, and
+        # THEN NOTHING until the lock is held
+        assert events[check_done + 1:plan_taken] == [
+            "clock", "cpu", "clock", "cpu",
+            "registry:timer", "registry:timer"]
         # under the second take: the wait phase closes on one read of each
-        # clock, the ledger writes its timers without reading a clock, then
-        # spill.plan opens
+        # clock and writes its wall and CPU, the holder's spill.plan opens,
+        # and up to the dispatch's span the ledger and the freshness check
+        # write the registry without reading a clock
         held = events[plan_taken + 1:events.index("release", plan_taken)]
-        assert held[:2] == ["clock", "cpu"]
-        # the wait's wall and CPU; hand-off and free time
-        assert held[2:held.index("clock", 2)] == ["registry:timer"] * 4
+        assert held[:6] == ["clock", "cpu", "registry:timer",
+                            "registry:timer", "clock", "cpu"]
+        ledger = held[6:held.index("clock", 6)]
+        assert ledger.count("registry:timer") == 2  # hand-off and free time
+        assert ledger[0] == "take:state"  # the freshness check, first
+        assert all(e.startswith("registry:") or e.endswith(":state")
+                   for e in ledger)
         # the release stamp is the last thing under the lock
         assert held[-2:] == ["clock", "store:_released_ns"]
     finally:

@@ -252,7 +252,7 @@ def test_unsupported_step_falls_back_transparently():
         # force-promote the digest so the refusal is event-worthy
         shape, digest = sp.traversal_digest(build())
         planner = g.spillover_planner
-        with planner._lock:
+        with planner._state:
             assert planner._check_promotion(digest, shape)
         before = flight_recorder.counts().get("spillover_fallback", 0)
         spilled_view = build().to_list()
@@ -609,7 +609,7 @@ def _device_counts(planner, plan, tx, host_hop):
 
     from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 
-    with planner._lock:
+    with planner._state:
         base = planner._snapshot()
     overlay = sp.tx_overlay(tx)
     csr = sp.patched_csr(base, overlay)
@@ -722,7 +722,7 @@ def test_seed_hop_stays_on_the_device(start):
         build().to_list()  # teach
         if start == "labelled-hop-without-edge-types":
             sorted(build().to_list())  # packs the snapshot
-            with planner._lock:
+            with planner._lock, planner._state:
                 planner._csr = dataclasses.replace(
                     planner._csr, out_edge_type=None, in_edge_type=None
                 )
@@ -796,8 +796,9 @@ class _Turnstile:
 
 def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
         monkeypatch):
-    """Four threads staged through the planner's lock on an injected
-    clock. `a` runs alone (FREE time: nobody was asking) while `b` and `c`
+    """Four threads staged through the device's lock on an injected clock,
+    each asking a shape of its own so that no holder can take another
+    along. `a` runs alone (FREE time: nobody was asking) while `b` and `c`
     arrive; `d` arrives after a's release and is let in first, overtaking
     both (a HAND-OFF all the same: the lock was wanted while it stood
     free); then `c` before `b`; then `a` again with nobody waiting."""
@@ -806,18 +807,22 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
     g, people, _ = _social_graph()
     try:
         planner = g.spillover_planner
-
-        def count():
-            return g.traversal().V(people[0]).out("knows").out(
-                "knows").count()
-
-        want = count()  # teach the shape ...
-        assert count() == want  # ... spill it once: compiles, and releases
+        t = g.traversal
+        asks = {
+            "a": lambda: t().V(people[0]).out("knows").out("knows").count(),
+            "b": lambda: t().V(people[0]).out("knows").out("lives").count(),
+            "c": lambda: t().V(people[0]).out("knows").in_("knows").count(),
+            "d": lambda: t().V(people[0]).in_("knows").out("knows").count(),
+        }
+        want = {}
+        for name, ask in asks.items():
+            want[name] = ask()  # teach the shape ...
+            assert ask() == want[name]  # ... spill it once: it compiles
         gate = _Turnstile(planner._lock)
         monkeypatch.setattr(planner, "_lock", gate)
 
         class Clock:  # moves only when the test says so
-            now = planner._released_ns  # the teaching run's release
+            now = planner._released_ns  # the teaching runs' last release
 
         monkeypatch.setattr(tracer, "_clock", lambda: Clock.now)
         def gaps():  # (observations, ns) of the ledger's two timers
@@ -831,13 +836,14 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
         seen = _spill_count("olap.spillover.lock.waiters_seen")
         overtakes = _spill_count("olap.spillover.lock.overtakes")
         spilled = _spill_count()
+        dispatched = _spill_count("olap.spillover.dispatches")
         answers, again = {}, threading.Event()
 
         def client(name, twice=False):
-            answers[name] = [count()]
+            answers[name] = [asks[name]()]
             if twice:
                 assert again.wait(60)
-                answers[name].append(count())
+                answers[name].append(asks[name]())
 
         threads = {
             name: threading.Thread(
@@ -847,14 +853,15 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
         at = dict(before)
 
         def finished(n):
-            """Once run `n` has spilled and released the lock (two takes a
+            """Once run `n` has spilled and released the lock (one take a
             request): its record's lock fields, and what it added to the
             ledger's timers ({timer: ns}, one of the two)."""
             deadline = time.monotonic() + 60
-            while _spill_count() < spilled + n or gate.exits < 2 * n:
+            while _spill_count() < spilled + n or gate.exits < n:
                 assert time.monotonic() < deadline, f"run {n} never ended"
                 time.sleep(0.002)
             record = registry.last_run("olap.spillover")["spillover"]
+            assert (record["batch"], record["led"]) == (1, True)
             now = gaps()
             moved = {name[len("spill.lock_"):]: ns - at[name][1]
                      for name, (seen_, ns) in now.items()
@@ -862,12 +869,11 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
             at.update(now)
             return record["queue_depth"], record["overtook"], moved
 
-        # a arrives 1,000 ns after the teaching run's release
+        # a arrives 1,000 ns after the teaching runs' last release
         Clock.now += 1_000
         threads["a"].start()
-        gate.admit("a")          # the promotion check
-        gate.arrives("a")        # a stands at the second take
-        for name in "bc":        # b, then c, arrive and stand at the first
+        gate.arrives("a")        # a stands at the lock, its plan made
+        for name in "bc":        # b, then c, arrive, plan and stand
             Clock.now += 10
             threads[name].start()
             gate.arrives(name)
@@ -876,31 +882,31 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
         # it had not arrived by that release: free time
         assert finished(1) == (2, 0, {"free": 1_025})
         # a released at the instant it was held (the clock stood still);
-        # d arrives 40 us later and is let through both takes at once
+        # d arrives 40 us later and is let in first
         Clock.now += 40_000
         threads["d"].start()
         gate.arrives("d")
         Clock.now += 2_000_000
-        gate.admit("d", takes=2)
+        gate.admit("d")
         # d came after a's release, b and c before it: the lock was wanted
         assert finished(2) == (2, 2, {"handoff": 2_040_000})
         Clock.now += 3_000_000
-        gate.admit("c", takes=2)
+        gate.admit("c")
         assert finished(3) == (1, 1, {"handoff": 3_000_000})
         Clock.now += 500_000
-        gate.admit("b", takes=2)
+        gate.admit("b")
         assert finished(4) == (0, 0, {"handoff": 500_000})
         # nobody waits: a's second request arrives 700 ns after b's release
         Clock.now += 700
         again.set()
-        gate.admit("a", takes=2)
+        gate.admit("a")
         assert finished(5) == (0, 0, {"free": 700})
         for th in threads.values():
             th.join(60)
             assert not th.is_alive()
-        assert answers == {"a": [want, want], "b": [want], "c": [want],
-                           "d": [want]}
-        assert planner._waiting == {}
+        assert answers == {"a": [want["a"]] * 2, "b": [want["b"]],
+                           "c": [want["c"]], "d": [want["d"]]}
+        assert planner._waiting == {} and planner._pending == {}
         assert not {"lock_wait_ms", "handoff_ms"} & set(
             registry.last_run("olap.spillover")["spillover"])
         assert {
@@ -914,6 +920,8 @@ def test_lock_ledger_counts_queue_overtakes_handoffs_and_free_time(
             "olap.spillover.lock.waiters_seen") == seen + 2 + 2 + 1
         assert _spill_count(
             "olap.spillover.lock.overtakes") == overtakes + 2
+        assert _spill_count(
+            "olap.spillover.dispatches") == dispatched + 5
     finally:
         g.close()
 
@@ -970,3 +978,418 @@ def test_a_hold_that_is_refused_counts_in_no_share(monkeypatch):
             before[0] + 1, before[1] + 1, before[2] + 1]
     finally:
         g.close()
+
+
+# ------------------------------------------------- the holder's dispatch
+class _Stage:
+    """Client threads staged through the planner's lock (`_Turnstile`)
+    with the planner's run function wrapped: the test decides who stands
+    at the lock when it is taken, and sees every dispatch (`runs`: the
+    width of the program's start, 0 for the (n,) one, and whether its
+    snapshot was patched)."""
+
+    def __init__(self, planner, monkeypatch, run=None):
+        self.gate = _Turnstile(planner._lock)
+        monkeypatch.setattr(planner, "_lock", self.gate)
+        self.runs = []
+        real = planner._run_program
+
+        def recorded(csr, program, patched):
+            self.runs.append((program.width, patched))
+            return (run or real)(csr, program, patched)
+
+        self.real = real
+        monkeypatch.setattr(planner, "_run_program", recorded)
+        self.answers, self.threads = {}, {}
+
+    def client(self, name, ask):
+        def body():
+            try:
+                self.answers[name] = ask()
+            except Exception as e:  # noqa: BLE001 - the answer IS the error
+                self.answers[name] = e
+
+        self.threads[name] = threading.Thread(target=body, name=name)
+        self.threads[name].start()
+
+    def plans(self, name, ask):
+        """Start `name` and leave it standing at the device's lock, past
+        the planner's short lock and with its plan made."""
+        self.client(name, ask)
+        self.gate.arrives(name)
+
+    def holds(self, name):
+        """Let `name` take the lock it stands at, and wait until it has
+        answered (a holder's dispatch, or a member finding its column)."""
+        self.gate.admit(name)
+        self.threads[name].join(60)
+        assert not self.threads[name].is_alive(), f"{name} never answered"
+        return self.answers[name]
+
+
+def _dispatches():
+    return _spill_count("olap.spillover.dispatches")
+
+
+def _taught(extra_cfg=None, n_people=40):
+    """A graph whose two-hop shape is promoted, spilled once (so both
+    widths of its step are prepared), `ask(i)` for person i's distinct
+    two-hop count through the planner, and the row path's answers."""
+    g, people, places = _social_graph(extra_cfg, n_people=n_people)
+    planner = g.spillover_planner
+
+    def ask(i, source=None):
+        return (source or g.traversal()).V(people[i]).out("knows").out(
+            "knows").dedup().count()
+
+    def oracle():
+        planner.enabled = False
+        try:
+            return [ask(i) for i in range(len(people))]
+        finally:
+            planner.enabled = True
+
+    want = oracle()
+    before = _spill_count()
+    assert ask(0) == want[0] and _spill_count() == before + 1
+    return g, people, planner, ask, oracle, want
+
+
+def _batches(n):
+    """(batch, led) of the newest n spilled run records."""
+    runs = registry.runs("olap.spillover")[-n:]
+    return sorted(
+        (r["spillover"]["batch"], r["spillover"]["led"]) for r in runs)
+
+
+def test_three_requests_at_the_lock_leave_in_one_dispatch(monkeypatch):
+    """(i) Three compatible requests stand at the lock; the one that
+    takes it runs all three as columns of ONE program, each answer the
+    row path's for ITS start."""
+    g, people, planner, ask, _, want = _taught()
+    try:
+        trio = [0, 1, 2]
+        assert len({want[i] for i in trio}) == 3, want
+        stage = _Stage(planner, monkeypatch)
+        spilled, dispatched = _spill_count(), _dispatches()
+        seen = _spill_count("olap.spillover.lock.waiters_seen")
+        for name, i in zip("abc", trio):
+            stage.plans(name, lambda i=i: ask(i))
+        assert len(planner._pending) == 3
+        assert stage.holds("b") == want[1]  # the holder: anyone will do
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        assert planner._pending == {} and planner._waiting == {}
+        # the members find their columns when they reach the lock
+        assert stage.holds("a") == want[0]
+        assert stage.holds("c") == want[2]
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        assert _spill_count() == spilled + 3
+        assert _dispatches() == dispatched + 1
+        assert _batches(3) == [(3, False), (3, False), (3, True)]
+        # the queue a holder finds is a dispatch's, counted once
+        assert _spill_count(
+            "olap.spillover.lock.waiters_seen") == seen + 2
+        records = registry.runs("olap.spillover")[-3:]
+        assert {r["spillover"]["queue_depth"] for r in records} == {2}
+        assert {r["spillover"]["overtook"] for r in records} == {0}
+        assert {r["supersteps"] for r in records} == {1}
+        # the next batch is stacked where this one was: two riders write
+        # their columns over the trio's, the third column keeps c's start
+        # and nobody reads it
+        assert len({want[i] for i in (7, 9)} | {want[1]}) == 3, want
+        stage.plans("d", lambda: ask(7))
+        stage.plans("e", lambda: ask(9))
+        assert stage.holds("e") == want[9]
+        assert stage.holds("d") == want[7]
+        assert stage.runs == [(sp.BATCH_WIDTH, False)] * 2
+        assert _batches(2) == [(2, False), (2, True)]
+    finally:
+        g.close()
+
+
+def test_a_lone_request_runs_the_narrow_program(monkeypatch):
+    """(v) Nobody stands at the lock: today's (n,) program, one dispatch
+    a request."""
+    g, people, planner, ask, _, want = _taught()
+    try:
+        stage = _Stage(planner, monkeypatch)
+        spilled, dispatched = _spill_count(), _dispatches()
+        stage.plans("a", lambda: ask(3))
+        assert stage.holds("a") == want[3]
+        assert stage.runs == [(0, False)]
+        assert (_spill_count(), _dispatches()) == (
+            spilled + 1, dispatched + 1)
+        assert _batches(1) == [(1, True)]
+    finally:
+        g.close()
+
+
+def test_the_first_spilled_request_of_a_shape_prepares_both_widths():
+    """The wide step is compiled where the narrow one is: by the first
+    spilled request of its shape on an executor, which runs alone."""
+    g, people, planner, ask, _, want = _taught()
+    try:
+        def widths():
+            return sorted(
+                dict(key[0][2])["width"] for key in planner._tpu_ex._prepared)
+
+        assert widths() == [0, sp.BATCH_WIDTH]
+        dispatched = _dispatches()
+        assert ask(1) == want[1]  # nothing more to prepare
+        assert widths() == [0, sp.BATCH_WIDTH]
+        assert _dispatches() == dispatched + 1
+
+        def three(i):
+            return g.traversal().V(people[i]).out("knows").out(
+                "knows").out("lives").count()
+
+        row = three(2)  # taught ...
+        assert three(2) == row  # ... and spilled: two steps on the device
+        assert widths() == sorted([0, sp.BATCH_WIDTH] * 3)
+    finally:
+        g.close()
+
+
+def test_incompatible_requests_run_alone_and_the_rest_still_batch(
+        monkeypatch):
+    """(ii) At the lock stand: `d`, planned against a snapshot a commit
+    has since replaced; `b`, whose transaction holds an uncommitted edge;
+    `c`, of another shape; `e` and `f`, plain. The holder `e` takes `f`
+    and nobody else; the others run alone, `d` on the refreshed
+    snapshot."""
+    from janusgraph_tpu.core.traversal import GraphTraversalSource
+
+    g, people, planner, ask, oracle, _ = _taught(
+        {"computer.spillover-max-staleness": 10_000})
+    try:
+        def other(i):
+            return g.traversal().V(people[i]).out("knows").out(
+                "lives").dedup().count()
+
+        planner.enabled = False
+        other_want = other(4)
+        planner.enabled = True
+        assert other(4) == other_want  # taught ...
+        assert other(4) == other_want  # ... spilled, its widths prepared
+        stage = _Stage(planner, monkeypatch)
+        stage.plans("d", lambda: ask(5))
+        old = planner._csr
+        tx = g.new_transaction()
+        tx.add_edge(tx.get_vertex(people[5]), "knows",
+                    tx.get_vertex(people[7]))
+        tx.commit()
+        want = oracle()
+        txb = g.new_transaction()
+        txb.add_edge(txb.get_vertex(people[6]), "knows",
+                     txb.get_vertex(people[9]))
+        src = GraphTraversalSource(g, txb)
+        planner.enabled = False
+        want_b = ask(6, src)
+        planner.enabled = True
+        stage.plans("b", lambda: ask(6, src))  # refreshes the snapshot
+        assert planner._csr is not old
+        stage.plans("c", lambda: other(4))
+        stage.plans("e", lambda: ask(1))
+        stage.plans("f", lambda: ask(2))
+        spilled, dispatched = _spill_count(), _dispatches()
+        assert stage.holds("e") == want[1]
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        assert len(planner._pending) == 3  # b, c, d still stand
+        assert stage.holds("f") == want[2]
+        assert _batches(2) == [(2, False), (2, True)]
+        # e overtook the three it left standing, all earlier than it
+        assert registry.runs("olap.spillover")[-1]["spillover"][
+            "overtook"] == 3
+        assert stage.holds("b") == want_b
+        assert stage.runs[-1] == (0, True)  # alone, on its patched snapshot
+        assert stage.holds("c") == other_want
+        assert stage.holds("d") == want[5]  # with the commit
+        # c's shape is the first of its kind on the refreshed snapshot's
+        # executor, so its wide step is prepared behind the narrow run;
+        # d's shape ran there already (e's dispatch)
+        assert stage.runs[2:] == [(0, False), (sp.BATCH_WIDTH, False),
+                                  (0, False)]
+        assert _spill_count() == spilled + 5
+        assert _dispatches() == dispatched + 4
+        assert _batches(3) == [(1, True)] * 3
+        txb.rollback()
+    finally:
+        g.close()
+
+
+@pytest.mark.parametrize("holder", ["earlier", "later"])
+def test_a_commit_between_two_arrivals(monkeypatch, holder):
+    """(vi) `x` plans, a commit lands, `y` arrives and its take refreshes
+    the snapshot. Whoever holds the lock first, no answer comes from a
+    snapshot older than the one a check after its arrival accepts: `x`
+    is planned again on the refreshed snapshot (as the holder, with `y`
+    riding; or alone, after `y` left it standing)."""
+    g, people, planner, ask, oracle, stale = _taught(
+        {"computer.spillover-max-staleness": 10_000})
+    try:
+        stage = _Stage(planner, monkeypatch)
+        stage.plans("x", lambda: ask(5))
+        tx = g.new_transaction()
+        tx.add_edge(tx.get_vertex(people[5]), "knows",
+                    tx.get_vertex(people[7]))
+        tx.add_edge(tx.get_vertex(people[7]), "knows",
+                    tx.get_vertex(people[10]))
+        tx.commit()
+        want = oracle()
+        assert want[5] != stale[5]
+        stage.plans("y", lambda: ask(7))
+        dispatched = _dispatches()
+        if holder == "earlier":
+            assert stage.holds("x") == want[5]
+            assert stage.holds("y") == want[7]
+            assert _dispatches() == dispatched + 1
+            assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        else:
+            assert stage.holds("y") == want[7]
+            assert len(planner._pending) == 1
+            assert stage.holds("x") == want[5]
+            assert _dispatches() == dispatched + 2
+            # y ran alone, the first of its shape on the refreshed
+            # snapshot's executor (the wide step prepared behind it)
+            assert [w for w, _ in stage.runs] == [0, sp.BATCH_WIDTH, 0]
+    finally:
+        g.close()
+
+
+@pytest.mark.parametrize("fault", ["count-overflow", "max-traversers"])
+def test_a_member_is_judged_alone(monkeypatch, fault):
+    """(iii) One member's column passes 2^24 (it falls back to the row
+    path), or its output passes `query.max-traversers` (it raises): the
+    others of the dispatch keep their answers."""
+    import numpy as np
+
+    from janusgraph_tpu.exceptions import QueryError
+
+    g, people, planner, ask, _, want = _taught()
+    try:
+        def ids(i):
+            return sorted(g.traversal().V(people[i]).out("knows").out(
+                "knows").id_().to_list())
+
+        planner.enabled = False
+        rows = [ids(i) for i in range(3)]
+        planner.enabled = True
+        assert ids(0) == rows[0] and ids(0) == rows[0]  # taught, spilled
+        stage = None
+
+        def run(csr, program, patched):
+            states = stage.real(csr, program, patched)
+            if fault == "count-overflow" and program.width:
+                counts = np.array(states["count"])
+                counts[0, 1] = float(1 << 24)  # b's column
+                states = {**states, "count": counts}
+            return states
+
+        stage = _Stage(planner, monkeypatch, run=run)
+        if fault == "max-traversers":
+            sizes = sorted(len(r) for r in rows)
+            assert sizes[1] < sizes[2]
+            monkeypatch.setattr(g, "_max_traversers", sizes[1])
+        fallbacks = _spill_count("olap.spillover.fallback")
+        spilled = _spill_count()
+        for name, i in zip("abc", range(3)):
+            stage.plans(name, lambda i=i: ids(i))
+        got = {"a": stage.holds("a")}
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        got.update(b=stage.holds("b"), c=stage.holds("c"))
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        if fault == "count-overflow":
+            assert [got[n] for n in "abc"] == rows
+            assert _spill_count() == spilled + 2
+            assert _spill_count("olap.spillover.fallback") == fallbacks + 1
+            assert registry.last_run("olap.spillover")["spillover"][
+                "fallback"] is None  # c's, the newest: spilled
+        else:
+            over = [len(r) > g._max_traversers for r in rows]
+            assert any(over) and not all(over)
+            for name, row, refused in zip("abc", rows, over):
+                if refused:
+                    assert isinstance(got[name], QueryError), got[name]
+                else:
+                    assert got[name] == row
+            assert _spill_count() == spilled + over.count(False)
+            assert _spill_count("olap.spillover.fallback") == fallbacks
+    finally:
+        g.close()
+
+
+def test_a_dispatch_that_raises_sends_every_member_to_the_row_path(
+        monkeypatch):
+    """(iv) The dispatch itself fails: every member falls back, each
+    counted, each answered by the row path."""
+    g, people, planner, ask, _, want = _taught()
+    try:
+        def run(csr, program, patched):
+            raise RuntimeError("staged device fault")
+
+        stage = _Stage(planner, monkeypatch, run=run)
+        fallbacks = _spill_count("olap.spillover.fallback")
+        errors = _spill_count("olap.spillover.fallback.error")
+        spilled, dispatched = _spill_count(), _dispatches()
+        for name, i in zip("abc", (0, 1, 5)):
+            stage.plans(name, lambda i=i: ask(i))
+        assert stage.holds("a") == want[0]
+        assert planner._pending == {}
+        assert stage.holds("b") == want[1]
+        assert stage.holds("c") == want[5]
+        assert stage.runs == [(sp.BATCH_WIDTH, False)]
+        assert _spill_count("olap.spillover.fallback") == fallbacks + 3
+        assert _spill_count("olap.spillover.fallback.error") == errors + 3
+        assert (_spill_count(), _dispatches()) == (spilled, dispatched)
+        reason = registry.last_run("olap.spillover")["spillover"]["fallback"]
+        assert reason.startswith("error:RuntimeError: staged device fault")
+        monkeypatch.undo()
+        assert planner.promotion_snapshot()[
+            sp.traversal_digest(g.traversal().V(people[0]).out(
+                "knows").out("knows").dedup())[1]]["fallbacks"] == 3
+    finally:
+        g.close()
+
+
+def test_concurrent_clients_each_get_their_own_answer():
+    """Unstaged: more clients than the wide start has columns, a short
+    switch interval, no barriers. Every answer is the row path's for its
+    own start, every request is counted once (the per-digest tally is
+    written from the clients' own threads), and nobody is left standing."""
+    import sys
+
+    g, people, planner, ask, _, want = _taught()
+    clients, rounds = sp.BATCH_WIDTH + 4, 12
+    digest = sp.traversal_digest(g.traversal().V(people[0]).out(
+        "knows").out("knows").dedup())[1]
+    tally = planner.promotion_snapshot()[digest]["spilled"]
+    spilled, dispatched = _spill_count(), _dispatches()
+    wrong, interval = [], sys.getswitchinterval()
+
+    def client(k):
+        for r in range(rounds):
+            i = (5 * k + r) % len(people)
+            got = ask(i)
+            if got != want[i]:
+                wrong.append((k, i, got, want[i]))
+
+    threads = [threading.Thread(target=client, args=(k,))
+               for k in range(clients)]
+    try:
+        sys.setswitchinterval(1e-5)
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(120)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(interval)
+        g.close()
+    assert wrong == []
+    assert _spill_count() == spilled + clients * rounds
+    assert planner.promotion_snapshot()[digest]["spilled"] == (
+        tally + clients * rounds)
+    assert dispatched < _dispatches() <= dispatched + clients * rounds
+    assert planner._pending == {} and planner._waiting == {}
+    assert max(r["spillover"]["batch"]
+               for r in registry.runs("olap.spillover")) <= sp.BATCH_WIDTH
