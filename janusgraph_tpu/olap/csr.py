@@ -764,3 +764,19 @@ def channel_edges(
     dst = np.concatenate(parts_dst) if parts_dst else np.empty(0, np.int64)
     w = np.concatenate(parts_w) if have_w and parts_w else None
     return src, dst, w
+
+
+def simple_closure(
+    n: int, src: np.ndarray, dst: np.ndarray
+) -> Tuple[np.ndarray, np.ndarray]:
+    """(lo, hi) of the simple undirected graph over an edge list: each
+    unordered pair of distinct vertices joined by some edge in either
+    direction, once, lo < hi, in rising (lo, hi) order, int64. The graph
+    as GAP's builder and Graphalytics' files read it: parallel edges once,
+    self loops dropped. The one builder of the intersection engine's
+    tables and of the frontier engine's simple view
+    (`TPUExecutor._simple_closure`)."""
+    src, dst = np.asarray(src, np.int64), np.asarray(dst, np.int64)
+    lo, hi = np.minimum(src, dst), np.maximum(src, dst)
+    key = np.unique((lo * n + hi)[lo != hi])
+    return key // n, key % n

@@ -34,6 +34,15 @@ order.
 Int32 throughout (the telescoping cumsum trick needs diff headroom, hence
 the ``m < 2**30`` eligibility guard — beyond that the executor keeps the
 dense path).
+
+Brandes (`run_brandes`, betweenness centrality) walks the same ladder with
+SUM where the programs above take MIN, K sources as the columns of `(n, K)`
+state, over the executor's SIMPLE closure (parallel edges once, loops
+dropped): a forward sweep sums path counts level by level, a backward sweep
+sums dependencies back over the same levels in reverse, each level at the
+tier its forward hop chose. Sums are float32 in the order the scatter or
+the pack's tree gives: within rounding of the float64 definition, not bit
+for bit.
 """
 
 from __future__ import annotations
@@ -46,6 +55,7 @@ from janusgraph_tpu.observability import tracer
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.kernels import (
     HybridPackView,
+    brandes_scope,
     frontier_scope,
     hybrid_fold,
     hybrid_gather,
@@ -55,6 +65,9 @@ from janusgraph_tpu.olap.kernels import (
 # dense program only because both use the IDENTICAL constant
 from janusgraph_tpu.olap.programs.shortest_path import INF
 from janusgraph_tpu.olap.vertex_program import Combiner, EdgeTransform
+
+#: the depth of a vertex a Brandes column has not reached
+UNSEEN = np.iinfo(np.int32).max
 
 
 def _tier(need: int, lo: int, hi: int, growth: int = 4) -> int:
@@ -112,7 +125,8 @@ def capped_expand(jnp, idx, indptr, dst, E_cap, sentinel):
 
 class FrontierEngine:
     """Per-executor engine: owns the device-resident CSR pointer arrays and
-    the tier-compiled step executables for ShortestPath-family programs."""
+    the tier-compiled step executables for ShortestPath-family programs
+    and for Brandes' sweeps (`run_brandes`)."""
 
     F_MIN = 1 << 10
     E_MIN = 1 << 13
@@ -410,6 +424,24 @@ class FrontierEngine:
         cache[key] = fn
         return fn
 
+    def _caps(self, count, edges, m, schedules):
+        """(F_cap, E_cap) of a hop whose frontier holds `count` vertices
+        and `edges` slots of an orientation of `m`: the autotuned ladders
+        `schedules` where the executor carries them, else the fixed
+        growth-factor ladder. `E_cap == m` is the top rung."""
+        f_schedule, e_schedule = schedules
+        if f_schedule and e_schedule:
+            from janusgraph_tpu.olap.autotune import pick_tier
+
+            return (
+                pick_tier(count, f_schedule, self.n),
+                pick_tier(max(edges, 1), e_schedule, m),
+            )
+        return (
+            _tier(count, self.F_MIN, self.n, self.GROWTH),
+            _tier(max(edges, 1), self.E_MIN, m, self.GROWTH),
+        )
+
     # ------------------------------------------------------------------- run
     def _hop_loop(
         self, value, pred, mask, weighted, track, und, fargs, max_iterations
@@ -434,19 +466,10 @@ class FrontierEngine:
                 count, tot_out, tot_in = (int(x) for x in planned)
                 if count == 0:
                     break
-                if self.f_schedule and self.e_schedule:
-                    from janusgraph_tpu.olap.autotune import pick_tier
-
-                    f_cap = pick_tier(count, self.f_schedule, self.n)
-                    e_cap = pick_tier(
-                        max(tot_out, tot_in, 1), self.e_schedule, self.m
-                    )
-                else:
-                    f_cap = _tier(count, self.F_MIN, self.n, self.GROWTH)
-                    e_cap = _tier(
-                        max(tot_out, tot_in, 1), self.E_MIN, self.m,
-                        self.GROWTH,
-                    )
+                f_cap, e_cap = self._caps(
+                    count, max(tot_out, tot_in), self.m,
+                    (self.f_schedule, self.e_schedule),
+                )
                 # the rule is the rung: a hop that would hold every edge
                 # is a wide round on the pack of the view it reads (built
                 # on first use, the dense path's own)
@@ -558,3 +581,227 @@ class FrontierEngine:
         )
         with tracer.phase("executor.fetch"):
             return {"component": np.asarray(labels)}
+
+    # --------------------------------------------------------------- brandes
+    def _simple_args(self):
+        """The executor's simple closure (`TPUExecutor._simple_closure`:
+        both orientations of each simple edge, by source) as ONE directed
+        orientation on the device: pointers padded to n + 2 as
+        `_orientation_args` pads them, neighbours, degrees. Built on first
+        use and kept; the plan reads `out_deg` as it reads a CSR's."""
+        args = self._fargs_cache.get("simple")
+        if args is None:
+            jnp, n = self.jnp, self.n
+            src, dst = self.ex._simple_closure()
+            deg = np.bincount(src, minlength=n)
+            indptr = np.zeros(n + 2, np.int64)
+            np.cumsum(deg, out=indptr[1:n + 1])
+            indptr[n + 1] = indptr[n]
+            args = {
+                "out_ip": jnp.asarray(indptr.astype(np.int32)),
+                "out_dst": jnp.asarray(dst.astype(np.int32)),
+                "out_deg": jnp.asarray(deg.astype(np.int32)),
+            }
+            self._fargs_cache["simple"] = args
+        return args
+
+    def _brandes_fn(self, sweep, F_cap, E_cap, K):
+        """One level of a Brandes sweep over the simple closure, K sources
+        as columns, at one tier; `E_cap == 0` is the wide round on the
+        simple closure's pack. A level's senders in column k are the
+        vertices at depth `level` of source k; the tier is sized from the
+        UNION of the K frontiers, and a sender in the union but not in
+        column k sends 0 there.
+
+          forward  `fn(depth, sigma, mask, t, args)`: each neighbour sums
+                   sigma over its senders at depth t; a vertex first
+                   reached gets depth t + 1 and that sum (sigma > 0 on
+                   every reached vertex, so a positive sum is "reached").
+                   Returns the new (depth, sigma, union of the newly
+                   reached, its count).
+          backward `fn(depth, sigma, delta, d, args)`: each neighbour sums
+                   (1 + delta[w]) / sigma[w] over its senders w at depth
+                   d; a vertex at depth d - 1 takes delta = sigma x sum,
+                   every term positive. Returns delta.
+
+        The narrow step compacts the union (`capped_expand`) and
+        scatter-adds rows of K words; the wide one reads every slot of the
+        pack with ONE `hybrid_gather` of the `(n, K)` messages and folds
+        with `Combiner.SUM`. Named `brandes_forward` / `brandes_backward`
+        (modules `jit_brandes_*`), each under its scope `brandes.<sweep>`
+        with the hop's `frontier.*` stages inside."""
+        key = ("brandes", sweep, F_cap, E_cap, K)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        jnp = self.jnp
+        n = self.n
+        f32 = jnp.float32
+        pack = (
+            self.ex._hybrid_pack(self.ex.SIMPLE_VIEW) if E_cap == 0 else None
+        )
+
+        def senders(depth, level, value):
+            """Per vertex and column the value where depth is `level`."""
+            return jnp.where(depth == level, value, f32(0.0))
+
+        def relax(args, mask, sent):
+            """What each vertex receives, summed: (n, K)."""
+            if pack is not None:
+                view = HybridPackView(args, pack)
+                with frontier_scope("relax"):
+                    leaves = hybrid_gather(jnp, view, sent, Combiner.SUM)
+                with frontier_scope("scatter"):
+                    return hybrid_fold(
+                        jnp, view, leaves, Combiner.SUM, sent.shape,
+                        sent.dtype,
+                    )
+            with frontier_scope("expand"):
+                idx = jnp.nonzero(mask, size=F_cap, fill_value=n)[0]
+                idx = idx.astype(jnp.int32)
+                own, _, nbr, valid = capped_expand(
+                    jnp, idx, args["out_ip"], args["out_dst"], E_cap, n
+                )
+            with frontier_scope("relax"):
+                rows = sent[jnp.clip(idx, 0, n - 1)]  # (F_cap, K)
+                msg = jnp.where(valid[:, None], rows[own], f32(0.0))
+            with frontier_scope("scatter"):
+                return jnp.zeros((n + 1, K), f32).at[nbr].add(msg)[:n]
+
+        if sweep == "forward":
+            def brandes_forward(depth, sigma, mask, t, args):
+                with brandes_scope("forward"):
+                    got = relax(args, mask, senders(depth, t, sigma))
+                    with frontier_scope("scatter"):
+                        newly = (depth == UNSEEN) & (got > 0.0)
+                        depth = jnp.where(newly, t + 1, depth)
+                        sigma = jnp.where(newly, got, sigma)
+                        mask = jnp.any(newly, axis=1)
+                        return (
+                            depth, sigma, mask,
+                            jnp.sum(mask.astype(jnp.int32)),
+                        )
+
+            fn = self.jax.jit(brandes_forward)
+        else:
+            def brandes_backward(depth, sigma, delta, d, args):
+                with brandes_scope("backward"):
+                    on = depth == d
+                    with frontier_scope("expand"):
+                        mask = jnp.any(on, axis=1)
+                    with frontier_scope("relax"):
+                        share = (1.0 + delta) / jnp.where(on, sigma, 1.0)
+                    got = relax(args, mask, senders(depth, d, share))
+                    with frontier_scope("scatter"):
+                        return jnp.where(depth == d - 1, sigma * got, delta)
+
+            fn = self.jax.jit(brandes_backward)
+        cache[key] = fn
+        return fn
+
+    def _brandes_total_fn(self):
+        """delta summed over the columns: the scores. Named like the
+        backward levels, whose end it is (module `jit_brandes_backward`)."""
+        key = ("brandes-total",)
+        cache = self.ex._compiled
+        if key not in cache:
+            jnp = self.jnp
+
+            def brandes_backward(delta):
+                with brandes_scope("backward"):
+                    return jnp.sum(delta, axis=1)
+
+            cache[key] = self.jax.jit(brandes_backward)
+        return cache[key]
+
+    def run_brandes(self, program) -> Dict[str, np.ndarray]:
+        """Brandes from `program.sources`, as the K columns of one run,
+        over the executor's simple closure (ONE directed orientation of
+        both orientations of each simple edge).
+
+        Forward: per hop t the plan's three scalars price the UNION of the
+        columns' frontiers (depth == t) and pick the tier as `_hop_loop`
+        does, on this view's ladders; a top-rung hop is a wide round on
+        the simple closure's pack. The sweep ends at the first empty
+        union, so hop L (the deepest level) reaches nothing. Backward,
+        for d = L ... 2, reuses hop d's tier with no plan and no host sync
+        between levels: the union at depth d is hop d's frontier. Level 1
+        would write delta of depth 0 alone, each source's own, which is
+        left out (Brandes' definition), so it is not run.
+
+        The host's time is tiled by phases: `executor.setup` (the start
+        vectors), `executor.dispatch` (per forward hop the plan, its
+        `executor.sync`, the tier choice and the step; every backward
+        level), `executor.sync`, `executor.fetch`. `last_trace` lists
+        every level run, forward then backward, with its sweep."""
+        jax, jnp = self.jax, self.jnp
+        n = self.n
+        sources = np.asarray(program.sources, np.int64)
+        K = len(sources)
+        if ((sources < 0) | (sources >= n)).any():
+            raise ValueError(
+                f"BetweennessCentralityProgram: sources {program.sources} "
+                f"are not all vertex indices of a graph of {n} vertices"
+            )
+        columns = np.arange(K)
+        with tracer.phase("executor.setup"):
+            depth = np.full((n, K), UNSEEN, np.int32)
+            depth[sources, columns] = 0
+            sigma = np.zeros((n, K), np.float32)
+            sigma[sources, columns] = 1.0
+            mask = np.zeros(n, bool)
+            mask[sources] = True
+            depth, sigma, mask = (
+                jnp.asarray(depth), jnp.asarray(sigma), jnp.asarray(mask)
+            )
+            args = self._simple_args()
+            m = int(args["out_dst"].shape[0])
+            decision = self.ex._autotune(self.ex.SIMPLE_VIEW)
+            schedules = (decision.f_schedule, decision.e_schedule)
+        plan = self._plan_fn(False)
+        trace = []
+        for t in range(n if m else 0):
+            with tracer.phase("executor.dispatch"):
+                planned = plan(mask, args)
+                with tracer.phase("executor.sync"):
+                    planned = jax.device_get(planned)
+                count, edges, _ = (int(x) for x in planned)
+                if count == 0:
+                    break
+                f_cap, e_cap = self._caps(count, edges, m, schedules)
+                wide = e_cap == m
+                pack = (
+                    self.ex._hybrid_pack(self.ex.SIMPLE_VIEW) if wide else None
+                )
+                trace.append(
+                    {"sweep": "forward", "hop": t, "frontier": count,
+                     "edges": edges, "relaxed_slots": edges,
+                     "tier_slots": pack.slots if wide else e_cap,
+                     "F_cap": f_cap, "E_cap": e_cap, "wide": wide}
+                )
+                fn = self._brandes_fn(
+                    "forward", f_cap, 0 if wide else e_cap, K
+                )
+                depth, sigma, mask, _ = fn(
+                    depth, sigma, mask, jnp.asarray(t, jnp.int32),
+                    pack.arrays if wide else args,
+                )
+        delta = jnp.zeros((n, K), jnp.float32)
+        with tracer.phase("executor.dispatch"):
+            for hop in trace[:1:-1]:  # levels L ... 2
+                fn = self._brandes_fn(
+                    "backward", hop["F_cap"],
+                    0 if hop["wide"] else hop["E_cap"], K,
+                )
+                delta = fn(
+                    depth, sigma, delta, jnp.asarray(hop["hop"], jnp.int32),
+                    self.ex._hybrid_pack(self.ex.SIMPLE_VIEW).arrays
+                    if hop["wide"] else args,
+                )
+                trace.append(dict(hop, sweep="backward"))
+            scores = self._brandes_total_fn()(delta)
+        with tracer.phase("executor.sync"):
+            await_arrays((scores,))
+        self.last_trace = trace
+        with tracer.phase("executor.fetch"):
+            return {"betweenness": np.asarray(scores)}

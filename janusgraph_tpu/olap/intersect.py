@@ -52,6 +52,7 @@ from typing import Dict
 import numpy as np
 
 from janusgraph_tpu.observability import tracer
+from janusgraph_tpu.olap.csr import simple_closure
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.frontier import capped_expand
 from janusgraph_tpu.olap.kernels import intersect_scope
@@ -144,10 +145,8 @@ class IntersectView:
 
     def __init__(self, n, src, dst, widths, word_ns, candidate_ns,
                  table_bytes_limit, class_floor):
-        lo, hi = np.minimum(src, dst), np.maximum(src, dst)
-        key = np.unique((lo * n + hi)[lo != hi])  # loops out, parallels once
-        lo, hi = key // n, key % n
-        self.simple_edges = int(len(key))
+        lo, hi = simple_closure(n, src, dst)  # loops out, parallels once
+        self.simple_edges = int(len(lo))
         degree = np.bincount(lo, minlength=n) + np.bincount(hi, minlength=n)
         if len(degree) and degree.max() >= MAX_DEGREE:
             raise ValueError(

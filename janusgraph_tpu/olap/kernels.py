@@ -283,6 +283,15 @@ def frontier_scope(name: str):
     return jax.named_scope("frontier." + name)
 
 
+def brandes_scope(name: str):
+    """`jax.named_scope("brandes.<name>")` around a hop of the frontier
+    engine's Brandes sweeps (forward / backward; the hop's `frontier.*`
+    stages nest inside): `frontier_scope`'s sibling."""
+    import jax
+
+    return jax.named_scope("brandes." + name)
+
+
 def intersect_scope(name: str):
     """`jax.named_scope("lcc.<name>")` around a stage of the intersection
     engine's compiled pass (expand / intersect / credit, none enclosing

@@ -9,6 +9,9 @@ from janusgraph_tpu.olap.programs.traversal_count import (  # noqa: F401
 from janusgraph_tpu.olap.programs.peer_pressure import PeerPressureProgram  # noqa: F401
 from janusgraph_tpu.olap.programs.cdlp import CDLPProgram  # noqa: F401
 from janusgraph_tpu.olap.programs.lcc import LCCProgram  # noqa: F401
+from janusgraph_tpu.olap.programs.betweenness import (  # noqa: F401
+    BetweennessCentralityProgram,
+)
 from janusgraph_tpu.olap.programs.olap_traversal import (  # noqa: F401
     OLAPTraversalProgram,
     TraversalStep,

@@ -321,8 +321,10 @@ class TPUExecutor:
         # aggregator's monoid inline; the fused path needs the full pytree
         # + identities BEFORE the first compiled dispatch)
         self._metric_ops: Dict[Tuple, Dict[str, str]] = {}
-        # the pack of each edge view, keyed by `undirected`
-        self._hybrid_packs: Dict[bool, object] = {}
+        # the pack of each edge view, keyed by `undirected` or SIMPLE_VIEW
+        self._hybrid_packs: Dict[object, object] = {}
+        # (src, dst) of the simple closure, built on first use
+        self._simple = None
         # per-orientation row-destination vectors for the dense tier's
         # fused SDDMM pass (features/kernels.hybrid_row_dsts)
         self._sddmm_rows_cache: Dict[bool, object] = {}
@@ -386,10 +388,12 @@ class TPUExecutor:
             "tier_growth": self._frontier_tier_growth,
         }
 
-    def _autotune(self, undirected: bool, measured: dict = None):
-        """The (cached) AutotuneDecision for one edge view (and, for dense
-        runs, one feature tier). Deterministic given (graph stats, device
-        kind, config, persisted measurement): olap/autotune.decide."""
+    def _autotune(self, undirected, measured: dict = None):
+        """The (cached) AutotuneDecision for one edge view (`undirected`,
+        or SIMPLE_VIEW: its pack's sizes and its frontier ladders) and, for
+        dense runs, one feature tier. Deterministic given (graph stats,
+        device kind, config, persisted measurement):
+        olap/autotune.decide."""
         key = (undirected, self._feature_dim_run)
         decision = self._autotune_decisions.get(key)
         if decision is not None and measured is None:
@@ -402,9 +406,16 @@ class TPUExecutor:
             measured = autotune.load_measured(
                 self._measured_path, shard_count=1
             )
-        stats = autotune.GraphStats.from_csr(
-            self.csr, undirected=undirected, **self._stats_kwargs()
-        )
+        if undirected == self.SIMPLE_VIEW:
+            src, dst = self._simple_closure()
+            stats = autotune.GraphStats.from_degrees(
+                np.bincount(dst, minlength=self.csr.num_vertices), len(src),
+                False, **self._stats_kwargs(),
+            )
+        else:
+            stats = autotune.GraphStats.from_csr(
+                self.csr, undirected=undirected, **self._stats_kwargs()
+            )
         decision = self._decide(stats, measured)
         self._autotune_decisions[key] = decision
         return decision
@@ -429,10 +440,33 @@ class TPUExecutor:
             feature_dim=self._feature_dim_run,
         )
 
-    def _edge_view(self, undirected: bool):
+    #: the key of the simple closure's view (`_simple_closure`) where a
+    #: view is keyed by `undirected`
+    SIMPLE_VIEW = "simple"
+
+    def _simple_closure(self):
+        """(src, dst) of the simple undirected closure, int64: both
+        orientations of each simple edge (`csr.simple_closure`: parallel
+        edges once, loops dropped), by source, so that it is also the
+        out-CSR of ONE directed orientation (the frontier engine's
+        Brandes view). Built on first use and kept."""
+        if self._simple is None:
+            from janusgraph_tpu.olap.csr import simple_closure
+
+            src, dst, _ = self._edge_view(False)
+            lo, hi = simple_closure(self.csr.num_vertices, src, dst)
+            src, dst = np.concatenate([lo, hi]), np.concatenate([hi, lo])
+            order = np.argsort(src, kind="stable")
+            self._simple = (src[order], dst[order])
+        return self._simple
+
+    def _edge_view(self, undirected):
         """(src, dst, w) edge arrays for one orientation view — the single
         assembly shared by the pack builders and the sddmm row-dst
-        builders, so their layouts can never disagree."""
+        builders, so their layouts can never disagree. SIMPLE_VIEW is the
+        simple closure's, weightless."""
+        if undirected == self.SIMPLE_VIEW:
+            return (*self._simple_closure(), None)
         csr = self.csr
         src = csr.in_src.astype(np.int64)
         dst = _segment_ids(csr.in_indptr, csr.num_edges).astype(np.int64)
@@ -468,9 +502,10 @@ class TPUExecutor:
             self._sddmm_rows_cache[undirected] = rows
         return rows
 
-    def _hybrid_pack(self, undirected: bool):
-        """HybridPack for one edge view, with the tuner's (or configured)
-        hub cutoff + tail chunk. Built and device-put once."""
+    def _hybrid_pack(self, undirected):
+        """HybridPack for one edge view (`undirected`, or SIMPLE_VIEW),
+        with the tuner's (or configured) hub cutoff + tail chunk. Built and
+        device-put once."""
         pack = self._hybrid_packs.get(undirected)
         if pack is None:
             pack = self._build_hybrid(
@@ -983,7 +1018,14 @@ class TPUExecutor:
             self._intersect_family(program)
             and mode != "off" and not checkpoint_path
         )
-        if not (use_frontier or use_intersect):
+        # nor have Brandes' two sweeps (the frontier engine's, under
+        # ShortestPath's guards); refused below where they do not run
+        use_brandes = (
+            self._brandes_family(program)
+            and mode != "off" and not checkpoint_path
+            and self._brandes_eligible()
+        )
+        if not (use_frontier or use_intersect or use_brandes):
             program.require_dense_capable(
                 "the dense superstep path of the single-device executor"
             )
@@ -992,6 +1034,7 @@ class TPUExecutor:
         use_fused = (
             not use_frontier
             and not use_intersect
+            and not use_brandes
             and fused
             and type(program).combiner_for is VertexProgram.combiner_for
         )
@@ -1016,6 +1059,8 @@ class TPUExecutor:
                         out = self._run_frontier(program)
                     elif use_intersect:
                         out = self._run_intersect(program)
+                    elif use_brandes:
+                        out = self._run_brandes(program)
                     elif use_fused:
                         out = self._run_fused(
                             program, checkpoint_path, checkpoint_every,
@@ -1094,6 +1139,10 @@ class TPUExecutor:
             sum(np.asarray(v).nbytes for v in result.values())
         )
         undirected = bool(getattr(program, "undirected", False))
+        # the edge view the run's pack and tiers are of
+        view = (
+            self.SIMPLE_VIEW if info.get("path") == "brandes" else undirected
+        )
         pad_ratio = None
         # a channel-switching program's packs are its channels' own, the
         # largest first; any other program's is its edge view's
@@ -1105,7 +1154,7 @@ class TPUExecutor:
             ),
             key=lambda entry: -entry[0].slots,
         )
-        hyb = self._hybrid_packs.get(undirected)
+        hyb = self._hybrid_packs.get(view)
         if channel_packs:
             pad_ratio = round(
                 sum(p.slots for p, _d in channel_packs)
@@ -1118,7 +1167,7 @@ class TPUExecutor:
         # record and observability/benchdiff.py carry it too
         info["ell_pad_ratio"] = pad_ratio
         info["pad_ratio"] = pad_ratio
-        if info.get("path") not in ("frontier", "intersect"):
+        if info.get("path") not in ("frontier", "intersect", "brandes"):
             # every dense superstep aggregates over the hybrid pack
             info["strategy_resolved"] = "hybrid"
         # the combiner(s) the run folded with; a MODE run also says what
@@ -1141,7 +1190,7 @@ class TPUExecutor:
         if info.get("path") != "intersect":
             decision = (
                 channel_packs[0][1] if channel_packs
-                else self._autotune(undirected)
+                else self._autotune(view)
             )
             info["autotune"] = decision.as_dict()
 
@@ -1296,7 +1345,7 @@ class TPUExecutor:
             registry.counter("olap.intersect.probe_slots").inc(
                 info["probe_slots"]
             )
-        if info.get("path") == "frontier":
+        if info.get("path") in ("frontier", "brandes"):
             registry.counter("olap.frontier.rounds").inc(info["rounds"])
             registry.counter("olap.frontier.relaxed_slots").inc(
                 info["relaxed_slots"]
@@ -1407,24 +1456,27 @@ class TPUExecutor:
             )
         return False
 
-    def _run_frontier(self, program: VertexProgram) -> Dict[str, np.ndarray]:
-        import time
-
-        from janusgraph_tpu.olap.frontier import FrontierEngine
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-
+    def _frontier(self):
+        """The frontier engine, made on first use."""
         if self._frontier_engine is None:
+            from janusgraph_tpu.olap.frontier import FrontierEngine
+
             # the tier-schedule half of the decision: computed before the
             # engine snapshots it (aggregation half unused here)
             self._autotune(False)
             self._frontier_engine = FrontierEngine(self)
+        return self._frontier_engine
+
+    def _run_frontier(self, program: VertexProgram) -> Dict[str, np.ndarray]:
+        from janusgraph_tpu.olap.programs.connected_components import (
+            ConnectedComponentsProgram,
+        )
+
         t0 = time.perf_counter()
         if type(program) is ConnectedComponentsProgram:
-            out = self._frontier_engine.run_cc(program)
+            out = self._frontier().run_cc(program)
         else:
-            out = self._frontier_engine.run(program)
+            out = self._frontier().run(program)
         trace = getattr(self._frontier_engine, "last_trace", [])
         self.last_run_info = {
             "path": "frontier",
@@ -1480,6 +1532,50 @@ class TPUExecutor:
                 "step": 0, "wall_ms": round(wall_s * 1000.0, 4),
                 "edges": view.simple_edges,
             }],
+        }
+        return out
+
+    @staticmethod
+    def _brandes_family(program: VertexProgram) -> bool:
+        from janusgraph_tpu.olap.programs.betweenness import (
+            BetweennessCentralityProgram,
+        )
+
+        return type(program) is BetweennessCentralityProgram
+
+    def _brandes_eligible(self) -> bool:
+        """ShortestPath's guards: int32 expansion, and |V| < 2^24."""
+        from janusgraph_tpu.olap.frontier import FrontierEngine
+
+        return (
+            self.csr.num_edges < FrontierEngine.MAX_EDGES
+            and self.csr.num_vertices < (1 << 24)
+        )
+
+    def _run_brandes(self, program: VertexProgram) -> Dict[str, np.ndarray]:
+        """`BetweennessCentralityProgram` on the frontier engine's two
+        sweeps over the simple closure (`FrontierEngine.run_brandes`)."""
+        t0 = time.perf_counter()
+        out = self._frontier().run_brandes(program)
+        trace = self._frontier_engine.last_trace
+        forward = [t for t in trace if t["sweep"] == "forward"]
+        self.last_run_info = {
+            "path": "brandes",
+            "supersteps": len(trace),
+            "wall_s": round(time.perf_counter() - t0, 4),
+            "tiers": trace,
+            "sources": list(program.sources),
+            # the deepest level any column reached (the last forward hop
+            # reaches nothing); the backward sweep runs levels L ... 2
+            "levels": max(len(forward) - 1, 0),
+            "forward_rounds": len(forward),
+            "backward_rounds": len(trace) - len(forward),
+            # totals a layer metric can read, as the frontier path's
+            "rounds": len(trace),
+            "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
+            "tier_slots": sum(t["tier_slots"] for t in trace),
+            "wide_rounds": sum(t["wide"] for t in trace),
+            "closure_slots": len(self._simple_closure()[0]),
         }
         return out
 
