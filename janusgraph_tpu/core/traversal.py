@@ -2698,11 +2698,14 @@ class GraphTraversal:
         )
 
     def connected_component(
-        self, key: str = "component", iterations: int = 200
+        self, key: str = "component", iterations: Optional[int] = None
     ) -> "GraphTraversal":
         """TinkerPop connectedComponent() step: the component id is the
         smallest member VERTEX ID (stable across runs, like the
-        reference's smallest-element-id convention)."""
+        reference's smallest-element-id convention). `iterations=None`
+        runs to the fixpoint (Afforest on the frontier engine where the
+        graph is large enough); an integer cuts propagation after that
+        many supersteps (`ConnectedComponentsProgram`)."""
         from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
 
         return self._olap_annotate(

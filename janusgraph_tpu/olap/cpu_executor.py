@@ -239,7 +239,8 @@ class CPUExecutor:
         import time as _time
 
         records = []
-        for step in range(start_step, program.max_iterations):
+        cap = program.iteration_cap(g.num_vertices)
+        for step in range(start_step, cap):
             if fault_hook is not None:
                 fault_hook(step)
             _s0 = _time.perf_counter()
@@ -366,7 +367,7 @@ class CPUExecutor:
                 checkpoint_every
             ) and (
                 steps_done % checkpoint_every == 0
-                or steps_done == program.max_iterations
+                or steps_done == cap
             ):
                 _ck0 = _time.perf_counter()
                 if shard_checkpoint_dir:

@@ -43,6 +43,17 @@ sums dependencies back over the same levels in reverse, each level at the
 tier its forward hop chose. Sums are float32 in the order the scatter or
 the pack's tree gives: within rounding of the float64 definition, not bit
 for bit.
+
+Connected components (`run_cc`) are GAP's Afforest (Sutton, Ben-Nun,
+Barak, IPDPS 2018) over the same simple closure, in two executables: the
+SAMPLE links every vertex with its first two neighbours, takes the most
+frequent root among 1,024 vertices as the giant component's, and marks the
+vertices outside it; the FINISH links only those vertices' rows, compacted
+on the closure's tier ladder, and never reads the rest of the giant's
+edges. A link hangs the larger of an edge's two roots under the smaller
+and pointer jumping compresses the forest, so every label only falls and
+each component's root is its smallest index: the labels of min-label
+propagation, which the dense path, `CPUExecutor` and the mesh still run.
 """
 
 from __future__ import annotations
@@ -55,10 +66,12 @@ from janusgraph_tpu.observability import tracer
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.kernels import (
     HybridPackView,
+    afforest_scope,
     brandes_scope,
     frontier_scope,
     hybrid_fold,
     hybrid_gather,
+    row_take,
 )
 
 # the reached-ness tests below ("dist >= INF") are parity-equivalent to the
@@ -123,16 +136,77 @@ def capped_expand(jnp, idx, indptr, dst, E_cap, sentinel):
     return own, pos, nbr, valid
 
 
+# graphlint: traced -- Afforest's pointer jumping, inside both executables
+def _compress(jnp, lax, comp):
+    """Pointer jumping to the fixpoint, `comp = comp[comp]` until nothing
+    moves, in one `lax.while_loop`: every vertex then points at its root.
+    Returns (comp, jumps); the last jump is the one that moved nothing."""
+
+    def jump(carry):
+        comp, _, jumps = carry
+        nxt = row_take(jnp, comp, comp)
+        return nxt, jnp.any(nxt != comp), jumps + 1
+
+    comp, _, jumps = lax.while_loop(
+        lambda carry: carry[1], jump, (comp, jnp.bool_(True), jnp.int32(0))
+    )
+    return comp, jumps
+
+
+# graphlint: traced -- Afforest's link, inside both executables
+def _link(jnp, lax, comp, u, v, valid):
+    """Afforest's link of the edges (u, v) where `valid`, over a forest in
+    which every vertex points at its root (`comp`, int32; `u` None means
+    each vertex its own end, `comp[u] == comp`). Bulk-synchronous where
+    GAP loops a compare-and-swap per edge: a pass reads both ends' roots,
+    scatter-mins the smaller onto the larger (a root only ever falls, so
+    the forest stays acyclic and a component's root is its smallest
+    index) and compresses; passes repeat until every edge's ends share a
+    root. Returns (comp, passes, jumps): passes that hooked, jumps of
+    their compressions."""
+    n = comp.shape[0]
+
+    def roots(comp):
+        ru = comp if u is None else row_take(jnp, comp, u)
+        return ru, row_take(jnp, comp, v)
+
+    def hook(carry):
+        comp, ru, rv, _, passes, jumps = carry
+        live = valid & (ru != rv)
+        comp = comp.at[jnp.where(live, jnp.maximum(ru, rv), n)].min(
+            jnp.minimum(ru, rv), mode="drop"
+        )
+        comp, j = _compress(jnp, lax, comp)
+        ru, rv = roots(comp)
+        return (comp, ru, rv, jnp.any(valid & (ru != rv)), passes + 1,
+                jumps + j)
+
+    ru, rv = roots(comp)
+    zero = jnp.int32(0)
+    comp, _, _, _, passes, jumps = lax.while_loop(
+        lambda carry: carry[3], hook,
+        (comp, ru, rv, jnp.any(valid & (ru != rv)), zero, zero),
+    )
+    return comp, passes, jumps
+
+
 class FrontierEngine:
     """Per-executor engine: owns the device-resident CSR pointer arrays and
-    the tier-compiled step executables for ShortestPath-family programs
-    and for Brandes' sweeps (`run_brandes`)."""
+    the tier-compiled step executables for ShortestPath-family programs,
+    for Brandes' sweeps (`run_brandes`) and for Afforest (`run_cc`)."""
 
     F_MIN = 1 << 10
     E_MIN = 1 << 13
     GROWTH = 4
     #: int32 telescoping headroom (see module docstring)
     MAX_EDGES = 1 << 30
+    #: Afforest's sample (GAP's `neighbor_rounds`): each vertex links the
+    #: neighbours at these positions of its closure row, which holds its
+    #: higher neighbours and then its lower ones (GAP's rows are sorted)
+    SAMPLE_ROUNDS = 2
+    #: vertices the giant's root is read from (GAP's `num_samples`), at a
+    #: fixed stride where GAP draws them
+    SAMPLE_SIZE = 1024
 
     def __init__(self, executor):
         self.ex = executor
@@ -325,8 +399,8 @@ class FrontierEngine:
         executor's pack of the view the hop reads: the frontier's messages
         as an n-vector (INF off the frontier, so those slots relax
         nothing), ONE gather over every slot with the weight added in
-        flight where the narrow step adds it (`add_weight`; never for
-        labels), a min tree, and the narrow step's tail. The call is the
+        flight where the narrow step adds it (`add_weight`: a weighted
+        search on a graph that has weights), a min tree, and the narrow step's tail. The call is the
         narrow step's with the pack's arrays in place of `fargs`,
         `fn(dist, pred, mask, t, pack.arrays)`: arguments, not constants
         (`TPUExecutor._graph_args`' reason). The function is named `step`
@@ -556,31 +630,172 @@ class FrontierEngine:
                 out["predecessor"] = np.asarray(pred)
         return out
 
+    # -------------------------------------------------------------- afforest
+    def _afforest_sample_fn(self):
+        """`afforest_sample(args) -> (comp, labels, mask, count, slots,
+        passes, jumps, giant)` over the simple closure (module
+        `jit_afforest_sample`, scope `afforest.sample`). For r <
+        SAMPLE_ROUNDS every vertex of degree above r links the neighbour at
+        position r of its row, to the fixpoint; then the most frequent
+        root among SAMPLE_SIZE vertices at a fixed stride is the giant's
+        (the first of equals). `mask` marks the vertices with an edge
+        outside the giant, `count` and `slots` price them as `_plan_fn`
+        prices a frontier, `giant` counts the vertices under its root.
+        `labels` is `comp` as float32, the answer when no finish runs."""
+        key = ("afforest-sample",)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        jnp, lax = self.jnp, self.jax.lax
+        n = self.n
+        picks = (
+            np.arange(self.SAMPLE_SIZE, dtype=np.int64) * n // self.SAMPLE_SIZE
+        ).astype(np.int32)
+
+        def afforest_sample(args):
+            with afforest_scope("sample"):
+                m = args["out_dst"].shape[0]
+                starts, deg = args["out_ip"][:n], args["out_deg"]
+                me = jnp.arange(n, dtype=jnp.int32)
+                comp, passes, jumps = me, jnp.int32(0), jnp.int32(0)
+                for r in range(self.SAMPLE_ROUNDS):
+                    has = deg > r
+                    at = jnp.clip(starts + r, 0, m - 1)
+                    nbr = jnp.where(has, args["out_dst"][at], me)
+                    comp, p, j = _link(jnp, lax, comp, None, nbr, has)
+                    passes, jumps = passes + p, jumps + j
+                seen = comp[picks]
+                votes = jnp.sum(
+                    (seen[:, None] == seen[None, :]).astype(jnp.int32), axis=1
+                )
+                giant = seen[jnp.argmax(votes)]
+                mask = (comp != giant) & (deg > 0)
+                return (
+                    comp, comp.astype(jnp.float32), mask,
+                    jnp.sum(mask.astype(jnp.int32)),
+                    jnp.sum(jnp.where(mask, deg, 0)),
+                    passes, jumps, jnp.sum((comp == giant).astype(jnp.int32)),
+                )
+
+        fn = self.jax.jit(afforest_sample)
+        cache[key] = fn
+        return fn
+
+    def _afforest_finish_fn(self, F_cap, E_cap):
+        """`afforest_finish(comp, mask, args) -> (labels, passes, jumps)`
+        (module `jit_afforest_finish`, scope `afforest.finish`): the rows
+        of the vertices in `mask` linked to the fixpoint, labels as
+        float32. Narrow: the rows compacted to F_cap vertices and E_cap
+        slots (`capped_expand`); a row is read whole, its positions 0 and
+        1 again too, whose ends the sample gave one root (GAP skips them:
+        no pass differs). `E_cap == 0` is the ladder's top rung:
+        every slot of the closure, its sender read off the pointers as
+        `_parent_fn` reads it. An edge there is linked from both ends, or
+        from neither where both lie in the giant, whose ends share a root
+        already: the same passes as the narrow form's, and no pack, since
+        a link is no fold by destination."""
+        key = ("afforest-finish", F_cap, E_cap)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        jnp, lax = self.jnp, self.jax.lax
+        n = self.n
+
+        def afforest_finish(comp, mask, args):
+            with afforest_scope("finish"):
+                ip, dst = args["out_ip"], args["out_dst"]
+                m = dst.shape[0]
+                if E_cap == 0:
+                    u = jnp.cumsum(
+                        jnp.zeros((m,), jnp.int32).at[ip[1:n]].add(
+                            1, mode="drop")
+                    )
+                    v, valid = dst, jnp.ones((m,), bool)
+                else:
+                    idx = jnp.nonzero(mask, size=F_cap, fill_value=n)[0]
+                    idx = idx.astype(jnp.int32)
+                    own, _, v, valid = capped_expand(
+                        jnp, idx, ip, dst, E_cap, n
+                    )
+                    u = jnp.minimum(idx[own], n - 1)
+                    v = jnp.minimum(v, n - 1)
+                comp, passes, jumps = _link(jnp, lax, comp, u, v, valid)
+                return comp.astype(jnp.float32), passes, jumps
+
+        fn = self.jax.jit(afforest_finish)
+        cache[key] = fn
+        return fn
+
     def run_cc(self, program) -> Dict[str, np.ndarray]:
-        """Frontier-compacted connected components: min-LABEL propagation
-        with a changed-vertex frontier. Reuses the weighted-relaxation step
-        (message = sender's value, scatter-min, changed mask) — labels
-        propagate exactly like distances with zero edge weight. Late
-        supersteps touch a shrinking frontier, so fixpoint convergence
-        costs far less than |E| per superstep (the dense path's price).
-        Per-step parity with the dense BSP path: an unchanged vertex's
-        label was already absorbed by its neighbors when it last changed.
-        Labels ride float32 (exact below 2^24 — eligibility-guarded)."""
+        """Connected components to the fixpoint by Afforest over the
+        executor's simple closure: the sample (one executable, then ONE
+        host sync for its five scalars), the finish's tier from its
+        vertices and slots on the closure's ladder (`_caps`), the finish
+        (none where no vertex outside the giant has an edge), one fetch
+        of the labels and the finish's counters. Labels are float32 vertex
+        indices (exact below 2^24: eligibility-guarded), each component's
+        smallest. The host's time is tiled by `executor.setup` /
+        `.dispatch` (with the sample's `executor.sync` inside) /
+        `.sync` / `.fetch`. `last_afforest` holds the run's counts for the
+        run record; `last_trace` the finish's tier, if one ran."""
         Combiner.require_foldable(program.combiner, "the frontier engine")
-        jnp = self.jnp
+        jax = self.jax
+        n = self.n
         with tracer.phase("executor.setup"):
-            labels = jnp.asarray(np.arange(self.n, dtype=np.float32))
-            mask = jnp.ones((self.n,), bool)
-            # both orientations, NO weight arrays: the step fn's
-            # value-message branch adds w[pos] whenever weights are present
-            # in fargs, and a label must never absorb an edge weight
-            fargs = self._fargs(True, False)
-        labels, _ = self._hop_loop(
-            labels, None, mask, True, False, True, fargs,
-            program.max_iterations,
-        )
+            args = self._simple_args()
+            m = int(args["out_dst"].shape[0])
+            decision = self.ex._autotune(self.ex.SIMPLE_VIEW)
+            schedules = (decision.f_schedule, decision.e_schedule)
+        trace = []
+        passes, jumps = [0, 0], [0, 0]
+        count = slots = 0
+        giant = 1
+        finished = ()
+        if m == 0:
+            # no edge that is no loop: every vertex is its own component
+            labels = np.arange(n, dtype=np.float32)
+        else:
+            with tracer.phase("executor.dispatch"):
+                comp, labels, mask, *plan = self._afforest_sample_fn()(args)
+                with tracer.phase("executor.sync"):
+                    plan = jax.device_get(plan)
+                count, slots, passes[0], jumps[0], giant = (
+                    int(x) for x in plan
+                )
+                if slots:
+                    f_cap, e_cap = self._caps(count, slots, m, schedules)
+                    top = e_cap == m
+                    trace.append(
+                        {"phase": "finish", "hop": 0, "frontier": count,
+                         "edges": slots, "relaxed_slots": slots,
+                         "tier_slots": e_cap, "F_cap": f_cap,
+                         "E_cap": e_cap, "wide": False, "top_rung": top,
+                         "tier_source": (
+                             "autotune" if schedules[1] else "static"
+                         )}
+                    )
+                    labels, *finished = self._afforest_finish_fn(
+                        f_cap, 0 if top else e_cap
+                    )(comp, mask, args)
+            with tracer.phase("executor.sync"):
+                await_arrays((labels,))
         with tracer.phase("executor.fetch"):
-            return {"component": np.asarray(labels)}
+            labels, finished = jax.device_get((labels, finished))
+            out = {"component": np.asarray(labels)}
+        if finished:
+            passes[1], jumps[1] = (int(x) for x in finished)
+        self.last_trace = trace
+        self.last_afforest = {
+            "algorithm": "afforest",
+            "link_passes": {"sample": passes[0], "finish": passes[1]},
+            "compress_jumps": {"sample": jumps[0], "finish": jumps[1]},
+            # what the finish read, its tier's padding excluded
+            "finish_vertices": count,
+            "finish_slots": slots,
+            "closure_slots": m,
+            "giant_share": giant / max(n, 1),
+        }
+        return out
 
     # --------------------------------------------------------------- brandes
     def _simple_args(self):

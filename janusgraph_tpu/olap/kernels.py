@@ -321,6 +321,15 @@ def brandes_scope(name: str):
     return jax.named_scope("brandes." + name)
 
 
+def afforest_scope(name: str):
+    """`jax.named_scope("afforest.<name>")` around one of the frontier
+    engine's two Afforest executables (sample / finish; neither encloses
+    the other): `frontier_scope`'s sibling."""
+    import jax
+
+    return jax.named_scope("afforest." + name)
+
+
 def intersect_scope(name: str):
     """`jax.named_scope("lcc.<name>")` around a stage of the intersection
     engine's compiled pass (expand / intersect / credit, none enclosing

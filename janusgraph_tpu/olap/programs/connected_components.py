@@ -5,9 +5,18 @@ FulgoraGraphComputer — every vertex starts with its own label and adopts the
 minimum label among itself and its (undirected) neighbors until fixpoint.
 Labels are global dense vertex indices (exactly representable in float64 up
 to 2^53), mapped back to 64-bit vertex ids after the run.
+
+`max_iterations=None` (the default) runs to the fixpoint: the single-device
+executor's frontier engine answers with Afforest (`olap/frontier.py`
+`run_cc`), every other path propagates labels until no vertex changes, at
+most n supersteps (propagation settles within n - 1). An integer is a cut:
+the labels after that many supersteps of propagation, which only a
+superstep path has, so it never routes to Afforest.
 """
 
 from __future__ import annotations
+
+from typing import Optional
 
 from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
 
@@ -17,7 +26,7 @@ class ConnectedComponentsProgram(VertexProgram):
     combiner = Combiner.MIN
     undirected = True
 
-    def __init__(self, max_iterations: int = 200):
+    def __init__(self, max_iterations: Optional[int] = None):
         self.max_iterations = max_iterations
 
     def setup(self, graph, xp):

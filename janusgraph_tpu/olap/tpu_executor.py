@@ -915,8 +915,11 @@ class TPUExecutor:
         `frontier` (default: the executor's configured mode) — per-run
         override of the frontier-compaction special case for
         ShortestPath/ConnectedComponents: "auto" sizes by graph (BFS/SSSP
-        always; CC only above FRONTIER_CC_MIN_EDGES), "always" forces it,
-        "off" forces the dense BSP path for this run.
+        always; CC to the fixpoint only above FRONTIER_CC_MIN_EDGES, by
+        Afforest), "always" forces it, "off" forces the dense BSP path for
+        this run. A CC with an explicit `max_iterations` always runs dense
+        ("always" refuses it by name: Afforest has no state after k
+        supersteps).
 
         `fused` (default: auto) — compile the whole iteration into one
         dispatch (programs with a constant combiner + a terminate_device
@@ -1009,6 +1012,15 @@ class TPUExecutor:
                     )
             elif self._frontier_eligible(program, mode):
                 use_frontier = True
+            elif mode == "always" and self._cc_cut(program):
+                raise ValueError(
+                    "frontier='always' but ConnectedComponentsProgram("
+                    f"max_iterations={program.max_iterations}) asks for the "
+                    f"labels after {program.max_iterations} supersteps of "
+                    "propagation, a state the frontier engine's Afforest "
+                    "never has — use max_iterations=None (to the fixpoint) "
+                    "or frontier='auto' / 'off' (the dense path)"
+                )
             elif mode == "always":
                 # surface WHY the guards refused instead of silently
                 # timing the dense path under a frontier label
@@ -1148,7 +1160,9 @@ class TPUExecutor:
         undirected = bool(getattr(program, "undirected", False))
         # the edge view the run's pack and tiers are of
         view = (
-            self.SIMPLE_VIEW if info.get("path") == "brandes" else undirected
+            self.SIMPLE_VIEW
+            if info.get("path") == "brandes" or "link_passes" in info
+            else undirected
         )
         pad_ratio = None
         # a channel-switching program's packs are its channels' own, the
@@ -1430,10 +1444,10 @@ class TPUExecutor:
         }
 
     #: graphs below this edge count run CC through the fused dense path
-    #: under frontier="auto": the frontier loop pays ~2 host round trips
-    #: per superstep, which only amortizes once a dense superstep costs
-    #: more than dispatch (BFS keeps frontier at every size — its dense
-    #: path rescans |E| for hops that touch a handful of vertices)
+    #: under frontier="auto": Afforest's two executables, its host sync
+    #: and its finish's tier compile only amortize once a dense superstep
+    #: costs more than dispatch (BFS keeps frontier at every size — its
+    #: dense path rescans |E| for hops that touch a handful of vertices)
     FRONTIER_CC_MIN_EDGES = 1 << 20
 
     @staticmethod
@@ -1471,12 +1485,31 @@ class TPUExecutor:
                 and self.csr.num_vertices >= (1 << 24)
             )
         if type(program) is ConnectedComponentsProgram:
-            # labels are float32 vertex indices: exact below 2^24 only
-            return self.csr.num_vertices < (1 << 24) and (
-                mode == "always"
-                or self.csr.num_edges >= self.FRONTIER_CC_MIN_EDGES
+            # Afforest runs to the fixpoint only (`_cc_cut`); labels are
+            # float32 vertex indices: exact below 2^24 only
+            return (
+                not self._cc_cut(program)
+                and self.csr.num_vertices < (1 << 24)
+                and (
+                    mode == "always"
+                    or self.csr.num_edges >= self.FRONTIER_CC_MIN_EDGES
+                )
             )
         return False
+
+    @staticmethod
+    def _cc_cut(program: VertexProgram) -> bool:
+        """A ConnectedComponentsProgram with an explicit superstep cap: the
+        labels after that many supersteps of propagation, which a
+        superstep path has and Afforest does not."""
+        from janusgraph_tpu.olap.programs.connected_components import (
+            ConnectedComponentsProgram,
+        )
+
+        return (
+            type(program) is ConnectedComponentsProgram
+            and program.max_iterations is not None
+        )
 
     def _frontier(self):
         """The frontier engine, made on first use."""
@@ -1495,23 +1528,32 @@ class TPUExecutor:
         )
 
         t0 = time.perf_counter()
+        engine = self._frontier()
+        afforest = {}
         if type(program) is ConnectedComponentsProgram:
-            out = self._frontier().run_cc(program)
+            out = engine.run_cc(program)
+            afforest = dict(
+                engine.last_afforest,
+                # a superstep of Afforest is a link pass
+                supersteps=sum(engine.last_afforest["link_passes"].values()),
+            )
         else:
-            out = self._frontier().run(program)
-        trace = getattr(self._frontier_engine, "last_trace", [])
+            out = engine.run(program)
+        trace = engine.last_trace
         self.last_run_info = {
             "path": "frontier",
             "supersteps": len(trace),
             "wall_s": round(time.perf_counter() - t0, 4),
             "tiers": trace,
-            # totals a layer metric can read: hops executed, slots they
-            # relaxed, slots their tiers held (padding = the difference),
-            # hops that ran as wide rounds on the pack (the top rung)
+            # totals a layer metric can read: hops executed (Afforest: its
+            # finish, if one ran), slots they relaxed, slots their tiers
+            # held (padding = the difference), hops that ran as wide
+            # rounds on the pack (the top rung; never Afforest's)
             "rounds": len(trace),
             "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
             "tier_slots": sum(t["tier_slots"] for t in trace),
             "wide_rounds": sum(t["wide"] for t in trace),
+            **afforest,
         }
         return out
 
@@ -1611,7 +1653,7 @@ class TPUExecutor:
     ) -> Dict[str, np.ndarray]:
         jnp = self.jnp
         op = program.combiner
-        max_iter = program.max_iterations
+        max_iter = program.iteration_cap(self.csr.num_vertices)
         steps_done = 0
         state = mem = None
 
@@ -1778,7 +1820,8 @@ class TPUExecutor:
             }
         steps_done = start_step
         records = []
-        for step in range(start_step, program.max_iterations):
+        cap = program.iteration_cap(self.csr.num_vertices)
+        for step in range(start_step, cap):
             with tracer.phase("executor.dispatch"):
                 if fault_hook is not None:
                     fault_hook(step)
@@ -1809,7 +1852,7 @@ class TPUExecutor:
                     **cost,
                 })
             steps_done += 1
-            last = step == program.max_iterations - 1
+            last = step == cap - 1
             if steps_done % sync_every == 0 or last:
                 with tracer.phase("executor.sync"):
                     host_vals = self.jax.device_get(metrics)  # one round trip

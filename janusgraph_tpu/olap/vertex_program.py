@@ -240,7 +240,8 @@ class VertexProgram:
                         passes untransformed). SUM combiner only — the
                         post-transform identity masking is uniform.
       undirected      — aggregate over both edge orientations
-      max_iterations  — hard superstep cap
+      max_iterations  — hard superstep cap; None where a program runs to
+                        its fixpoint (`iteration_cap`)
     """
 
     compute_keys: Tuple[str, ...] = ()
@@ -256,6 +257,15 @@ class VertexProgram:
     #: cannot be mutated in place, so per-class declarations can't leak
     #: across programs)
     edge_channels: Mapping[str, EdgeChannel] = MappingProxyType({})
+
+    def iteration_cap(self, num_vertices: int) -> int:
+        """The supersteps a loop runs at most: `max_iterations`, or where
+        that is None (to the fixpoint, which the program's `terminate`
+        detects) `num_vertices`, the most a propagation over n vertices
+        needs to settle and see that it has (n - 1 hops and one more)."""
+        if self.max_iterations is None:
+            return max(int(num_vertices), 1)
+        return self.max_iterations
 
     def combiner_for(self, superstep: int) -> str:
         """Monoid for a given superstep — overridable for phase-alternating

@@ -1991,7 +1991,8 @@ class ShardedExecutor:
         gargs = self._graph_args(sc, program.undirected)
         steps_done = start_step
         records: List[dict] = []
-        for step in range(start_step, program.max_iterations):
+        cap = program.iteration_cap(self.csr.num_vertices)
+        for step in range(start_step, cap):
             # fault boundary: the barrier between supersteps — the one
             # point where no shard holds partial superstep state
             self._consult(hook, step)
@@ -2014,7 +2015,7 @@ class ShardedExecutor:
                 for k in set(device_memory) | set(metrics)
             }
             steps_done += 1
-            last = step == program.max_iterations - 1
+            last = step == cap - 1
             records.append({
                 "step": step,
                 "wall_ms": round(
@@ -2067,7 +2068,7 @@ class ShardedExecutor:
         import jax.numpy as jnp
 
         op = program.combiner
-        max_iter = program.max_iterations
+        max_iter = program.iteration_cap(self.csr.num_vertices)
         gargs = self._graph_args(sc, program.undirected)
         steps_done = 0
         state = mem = None

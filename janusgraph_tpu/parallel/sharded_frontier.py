@@ -378,7 +378,9 @@ class ShardedFrontierEngine:
         """Frontier-compacted connected components on the mesh: min-label
         propagation with a changed-vertex frontier, value-messages through
         the weighted step with NO weight arrays (a label must never absorb
-        an edge weight — the same reuse as olap/frontier.py.run_cc)."""
+        an edge weight). `max_iterations=None` runs until no label changes
+        (`iteration_cap`); the single-device engine answers the same labels
+        by Afforest (olap/frontier.py `run_cc`)."""
         sc = self.ex._sharded(True)  # symmetric closure = both orientations
         labels = self._device_put_sharded(
             np.arange(sc.padded_n, dtype=np.float32)
@@ -386,7 +388,7 @@ class ShardedFrontierEngine:
         mask = self._device_put_sharded(sc.active > 0)
         labels, _ = self._hop_loop(
             sc, True, labels, None, mask, True, False,
-            program.max_iterations, use_weights=False,
+            program.iteration_cap(sc.real_n), use_weights=False,
             fault_hook=fault_hook,
         )
         return {"component": self.ex._fetch(labels)[: sc.real_n]}

@@ -190,6 +190,9 @@ def test_frontier_cc_auto_heuristic():
     ex = TPUExecutor(small)
     assert not ex._frontier_eligible(ConnectedComponentsProgram(), "auto")
     assert ex._frontier_eligible(ConnectedComponentsProgram(), "always")
+    # an explicit cap is propagation's cut: never Afforest's
+    capped = ConnectedComponentsProgram(max_iterations=5)
+    assert not ex._frontier_eligible(capped, "always")
     # BFS keeps frontier at every size
     assert ex._frontier_eligible(ShortestPathProgram(seed_index=0), "auto")
 
@@ -198,13 +201,15 @@ def test_frontier_cc_matches_cpu_and_dense():
     from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
 
     csr = random_graph(n=250, m=600, seed=23)
-    mk = lambda: ConnectedComponentsProgram(max_iterations=100)  # noqa: E731
+    mk = lambda: ConnectedComponentsProgram(max_iterations=None)  # noqa: E731
     cpu = CPUExecutor(csr).run(mk())
     dense = TPUExecutor(csr, frontier="off").run(mk())
     ex = TPUExecutor(csr, frontier="always", **RUNGS)
     assert ex._frontier_eligible(mk(), "always")
     sparse = ex.run(mk())
-    assert not all(t["wide"] for t in ex.last_run_info["tiers"])
+    info = ex.last_run_info
+    assert info["path"] == "frontier" and info["link_passes"]["sample"] >= 1
+    assert not any(t["wide"] for t in info["tiers"])
     np.testing.assert_array_equal(
         np.asarray(sparse["component"]), np.asarray(cpu["component"])
     )
@@ -214,16 +219,34 @@ def test_frontier_cc_matches_cpu_and_dense():
 
 
 def test_frontier_cc_step_cutoff_parity():
+    """An explicit cap keeps its cut semantics, on the dense path: under
+    'auto' it routes there (as 'off' does) and answers propagation's
+    labels after that many supersteps; under 'always' it is refused by
+    the cap's name, since Afforest has no state after k supersteps."""
     from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
 
     csr = random_graph(n=120, m=260, seed=29)
     for it in (1, 2, 3):
         mk = lambda: ConnectedComponentsProgram(max_iterations=it)  # noqa: E731
         dense = TPUExecutor(csr, frontier="off").run(mk())
-        sparse = TPUExecutor(csr, frontier="always", **RUNGS).run(mk())
+        cpu = CPUExecutor(csr).run(mk())
+        ex = TPUExecutor(csr, frontier_cc_min_edges=1, **RUNGS)
+        auto = ex.run(mk())
+        assert ex.last_run_info["path"] == "fused"
+        assert ex.last_run_info["supersteps"] == it
         np.testing.assert_array_equal(
-            np.asarray(sparse["component"]), np.asarray(dense["component"])
+            np.asarray(auto["component"]), np.asarray(dense["component"])
         )
+        np.testing.assert_array_equal(
+            np.asarray(auto["component"]),
+            np.asarray(cpu["component"]).astype(np.float32),
+        )
+        with pytest.raises(ValueError, match=f"max_iterations={it}\\)"):
+            TPUExecutor(csr, frontier="always").run(mk())
+    # the cut is a cut: one superstep leaves labels to fall
+    full = TPUExecutor(csr, frontier="always").run(
+        ConnectedComponentsProgram())
+    assert (np.asarray(full["component"]) < np.asarray(auto["component"])).any()
 
 
 def test_frontier_cc_disconnected_and_isolated():
@@ -246,7 +269,7 @@ def test_frontier_cc_on_ldbc_proxy():
     from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
 
     csr = ldbc_snb_csr(11)
-    mk = lambda: ConnectedComponentsProgram(max_iterations=64)  # noqa: E731
+    mk = lambda: ConnectedComponentsProgram(max_iterations=None)  # noqa: E731
     sparse = TPUExecutor(csr, frontier="always").run(mk())
     cpu = CPUExecutor(csr).run(mk())
     np.testing.assert_array_equal(
@@ -287,10 +310,10 @@ def test_frontier_fuzz_vs_dense():
                         f"it={it} rungs={rungs}",
             )
         cc_d = TPUExecutor(csr, frontier="off").run(
-            ConnectedComponentsProgram(max_iterations=64)
+            ConnectedComponentsProgram(max_iterations=None)
         )
         cc_s = TPUExecutor(csr, frontier="always", **RUNGS).run(
-            ConnectedComponentsProgram(max_iterations=64)
+            ConnectedComponentsProgram(max_iterations=None)
         )
         np.testing.assert_array_equal(
             np.asarray(cc_s["component"]), np.asarray(cc_d["component"]),
@@ -321,7 +344,7 @@ def awkward_multigraph(seed, n=260, m=1100, island=30):
 def _components(**kw):
     from janusgraph_tpu.olap.programs import ConnectedComponentsProgram
 
-    return ConnectedComponentsProgram(max_iterations=100, **kw)
+    return ConnectedComponentsProgram(max_iterations=None, **kw)
 
 
 #: flavour -> (program factory, the oracle that folds in the same float)
@@ -367,8 +390,20 @@ def _oracle(flavour, seed):
 def assert_hop_mix(info, num_edges, expect):
     """The record says which hops were wide, by the rule (the rung), and
     the run is the mix of wide and narrow hops the case means to drive.
-    CC starts from every vertex, so its first hop holds every edge and is
-    wide under any ladder."""
+    CC is Afforest: no hop, no wide round; its record says whether its
+    finish (the rows outside the giant) was narrow or read every slot of
+    the closure, the top rung: narrow under the narrow ladder, top-rung
+    under the default one, whose one rung is the closure."""
+    if "link_passes" in info:
+        (finish,) = info["tiers"]
+        assert info["wide_rounds"] == 0 and not finish["wide"]
+        assert finish["top_rung"] == (
+            finish["E_cap"] == info["closure_slots"])
+        if expect == "all":
+            assert finish["top_rung"]
+        elif expect == "none":
+            assert not finish["top_rung"]
+        return
     wide = [t["wide"] for t in info["tiers"]]
     assert wide == [t["E_cap"] == num_edges for t in info["tiers"]]
     assert info["wide_rounds"] == sum(wide)
@@ -376,8 +411,6 @@ def assert_hop_mix(info, num_edges, expect):
         assert all(wide)
     elif expect == "some":
         assert any(wide) and not all(wide)
-    elif info["tiers"][0]["frontier"] > 1:  # CC
-        assert wide[0] and not all(wide)
     else:
         assert not any(wide)
 
@@ -431,15 +464,16 @@ def test_wide_executable_is_one_per_flavour_and_top_rung_steps_are_gone():
     pack = ex._hybrid_packs[False]
     assert all(t["tier_slots"] == pack.slots
                for t in ex.last_run_info["tiers"] if t["wide"])
-    # CC on the same (weighted) graph and weighted SSSP read the closure
-    # pack, with and without its weights: two executables, one pack
-    ex.run(_components(), frontier="always")
+    # an undirected BFS on the same (weighted) graph and weighted SSSP
+    # read the closure pack, without and with its weights: two
+    # executables, one pack
+    ex.run(ShortestPathProgram(seed_index=3, undirected=True))
     ex.run(ShortestPathProgram(seed_index=3, weighted=True, undirected=True,
                                max_iterations=1000))
     wide = sorted(k for k in ex._compiled if k[0] == "frontier-wide")
     assert wide == [
         ("frontier-wide", False, False, False, False),
-        ("frontier-wide", True, False, True, False),
+        ("frontier-wide", False, False, True, False),
         ("frontier-wide", True, False, True, True),
     ]
     assert sorted(ex._hybrid_packs) == [False, True]
