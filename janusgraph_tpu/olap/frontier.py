@@ -54,6 +54,8 @@ edges. A link hangs the larger of an edge's two roots under the smaller
 and pointer jumping compresses the forest, so every label only falls and
 each component's root is its smallest index: the labels of min-label
 propagation, which the dense path, `CPUExecutor` and the mesh still run.
+Both run over the vertices that have an edge alone, renumbered in their own
+order (`_cc_args`): a vertex without one is its own root throughout.
 """
 
 from __future__ import annotations
@@ -188,6 +190,19 @@ def _link(jnp, lax, comp, u, v, valid):
         (comp, ru, rv, jnp.any(valid & (ru != rv)), zero, zero),
     )
     return comp, passes, jumps
+
+
+# graphlint: traced -- Afforest's answer, inside both executables
+def _expand_labels(jnp, comp, rank, ids):
+    """Afforest's float32 labels of all n vertices from the forest `comp`
+    over a renumbered CC view's rows (`FrontierEngine._cc_args`): each
+    row's root row back to its vertex index (`ids`, n' rows), then each
+    vertex its row's label (`rank`, n rows), a vertex without an edge its
+    own index."""
+    root = row_take(jnp, ids, comp)
+    got = row_take(jnp, root, jnp.maximum(rank, 0))
+    own = jnp.arange(rank.shape[0], dtype=jnp.int32)
+    return jnp.where(rank >= 0, got, own).astype(jnp.float32)
 
 
 class FrontierEngine:
@@ -633,30 +648,33 @@ class FrontierEngine:
     # -------------------------------------------------------------- afforest
     def _afforest_sample_fn(self):
         """`afforest_sample(args) -> (comp, labels, mask, count, slots,
-        passes, jumps, giant)` over the simple closure (module
-        `jit_afforest_sample`, scope `afforest.sample`). For r <
-        SAMPLE_ROUNDS every vertex of degree above r links the neighbour at
+        passes, jumps, giant)` over the rows of the CC view (`_cc_args`;
+        module `jit_afforest_sample`, scope `afforest.sample`). For r <
+        SAMPLE_ROUNDS every row of degree above r links the neighbour at
         position r of its row, to the fixpoint; then the most frequent
-        root among SAMPLE_SIZE vertices at a fixed stride is the giant's
-        (the first of equals). `mask` marks the vertices with an edge
-        outside the giant, `count` and `slots` price them as `_plan_fn`
-        prices a frontier, `giant` counts the vertices under its root.
-        `labels` is `comp` as float32, the answer when no finish runs."""
+        root among the view's `picks` is the giant's (the first of
+        equals; a pick of a vertex v without an edge votes `-1 - v`, its
+        own component). `mask` marks
+        the rows with an edge outside the giant, `count` and `slots` price
+        them as `_plan_fn` prices a frontier, `giant` counts the vertices
+        under its root. `labels` is the answer when no finish runs: on a
+        renumbered view it is expanded to n rows (`_expand_labels`) only
+        then, under a `lax.cond` whose other branch writes zeros, so a
+        submit expands once, in the finish where one runs."""
         key = ("afforest-sample",)
         cache = self.ex._compiled
         if key in cache:
             return cache[key]
         jnp, lax = self.jnp, self.jax.lax
         n = self.n
-        picks = (
-            np.arange(self.SAMPLE_SIZE, dtype=np.int64) * n // self.SAMPLE_SIZE
-        ).astype(np.int32)
+        renumbered = "rank" in self._cc_args()
 
         def afforest_sample(args):
             with afforest_scope("sample"):
                 m = args["out_dst"].shape[0]
-                starts, deg = args["out_ip"][:n], args["out_deg"]
-                me = jnp.arange(n, dtype=jnp.int32)
+                rows = args["out_deg"].shape[0]
+                starts, deg = args["out_ip"][:rows], args["out_deg"]
+                me = jnp.arange(rows, dtype=jnp.int32)
                 comp, passes, jumps = me, jnp.int32(0), jnp.int32(0)
                 for r in range(self.SAMPLE_ROUNDS):
                     has = deg > r
@@ -664,17 +682,31 @@ class FrontierEngine:
                     nbr = jnp.where(has, args["out_dst"][at], me)
                     comp, p, j = _link(jnp, lax, comp, None, nbr, has)
                     passes, jumps = passes + p, jumps + j
-                seen = comp[picks]
+                picks = args["picks"]
+                seen = jnp.where(
+                    picks >= 0, comp[jnp.maximum(picks, 0)], picks)
                 votes = jnp.sum(
                     (seen[:, None] == seen[None, :]).astype(jnp.int32), axis=1
                 )
                 giant = seen[jnp.argmax(votes)]
                 mask = (comp != giant) & (deg > 0)
+                slots = jnp.sum(jnp.where(mask, deg, 0))
+                if renumbered:
+                    labels = lax.cond(
+                        slots == 0,
+                        lambda c: _expand_labels(
+                            jnp, c, args["rank"], args["ids"]),
+                        lambda c: jnp.zeros((n,), jnp.float32),
+                        comp,
+                    )
+                else:
+                    labels = comp.astype(jnp.float32)
                 return (
-                    comp, comp.astype(jnp.float32), mask,
-                    jnp.sum(mask.astype(jnp.int32)),
-                    jnp.sum(jnp.where(mask, deg, 0)),
-                    passes, jumps, jnp.sum((comp == giant).astype(jnp.int32)),
+                    comp, labels, mask, jnp.sum(mask.astype(jnp.int32)),
+                    slots, passes, jumps,
+                    # a giant without an edge is its one vertex
+                    jnp.where(giant < 0, 1,
+                              jnp.sum((comp == giant).astype(jnp.int32))),
                 )
 
         fn = self.jax.jit(afforest_sample)
@@ -684,43 +716,50 @@ class FrontierEngine:
     def _afforest_finish_fn(self, F_cap, E_cap):
         """`afforest_finish(comp, mask, args) -> (labels, passes, jumps)`
         (module `jit_afforest_finish`, scope `afforest.finish`): the rows
-        of the vertices in `mask` linked to the fixpoint, labels as
-        float32. Narrow: the rows compacted to F_cap vertices and E_cap
-        slots (`capped_expand`); a row is read whole, its positions 0 and
-        1 again too, whose ends the sample gave one root (GAP skips them:
-        no pass differs). `E_cap == 0` is the ladder's top rung:
-        every slot of the closure, its sender read off the pointers as
-        `_parent_fn` reads it. An edge there is linked from both ends, or
-        from neither where both lie in the giant, whose ends share a root
-        already: the same passes as the narrow form's, and no pack, since
-        a link is no fold by destination."""
+        in `mask` linked to the fixpoint over the CC view (`_cc_args`),
+        then the labels of all n vertices as float32 (on a renumbered
+        view the expansion to n rows, `_expand_labels`, runs here).
+        Narrow: the rows compacted to F_cap rows and E_cap slots
+        (`capped_expand`); a row is read whole, its positions 0 and 1
+        again too, whose ends the sample gave one root (GAP skips them: no
+        pass differs). `E_cap == 0` is the ladder's top rung: every slot
+        of the closure, its sender read off the pointers as `_parent_fn`
+        reads it. An edge there is linked from both ends, or from neither
+        where both lie in the giant, whose ends share a root already: the
+        same passes as the narrow form's, and no pack, since a link is no
+        fold by destination."""
         key = ("afforest-finish", F_cap, E_cap)
         cache = self.ex._compiled
         if key in cache:
             return cache[key]
         jnp, lax = self.jnp, self.jax.lax
-        n = self.n
+        renumbered = "rank" in self._cc_args()
 
         def afforest_finish(comp, mask, args):
             with afforest_scope("finish"):
                 ip, dst = args["out_ip"], args["out_dst"]
-                m = dst.shape[0]
+                m, rows = dst.shape[0], comp.shape[0]
                 if E_cap == 0:
                     u = jnp.cumsum(
-                        jnp.zeros((m,), jnp.int32).at[ip[1:n]].add(
+                        jnp.zeros((m,), jnp.int32).at[ip[1:rows]].add(
                             1, mode="drop")
                     )
                     v, valid = dst, jnp.ones((m,), bool)
                 else:
-                    idx = jnp.nonzero(mask, size=F_cap, fill_value=n)[0]
+                    idx = jnp.nonzero(mask, size=F_cap, fill_value=rows)[0]
                     idx = idx.astype(jnp.int32)
                     own, _, v, valid = capped_expand(
-                        jnp, idx, ip, dst, E_cap, n
+                        jnp, idx, ip, dst, E_cap, rows
                     )
-                    u = jnp.minimum(idx[own], n - 1)
-                    v = jnp.minimum(v, n - 1)
+                    u = jnp.minimum(idx[own], rows - 1)
+                    v = jnp.minimum(v, rows - 1)
                 comp, passes, jumps = _link(jnp, lax, comp, u, v, valid)
-                return comp.astype(jnp.float32), passes, jumps
+                if renumbered:
+                    labels = _expand_labels(
+                        jnp, comp, args["rank"], args["ids"])
+                else:
+                    labels = comp.astype(jnp.float32)
+                return labels, passes, jumps
 
         fn = self.jax.jit(afforest_finish)
         cache[key] = fn
@@ -728,21 +767,25 @@ class FrontierEngine:
 
     def run_cc(self, program) -> Dict[str, np.ndarray]:
         """Connected components to the fixpoint by Afforest over the
-        executor's simple closure: the sample (one executable, then ONE
+        executor's simple closure, in its CC view (`_cc_args`: the
+        vertices that have an edge alone, renumbered in their own order,
+        where some vertex has none): the sample (one executable, then ONE
         host sync for its five scalars), the finish's tier from its
         vertices and slots on the closure's ladder (`_caps`), the finish
         (none where no vertex outside the giant has an edge), one fetch
-        of the labels and the finish's counters. Labels are float32 vertex
-        indices (exact below 2^24: eligibility-guarded), each component's
-        smallest. The host's time is tiled by `executor.setup` /
-        `.dispatch` (with the sample's `executor.sync` inside) /
-        `.sync` / `.fetch`. `last_afforest` holds the run's counts for the
-        run record; `last_trace` the finish's tier, if one ran."""
+        of the labels and the finish's counters. The labels come back
+        expanded to n rows by whichever of the two executables ran last.
+        Labels are float32 vertex indices (exact below 2^24:
+        eligibility-guarded), each component's smallest. The host's time
+        is tiled by `executor.setup` / `.dispatch` (with the sample's
+        `executor.sync` inside) / `.sync` / `.fetch`. `last_afforest`
+        holds the run's counts for the run record; `last_trace` the
+        finish's tier, if one ran."""
         Combiner.require_foldable(program.combiner, "the frontier engine")
         jax = self.jax
         n = self.n
         with tracer.phase("executor.setup"):
-            args = self._simple_args()
+            args = self._cc_args()
             m = int(args["out_dst"].shape[0])
             decision = self.ex._autotune(self.ex.SIMPLE_VIEW)
             schedules = (decision.f_schedule, decision.e_schedule)
@@ -793,9 +836,58 @@ class FrontierEngine:
             "finish_vertices": count,
             "finish_slots": slots,
             "closure_slots": m,
+            # the rows the sample's and the finish's vectors hold, and n
+            "sample_vertices": int(args["out_deg"].shape[0]),
+            "vertices": n,
             "giant_share": giant / max(n, 1),
         }
         return out
+
+    def _cc_args(self):
+        """Afforest's view of the simple closure (`run_cc`): where some
+        vertex has no edge in it, `_simple_args`' arrays over the n'
+        vertices that have one, renumbered in rising order of their own
+        index, with `rank` (each vertex's row, -1 without an edge) and
+        `ids` (each row's vertex). Renaming in order keeps every min and
+        max, so every hook, jump and pass is the one over all n vertices,
+        on 38% fewer rows at GAP's kron. `picks` are the rows of the
+        SAMPLE_SIZE vertices at the stride i x n / SAMPLE_SIZE of the
+        vertices' own numbering; a pick without an edge is `-1 - v`, a
+        vote no root can equal and that only another pick of v shares.
+        Where every vertex has an edge the view is `_simple_args`' own
+        arrays and `picks`: nothing is renumbered or expanded. Built on
+        the host on first use and kept, an index of the graph like the
+        CSR; `_simple_args` is built only where something reads it, so a
+        process that runs both Brandes and CC holds the neighbours twice."""
+        args = self._fargs_cache.get("cc")
+        if args is None:
+            jnp, n = self.jnp, self.n
+            src, dst = self.ex._simple_closure()
+            deg = np.bincount(src, minlength=n)
+            picks = (
+                np.arange(self.SAMPLE_SIZE, dtype=np.int64) * n
+                // self.SAMPLE_SIZE
+            )
+            ids = np.flatnonzero(deg)
+            if len(ids) == n:
+                args = dict(self._simple_args())
+            else:
+                rank = np.full(n, -1, np.int32)
+                rank[ids] = np.arange(len(ids), dtype=np.int32)
+                indptr = np.zeros(len(ids) + 2, np.int64)
+                np.cumsum(deg[ids], out=indptr[1:len(ids) + 1])
+                indptr[-1] = indptr[-2]
+                picks = np.where(rank[picks] >= 0, rank[picks], -1 - picks)
+                args = {
+                    "out_ip": jnp.asarray(indptr.astype(np.int32)),
+                    "out_dst": jnp.asarray(rank[dst]),
+                    "out_deg": jnp.asarray(deg[ids].astype(np.int32)),
+                    "rank": jnp.asarray(rank),
+                    "ids": jnp.asarray(ids.astype(np.int32)),
+                }
+            args["picks"] = jnp.asarray(picks.astype(np.int32))
+            self._fargs_cache["cc"] = args
+        return args
 
     # --------------------------------------------------------------- brandes
     def _simple_args(self):

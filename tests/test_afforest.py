@@ -260,3 +260,172 @@ def test_a_narrow_finish_compacts_the_rows_outside_the_giant():
     assert tier["F_cap"] >= info["finish_vertices"]
     assert info["link_passes"]["finish"] >= 1
     assert info["compress_jumps"]["finish"] >= info["link_passes"]["finish"]
+
+
+# ------------------------------------------------- the renumbered CC view
+#: the fields whose values the renumbering must leave as they were
+SAME_WORK = ("link_passes", "compress_jumps", "finish_vertices",
+             "finish_slots", "tiers")
+
+
+def with_isolated(edges, where):
+    """`edges` with 8 vertices of no edge interleaved (`where`): at index
+    0, at the end, on vertices the sample picks (i x n / 1024 of the new
+    numbering), or none at all. Returns the graph and the new index of
+    each old vertex (the order kept)."""
+    k = 0 if where == "none" else 8
+    n = edges.n + k
+    if where == "first":
+        new = np.arange(k)
+    elif where == "last":
+        new = np.arange(n - k, n)
+    elif where == "picks":
+        picks = np.unique(np.arange(1024) * n // 1024)
+        new = picks[np.linspace(0, len(picks) - 1, k).astype(int)]
+    else:
+        new = np.arange(0)
+    kept = np.setdiff1d(np.arange(n), new)
+    return Edges(n, kept[edges.src], kept[edges.dst]), kept
+
+
+def full_view(ex):
+    """The executor's engine set to run Afforest over all n vertices, the
+    view as it was before the renumbering: the simple closure's own arrays
+    and the picks at i x n / 1024."""
+    engine = ex._frontier()
+    picks = np.arange(1024) * engine.n // 1024
+    engine._fargs_cache["cc"] = dict(
+        engine._simple_args(), picks=ex.jnp.asarray(picks.astype(np.int32)))
+    return ex
+
+
+def counts(info):
+    out = {key: info[key] for key in SAME_WORK}
+    out["giant"] = round(info["giant_share"] * info["vertices"])
+    return out
+
+
+@pytest.mark.parametrize("where", ["first", "last", "picks", "none"])
+@pytest.mark.parametrize("graph", list(GRAPHS))
+def test_interleaved_isolated_vertices_change_no_count(graph, where):
+    base = GRAPHS[graph]()
+    edges, kept = with_isolated(base, where)
+    csr = csr_from_edges(edges.n, edges.src, edges.dst)
+    ex = TPUExecutor(csr, frontier="always", **RUNGS)
+    got = ex.run(ConnectedComponentsProgram())["component"]
+    info = ex.last_run_info
+    want = CPUExecutor(csr).run(ConnectedComponentsProgram())["component"]
+    np.testing.assert_array_equal(got, np.asarray(want).astype(np.float32))
+    assert REFERENCE.agrees(got, REFERENCE.expect(edges))
+    link = edges.src != edges.dst
+    with_edge = np.unique(np.concatenate([edges.src[link], edges.dst[link]]))
+    assert info["vertices"] == edges.n
+    assert info["sample_vertices"] == len(with_edge)
+    assert where == "none" or len(with_edge) < edges.n
+    # the same work as over all n rows, on the same graph
+    full = full_view(TPUExecutor(csr, frontier="always", **RUNGS))
+    again = full.run(ConnectedComponentsProgram())["component"]
+    np.testing.assert_array_equal(again, got)
+    assert full.last_run_info["sample_vertices"] == edges.n
+    assert counts(info) == counts(full.last_run_info)
+    # and as on the graph without the 8 vertices, but for the tier, whose
+    # ladder is clamped at n
+    _, alone, info0 = afforest(base, **RUNGS)
+    assert ({**counts(info), "tiers": None}
+            == {**counts(info0), "tiers": None})
+    np.testing.assert_array_equal(got[kept], kept[alone.astype(np.int64)])
+
+
+def test_a_graph_whose_every_pick_is_isolated_still_answers():
+    # 4,096 vertices, picks at every 4th: edges among the others alone,
+    # triangles of three consecutive non-picks and a path through them
+    n = 4096
+    others = np.flatnonzero(np.arange(n) % 4)
+    src = np.concatenate([others[:-1:3], others[1::3][:len(others) // 3]])
+    dst = np.concatenate([others[1::3], others[2::3]])[:len(src)]
+    src, dst = src[:len(dst)], dst
+    src = np.concatenate([src, others[:200:3]])
+    dst = np.concatenate([dst, others[3:203:3]])
+    edges = Edges(n, src, dst)
+    picks = np.arange(1024) * n // 1024
+    assert not np.isin(picks, np.concatenate([src, dst])).any()
+    csr, got, info = afforest(edges, **RUNGS)
+    assert REFERENCE.agrees(got, REFERENCE.expect(edges))
+    want = CPUExecutor(csr).run(ConnectedComponentsProgram())["component"]
+    np.testing.assert_array_equal(got, np.asarray(want).astype(np.float32))
+    # the giant is the first pick, its own vertex: the finish reads every
+    # vertex with an edge
+    assert info["giant_share"] == 1 / n
+    assert info["finish_vertices"] == info["sample_vertices"] < n
+    assert info["finish_slots"] == info["closure_slots"]
+    full = full_view(TPUExecutor(csr, frontier="always", **RUNGS))
+    full.run(ConnectedComponentsProgram())
+    assert counts(info) == counts(full.last_run_info)
+
+
+@pytest.mark.parametrize("graph", ["small-components", "multigraph-2"])
+def test_a_graph_without_isolated_vertices_is_not_renumbered(graph):
+    edges = GRAPHS[graph]()
+    csr = csr_from_edges(edges.n, edges.src, edges.dst)
+    assert (np.diff(csr.out_indptr) + np.diff(csr.in_indptr) > 0).all()
+    ex = TPUExecutor(csr, frontier="always", **RUNGS)
+    got = ex.run(ConnectedComponentsProgram())["component"]
+    info = ex.last_run_info
+    assert info["sample_vertices"] == info["vertices"] == edges.n
+    view = ex._frontier_engine._fargs_cache["cc"]
+    assert "rank" not in view and "ids" not in view
+    # the closure's own arrays, not a copy
+    assert view["out_dst"] is ex._frontier_engine._simple_args()["out_dst"]
+    assert REFERENCE.agrees(got, REFERENCE.expect(edges))
+
+
+def test_the_brandes_view_is_unchanged_by_a_cc_run():
+    from janusgraph_tpu.olap.programs import BetweennessCentralityProgram
+
+    edges = multigraph(1, n=500, m=600)
+    csr = csr_from_edges(edges.n, edges.src, edges.dst)
+    ex = TPUExecutor(csr, frontier="always", **RUNGS)
+    sources = [int(edges.src[20]), int(edges.dst[30])]
+    before = ex.run(BetweennessCentralityProgram(sources))["betweenness"]
+    engine = ex._frontier_engine
+    simple = engine._simple_args()
+    kept = {key: np.asarray(a).copy() for key, a in simple.items()}
+    ex.run(ConnectedComponentsProgram())
+    assert ex.last_run_info["sample_vertices"] < edges.n
+    assert engine._simple_args() is simple
+    for key, a in simple.items():
+        np.testing.assert_array_equal(np.asarray(a), kept[key])
+    # the closure's view over all n vertices, as Brandes reads it
+    assert simple["out_deg"].shape == (edges.n,)
+    after = ex.run(BetweennessCentralityProgram(sources))["betweenness"]
+    np.testing.assert_array_equal(after, before)
+
+
+def test_a_cc_run_alone_builds_only_its_own_view():
+    edges = multigraph(1, n=500, m=600)
+    csr = csr_from_edges(edges.n, edges.src, edges.dst)
+    ex = TPUExecutor(csr, frontier="always")
+    ex.run(ConnectedComponentsProgram())
+    cache = ex._frontier_engine._fargs_cache
+    assert "cc" in cache and "simple" not in cache
+    n1 = ex.last_run_info["sample_vertices"]
+    assert cache["cc"]["out_deg"].shape == (n1,)
+    assert cache["cc"]["rank"].shape == (edges.n,)
+
+
+def test_a_vertex_picked_twice_votes_twice_without_an_edge():
+    # n = 600 < 1024: a vertex is picked once or twice. Vertex 0 (picked
+    # twice, no edge) ties with every pair of once-picked vertices and
+    # comes first, so it is the giant, as over all n rows
+    n = 600
+    picked = np.bincount(np.arange(1024) * n // 1024, minlength=n)
+    once = np.flatnonzero(picked == 1)
+    assert picked[0] == 2
+    edges = Edges(n, once[0:-1:2], once[1::2])
+    csr, got, info = afforest(edges, **RUNGS)
+    assert REFERENCE.agrees(got, REFERENCE.expect(edges))
+    assert info["giant_share"] == 1 / n
+    assert info["finish_vertices"] == info["sample_vertices"] < n
+    full = full_view(TPUExecutor(csr, frontier="always", **RUNGS))
+    full.run(ConnectedComponentsProgram())
+    assert counts(info) == counts(full.last_run_info)
