@@ -444,7 +444,7 @@ class CPUExecutor:
 
     def _sddmm_rows(self, undirected: bool):
         """Row-destination vectors for the fused SDDMM pass, aligned with
-        `_pack`'s layout — the numpy twins of TPUExecutor._sddmm_rows."""
+        `_pack`'s layout — the numpy twins of TPUExecutor._row_dsts."""
         from janusgraph_tpu.olap.features import kernels as fkernels
 
         key = ("sddmm", self.strategy, undirected)

@@ -206,14 +206,11 @@ def hybrid_row_dsts(
     """One destination index per row of the equivalent ``HybridPack``, in
     the order of its flat index vector: torso rows, then tail chunks."""
     dst = np.asarray(dst, dtype=np.int64)
-    shadow = HybridPack(
+    return HybridPack(
         dst, dst, None, num_vertices,
         hub_cutoff=hub_cutoff, tail_chunk=tail_chunk,
         max_capacity=max_capacity,
-    )
-    return np.ascontiguousarray(
-        shadow.arrays["idx"][shadow.row_first_slots()]
-    )
+    ).row_dsts
 
 
 # --------------------------------------------------------------------------

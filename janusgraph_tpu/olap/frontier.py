@@ -70,6 +70,7 @@ from janusgraph_tpu.olap.kernels import (
     HybridPackView,
     afforest_scope,
     brandes_scope,
+    flat_take,
     frontier_scope,
     hybrid_fold,
     hybrid_gather,
@@ -457,9 +458,14 @@ class FrontierEngine:
         return fn
 
     def _parent_fn(self, undirected):
-        """(dist, when, fargs) -> parent array of a converged WEIGHTED
-        search, in one full-width pass over the out-orientation (every
-        edge once; read from both ends where `undirected`).
+        """`parent_pass(dist, when, pack.arrays, row_dsts)` -> parent
+        array of a converged WEIGHTED search, read off the executor's
+        pack of the view the search reads (the wide round's, built on
+        first use) by destination: ONE gather of every slot's sender as a
+        row `(dist[p] + w, when[p], dist[p], p)`, the weight added in
+        flight as the wide round adds it; each row of the pack's
+        `(dist[v], when[v])` (`row_dsts`: its destination, per row); the
+        test below per slot in `hybrid_fold`'s `leaf_fn`; the min tree.
 
         `p` may be `v`'s parent if an edge between them of weight `w`
         explains `v`'s distance, `fl32(dist[p] + w) == dist[v]`, and `p`
@@ -470,44 +476,47 @@ class FrontierEngine:
         equal (a weight of 0, or one the addition absorbs) and is smaller
         otherwise. The order strictly falls along parent pointers, so they
         form a tree that ends at the root, the one vertex with `when` 0.
-        Among a vertex's candidates the smallest index is returned."""
+        Among a vertex's candidates the smallest index is returned: the
+        pack holds the edges into `v` (both orientations where
+        `undirected`), each once, so the MIN over the candidates' float32
+        indices (exact below 2^24) is that index whatever the fold's
+        order, on every graph."""
         key = ("frontier-parent", undirected)
         cache = self.ex._compiled
         if key in cache:
             return cache[key]
+        pack = self.ex._hybrid_pack(undirected)  # for its static sizes
         jnp = self.jnp
-        n, m = self.n, self.m
+        n = self.n
+        f32 = jnp.float32
+        # the weight rides on the sender's distance alone
+        cols = (EdgeTransform.ADD_WEIGHT,) + (EdgeTransform.NONE,) * 3
 
-        def parent_pass(dist, when, fargs):
+        def parent_pass(dist, when, hyb, row_dsts):
+            view = HybridPackView(hyb, pack)
             with frontier_scope("parent"):
-                dst, w = fargs["out_dst"], fargs["out_w"]
-                # the sender of slot s: +1 at each row's first slot
-                # (empty rows accumulate on the next one), as capped_expand
-                src = jnp.cumsum(
-                    jnp.zeros((m,), jnp.int32)
-                    .at[fargs["out_ip"][1:n]].add(1, mode="drop")
+                index = jnp.arange(n, dtype=f32)
+                leaves = hybrid_gather(
+                    jnp, view, jnp.stack([dist, when, dist, index], axis=1),
+                    Combiner.MIN, edge_transform_cols=cols,
                 )
-                d_src, d_dst = dist[src], dist[dst]
-                w_src, w_dst = when[src], when[dst]
-                best = jnp.full((n + 1,), INF, jnp.float32)
+                mine = flat_take(jnp, jnp.stack([dist, when], axis=1),
+                                 row_dsts)
 
-                def offer(best, d_p, when_p, p, d_v, when_v, v):
+                def candidates(block, lo, hi):
+                    d_v, when_v = mine[lo:hi, 0], mine[lo:hi, 1]
                     ok = (
-                        (d_p + w == d_v) & (d_v < INF)
-                        & ((d_p < d_v) | (when_p < when_v))
+                        (block[..., 0] == d_v) & (d_v < INF)
+                        & ((block[..., 2] < d_v) | (block[..., 1] < when_v))
                     )
-                    return best.at[jnp.where(ok, v, n)].min(
-                        p.astype(jnp.float32)
-                    )
+                    return jnp.where(ok, block[..., 3], INF)
 
-                best = offer(best, d_src, w_src, src, d_dst, w_dst, dst)
-                if undirected:
-                    best = offer(best, d_dst, w_dst, dst, d_src, w_src, src)
-                best = best[:n]
-                pred = jnp.where(best < INF, best, -1.0)
-                return jnp.where(
-                    when == 0.0, jnp.arange(n, dtype=jnp.float32), pred
+                best = hybrid_fold(
+                    jnp, view, leaves, Combiner.MIN, (n,), f32,
+                    leaf_fn=candidates, leaf_cols=(),
                 )
+                pred = jnp.where(best < INF, best, -1.0)
+                return jnp.where(when == 0.0, index, pred)
 
         fn = self.jax.jit(parent_pass)
         cache[key] = fn
@@ -632,11 +641,22 @@ class FrontierEngine:
             dist, pred, mask, weighted, track, und, fargs,
             program.max_iterations,
         )
+        self.last_parent_slots = 0
         if weighted and track:
             # the loop carried rounds, not parents: read the parents off
-            # the converged distances in one full-width pass
+            # the converged distances in one pass over the pack
             with tracer.phase("executor.dispatch"):
-                pred = self._parent_fn(und)(dist, pred, fargs)
+                if self.m == 0:
+                    # no pack: every vertex is unreached but the root
+                    pred = jnp.where(
+                        pred == 0.0, jnp.arange(n, dtype=jnp.float32), -1.0
+                    )
+                else:
+                    pack = self.ex._hybrid_pack(und)
+                    pred = self._parent_fn(und)(
+                        dist, pred, pack.arrays, self.ex._row_dsts(und)
+                    )
+                    self.last_parent_slots = pack.slots
             with tracer.phase("executor.sync"):
                 await_arrays((pred,))
         with tracer.phase("executor.fetch"):
@@ -723,11 +743,12 @@ class FrontierEngine:
         (`capped_expand`); a row is read whole, its positions 0 and 1
         again too, whose ends the sample gave one root (GAP skips them: no
         pass differs). `E_cap == 0` is the ladder's top rung: every slot
-        of the closure, its sender read off the pointers as `_parent_fn`
-        reads it. An edge there is linked from both ends, or from neither
-        where both lie in the giant, whose ends share a root already: the
-        same passes as the narrow form's, and no pack, since a link is no
-        fold by destination."""
+        of the closure, its sender read off the pointers (ones
+        scatter-added at each row's first slot, then a cumsum). An edge
+        there is linked from both ends, or from neither where both lie in
+        the giant, whose ends share a root already: the same passes as the
+        narrow form's, and no pack, since a link is no fold by
+        destination."""
         key = ("afforest-finish", F_cap, E_cap)
         cache = self.ex._compiled
         if key in cache:

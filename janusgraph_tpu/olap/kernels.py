@@ -636,6 +636,7 @@ class HybridPack:
         self.tail_meta: List[Tuple[int, int, int, int]] = []
         ch_starts, ch_degs, slots = [], [], []
         own_vertex, own_chunks = [], []  # per hub: id, chunks (all its rows)
+        row_dsts = [members]  # each row's destination: torso rows, chunks
         rowseg = None
         table_rows = 0
         hub = deg > self.hub_cutoff
@@ -669,6 +670,10 @@ class HybridPack:
                 ch_starts.append(starts_r[row_of] + posr * T)
                 ch_degs.append(np.minimum(T, degs_r[row_of] - posr * T))
                 slots.append(table_rows + posr * rows + row_of)
+                row_dsts.append(
+                    members[row_of] if rows == len(members)
+                    else members[rowseg[row_of]]
+                )
                 table_rows += rows * ppr
                 self.tail_meta.append((c, ppr, rows, len(members)))
                 vertex_order_parts.append(members)
@@ -678,6 +683,10 @@ class HybridPack:
                     weights=nch, minlength=len(members),
                 ).astype(np.int64))
         self.tail_chunks = sum(len(s) for s in ch_starts)
+        #: the destination of each row of the flat index vector's order
+        #: (torso rows bucket after bucket, then the tail's chunks), what a
+        #: `hybrid_fold` leaf_fn's rows [row_lo, row_hi) belong to
+        self.row_dsts = np.concatenate(row_dsts).astype(np.int32)
         self.table_rows = table_rows
         self._mode_tables = {}  # array module -> tables (mode_tables)
         self._mode_owners = (
@@ -786,16 +795,6 @@ class HybridPack:
             }
         return self._mode_tables[xp]
 
-    def row_first_slots(self) -> np.ndarray:
-        """Position in the flat index vector of each row's first slot:
-        torso rows bucket after bucket, then the tail's chunks."""
-        parts, off = [], 0
-        for d, _cap, rows in self.torso_meta:
-            parts.append(off + np.arange(rows, dtype=np.int64))
-            off += d * rows
-        parts.append(off + np.arange(self.tail_chunks, dtype=np.int64))
-        return np.concatenate(parts)
-
     def device_put(self, jnp, sharding=None):
         """Move the index/weight/slot vectors to device once."""
         put = (lambda a: a) if sharding is None else (
@@ -845,7 +844,9 @@ class HybridPackView:
 
 
 # graphlint: traced -- shared by the hybrid aggregation bodies
-def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
+def hybrid_fold(
+    xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None, leaf_cols=None
+):
     """Fold a HybridPack's gathered (and transformed) `leaves`, shaped
     (slots[, k]) in the flat index vector's order, into the per-vertex
     result. Every bucket is a static slice of `leaves`, a (width, rows)
@@ -853,11 +854,14 @@ def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
     to it *in-kernel* (the ELL bucket's sentinel slots, never gathered)
     and laid side by side, so there is one `tree_reduce` per tree width
     and not per exact width — same leaves, same tree as `ell_aggregate`.
-    `leaf_fn(block, row_lo, row_hi)` (the SDDMM coefficient pass) maps a
-    (width, rows[, k]) block of rows [row_lo, row_hi) of the pack's row
-    order before it is reduced."""
+    `leaf_fn(block, row_lo, row_hi)` (the SDDMM coefficient pass, the
+    frontier engine's parent pass) maps a (width, rows[, k]) block of rows
+    [row_lo, row_hi) of the pack's row order before it is reduced;
+    `leaf_cols` is the column shape it maps a leaf to where that is not
+    the leaves' own (`()`: one word)."""
     identity = Combiner.IDENTITY[op]
     cols = tuple(leaves.shape[1:])
+    out = cols if leaf_cols is None else tuple(leaf_cols)
     no_pad = [(0, 0)] * (1 + len(cols))
     parts = []
     off = row = 0
@@ -878,7 +882,7 @@ def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
         parts.append(tree_reduce(xp, m, op, axis=0))
 
     if pack.num_zero:
-        parts.append(xp.full((pack.num_zero,) + cols, identity, dtype=dtype))
+        parts.append(xp.full((pack.num_zero,) + out, identity, dtype=dtype))
 
     if pack.tail_chunks:
         m = leaves[off:off + pack.tail_chunk * pack.tail_chunks].reshape(
@@ -887,7 +891,7 @@ def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
         if leaf_fn is not None:
             m = leaf_fn(m, row, row + pack.tail_chunks)
         part = tree_reduce(xp, m, op, axis=0)  # (chunks[, k]): subtrees
-        table = xp.full((pack.table_rows,) + cols, identity, dtype=part.dtype)
+        table = xp.full((pack.table_rows,) + out, identity, dtype=part.dtype)
         slot = pack.arrays["slot"]
         if _is_jax(xp):
             table = table.at[slot].set(part)
@@ -898,7 +902,7 @@ def hybrid_fold(xp, pack, leaves, op: str, out_shape, dtype, leaf_fn=None):
             # remaining upper tree levels: fold each row's partial vector
             r = tree_reduce(
                 xp,
-                table[toff:toff + ppr * rows].reshape((ppr, rows) + cols),
+                table[toff:toff + ppr * rows].reshape((ppr, rows) + out),
                 op, axis=0,
             )
             toff += ppr * rows

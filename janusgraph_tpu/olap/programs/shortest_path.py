@@ -51,8 +51,9 @@ class ShortestPathProgram(VertexProgram):
       is its own predecessor; unreached is -1; following predecessors from
       any reached vertex ends at the root, also over an edge of weight 0
       or one the addition absorbs (``FrontierEngine._parent_fn`` has the
-      rule). Which of several valid parents comes back is the engine's
-      choice (the smallest index). A weighted parent is an arg-min, which
+      rule, and reads it off the pack by destination in one pass). Which
+      of several valid parents comes back is the engine's choice (the
+      smallest index). A weighted parent is an arg-min, which
       no per-column monoid fold gives, so the dense superstep path
       (``frontier="off"``, checkpointed or delta-fused runs), ``CPUExecutor``
       and the sharded executor refuse the combination by name

@@ -329,9 +329,9 @@ class TPUExecutor:
         self._hybrid_packs: Dict[object, object] = {}
         # (src, dst) of the simple closure, built on first use
         self._simple = None
-        # per-orientation row-destination vectors for the dense tier's
-        # fused SDDMM pass (features/kernels.hybrid_row_dsts)
-        self._sddmm_rows_cache: Dict[bool, object] = {}
+        # per-view row-destination vectors of the packs (the dense tier's
+        # fused SDDMM pass, the frontier engine's parent pass)
+        self._row_dsts_cache: Dict[bool, object] = {}
         self._channel_packs: "OrderedDict" = OrderedDict()
 
     def set_delta(self, delta) -> None:
@@ -488,22 +488,14 @@ class TPUExecutor:
             )
         return src, dst, w
 
-    def _sddmm_rows(self, undirected: bool):
-        """Row-destination vector for the fused SDDMM pass, aligned with
-        the pack's layout (features/kernels.hybrid_row_dsts); built once
-        per orientation and kept device-resident."""
-        from janusgraph_tpu.olap.features import kernels as fkernels
-
-        rows = self._sddmm_rows_cache.get(undirected)
+    def _row_dsts(self, undirected: bool):
+        """The destination of each row of the view's pack (`HybridPack.
+        row_dsts`), for the fused SDDMM pass and the frontier engine's
+        parent pass; moved to the device once per view and kept."""
+        rows = self._row_dsts_cache.get(undirected)
         if rows is None:
-            src, dst, _w = self._edge_view(undirected)
-            pack = self._hybrid_pack(undirected)
-            rows = self.jnp.asarray(fkernels.hybrid_row_dsts(
-                src, dst, self.csr.num_vertices,
-                hub_cutoff=pack.hub_cutoff, tail_chunk=pack.tail_chunk,
-                max_capacity=self.ell_max_capacity,
-            ))
-            self._sddmm_rows_cache[undirected] = rows
+            rows = self.jnp.asarray(self._hybrid_pack(undirected).row_dsts)
+            self._row_dsts_cache[undirected] = rows
         return rows
 
     def _hybrid_pack(self, undirected):
@@ -660,7 +652,7 @@ class TPUExecutor:
             hyb.update(pack.mode_tables(self.jnp))
         args = {"hyb": hyb}
         if getattr(program, "message_mode", None) == "sddmm":
-            args["sddmm"] = self._sddmm_rows(program.undirected)
+            args["sddmm"] = self._row_dsts(program.undirected)
         if self._delta is not None:
             args["delta"] = self._delta.device_args(
                 self.jnp, bool(program.undirected)
@@ -1193,8 +1185,10 @@ class TPUExecutor:
             info["strategy_resolved"] = "hybrid"
         # the words of a gathered piece of the pack's aggregation: 2 where
         # a 1-D state rode as rows (`kernels.row_take`), k for (n, k); a
-        # frontier run gathers the pack in its wide rounds only (an
-        # n-vector of messages; Brandes' are (n, K))
+        # frontier run's hops gather the pack in their wide rounds only
+        # (an n-vector of messages; Brandes' are (n, K)); its weighted
+        # parent pass's one gather of (n, 4) rows is `parent_slots`, and
+        # neither this nor the row counter counts it
         if info.get("path") == "frontier":
             gathers, cols = info["wide_rounds"], ()
         elif info.get("path") == "brandes":
@@ -1529,16 +1523,17 @@ class TPUExecutor:
 
         t0 = time.perf_counter()
         engine = self._frontier()
-        afforest = {}
         if type(program) is ConnectedComponentsProgram:
             out = engine.run_cc(program)
-            afforest = dict(
+            extra = dict(
                 engine.last_afforest,
                 # a superstep of Afforest is a link pass
                 supersteps=sum(engine.last_afforest["link_passes"].values()),
             )
         else:
             out = engine.run(program)
+            # the slots the weighted parent pass gathered, 0 where none ran
+            extra = {"parent_slots": engine.last_parent_slots}
         trace = engine.last_trace
         self.last_run_info = {
             "path": "frontier",
@@ -1553,7 +1548,7 @@ class TPUExecutor:
             "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
             "tier_slots": sum(t["tier_slots"] for t in trace),
             "wide_rounds": sum(t["wide"] for t in trace),
-            **afforest,
+            **extra,
         }
         return out
 

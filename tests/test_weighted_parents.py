@@ -11,6 +11,7 @@ stay bit-equal to the same fixpoint on every path that folds in float32."""
 
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -182,6 +183,156 @@ def test_zero_and_absorbed_weights_give_a_tree(weights):
         assert reconstruct_path(got, 2)[0] == 0
 
 
+def _replay(data, root, undirected):
+    """The search as plain Bellman-Ford rounds over every edge in float32:
+    (dist, when), `when` the round of each vertex's last strict improvement
+    (0 at the root, -1 where never reached). Relaxing an edge whose sender
+    did not change last round changes nothing, so these are the frontier
+    engine's rounds."""
+    src, dst = data.src.astype(np.int64), data.dst.astype(np.int64)
+    w = data.weight.astype(np.float32)
+    if undirected:
+        src, dst, w = np.r_[src, dst], np.r_[dst, src], np.r_[w, w]
+    inf = np.float32(INF)
+    dist = np.full(data.n, inf, np.float32)
+    when = np.full(data.n, -1.0, np.float32)
+    dist[root] = when[root] = 0.0
+    for t in range(data.n + 1):
+        got = np.full(data.n, inf, np.float32)
+        np.minimum.at(got, dst, dist[src] + w)
+        changed = got < dist
+        if not changed.any():
+            return dist, when, (src, dst, w)
+        dist = np.minimum(dist, got)
+        when[changed] = t + 1
+    raise AssertionError("no fixpoint")
+
+
+def _smallest_candidates(data, root, undirected):
+    """The parent rule written out: `p` is a candidate for `v` over an edge
+    p -> v of weight w where fl32(dist[p] + w) == dist[v] < INF and (dist,
+    when) of `p` is the smaller; the smallest candidate, -1 where none, the
+    root its own."""
+    dist, when, (src, dst, w) = _replay(data, root, undirected)
+    ok = ((dist[src] + w == dist[dst]) & (dist[dst] < np.float32(INF))
+          & ((dist[src] < dist[dst]) | (when[src] < when[dst])))
+    best = np.full(data.n, np.inf, np.float32)
+    np.minimum.at(best, dst[ok], src[ok].astype(np.float32))
+    pred = np.where(np.isfinite(best), best, np.float32(-1.0))
+    pred[when == 0] = root
+    return dist, pred.astype(np.float32)
+
+
+def _with_hub(seed):
+    """multigraph(seed) and 200 edges more into vertex 5, zero weights
+    among them: a hub whose row the pack cuts into tail chunks and, at a
+    small capacity, splits."""
+    data = multigraph(seed)
+    rng = np.random.default_rng(seed + 100)
+    src = rng.integers(0, data.n - 40, 200).astype(np.int32)
+    weight = rng.random(200, dtype=np.float32)
+    weight[:20] = 0.0
+    return Edges(data.n, np.r_[data.src, src],
+                 np.r_[data.dst, np.full(200, 5, np.int32)],
+                 np.r_[data.weight, weight])
+
+
+def _triangle(weights):
+    return Edges(3, np.array([0, 0, 1, 2], np.int32),
+                 np.array([1, 2, 2, 1], np.int32),
+                 np.array(weights, np.float32))
+
+
+#: case -> (graph, executor options, what the search must have done)
+PARENT_CASES = {
+    **{f"multigraph-{seed}": (lambda seed=seed: multigraph(seed), {}, "wide")
+       for seed in range(3)},
+    "zero-triangle": (lambda: _triangle([1.0, 1.0, 0.0, 0.0]), {}, "wide"),
+    "absorbed-triangle": (
+        lambda: _triangle([0.5, 0.5, 2.0 ** -30, 2.0 ** -30]), {}, "wide"),
+    "tail-and-split-hub": (
+        lambda: _with_hub(4),
+        dict(hub_cutoff=4, tail_chunk=4, ell_max_capacity=32), "split"),
+    "never-wide": (
+        lambda: multigraph(5, n=260, m=1100, island=30),
+        dict(frontier_e_min=1024, frontier_f_min=16), "narrow"),
+    "no-edges": (
+        lambda: Edges(5, np.zeros(0, np.int32), np.zeros(0, np.int32),
+                      np.zeros(0, np.float32)), {}, "empty"),
+}
+
+
+@pytest.mark.parametrize("undirected", [True, False],
+                         ids=["undirected", "directed"])
+@pytest.mark.parametrize("case", list(PARENT_CASES))
+def test_parent_pass_returns_the_smallest_candidate_bit_for_bit(case,
+                                                                undirected):
+    """The parent pass reads the pack by destination: for every vertex the
+    smallest index among the senders that pass the parent rule, equal to
+    the rule written out over the edge list, bit for bit, whatever the
+    pack's layout (torso, tail chunks, split rows), whether or not a wide
+    round built the pack before the pass, and on a graph with no edge."""
+    build, options, expect = PARENT_CASES[case]
+    data = build()
+    ex = TPUExecutor(_csr(data), **options)
+    got = ex.run(_program(0, undirected, track_paths=True),
+                 frontier="always")
+    info = ex.last_run_info
+    assert (info["wide_rounds"] > 0) == (expect in ("wide", "split"))
+    if expect == "empty":  # no edge, no pack: no pass
+        assert info["parent_slots"] == 0
+    else:
+        pack = ex._hybrid_pack(undirected)
+        assert info["parent_slots"] == pack.slots
+        if expect == "split":
+            assert pack.tail_chunks > 0 and "rowseg" in pack.arrays
+    dist, pred = _smallest_candidates(data, 0, undirected)
+    np.testing.assert_array_equal(
+        np.asarray(got["distance"]).view(np.uint32), dist.view(np.uint32))
+    np.testing.assert_array_equal(
+        np.asarray(got["predecessor"]).view(np.uint32), pred.view(np.uint32))
+
+
+#: an HLO instruction: name, leading dimensions, op, operands
+_HLO_DEF = re.compile(
+    r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]\S* (\S+)\(([^)]*)\)", re.M)
+
+
+@pytest.mark.parametrize("undirected", [True, False],
+                         ids=["undirected", "directed"])
+def test_parent_pass_gathers_the_pack_once_and_scatters_no_slot(undirected):
+    """The lowering of the parent pass holds exactly one gather whose index
+    vector has the pack's slot count, and no scatter whose updates have the
+    count of the view's edges or of the pack's slots: the element gathers
+    and scatter-mins over the edges are gone."""
+    data = _with_hub(6)
+    ex = TPUExecutor(_csr(data), hub_cutoff=4, tail_chunk=4,
+                     ell_max_capacity=32)
+    got = ex.run(_program(0, undirected, track_paths=True),
+                 frontier="always")
+    pack, rows = ex._hybrid_pack(undirected), ex._row_dsts(undirected)
+    assert pack.tail_chunks > 0 and "rowseg" in pack.arrays
+    counts = {pack.slots, pack.num_edges, ex.csr.num_edges}
+    assert not counts & {data.n, rows.shape[0], pack.tail_chunks}
+    fn = ex._frontier()._parent_fn(undirected)
+    text = fn.lower(
+        np.asarray(got["distance"]), np.zeros(data.n, np.float32),
+        pack.arrays, rows,
+    ).as_text(dialect="hlo")
+    # HLO names are unique in a module: each operand's leading dimension
+    lead, ops = {}, {"gather": [], "scatter": []}
+    for name, dims, op, operands in _HLO_DEF.findall(text):
+        lead[name] = int(dims.split(",")[0]) if dims else 1
+        if op in ops:
+            ops[op].append([a.strip() for a in operands.split(",")])
+    # gather(operand, indices); scatter(operand, indices, updates)
+    gathered = [lead[a[1]] for a in ops["gather"]]
+    scattered = [lead[a[2]] for a in ops["scatter"]]
+    assert len(gathered) >= 2 and scattered
+    assert gathered.count(pack.slots) == 1
+    assert not counts & set(scattered)
+
+
 def test_submit_runs_the_combination_on_the_frontier_engine():
     from janusgraph_tpu.core.graph import open_graph
     from janusgraph_tpu.olap import delta
@@ -212,20 +363,27 @@ def test_frontier_run_record_totals_and_registry_counters(program,
     """`rounds`, `relaxed_slots`, `tier_slots`, `wide_rounds`: sums over
     the hops of the record's own `tiers`, and the registry's counters move
     by them. A hop holds `E_cap` slots an orientation, a wide one (the top
-    rung) the slots of the pack it gathers."""
+    rung) the slots of the pack it gathers. `parent_slots` is the pack's
+    slots where the weighted parent pass ran, else 0; its gather is rows
+    of four words, so `gather_words` (2) and `olap.pack.row_gathers` (one
+    a wide round) count the wide rounds alone."""
     from janusgraph_tpu.observability import registry
 
     def counters():
         snap = registry.snapshot()
-        return {k: snap.get("olap.frontier." + k, {}).get("count", 0)
-                for k in ("rounds", "relaxed_slots", "tier_slots",
-                          "wide_rounds")}
+        got = {k: snap.get("olap.frontier." + k, {}).get("count", 0)
+               for k in ("rounds", "relaxed_slots", "tier_slots",
+                         "wide_rounds")}
+        got["row_gathers"] = snap.get(
+            "olap.pack.row_gathers", {}).get("count", 0)
+        return got
 
     # the ladder (128, m): every search here has wide and narrow hops
     ex = TPUExecutor(_csr(multigraph(3)), frontier_e_min=64,
                      frontier_f_min=16, autotune_max_tiers=2)
     before = counters()
-    ex.run(program())
+    program = program()
+    ex.run(program)
     info = registry.last_run("olap")
     assert info["path"] == "frontier" and info["rounds"] == len(info["tiers"])
     assert info["relaxed_slots"] == sum(
@@ -239,7 +397,11 @@ def test_frontier_run_record_totals_and_registry_counters(program,
     assert 0 < info["wide_rounds"] < info["rounds"]
     assert info["wide_rounds"] == sum(t["wide"] for t in info["tiers"])
     assert all(t["relaxed_slots"] <= t["tier_slots"] for t in info["tiers"])
+    parents = program.weighted and program.track_paths
+    assert info["parent_slots"] == (pack_slots if parents else 0)
+    assert info["gather_words"] == 2
     moved = {k: v - before[k] for k, v in counters().items()}
+    assert moved.pop("row_gathers") == info["wide_rounds"]
     assert moved == {k: info[k] for k in moved}
 
 
