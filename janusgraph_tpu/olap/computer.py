@@ -304,12 +304,15 @@ class GraphComputer:
                 ndev = len(jax.devices())
             except Exception:
                 ndev = 1
+            from janusgraph_tpu.olap.routes import has_superstep_form
+
             # the mesh's exchanges pre-combine partials: a MODE program
-            # stays on one device, like an sddmm one
+            # stays on one device, like an sddmm one and one with no
+            # superstep form (the mesh's executor runs supersteps)
             whole_multiset = self._program.combiner == Combiner.MODE
             if ndev > 1 and not whole_multiset and getattr(
                 self._program, "sharded_compatible", True
-            ):
+            ) and has_superstep_form(self._program):
                 executor_kind = "sharded"
                 routing = {
                     "requested": self.executor_kind, "routed": "sharded",

@@ -1284,17 +1284,19 @@ def program_delta_compatible(program) -> bool:
     no MODE combiner (`fused_delta_aggregate` merges lane partials into
     the base aggregate, and a mode cannot be folded from partials: the
     overlay is materialized before such a program runs), no program that
-    runs no superstep at all (`LCCProgram`: `fuses_delta_overlay`)."""
-    from janusgraph_tpu.olap.vertex_program import Combiner, VertexProgram
+    runs no superstep at all (`olap/routes.py`: `LCCProgram`,
+    `BetweennessCentralityProgram`)."""
+    from janusgraph_tpu.olap.routes import has_superstep_form
+    from janusgraph_tpu.olap.vertex_program import Combiner
 
     if getattr(program, "combiner", None) == Combiner.MODE:
         return False
-    if not getattr(program, "fuses_delta_overlay", True):
+    if not has_superstep_form(program):
         return False
     if getattr(program, "message_mode", None) == "sddmm":
         return False
     if getattr(program, "edge_channels", None):
         return False
-    if type(program).channel_for is not VertexProgram.channel_for:
+    if program.overrides("channel_for"):
         return False
     return True

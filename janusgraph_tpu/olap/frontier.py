@@ -60,11 +60,9 @@ order (`_cc_args`): a vertex without one is its own root throughout.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
-
 import numpy as np
 
-from janusgraph_tpu.observability import tracer
+from janusgraph_tpu.observability import registry, tracer
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.kernels import (
     HybridPackView,
@@ -604,13 +602,51 @@ class FrontierEngine:
             # the last step is still running: wait for it here, so that
             # the caller's fetch is the copy alone
             await_arrays((value, pred))
-        # observability: which tiers each hop actually priced at — the
-        # per-hop analogue of .profile() (read via executor.last_run_info)
-        self.last_trace = trace
-        return value, pred
+        # which tiers each hop actually priced at: the per-hop analogue of
+        # .profile(), the run record's `tiers`
+        return value, pred, trace
 
-    def run(self, program) -> Dict[str, np.ndarray]:
-        """SSSP/BFS through the shared hop loop."""
+    def _record(self, path, trace, view, cols=(), **fields):
+        """The run record of `trace`'s hops over `view` (its pack and
+        ladder), and the engine's counters: totals a layer metric can read
+        (hops, slots relaxed, slots their tiers held, hops run as wide
+        rounds on the pack), one superstep record a hop. A wide round
+        gathers pieces `cols` wide (`()`: a 1-D state as rows of two
+        words, `kernels.row_take`, counted by `olap.pack.row_gathers`)."""
+        relaxed = sum(t["relaxed_slots"] for t in trace)
+        held = sum(t["tier_slots"] for t in trace)
+        wide = sum(t["wide"] for t in trace)
+        registry.counter("olap.frontier.rounds").inc(len(trace))
+        registry.counter("olap.frontier.relaxed_slots").inc(relaxed)
+        registry.counter("olap.frontier.tier_slots").inc(held)
+        registry.counter("olap.frontier.wide_rounds").inc(wide)
+        registry.counter("olap.pack.row_gathers").inc(0 if cols else wide)
+        pack = self.ex._hybrid_packs.get(view)
+        record = {
+            "path": path,
+            "supersteps": len(trace),
+            "tiers": trace,
+            "rounds": len(trace),
+            "relaxed_slots": relaxed,
+            "tier_slots": held,
+            "wide_rounds": wide,
+            "pad_ratio": None if pack is None else round(pack.pad_ratio, 4),
+            "autotune": self.ex._autotune(view).as_dict(),
+            "superstep_records": [
+                {"step": int(t.get("hop", i)),
+                 "frontier": int(t.get("frontier", 0)),
+                 "edges": int(t.get("edges", 0)),
+                 "e_cap": int(t.get("E_cap", 0))}
+                for i, t in enumerate(trace)
+            ],
+        }
+        if wide:
+            record["gather_words"] = cols[0] if cols else 2
+        record.update(fields)
+        return record
+
+    def run(self, program):
+        """SSSP/BFS through the shared hop loop: (states, run record)."""
         # the hop loop relaxes by scatter-min: partials merged in place
         Combiner.require_foldable(program.combiner, "the frontier engine")
         jnp = self.jnp
@@ -637,11 +673,14 @@ class FrontierEngine:
                 )
             mask = jnp.asarray(idx0 == program.seed_index)
             fargs = self._fargs(und, weighted)
-        dist, pred = self._hop_loop(
+        dist, pred, trace = self._hop_loop(
             dist, pred, mask, weighted, track, und, fargs,
             program.max_iterations,
         )
-        self.last_parent_slots = 0
+        # the slots the weighted parent pass gathered, 0 where none ran
+        # (its one gather of (n, 4) rows is neither `gather_words` nor a
+        # row gather)
+        parent_slots = 0
         if weighted and track:
             # the loop carried rounds, not parents: read the parents off
             # the converged distances in one pass over the pack
@@ -656,14 +695,16 @@ class FrontierEngine:
                     pred = self._parent_fn(und)(
                         dist, pred, pack.arrays, self.ex._row_dsts(und)
                     )
-                    self.last_parent_slots = pack.slots
+                    parent_slots = pack.slots
             with tracer.phase("executor.sync"):
                 await_arrays((pred,))
         with tracer.phase("executor.fetch"):
             out = {"distance": np.asarray(dist)}
             if track:
                 out["predecessor"] = np.asarray(pred)
-        return out
+        return out, self._record(
+            "frontier", trace, und, parent_slots=parent_slots
+        )
 
     # -------------------------------------------------------------- afforest
     def _afforest_sample_fn(self):
@@ -786,7 +827,7 @@ class FrontierEngine:
         cache[key] = fn
         return fn
 
-    def run_cc(self, program) -> Dict[str, np.ndarray]:
+    def run_cc(self, program):
         """Connected components to the fixpoint by Afforest over the
         executor's simple closure, in its CC view (`_cc_args`: the
         vertices that have an edge alone, renumbered in their own order,
@@ -799,9 +840,9 @@ class FrontierEngine:
         Labels are float32 vertex indices (exact below 2^24:
         eligibility-guarded), each component's smallest. The host's time
         is tiled by `executor.setup` / `.dispatch` (with the sample's
-        `executor.sync` inside) / `.sync` / `.fetch`. `last_afforest`
-        holds the run's counts for the run record; `last_trace` the
-        finish's tier, if one ran."""
+        `executor.sync` inside) / `.sync` / `.fetch`. Returns (states, run
+        record): the record's `tiers` hold the finish's tier, if one ran,
+        and a superstep of Afforest is a link pass."""
         Combiner.require_foldable(program.combiner, "the frontier engine")
         jax = self.jax
         n = self.n
@@ -848,21 +889,21 @@ class FrontierEngine:
             out = {"component": np.asarray(labels)}
         if finished:
             passes[1], jumps[1] = (int(x) for x in finished)
-        self.last_trace = trace
-        self.last_afforest = {
-            "algorithm": "afforest",
-            "link_passes": {"sample": passes[0], "finish": passes[1]},
-            "compress_jumps": {"sample": jumps[0], "finish": jumps[1]},
+        return out, self._record(
+            "frontier", trace, self.ex.SIMPLE_VIEW,
+            supersteps=sum(passes),
+            algorithm="afforest",
+            link_passes={"sample": passes[0], "finish": passes[1]},
+            compress_jumps={"sample": jumps[0], "finish": jumps[1]},
             # what the finish read, its tier's padding excluded
-            "finish_vertices": count,
-            "finish_slots": slots,
-            "closure_slots": m,
+            finish_vertices=count,
+            finish_slots=slots,
+            closure_slots=m,
             # the rows the sample's and the finish's vectors hold, and n
-            "sample_vertices": int(args["out_deg"].shape[0]),
-            "vertices": n,
-            "giant_share": giant / max(n, 1),
-        }
-        return out
+            sample_vertices=int(args["out_deg"].shape[0]),
+            vertices=n,
+            giant_share=giant / max(n, 1),
+        )
 
     def _cc_args(self):
         """Afforest's view of the simple closure (`run_cc`): where some
@@ -1042,7 +1083,7 @@ class FrontierEngine:
             cache[key] = self.jax.jit(brandes_backward)
         return cache[key]
 
-    def run_brandes(self, program) -> Dict[str, np.ndarray]:
+    def run_brandes(self, program):
         """Brandes from `program.sources`, as the K columns of one run,
         over the executor's simple closure (ONE directed orientation of
         both orientations of each simple edge).
@@ -1060,8 +1101,9 @@ class FrontierEngine:
         The host's time is tiled by phases: `executor.setup` (the start
         vectors), `executor.dispatch` (per forward hop the plan, its
         `executor.sync`, the tier choice and the step; every backward
-        level), `executor.sync`, `executor.fetch`. `last_trace` lists
-        every level run, forward then backward, with its sweep."""
+        level), `executor.sync`, `executor.fetch`. Returns (states, run
+        record): its `tiers` list every level run, forward then backward,
+        with its sweep."""
         jax, jnp = self.jax, self.jnp
         n = self.n
         sources = np.asarray(program.sources, np.int64)
@@ -1130,6 +1172,16 @@ class FrontierEngine:
             scores = self._brandes_total_fn()(delta)
         with tracer.phase("executor.sync"):
             await_arrays((scores,))
-        self.last_trace = trace
         with tracer.phase("executor.fetch"):
-            return {"betweenness": np.asarray(scores)}
+            out = {"betweenness": np.asarray(scores)}
+        forward = sum(t["sweep"] == "forward" for t in trace)
+        return out, self._record(
+            "brandes", trace, self.ex.SIMPLE_VIEW, cols=(K,),
+            sources=list(program.sources),
+            # the deepest level any column reached (the last forward hop
+            # reaches nothing); the backward sweep runs levels L ... 2
+            levels=max(forward - 1, 0),
+            forward_rounds=forward,
+            backward_rounds=len(trace) - forward,
+            closure_slots=m,
+        )

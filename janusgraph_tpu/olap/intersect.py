@@ -1,7 +1,7 @@
 """Neighbourhood intersection: per-vertex triangle counts and the local
 clustering coefficient in one compiled pass (LDBC Graphalytics LCC;
 ROADMAP M4). The frontier engine's sibling: an engine with no numpy
-superstep, reached from `TPUExecutor.run` for `LCCProgram`.
+superstep, `LCCProgram`'s route (`olap/routes.py`).
 
 Every other path of the executor moves ONE value per edge from a sender to
 a receiver and folds it. A common-neighbour count reads two adjacency ROWS
@@ -47,11 +47,11 @@ the count: such a graph is refused by name at submit.
 
 from __future__ import annotations
 
-from typing import Dict
+import time
 
 import numpy as np
 
-from janusgraph_tpu.observability import tracer
+from janusgraph_tpu.observability import registry, tracer
 from janusgraph_tpu.olap.csr import simple_closure
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.frontier import capped_expand
@@ -435,7 +435,10 @@ class IntersectEngine:
         cache[key] = fn
         return fn
 
-    def run(self, program) -> Dict[str, np.ndarray]:
+    def run(self, program):
+        """One compiled pass: (states, run record), and the engine's
+        counters."""
+        t0 = time.perf_counter()
         with tracer.phase("executor.setup"):
             fn = self._pass_fn()
         with tracer.phase("executor.dispatch"):
@@ -443,6 +446,30 @@ class IntersectEngine:
         with tracer.phase("executor.sync"):
             await_arrays((triangles, lcc))
         with tracer.phase("executor.fetch"):
-            return {
+            out = {
                 "triangles": np.asarray(triangles), "lcc": np.asarray(lcc),
             }
+        wall_ms = round((time.perf_counter() - t0) * 1000.0, 4)
+        view = self.view
+        registry.counter("olap.intersect.runs").inc()
+        registry.counter("olap.intersect.candidates").inc(view.candidates)
+        registry.counter("olap.intersect.probe_slots").inc(view.probe_slots)
+        return out, {
+            "path": "intersect",
+            "supersteps": 1,
+            "dispatches": 1,
+            # totals a layer metric can read: the closure's edges, the
+            # pairs the pass decides (as a forward count lists them), the
+            # words and elements it gathers to decide them (padding
+            # included), the triangles of the graph
+            "simple_edges": view.simple_edges,
+            "candidates": view.candidates,
+            "probe_slots": view.probe_slots,
+            "triangles_total": int(
+                out["triangles"].sum(dtype=np.int64) // 3
+            ),
+            "intersect": dict(view.sizes),
+            "superstep_records": [{
+                "step": 0, "wall_ms": wall_ms, "edges": view.simple_edges,
+            }],
+        }

@@ -19,8 +19,8 @@ scalar). State `betweenness`: float32 per vertex.
 No message / fold / apply superstep computes it: the path counts are
 summed forward level by level and the dependencies summed BACK over the
 same levels in reverse. The single-device executor runs the program on
-its frontier engine (`FrontierEngine.run_brandes`), the sources as the
-columns of one `(n, K)` run; every executor whose run is supersteps
+its frontier engine (`FrontierEngine.run_brandes`, by its route in
+`olap/routes.py`), the sources as the columns of one `(n, K)` run; every executor whose run is supersteps
 refuses it by name (`require_dense_capable`).
 """
 
@@ -35,12 +35,6 @@ MAX_SOURCES = 8
 class BetweennessCentralityProgram(VertexProgram):
     compute_keys = ("betweenness",)
     undirected = True
-    #: the mesh's executor runs supersteps: auto-routing keeps the program
-    #: on one device (olap/computer.py), as it keeps LCCProgram
-    sharded_compatible = False
-    #: a pending overlay is folded into fresh arrays before the program
-    #: runs (olap/delta.program_delta_compatible)
-    fuses_delta_overlay = False
 
     def __init__(self, sources):
         sources = tuple(int(s) for s in sources)

@@ -13,8 +13,8 @@ once, self loops never, direction is ignored, isolated vertices read 0.
 
 No message / fold / apply superstep computes it: a common-neighbour count
 reads two adjacency rows against each other. The single-device executor
-runs the program on its intersection engine (`olap/intersect.py`), one
-compiled pass a submit; every executor whose run is supersteps refuses it
+runs the program on its intersection engine (`olap/intersect.py`, by its
+route in `olap/routes.py`), one compiled pass a submit; every executor whose run is supersteps refuses it
 by name (`require_dense_capable`). States: `triangles` (int32, T(v),
 exact) and `lcc` (`float32(T) / float32(d (d - 1) / 2)`).
 
@@ -31,12 +31,6 @@ class LCCProgram(VertexProgram):
     compute_keys = ("triangles", "lcc")
     undirected = True
     max_iterations = 1
-    #: the mesh's executor runs supersteps: auto-routing keeps the program
-    #: on one device (olap/computer.py), as it keeps an sddmm program
-    sharded_compatible = False
-    #: a pending overlay is folded into fresh arrays before the program
-    #: runs (olap/delta.program_delta_compatible)
-    fuses_delta_overlay = False
 
     def require_dense_capable(self, path: str) -> None:
         raise ValueError(

@@ -1124,7 +1124,11 @@ class SpilloverPlanner:
                 ndev = len(jax.devices())
             except Exception:  # noqa: BLE001 - jax may be uninitialized
                 ndev = 1
-            if ndev > 1 and getattr(program, "sharded_compatible", True):
+            from janusgraph_tpu.olap.routes import has_superstep_form
+
+            if ndev > 1 and getattr(
+                program, "sharded_compatible", True
+            ) and has_superstep_form(program):
                 executor = "sharded"
         if executor == "tpu":
             from janusgraph_tpu.olap.tpu_executor import TPUExecutor

@@ -15,7 +15,6 @@ handful of scalars.
 from __future__ import annotations
 
 import time
-from functools import partial
 from typing import Dict, Tuple
 
 import numpy as np
@@ -24,6 +23,11 @@ from janusgraph_tpu.observability import registry, tracer
 from janusgraph_tpu.olap.csr import CSRGraph
 from janusgraph_tpu.olap.device import await_arrays
 from janusgraph_tpu.olap.kernels import superstep_scope
+from janusgraph_tpu.olap.routes import (
+    CHECKPOINT_REFUSAL,
+    FRONTIER_CC_MIN_EDGES,
+    route_of,
+)
 from janusgraph_tpu.olap.vertex_program import (
     Combiner,
     Memory,
@@ -904,14 +908,17 @@ class TPUExecutor:
     ) -> Dict[str, np.ndarray]:
         """Run to termination.
 
-        `frontier` (default: the executor's configured mode) — per-run
-        override of the frontier-compaction special case for
-        ShortestPath/ConnectedComponents: "auto" sizes by graph (BFS/SSSP
-        always; CC to the fixpoint only above FRONTIER_CC_MIN_EDGES, by
-        Afforest), "always" forces it, "off" forces the dense BSP path for
-        this run. A CC with an explicit `max_iterations` always runs dense
-        ("always" refuses it by name: Afforest has no state after k
-        supersteps).
+        A program whose exact type has a route (`olap/routes.py`: BFS /
+        SSSP, CC, betweenness, LCC) runs on that route's engine, which
+        writes its own run record, where the route's guards admit the
+        graph; every other run is dense supersteps. `frontier` (default:
+        the executor's configured mode) is the per-run override: "auto"
+        takes a route its guards admit (CC only to the fixpoint, by
+        Afforest, from FRONTIER_CC_MIN_EDGES edges), "always" takes it or
+        refuses the run with the reason, "off" runs this one dense. A
+        checkpointed run or a pending delta overlay takes no route. A
+        program with no superstep form (betweenness, LCC) is refused by
+        name wherever its route is not taken.
 
         `fused` (default: auto) — compile the whole iteration into one
         dispatch (programs with a constant combiner + a terminate_device
@@ -952,7 +959,7 @@ class TPUExecutor:
                     "sddmm message mode aggregates over the in-CSR only — "
                     "undirected dense programs are not supported"
                 )
-            if type(program).channel_for is not VertexProgram.channel_for:
+            if program.overrides("channel_for"):
                 raise ValueError(
                     "sddmm message mode cannot ride typed edge channels"
                 )
@@ -988,66 +995,19 @@ class TPUExecutor:
             frontier = "off"
         if frontier not in (None, "auto", "off", "always"):
             raise ValueError(f"unknown frontier mode: {frontier!r}")
-        mode = frontier or self._frontier_cfg
-        use_frontier = False
-        if mode != "off" and self._frontier_family(program):
-            if checkpoint_path:
-                # the frontier loop has no checkpoint support; "always"
-                # must never silently time the dense path under a frontier
-                # label, so refuse the combination outright
-                if mode == "always":
-                    raise ValueError(
-                        "frontier='always' cannot be combined with "
-                        "checkpointing (the frontier loop does not "
-                        "checkpoint) — drop checkpoint_path or use "
-                        "frontier='auto'"
-                    )
-            elif self._frontier_eligible(program, mode):
-                use_frontier = True
-            elif mode == "always" and self._cc_cut(program):
-                raise ValueError(
-                    "frontier='always' but ConnectedComponentsProgram("
-                    f"max_iterations={program.max_iterations}) asks for the "
-                    f"labels after {program.max_iterations} supersteps of "
-                    "propagation, a state the frontier engine's Afforest "
-                    "never has — use max_iterations=None (to the fixpoint) "
-                    "or frontier='auto' / 'off' (the dense path)"
-                )
-            elif mode == "always":
-                # surface WHY the guards refused instead of silently
-                # timing the dense path under a frontier label
-                raise ValueError(
-                    "frontier='always' but the graph exceeds the frontier "
-                    f"engine's guards (|V|={self.csr.num_vertices}, "
-                    f"|E|={self.csr.num_edges}; float32 label/predecessor "
-                    "exactness needs |V| < 2^24, int32 expansion needs "
-                    "|E| < 2^30) — use frontier='auto' or 'off'"
-                )
-        # the intersection engine has no dense form to fall back on and
-        # no checkpoint: off or checkpointed, the program is refused below
-        use_intersect = (
-            self._intersect_family(program)
-            and mode != "off" and not checkpoint_path
+        route = self._route(
+            program, frontier or self._frontier_cfg, checkpoint_path
         )
-        # nor have Brandes' two sweeps (the frontier engine's, under
-        # ShortestPath's guards); refused below where they do not run
-        use_brandes = (
-            self._brandes_family(program)
-            and mode != "off" and not checkpoint_path
-            and self._brandes_eligible()
-        )
-        if not (use_frontier or use_intersect or use_brandes):
+        if route is None:
             program.require_dense_capable(
                 "the dense superstep path of the single-device executor"
             )
         if fused is None:
             fused = program.fused_eligible()
         use_fused = (
-            not use_frontier
-            and not use_intersect
-            and not use_brandes
+            route is None
             and fused
-            and type(program).combiner_for is VertexProgram.combiner_for
+            and not program.overrides("combiner_for")
         )
         # telemetry around the whole run: walls/sizes/compile counts are
         # all host-resident — nothing here records from traced code
@@ -1057,7 +1017,7 @@ class TPUExecutor:
         t0 = time.perf_counter()
         with tracer.span(
             "olap.run",
-            program=type(program).__name__,
+            program=program.__class__.__name__,
             executor="tpu",
         ) as sp:
             from janusgraph_tpu.exceptions import SuperstepPreempted
@@ -1066,12 +1026,11 @@ class TPUExecutor:
             resume_steps = []
             while True:
                 try:
-                    if use_frontier:
-                        out = self._run_frontier(program)
-                    elif use_intersect:
-                        out = self._run_intersect(program)
-                    elif use_brandes:
-                        out = self._run_brandes(program)
+                    if route is not None:
+                        engine = getattr(self, "_" + route.engine)()
+                        out, self.last_run_info = getattr(
+                            engine, route.entry
+                        )(program)
                     elif use_fused:
                         out = self._run_fused(
                             program, checkpoint_path, checkpoint_every,
@@ -1103,7 +1062,7 @@ class TPUExecutor:
 
                     flight_recorder.record(
                         "olap_resume", executor="tpu", attempt=resumes,
-                        program=type(program).__name__,
+                        program=program.__class__.__name__,
                     )
             if self._delta is not None:
                 # trim vcap-tier padding: real rows are the base snapshot
@@ -1123,17 +1082,20 @@ class TPUExecutor:
                 self.last_run_info["resume_steps"] = resume_steps
                 sp.annotate(resumes=resumes)
             # the run record's own cost (estimate roofline, gauges,
-            # per-superstep record_spans): what ROADMAP D7 would remove
+            # per-superstep record_spans): what ROADMAP D9 would remove
             with tracer.phase("executor.publish"):
                 self._finish_run(
                     sp, program, out,
                     time.perf_counter() - t0,
                     len(self._compiled) - compiled_before,
+                    dense=route is None,
                 )
         return out
 
     # ------------------------------------------------------------ telemetry
-    def _finish_run(self, sp, program, result, wall_s, new_execs) -> None:
+    def _finish_run(
+        self, sp, program, result, wall_s, new_execs, dense
+    ) -> None:
         """Publish the finished run: enrich `last_run_info` with retrace/
         transfer/pad numbers, attach per-superstep child spans, set the
         OLAP gauges, and hand the record to the telemetry registry
@@ -1150,100 +1112,72 @@ class TPUExecutor:
             sum(np.asarray(v).nbytes for v in result.values())
         )
         undirected = bool(getattr(program, "undirected", False))
-        # the edge view the run's pack and tiers are of
-        view = (
-            self.SIMPLE_VIEW
-            if info.get("path") == "brandes" or "link_passes" in info
-            else undirected
-        )
-        pad_ratio = None
-        # a channel-switching program's packs are its channels' own, the
-        # largest first; any other program's is its edge view's
-        channel_packs = sorted(
-            (
-                self._channel_packs[c]
-                for c in set(program.edge_channels.values())
-                if c in self._channel_packs
-            ),
-            key=lambda entry: -entry[0].slots,
-        )
-        hyb = self._hybrid_packs.get(view)
-        if channel_packs:
-            pad_ratio = round(
-                sum(p.slots for p, _d in channel_packs)
-                / max(1, sum(p.num_edges for p, _d in channel_packs)),
-                4,
+        records = info.setdefault("superstep_records", [])
+        if dense:
+            # an engine writes its own pack's pad ratio, gathers and tuner
+            # decision; every dense superstep aggregates over a pack: a
+            # channel-switching program's are its channels' own, the
+            # largest first, any other program's is its edge view's
+            channel_packs = sorted(
+                (
+                    self._channel_packs[c]
+                    for c in set(program.edge_channels.values())
+                    if c in self._channel_packs
+                ),
+                key=lambda entry: -entry[0].slots,
             )
-        elif hyb is not None:
-            pad_ratio = round(hyb.pad_ratio, 4)
+            if channel_packs:
+                info["pad_ratio"] = round(
+                    sum(p.slots for p, _d in channel_packs)
+                    / max(1, sum(p.num_edges for p, _d in channel_packs)),
+                    4,
+                )
+            elif undirected in self._hybrid_packs:
+                info["pad_ratio"] = round(
+                    self._hybrid_packs[undirected].pad_ratio, 4
+                )
+            info["strategy_resolved"] = "hybrid"
+            # the words of a gathered piece of the pack's aggregation: 2
+            # where a 1-D state rode as rows (`kernels.row_take`), k for
+            # (n, k)
+            gathers = info.get("supersteps", 0)
+            cols = self._gather_cols.get(program.cache_key())
+            if gathers and cols is not None:
+                info["gather_words"] = cols[0] if cols else 2
+            registry.counter("olap.pack.row_gathers").inc(
+                gathers if cols == () else 0
+            )
+            # a MODE run also says what its fold was given, as the pack
+            # knows it (static numbers)
+            if program.combiner == Combiner.MODE:
+                from janusgraph_tpu.olap.kernels import mode_fold_sizes
+
+                info["mode_fold"] = mode_fold_sizes(
+                    channel_packs[0][0] if channel_packs
+                    else self._hybrid_pack(undirected)
+                )
+            # the tuner's decision travels with every run record
+            # (/telemetry reads it from here)
+            info["autotune"] = (
+                channel_packs[0][1] if channel_packs
+                else self._autotune(undirected)
+            ).as_dict()
+        pad_ratio = info.setdefault("pad_ratio", None)
         # `ell_pad_ratio` is the older name of `pad_ratio`: the mesh's
         # record and observability/benchdiff.py carry it too
         info["ell_pad_ratio"] = pad_ratio
-        info["pad_ratio"] = pad_ratio
-        if info.get("path") not in ("frontier", "intersect", "brandes"):
-            # every dense superstep aggregates over the hybrid pack
-            info["strategy_resolved"] = "hybrid"
-        # the words of a gathered piece of the pack's aggregation: 2 where
-        # a 1-D state rode as rows (`kernels.row_take`), k for (n, k); a
-        # frontier run's hops gather the pack in their wide rounds only
-        # (an n-vector of messages; Brandes' are (n, K)); its weighted
-        # parent pass's one gather of (n, 4) rows is `parent_slots`, and
-        # neither this nor the row counter counts it
-        if info.get("path") == "frontier":
-            gathers, cols = info["wide_rounds"], ()
-        elif info.get("path") == "brandes":
-            gathers, cols = info["wide_rounds"], (len(program.sources),)
-        else:
-            gathers = info.get("supersteps", 0)
-            cols = self._gather_cols.get(program.cache_key())
-        if gathers and cols is not None:
-            info["gather_words"] = cols[0] if cols else 2
-        row_gathers = gathers if cols == () else 0
-        # the combiner(s) the run folded with; a MODE run also says what
-        # its fold was given, as the pack knows it (static numbers)
+        # the combiner(s) the run folded with
         info["combiner"] = "+".join(dict.fromkeys(
-            r.get("combiner", program.combiner)
-            for r in info.get("superstep_records") or [{}]
+            r.get("combiner", program.combiner) for r in records or [{}]
         ))
-        if program.combiner == Combiner.MODE:
-            from janusgraph_tpu.olap.kernels import mode_fold_sizes
-
-            info["mode_fold"] = mode_fold_sizes(
-                channel_packs[0][0] if channel_packs
-                else self._hybrid_pack(undirected)
-            )
-        # the tuner's decision travels with every run record (/telemetry
-        # reads it from here)
-        # (the intersection engine sizes its own tables from the degrees:
-        # `info["intersect"]`; it reads no pack and no tier)
-        if info.get("path") != "intersect":
-            decision = (
-                channel_packs[0][1] if channel_packs
-                else self._autotune(view)
-            )
-            info["autotune"] = decision.as_dict()
-
-        records = info.get("superstep_records")
-        if records is None:
-            # frontier path: the tier trace IS the per-superstep record
-            records = [
-                {
-                    "step": int(t.get("hop", i)),
-                    "frontier": int(t.get("frontier", 0)),
-                    "edges": int(t.get("edges", 0)),
-                    "e_cap": int(t.get("E_cap", 0)),
-                }
-                for i, t in enumerate(info.get("tiers", []))
-            ]
         n = self.g.num_vertices
         for i, r in enumerate(records):
-            # dense BSP touches every vertex each superstep; the frontier
-            # path records its true (compacted) sizes above
+            # dense BSP touches every vertex each superstep; an engine's
+            # records carry their true (compacted) sizes
             r.setdefault("frontier", n)
             if pad_ratio is not None:
                 r.setdefault("pad_ratio", pad_ratio)
             r.setdefault("h2d_bytes", info["h2d_arg_bytes"] if i == 0 else 0)
-        info["superstep_records"] = records
 
         # roofline: every superstep record reports flops, bytes accessed,
         # operational intensity, and %-of-roofline utilization; frontier
@@ -1355,7 +1289,6 @@ class TPUExecutor:
         )
 
         registry.counter("olap.runs").inc()
-        registry.counter("olap.pack.row_gathers").inc(row_gathers)
         registry.timer("olap.run").update(int(wall_s * 1e9))
         registry.set_gauge(
             "olap.superstep.count", float(info.get("supersteps", 0) or 0)
@@ -1367,25 +1300,6 @@ class TPUExecutor:
         registry.set_gauge("olap.transfer.d2h_bytes", float(info["d2h_bytes"]))
         if pad_ratio is not None:
             registry.set_gauge("olap.ell.pad_ratio", pad_ratio)
-        if info.get("path") == "intersect":
-            registry.counter("olap.intersect.runs").inc()
-            registry.counter("olap.intersect.candidates").inc(
-                info["candidates"]
-            )
-            registry.counter("olap.intersect.probe_slots").inc(
-                info["probe_slots"]
-            )
-        if info.get("path") in ("frontier", "brandes"):
-            registry.counter("olap.frontier.rounds").inc(info["rounds"])
-            registry.counter("olap.frontier.relaxed_slots").inc(
-                info["relaxed_slots"]
-            )
-            registry.counter("olap.frontier.tier_slots").inc(
-                info["tier_slots"]
-            )
-            registry.counter("olap.frontier.wide_rounds").inc(
-                info["wide_rounds"]
-            )
         if records:
             registry.set_gauge(
                 "olap.frontier.last", float(records[-1].get("frontier", n))
@@ -1437,72 +1351,41 @@ class TPUExecutor:
             + int(info.get("d2h_bytes", 0)),
         }
 
-    #: graphs below this edge count run CC through the fused dense path
-    #: under frontier="auto": Afforest's two executables, its host sync
-    #: and its finish's tier compile only amortize once a dense superstep
-    #: costs more than dispatch (BFS keeps frontier at every size — its
-    #: dense path rescans |E| for hops that touch a handful of vertices)
-    FRONTIER_CC_MIN_EDGES = 1 << 20
+    #: `olap/routes.py`'s; computer.frontier-cc-min-edges sets its own
+    FRONTIER_CC_MIN_EDGES = FRONTIER_CC_MIN_EDGES
 
-    @staticmethod
-    def _frontier_family(program: VertexProgram) -> bool:
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-        from janusgraph_tpu.olap.programs.shortest_path import (
-            ShortestPathProgram,
-        )
-
-        return type(program) in (
-            ShortestPathProgram, ConnectedComponentsProgram
-        )
-
-    def _frontier_eligible(self, program: VertexProgram, mode: str) -> bool:
-        from janusgraph_tpu.olap.frontier import FrontierEngine
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-        from janusgraph_tpu.olap.programs.shortest_path import (
-            ShortestPathProgram,
-        )
-
-        if not self._frontier_family(program):
-            return False
-        if self.csr.num_edges >= FrontierEngine.MAX_EDGES:
-            return False
-        if type(program) is ShortestPathProgram:
-            # track_paths encodes predecessor indices in float32 — the
-            # dense path's setup() raises above 2^24 vertices; mirror that
-            # guard here instead of silently rounding predecessors
-            return not (
-                program.track_paths
-                and self.csr.num_vertices >= (1 << 24)
+    def _route(self, program, mode, checkpoint_path):
+        """The engine route this run takes (`olap/routes.py`), None for the
+        dense superstep path. Under "always" a program with a superstep
+        form whose route is not taken is refused, with the reason: the
+        dense path must never be timed under an engine's label."""
+        route = None if mode == "off" else route_of(program)
+        if route is None:
+            return None
+        n, m = self.csr.num_vertices, self.csr.num_edges
+        cut = route.fixpoint_only and program.max_iterations is not None
+        if not (checkpoint_path or cut) and route.admits(
+            program, n, m, mode, self.FRONTIER_CC_MIN_EDGES
+        ):
+            return route
+        if mode != "always" or not route.superstep_form:
+            return None
+        if checkpoint_path:
+            raise ValueError(CHECKPOINT_REFUSAL)
+        if cut:
+            raise ValueError(
+                "frontier='always' but ConnectedComponentsProgram("
+                f"max_iterations={program.max_iterations}) asks for the "
+                f"labels after {program.max_iterations} supersteps of "
+                "propagation, a state the frontier engine's Afforest "
+                "never has — use max_iterations=None (to the fixpoint) "
+                "or frontier='auto' / 'off' (the dense path)"
             )
-        if type(program) is ConnectedComponentsProgram:
-            # Afforest runs to the fixpoint only (`_cc_cut`); labels are
-            # float32 vertex indices: exact below 2^24 only
-            return (
-                not self._cc_cut(program)
-                and self.csr.num_vertices < (1 << 24)
-                and (
-                    mode == "always"
-                    or self.csr.num_edges >= self.FRONTIER_CC_MIN_EDGES
-                )
-            )
-        return False
-
-    @staticmethod
-    def _cc_cut(program: VertexProgram) -> bool:
-        """A ConnectedComponentsProgram with an explicit superstep cap: the
-        labels after that many supersteps of propagation, which a
-        superstep path has and Afforest does not."""
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-
-        return (
-            type(program) is ConnectedComponentsProgram
-            and program.max_iterations is not None
+        raise ValueError(
+            "frontier='always' but the graph exceeds the frontier "
+            f"engine's guards (|V|={n}, |E|={m}; float32 label/predecessor "
+            "exactness needs |V| < 2^24, int32 expansion needs "
+            "|E| < 2^30) — use frontier='auto' or 'off'"
         )
 
     def _frontier(self):
@@ -1516,127 +1399,15 @@ class TPUExecutor:
             self._frontier_engine = FrontierEngine(self)
         return self._frontier_engine
 
-    def _run_frontier(self, program: VertexProgram) -> Dict[str, np.ndarray]:
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-
-        t0 = time.perf_counter()
-        engine = self._frontier()
-        if type(program) is ConnectedComponentsProgram:
-            out = engine.run_cc(program)
-            extra = dict(
-                engine.last_afforest,
-                # a superstep of Afforest is a link pass
-                supersteps=sum(engine.last_afforest["link_passes"].values()),
-            )
-        else:
-            out = engine.run(program)
-            # the slots the weighted parent pass gathered, 0 where none ran
-            extra = {"parent_slots": engine.last_parent_slots}
-        trace = engine.last_trace
-        self.last_run_info = {
-            "path": "frontier",
-            "supersteps": len(trace),
-            "wall_s": round(time.perf_counter() - t0, 4),
-            "tiers": trace,
-            # totals a layer metric can read: hops executed (Afforest: its
-            # finish, if one ran), slots they relaxed, slots their tiers
-            # held (padding = the difference), hops that ran as wide
-            # rounds on the pack (the top rung; never Afforest's)
-            "rounds": len(trace),
-            "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
-            "tier_slots": sum(t["tier_slots"] for t in trace),
-            "wide_rounds": sum(t["wide"] for t in trace),
-            **extra,
-        }
-        return out
-
-    @staticmethod
-    def _intersect_family(program: VertexProgram) -> bool:
-        from janusgraph_tpu.olap.programs.lcc import LCCProgram
-
-        return type(program) is LCCProgram
-
-    def _run_intersect(self, program: VertexProgram) -> Dict[str, np.ndarray]:
-        """`LCCProgram` on the intersection engine: the snapshot's tables
-        on first use (a vertex of degree 65,536 or more is refused there,
-        by name), then one compiled pass a submit."""
-        from janusgraph_tpu.olap.intersect import IntersectEngine
-
+    def _intersect(self):
+        """The intersection engine: the snapshot's tables on first use (a
+        vertex of degree 65,536 or more is refused there, by name)."""
         if self._intersect_engine is None:
+            from janusgraph_tpu.olap.intersect import IntersectEngine
+
             with tracer.phase("executor.setup"):
                 self._intersect_engine = IntersectEngine(self)
-        t0 = time.perf_counter()
-        out = self._intersect_engine.run(program)
-        wall_s = time.perf_counter() - t0
-        view = self._intersect_engine.view
-        self.last_run_info = {
-            "path": "intersect",
-            "supersteps": 1,
-            "dispatches": 1,
-            "wall_s": round(wall_s, 4),
-            # totals a layer metric can read: the closure's edges, the
-            # pairs the pass decides (as a forward count lists them), the
-            # words and elements it gathers to decide them (padding
-            # included), the triangles of the graph
-            "simple_edges": view.simple_edges,
-            "candidates": view.candidates,
-            "probe_slots": view.probe_slots,
-            "triangles_total": int(
-                out["triangles"].sum(dtype=np.int64) // 3
-            ),
-            "intersect": dict(view.sizes),
-            "superstep_records": [{
-                "step": 0, "wall_ms": round(wall_s * 1000.0, 4),
-                "edges": view.simple_edges,
-            }],
-        }
-        return out
-
-    @staticmethod
-    def _brandes_family(program: VertexProgram) -> bool:
-        from janusgraph_tpu.olap.programs.betweenness import (
-            BetweennessCentralityProgram,
-        )
-
-        return type(program) is BetweennessCentralityProgram
-
-    def _brandes_eligible(self) -> bool:
-        """ShortestPath's guards: int32 expansion, and |V| < 2^24."""
-        from janusgraph_tpu.olap.frontier import FrontierEngine
-
-        return (
-            self.csr.num_edges < FrontierEngine.MAX_EDGES
-            and self.csr.num_vertices < (1 << 24)
-        )
-
-    def _run_brandes(self, program: VertexProgram) -> Dict[str, np.ndarray]:
-        """`BetweennessCentralityProgram` on the frontier engine's two
-        sweeps over the simple closure (`FrontierEngine.run_brandes`)."""
-        t0 = time.perf_counter()
-        out = self._frontier().run_brandes(program)
-        trace = self._frontier_engine.last_trace
-        forward = [t for t in trace if t["sweep"] == "forward"]
-        self.last_run_info = {
-            "path": "brandes",
-            "supersteps": len(trace),
-            "wall_s": round(time.perf_counter() - t0, 4),
-            "tiers": trace,
-            "sources": list(program.sources),
-            # the deepest level any column reached (the last forward hop
-            # reaches nothing); the backward sweep runs levels L ... 2
-            "levels": max(len(forward) - 1, 0),
-            "forward_rounds": len(forward),
-            "backward_rounds": len(trace) - len(forward),
-            # totals a layer metric can read, as the frontier path's
-            "rounds": len(trace),
-            "relaxed_slots": sum(t["relaxed_slots"] for t in trace),
-            "tier_slots": sum(t["tier_slots"] for t in trace),
-            "wide_rounds": sum(t["wide"] for t in trace),
-            "closure_slots": len(self._simple_closure()[0]),
-        }
-        return out
+        return self._intersect_engine
 
     def _run_fused(
         self,

@@ -339,7 +339,12 @@ class VertexProgram:
         early, which would change semantics for programs relying on host
         terminate())."""
         return (
-            type(self).combiner_for is VertexProgram.combiner_for
-            and type(self).channel_for is VertexProgram.channel_for
-            and type(self).terminate_device is not VertexProgram.terminate_device
+            not self.overrides("combiner_for")
+            and not self.overrides("channel_for")
+            and self.overrides("terminate_device")
         )
+
+    def overrides(self, method: str) -> bool:
+        """Whether this program's class replaces VertexProgram's `method`."""
+        base = getattr(VertexProgram, method)
+        return getattr(type(self), method) is not base

@@ -702,7 +702,7 @@ class ShardedExecutor:
         self._channel_views: "OrderedDict" = OrderedDict()
         self._device_cache: Dict[Tuple[object, str], object] = {}
         # (cache_key, op) -> {metric_key: combiner_op}; recorded when the
-        # shard body is traced (see TPUExecutor._metric_ops)
+        # shard body is traced (see `_metric_ops` in olap/tpu_executor.py)
         self._metric_ops: Dict[Tuple, Dict[str, str]] = {}
         self._frontier_engine = None
         # computer.frontier-tier-growth (ShardedFrontierEngine override)
@@ -1226,7 +1226,7 @@ class ShardedExecutor:
         in the loop body, `terminate_device` on the replicated aggregators as
         the on-device stop condition. steps/limit flow as traced scalars so
         one executable serves the full run and checkpoint-bounded chunks. See
-        TPUExecutor._fused_fn."""
+        `_fused_fn` in olap/tpu_executor.py."""
         key = ("fused", program.cache_key(), op, self.exchange, self.agg)
         if key in self._compiled:
             return self._compiled[key]
@@ -1272,54 +1272,21 @@ class ShardedExecutor:
         self._compiled[key] = fn
         return fn
 
-    def _frontier_eligible(self, program: VertexProgram, mode: str) -> bool:
-        """Mirror of TPUExecutor._frontier_eligible on the mesh: the
-        ShortestPath family dispatches to per-shard frontier compaction
-        (parallel/sharded_frontier.py) unless numeric guards say no."""
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
-        from janusgraph_tpu.olap.programs.shortest_path import (
-            ShortestPathProgram,
-        )
-        from janusgraph_tpu.olap.tpu_executor import TPUExecutor
-        from janusgraph_tpu.parallel.sharded_frontier import (
-            ShardedFrontierEngine,
-        )
-
-        if type(program) not in (
-            ShortestPathProgram, ConnectedComponentsProgram
-        ):
-            return False
-        if self.csr.num_edges >= ShardedFrontierEngine.MAX_EDGES:
-            return False
-        # float32-exact vertex-index encodings cover the PADDED index space
-        padded_n = self._sharded(program.undirected).padded_n
-        if type(program) is ShortestPathProgram:
-            return not (program.track_paths and padded_n >= (1 << 24))
-        # ConnectedComponents: labels are float32 padded indices
-        return padded_n < (1 << 24) and (
-            mode == "always"
-            or self.csr.num_edges >= TPUExecutor.FRONTIER_CC_MIN_EDGES
-        )
-
     def _run_frontier(
-        self, program: VertexProgram, fault_hook=None
+        self, program: VertexProgram, route, fault_hook=None
     ) -> Dict[str, np.ndarray]:
-        from janusgraph_tpu.olap.programs.connected_components import (
-            ConnectedComponentsProgram,
-        )
+        """The program on the mesh's frontier engine, at its route's entry
+        (`olap/routes.py`)."""
         from janusgraph_tpu.parallel.sharded_frontier import (
             ShardedFrontierEngine,
         )
 
-        if getattr(self, "_frontier_engine", None) is None:
+        if self._frontier_engine is None:
             self._frontier_engine = ShardedFrontierEngine(self)
         t0 = time.perf_counter()
-        if type(program) is ConnectedComponentsProgram:
-            out = self._frontier_engine.run_cc(program, fault_hook=fault_hook)
-        else:
-            out = self._frontier_engine.run(program, fault_hook=fault_hook)
+        out = getattr(self._frontier_engine, route.entry)(
+            program, fault_hook=fault_hook
+        )
         trace = self._frontier_engine.last_trace
         self.last_run_info = {
             "path": "frontier",
@@ -1718,37 +1685,41 @@ class ShardedExecutor:
                     apath, shard_count=self.num_shards
                 )
         self._resolve_exchange(program.undirected)
-        from janusgraph_tpu.olap.tpu_executor import TPUExecutor
+        # the ShortestPath family runs per-shard frontier compaction
+        # (parallel/sharded_frontier.py) unless its route's guards say no;
+        # float32-exact vertex indices cover the PADDED index space, and
+        # the mesh's CC propagates, so a superstep cap is no refusal here
+        from janusgraph_tpu.olap.routes import CHECKPOINT_REFUSAL, route_of
 
-        use_frontier = False
-        if frontier != "off" and TPUExecutor._frontier_family(program):
+        route = None if frontier == "off" else route_of(program)
+        if route is not None:
             if checkpoint_path or shard_checkpoint_dir:
                 # "always" must never silently time the dense path under a
                 # frontier label (mirrors TPUExecutor.run)
                 if frontier == "always":
+                    raise ValueError(CHECKPOINT_REFUSAL)
+                route = None
+            elif not route.admits(
+                program, self._sharded(program.undirected).padded_n,
+                self.csr.num_edges, frontier,
+            ):
+                if frontier == "always":
                     raise ValueError(
-                        "frontier='always' cannot be combined with "
-                        "checkpointing (the frontier loop does not "
-                        "checkpoint) — drop checkpoint_path or use "
-                        "frontier='auto'"
+                        "frontier='always' but the graph exceeds the "
+                        "frontier engine's guards (|V|="
+                        f"{self.csr.num_vertices}, |E|={self.csr.num_edges}; "
+                        "float32 label/predecessor exactness needs padded "
+                        "|V| < 2^24, int32 expansion needs |E| < 2^30) — "
+                        "use frontier='auto' or 'off'"
                     )
-            elif self._frontier_eligible(program, frontier):
-                use_frontier = True
-            elif frontier == "always":
-                raise ValueError(
-                    "frontier='always' but the graph exceeds the frontier "
-                    f"engine's guards (|V|={self.csr.num_vertices}, "
-                    f"|E|={self.csr.num_edges}; float32 label/predecessor "
-                    "exactness needs padded |V| < 2^24, int32 expansion "
-                    "needs |E| < 2^30) — use frontier='auto' or 'off'"
-                )
+                route = None
         sc = self._sharded(program.undirected)
         if fused is None:
             fused = program.fused_eligible()
         use_fused = (
-            not use_frontier
+            route is None
             and fused
-            and type(program).combiner_for is VertexProgram.combiner_for
+            and not program.overrides("combiner_for")
         )
 
         from janusgraph_tpu.observability import tracer
@@ -1768,7 +1739,7 @@ class ShardedExecutor:
             out = self._run_guarded(
                 program, sc, sync_every, checkpoint_path, checkpoint_every,
                 resume, frontier, hook, resume_attempts,
-                shard_checkpoint_dir, use_frontier, use_fused,
+                shard_checkpoint_dir, route, use_fused,
             )
             self._publish_run(sp, program, out, time.perf_counter() - t_run)
             return out
@@ -1776,7 +1747,7 @@ class ShardedExecutor:
     def _run_guarded(
         self, program, sc, sync_every, checkpoint_path, checkpoint_every,
         resume, frontier, hook, resume_attempts, shard_checkpoint_dir,
-        use_frontier, use_fused,
+        route, use_fused,
     ):
         from janusgraph_tpu.exceptions import SuperstepPreempted
         from janusgraph_tpu.observability import flight_recorder, registry
@@ -1787,8 +1758,8 @@ class ShardedExecutor:
         resumes = 0
         while True:
             try:
-                if use_frontier:
-                    out = self._run_frontier(program, fault_hook=hook)
+                if route is not None:
+                    out = self._run_frontier(program, route, fault_hook=hook)
                 elif use_fused:
                     out = self._run_fused(
                         program, sc, checkpoint_path, checkpoint_every,
@@ -1806,7 +1777,7 @@ class ShardedExecutor:
                 # frontier runs restart from scratch (deterministic and
                 # short); dense paths need a checkpoint to roll back to
                 if resumes >= resume_attempts or not (
-                    use_frontier or can_resume
+                    route is not None or can_resume
                 ):
                     raise
                 resumes += 1
@@ -1816,11 +1787,11 @@ class ShardedExecutor:
                 registry.counter("olap.sharded.resumes").inc()
                 flight_recorder.record(
                     "olap_resume", executor="sharded", attempt=resumes,
-                    program=type(program).__name__,
+                    program=program.__class__.__name__,
                     fault=type(e).__name__,
                     format="sharded" if shard_checkpoint_dir else "single",
                 )
-                if use_frontier:
+                if route is not None:
                     # nothing to reload: the restart IS the recovery
                     self._resume_ms += (
                         time.perf_counter() - self._resume_t_catch
@@ -1839,7 +1810,7 @@ class ShardedExecutor:
 
     def _publish_run(self, sp, program, result, wall_s) -> None:
         """Publish the finished run in the SAME record vocabulary as
-        TPUExecutor._finish_run — path/supersteps/superstep_records,
+        the single-device executor's — path/supersteps/superstep_records,
         transfer bytes, compile-cache economics, device memory, slowest-
         superstep exemplar, and the olap.* gauges — so dashboards and
         tests read one shape regardless of which executor a submit()
@@ -2102,7 +2073,7 @@ class ShardedExecutor:
             # each metric's monoid op, no XLA compile), seed missing keys
             # with the monoid identity, and run superstep 0 INSIDE the
             # fused executable — one compile per program instead of two
-            # (mirrors TPUExecutor._run_fused)
+            # (mirrors the single-device `_run_fused`)
             mkey = (program.cache_key(), op)
             if mkey not in self._metric_ops:
                 step_fn = self._superstep_fn(program, op, sc)

@@ -18,6 +18,7 @@ from janusgraph_tpu.olap import kernels
 from janusgraph_tpu.olap.cpu_executor import CPUExecutor
 from janusgraph_tpu.olap.csr import csr_from_edges, load_csr_snapshot
 from janusgraph_tpu.olap.programs import CDLPProgram, PageRankProgram
+from janusgraph_tpu.olap.routes import route_of
 from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 from janusgraph_tpu.olap.vertex_program import Combiner
 
@@ -372,7 +373,7 @@ def test_frontier_engine_refuses_mode():
         with pytest.raises(ValueError, match="MODE.*frontier engine"):
             entry(CDLPProgram(2))
     # the executor never sends it there: 'always' still runs it dense
-    assert not ex._frontier_family(CDLPProgram(2))
+    assert route_of(CDLPProgram(2)) is None
     ex.run(CDLPProgram(2), frontier="always")
     assert ex.last_run_info["path"] == "fused"
 

@@ -14,6 +14,7 @@ from janusgraph_tpu.olap.cpu_executor import CPUExecutor
 from janusgraph_tpu.olap.frontier import _tier
 from janusgraph_tpu.olap.programs import ShortestPathProgram
 from janusgraph_tpu.olap.programs.shortest_path import reconstruct_path
+from janusgraph_tpu.olap.routes import route_of
 from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 
 
@@ -69,7 +70,7 @@ def test_frontier_matches_cpu_and_dense(name, kw):
     cpu = CPUExecutor(csr).run(prog())
     dense = TPUExecutor(csr, frontier="off").run(prog())
     ex = TPUExecutor(csr, **RUNGS)
-    assert ex._frontier_eligible(prog(), "auto")
+    assert ex._route(prog(), "auto", None).entry == "run"
     sparse = ex.run(prog())
     assert not all(t["wide"] for t in ex.last_run_info["tiers"])
     np.testing.assert_allclose(_dist(sparse), _dist(cpu), rtol=1e-6)
@@ -168,8 +169,10 @@ def test_frontier_off_and_subclass_fall_back_dense():
     class Custom(ShortestPathProgram):
         pass
 
-    # subclasses may override message/apply — never special-case them
-    assert not TPUExecutor(csr)._frontier_eligible(Custom(seed_index=0), "auto")
+    # subclasses may override message/apply — never special-case them,
+    # not even under "always"
+    assert route_of(Custom(seed_index=0)) is None
+    assert TPUExecutor(csr)._route(Custom(seed_index=0), "always", None) is None
 
 
 def test_tier_ladder():
@@ -188,13 +191,17 @@ def test_frontier_cc_auto_heuristic():
 
     small = random_graph(n=50, m=120)
     ex = TPUExecutor(small)
-    assert not ex._frontier_eligible(ConnectedComponentsProgram(), "auto")
-    assert ex._frontier_eligible(ConnectedComponentsProgram(), "always")
+    assert ex._route(ConnectedComponentsProgram(), "auto", None) is None
+    route = ex._route(ConnectedComponentsProgram(), "always", None)
+    assert route.entry == "run_cc"
     # an explicit cap is propagation's cut: never Afforest's
     capped = ConnectedComponentsProgram(max_iterations=5)
-    assert not ex._frontier_eligible(capped, "always")
+    assert ex._route(capped, "auto", None) is None
+    with pytest.raises(ValueError, match="Afforest never has"):
+        ex._route(capped, "always", None)
     # BFS keeps frontier at every size
-    assert ex._frontier_eligible(ShortestPathProgram(seed_index=0), "auto")
+    bfs = ex._route(ShortestPathProgram(seed_index=0), "auto", None)
+    assert bfs.entry == "run"
 
 
 def test_frontier_cc_matches_cpu_and_dense():
@@ -205,7 +212,7 @@ def test_frontier_cc_matches_cpu_and_dense():
     cpu = CPUExecutor(csr).run(mk())
     dense = TPUExecutor(csr, frontier="off").run(mk())
     ex = TPUExecutor(csr, frontier="always", **RUNGS)
-    assert ex._frontier_eligible(mk(), "always")
+    assert ex._route(mk(), "always", None) is not None
     sparse = ex.run(mk())
     info = ex.last_run_info
     assert info["path"] == "frontier" and info["link_passes"]["sample"] >= 1
