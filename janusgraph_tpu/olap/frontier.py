@@ -1,4 +1,6 @@
-"""Frontier-compacted SSSP/BFS supersteps (push-style, capped expansion).
+"""Frontier-compacted SSSP/BFS supersteps: top-down hops (push-style,
+capped expansion, or a wide round on the pack) and, for unweighted
+searches, bottom-up hops (pull-style, direction-optimising).
 
 Reference behavior modeled: FulgoraGraphComputer special-cases the
 ShortestPath programs rather than running them through the generic BSP loop
@@ -6,7 +8,8 @@ ShortestPath programs rather than running them through the generic BSP loop
 The TPU-native form of that special case is *frontier compaction*: a dense
 superstep gathers every edge every superstep — at the measured v5e gather
 wall (~140M gathered elem/s, docs/tpu_notes.md) that is ~1.9 s/superstep at
-scale 23 even when the BFS frontier is a handful of vertices. Here each hop:
+scale 23 even when the BFS frontier is a handful of vertices. Here each
+top-down hop:
 
   1. compacts the active frontier to a capped index buffer
      (``jnp.nonzero(size=F_cap)`` — static shape, XLA-friendly),
@@ -30,6 +33,19 @@ non-frontier edge is a no-op (its source's distance has not changed since
 it was last relaxed), so skipping it cannot change any superstep's output,
 weighted or not; a message is one float32 add and `min` is exact in any
 order.
+
+A BOTTOM-UP hop (Beamer, Asanović, Patterson, SC12; GAP's `bfs.cc`) runs
+where an unweighted search's frontier holds more slots than the unreached
+vertices' rows / ALPHA, and until the frontier holds fewer than n / BETA
+vertices and shrinks (`_direction`). It reads the rows of the unreached
+vertices instead of the frontier's (the simple closure's rows for an
+undirected search, in-rows for a directed one), compacted on the tier
+ladder, in blocks of row positions: a row stops at its first neighbour in
+the frontier, which becomes its parent, and leaves the next block
+(`_bottom_up_fn`). A vertex joins the next frontier exactly when some
+neighbour is on this one, so levels are the top-down hop's bit for bit;
+parents differ (first by row position, where a top-down hop takes the
+smallest-index sender), each a frontier neighbour one level up.
 
 Int32 throughout (the telescoping cumsum trick needs diff headroom, hence
 the ``m < 2**30`` eligibility guard — beyond that the executor keeps the
@@ -60,6 +76,8 @@ order (`_cc_args`): a vertex without one is its own root throughout.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from janusgraph_tpu.observability import registry, tracer
@@ -82,6 +100,13 @@ from janusgraph_tpu.olap.vertex_program import Combiner, EdgeTransform
 
 #: the depth of a vertex a Brandes column has not reached
 UNSEEN = np.iinfo(np.int32).max
+
+#: Beamer's direction rule with GAP's constants (`bfs.cc`, alpha 15, beta
+#: 18): an unweighted search goes bottom-up when its frontier's slots
+#: exceed the unreached vertices' slots / ALPHA, and returns top-down when
+#: its frontier holds fewer than n / BETA vertices and is shrinking
+ALPHA = 15
+BETA = 18
 
 
 def _tier(need: int, lo: int, hi: int, growth: int = 4) -> int:
@@ -204,6 +229,21 @@ def _expand_labels(jnp, comp, rank, ids):
     return jnp.where(rank >= 0, got, own).astype(jnp.float32)
 
 
+# graphlint: traced -- the bottom-up hop's compactions
+def _first_true(jnp, flags, size, fill):
+    """`jnp.nonzero(flags, size=size, fill_value=fill)[0]` as int32. Where
+    `size` is small against the flags, by binary search over their running
+    count (log2(len) gathers of `size` indices) in place of nonzero's
+    scatter-add of every flag into `size` buckets."""
+    if 32 * size > flags.shape[0]:
+        return jnp.nonzero(flags, size=size, fill_value=fill)[0].astype(
+            jnp.int32)
+    count = jnp.cumsum(flags.astype(jnp.int32))
+    which = jnp.arange(1, size + 1, dtype=jnp.int32)
+    found = jnp.searchsorted(count, which, side="left").astype(jnp.int32)
+    return jnp.where(which <= count[-1], found, fill)
+
+
 class FrontierEngine:
     """Per-executor engine: owns the device-resident CSR pointer arrays and
     the tier-compiled step executables for ShortestPath-family programs,
@@ -221,6 +261,14 @@ class FrontierEngine:
     #: vertices the giant's root is read from (GAP's `num_samples`), at a
     #: fixed stride where GAP draws them
     SAMPLE_SIZE = 1024
+    #: a bottom-up hop's stages (`_bottom_up_stages`): each holds this
+    #: many times fewer rows than the one before and reads twice as many
+    #: positions of each a block
+    BOTTOM_UP_SHRINK = 4
+    #: None: an unweighted search picks each hop's direction by the rule
+    #: (`_direction`); "top_down" or "bottom_up" forces every hop after
+    #: hop 0 one way (the engine's own switch, for tests; no option)
+    direction = None
 
     def __init__(self, executor):
         self.ex = executor
@@ -294,15 +342,19 @@ class FrontierEngine:
         return args
 
     # ------------------------------------------------------------------ plan
-    def _plan_fn(self, undirected: bool):
+    def _plan_fn(self, undirected: bool, bottom_up: bool = False):
         """(mask, fargs) -> (frontier count, out-edge total, in-edge total):
-        O(n) vector work, one fetch of three scalars per hop."""
-        plan = self._plans.get(undirected)
+        O(n) vector work, one fetch of three scalars per hop. With
+        `bottom_up` (an unweighted search) the call is `plan(mask, fargs,
+        dist, deg)` and two scalars follow in the same fetch: the slots of
+        the unreached vertices' rows in the bottom-up view (`deg`, its
+        degrees) and how many of those rows hold an edge."""
+        plan = self._plans.get((undirected, bottom_up))
         if plan is not None:
             return plan
         jnp = self.jnp
 
-        def plan_body(mask, fargs):
+        def frontier_sums(mask, fargs):
             zero = jnp.zeros((), jnp.int32)
             count = jnp.sum(mask.astype(jnp.int32))
             tot_out = jnp.sum(jnp.where(mask, fargs["out_deg"], zero))
@@ -313,9 +365,36 @@ class FrontierEngine:
             )
             return count, tot_out, tot_in
 
+        if bottom_up:
+            def plan_body(mask, fargs, dist, deg):
+                open_rows = (dist >= INF) & (deg > 0)
+                return (
+                    *frontier_sums(mask, fargs),
+                    jnp.sum(jnp.where(open_rows, deg, 0)),
+                    jnp.sum(open_rows.astype(jnp.int32)),
+                )
+        else:
+            def plan_body(mask, fargs):
+                return frontier_sums(mask, fargs)
+
         plan = self.jax.jit(plan_body)
-        self._plans[undirected] = plan
+        self._plans[(undirected, bottom_up)] = plan
         return plan
+
+    def _direction(self, t, bottom_up, count, last_count, slots, unreached):
+        """Whether hop `t` of an unweighted search runs bottom-up (Beamer's
+        rule, ALPHA and BETA), given the last hop's direction, the
+        frontier's vertices now and a hop ago, its slots (what a top-down
+        hop would relax) and the unreached vertices' slots (what a
+        bottom-up hop reads at most). Hop 0 is always top-down: its
+        frontier is the root. `direction` forces the rest one way."""
+        if t == 0:
+            return False
+        if self.direction is not None:
+            return self.direction == "bottom_up"
+        if not bottom_up:
+            return slots * ALPHA > unreached
+        return not (count < last_count and count * BETA < self.n)
 
     # ------------------------------------------------------------------ step
     def _expand(self, idx, indptr, dst, E_cap):
@@ -455,6 +534,139 @@ class FrontierEngine:
         cache[key] = fn
         return fn
 
+    def _bottom_up_args(self, undirected: bool):
+        """The rows a bottom-up hop reads, as `{ip, nbr, deg}` (pointers
+        padded to n + 2, neighbours, degrees): an undirected search's the
+        simple closure's (`_simple_args`; a parallel edge or a loop changes
+        no level and no valid parent), a directed one's the in-rows."""
+        if undirected:
+            args = self._simple_args()
+            return {"ip": args["out_ip"], "nbr": args["out_dst"],
+                    "deg": args["out_deg"]}
+        args = self._orientation_args("in")
+        return {"ip": args["in_ip"], "nbr": args["in_src"],
+                "deg": args["in_deg"]}
+
+    def _bottom_up_stages(self, F_cap):
+        """(rows, positions) of each stage of a bottom-up hop at tier
+        F_cap: the rows held, and the positions of each a block reads.
+        Each stage holds BOTTOM_UP_SHRINK times fewer rows than the last,
+        down to the ladder's first rung, and reads twice as many positions
+        a block, so a block's size falls by half a stage while the rows
+        that are left open run longer."""
+        stages = [(F_cap, 1)]
+        while stages[-1][0] > self.F_MIN:
+            rows, width = stages[-1]
+            stages.append((
+                max(-(-rows // self.BOTTOM_UP_SHRINK), self.F_MIN), 2 * width
+            ))
+        return stages
+
+    def _bottom_up_fn(self, F_cap, track_paths):
+        """A bottom-up hop at tier F_cap (module `jit_bottom_up`, all of it
+        under `frontier.bottom_up`): `fn(dist, pred, mask, t, args)` over
+        the rows `_bottom_up_args` gives, returning the narrow step's
+        (value, pred, next mask, its count) and two counts: the row
+        positions read (within their rows) and the positions the stages'
+        blocks held.
+
+        The unreached vertices with an edge are compacted to F_cap rows
+        (`_first_true`; at the ladder's top, F_cap == n, every vertex is
+        its own row and nothing is compacted). Every open row then reads
+        the same positions at once, block after block
+        (`_bottom_up_stages`): each position's neighbour, and whether it
+        is on the frontier. A row that found one is closed: its first
+        such neighbour by position is its parent. A row whose positions
+        are all read is closed too. A stage runs blocks until its open
+        rows fit the next stage, whose buffer they are compacted into; the
+        last runs until none is open. So the levels are the top-down
+        hop's: a vertex is reached exactly when some neighbour is on the
+        frontier."""
+        key = ("frontier-bottom-up", F_cap, track_paths)
+        cache = self.ex._compiled
+        if key in cache:
+            return cache[key]
+        jnp, lax = self.jnp, self.jax.lax
+        n = self.n
+        i32 = jnp.int32
+        stages = self._bottom_up_stages(F_cap)
+
+        def block(carry, nbr, front, start, end, width, size):
+            """The next `width` positions of every open row."""
+            open_, got, _, done, read, held = carry
+            steps = jnp.arange(width, dtype=i32)
+            pos = start[:, None] + done + steps
+            valid = open_[:, None] & (pos < end[:, None])
+            u = jnp.where(
+                valid, nbr[jnp.clip(pos, 0, nbr.shape[0] - 1)], n)
+            hit = front[u]
+            found = jnp.any(hit, axis=1)
+            # the first hit by position, picked without a gather
+            at = jnp.argmax(hit, axis=1)
+            first = jnp.sum(jnp.where(steps == at[:, None], u, 0), axis=1)
+            got = jnp.where(found, first, got)
+            done = done + width
+            open_ = open_ & ~found & (start + done < end)
+            return (
+                open_, got, jnp.sum(open_.astype(i32)), done,
+                read + jnp.sum(valid.astype(i32)), held + size * width,
+            )
+
+        def bottom_up(dist, pred, mask, t, args):
+            with frontier_scope("bottom_up"):
+                ip, nbr = args["ip"], args["nbr"]
+                # the sentinel row n is never on the frontier
+                front = jnp.concatenate([mask, jnp.zeros((1,), bool)])
+                unreached = dist >= INF
+                if F_cap == n:
+                    rows = jnp.arange(n, dtype=i32)
+                    start, end = ip[:n], ip[1:n + 1]
+                    open_ = unreached & (start < end)
+                else:
+                    rows = _first_true(
+                        jnp, unreached & (args["deg"] > 0), F_cap, n)
+                    # row n reads degree 0: padding is never open
+                    start, end = ip[rows], ip[rows + 1]
+                    open_ = start < end
+                parent = jnp.full((n,), -1, i32)
+                done = read = held = i32(0)
+                for s, (size, width) in enumerate(stages):
+                    if s:
+                        # the open rows into this stage's smaller buffer
+                        pick = _first_true(
+                            jnp, open_, size, open_.shape[0])
+                        rows, start, end = (
+                            jnp.concatenate([a, jnp.array([fill], i32)])[pick]
+                            for a, fill in ((rows, n), (start, 0), (end, 0))
+                        )
+                        open_ = start + done < end
+                    floor = stages[s + 1][0] if s + 1 < len(stages) else 0
+                    open_, got, _, done, read, held = lax.while_loop(
+                        lambda carry, floor=floor: carry[2] > floor,
+                        functools.partial(
+                            block, nbr=nbr, front=front, start=start,
+                            end=end, width=width, size=size),
+                        (open_, jnp.full((size,), -1, i32),
+                         jnp.sum(open_.astype(i32)), done, read, held),
+                    )
+                    if size == n:  # every vertex its own row, in order
+                        parent = jnp.maximum(parent, got)
+                    else:
+                        parent = parent.at[
+                            jnp.where(got >= 0, rows, n)
+                        ].set(got, mode="drop")
+                tmp = jnp.where(
+                    parent >= 0, parent.astype(jnp.float32), INF
+                )
+                return (
+                    *self._settle(dist, pred, tmp, t, False, track_paths),
+                    read, held,
+                )
+
+        fn = self.jax.jit(bottom_up)
+        cache[key] = fn
+        return fn
+
     def _parent_fn(self, undirected):
         """`parent_pass(dist, when, pack.arrays, row_dsts)` -> parent
         array of a converged WEIGHTED search, read off the executor's
@@ -542,51 +754,88 @@ class FrontierEngine:
     def _hop_loop(
         self, value, pred, mask, weighted, track, und, fargs, max_iterations
     ):
-        """The shared host-driven loop: plan (3 scalars) -> pick tier ->
-        one compiled step, the wide round (`_wide_fn`) where the tier is
-        the ladder's top. Two device round trips per hop; per-step output
-        is identical to the dense BSP path's. The host's time in it is
+        """The shared host-driven loop: plan -> pick direction and tier ->
+        one compiled step: top-down narrow, the wide round (`_wide_fn`)
+        where the tier is the ladder's top, or, in an unweighted search
+        where the rule says so (`_direction`), bottom-up
+        (`_bottom_up_fn`). Two device round trips per hop; per-step
+        output is identical to the dense BSP path's (a bottom-up hop's
+        parents excepted: the module docstring). The host's time in it is
         tiled by phases: per hop `executor.tier` (the plan's dispatch and
-        the tier choice; the wait for its three scalars is the
-        `executor.sync` inside it) and `executor.dispatch` (the step)."""
+        the choices; the wait for its scalars is the `executor.sync`
+        inside it) and `executor.dispatch` (the step). Returns the state
+        and the hops' `tiers`; a bottom-up hop's two counts are left in
+        its entry as device scalars, for the caller's fetch."""
         jax, jnp = self.jax, self.jnp
-        plan = self._plan_fn(und)
+        rows = None if weighted else self._bottom_up_args(und)
+        plan = self._plan_fn(und, rows is not None)
         if self.m == 0:
             mask = jnp.zeros_like(mask)
         trace = []
+        bottom_up, last_count = False, 0
         for t in range(max_iterations):
             with tracer.phase("executor.tier"):
-                planned = plan(mask, fargs)
+                planned = (
+                    plan(mask, fargs) if rows is None
+                    else plan(mask, fargs, value, rows["deg"])
+                )
                 with tracer.phase("executor.sync"):
                     planned = jax.device_get(planned)
-                count, tot_out, tot_in = (int(x) for x in planned)
+                count, tot_out, tot_in, *unreached = (int(x) for x in planned)
                 if count == 0:
                     break
                 f_cap, e_cap = self._caps(
                     count, max(tot_out, tot_in), self.m,
                     (self.f_schedule, self.e_schedule),
                 )
-                # the rule is the rung: a hop that would hold every edge
-                # is a wide round on the pack of the view it reads (built
-                # on first use, the dense path's own)
-                wide = e_cap == self.m
+                if rows is not None:
+                    bottom_up = self._direction(
+                        t, bottom_up, count, last_count, tot_out + tot_in,
+                        unreached[0],
+                    )
+                    last_count = count
+                # the rule is the rung: a top-down hop that would hold
+                # every edge is a wide round on the pack of the view it
+                # reads (built on first use, the dense path's own)
+                wide = e_cap == self.m and not bottom_up
                 pack = self.ex._hybrid_pack(und) if wide else None
-                trace.append(
-                    {"hop": t, "frontier": count,
-                     "edges": max(tot_out, tot_in),
-                     # slots the hop relaxes and slots its tier holds,
-                     # over the orientations it runs (tot_in is 0
-                     # directed); a wide hop holds what it gathers
-                     "relaxed_slots": tot_out + tot_in,
-                     "tier_slots": (
-                         pack.slots if wide else e_cap * (2 if und else 1)
-                     ),
-                     "F_cap": f_cap, "E_cap": e_cap, "wide": wide,
-                     "tier_source": (
-                         "autotune" if self.e_schedule else "static"
-                     )}
-                )
+                hop = {"hop": t, "frontier": count,
+                       "edges": max(tot_out, tot_in),
+                       "direction": (
+                           "bottom_up" if bottom_up
+                           else "wide" if wide else "top_down"),
+                       "tier_source": (
+                           "autotune" if self.e_schedule else "static")}
+                if bottom_up:
+                    # the unreached rows with an edge, on the F ladder
+                    f_cap = self._caps(
+                        unreached[1], 1, self.m,
+                        (self.f_schedule, self.e_schedule),
+                    )[0]
+                    hop.update(relaxed_slots=0, tier_slots=0, F_cap=f_cap,
+                               E_cap=0, wide=False,
+                               unreached_slots=unreached[0])
+                else:
+                    # slots the hop relaxes and slots its tier holds, over
+                    # the orientations it runs (tot_in is 0 directed); a
+                    # wide hop holds what it gathers
+                    hop.update(
+                        relaxed_slots=tot_out + tot_in,
+                        tier_slots=(
+                            pack.slots if wide
+                            else e_cap * (2 if und else 1)
+                        ),
+                        F_cap=f_cap, E_cap=e_cap, wide=wide,
+                    )
+                trace.append(hop)
             with tracer.phase("executor.dispatch"):
+                t_dev = jnp.asarray(t, jnp.float32)
+                if bottom_up:
+                    fn = self._bottom_up_fn(f_cap, track)
+                    (value, pred, mask, _, hop["bottom_up_slots"],
+                     hop["bottom_up_tier_slots"]) = fn(
+                        value, pred, mask, t_dev, rows)
+                    continue
                 if wide:
                     fn = self._wide_fn(
                         weighted, track, und, weighted and "out_w" in fargs
@@ -595,9 +844,7 @@ class FrontierEngine:
                 else:
                     fn = self._step_fn(f_cap, e_cap, weighted, track, und)
                     args = fargs
-                value, pred, mask, _ = fn(
-                    value, pred, mask, jnp.asarray(t, jnp.float32), args
-                )
+                value, pred, mask, _ = fn(value, pred, mask, t_dev, args)
         with tracer.phase("executor.sync"):
             # the last step is still running: wait for it here, so that
             # the caller's fetch is the copy alone
@@ -605,6 +852,44 @@ class FrontierEngine:
         # which tiers each hop actually priced at: the per-hop analogue of
         # .profile(), the run record's `tiers`
         return value, pred, trace
+
+    def _start_fn(self, track_paths):
+        """`start(root) -> (dist, pred, mask)`, an unweighted search's
+        start vectors made on the device from the root's index (module
+        `jit_start`, one execution a search): 0 at the root and INF
+        elsewhere; with `track_paths` the root's own index and -1
+        elsewhere, else None; the mask of the root alone. Nothing of n
+        rows is made on the host."""
+        key = ("frontier-start", track_paths)
+        cache = self.ex._compiled
+        if key not in cache:
+            jnp = self.jnp
+            f32 = jnp.float32
+
+            def start(root):
+                here = jnp.arange(self.n, dtype=jnp.int32) == root
+                pred = None
+                if track_paths:
+                    pred = jnp.where(here, root.astype(f32), f32(-1.0))
+                return jnp.where(here, f32(0.0), f32(INF)), pred, here
+
+            cache[key] = self.jax.jit(start)
+        return cache[key]
+
+    def _host_start(self, seed, track_paths):
+        """A weighted search's start vectors, made on the host and copied:
+        0 at the root and INF elsewhere; with `track_paths` the root's
+        round, 0 (`_step_fn`'s slot carries rounds until `_parent_fn`), and
+        -1 elsewhere, else None; the mask of the root alone. Device-made
+        ones (`_start_fn`) ran kernel 3 at scale 18 4.6-4.7% slower a search
+        on a TPU v5e, every key alike, for a cause not found."""
+        jnp = self.jnp
+        idx0 = np.arange(self.n, dtype=np.int64)
+        dist = jnp.asarray(np.where(idx0 == seed, 0.0, INF), jnp.float32)
+        pred = None
+        if track_paths:
+            pred = jnp.asarray(np.where(idx0 == seed, 0.0, -1.0), jnp.float32)
+        return dist, pred, jnp.asarray(idx0 == seed)
 
     def _record(self, path, trace, view, cols=(), **fields):
         """The run record of `trace`'s hops over `view` (its pack and
@@ -655,23 +940,11 @@ class FrontierEngine:
         track = program.track_paths
         und = program.undirected
         with tracer.phase("executor.setup"):
-            idx0 = np.arange(n, dtype=np.int64)
-            dist = jnp.asarray(
-                np.where(idx0 == program.seed_index, 0.0, INF), jnp.float32
-            )
-            pred = None
-            if track:
-                # the root's own index; in a weighted run its round, 0
-                # (`_step_fn`: the slot carries rounds until `_parent_fn`)
-                pred = jnp.asarray(
-                    np.where(
-                        idx0 == program.seed_index,
-                        0.0 if weighted else float(program.seed_index),
-                        -1.0,
-                    ),
-                    jnp.float32,
-                )
-            mask = jnp.asarray(idx0 == program.seed_index)
+            if weighted:
+                dist, pred, mask = self._host_start(program.seed_index, track)
+            else:
+                dist, pred, mask = self._start_fn(track)(
+                    jnp.asarray(program.seed_index, jnp.int32))
             fargs = self._fargs(und, weighted)
         dist, pred, trace = self._hop_loop(
             dist, pred, mask, weighted, track, und, fargs,
@@ -698,13 +971,32 @@ class FrontierEngine:
                     parent_slots = pack.slots
             with tracer.phase("executor.sync"):
                 await_arrays((pred,))
+        bottom_up = [t for t in trace if t["direction"] == "bottom_up"]
         with tracer.phase("executor.fetch"):
             out = {"distance": np.asarray(dist)}
             if track:
                 out["predecessor"] = np.asarray(pred)
-        return out, self._record(
-            "frontier", trace, und, parent_slots=parent_slots
+            counted = self.jax.device_get([
+                (t["bottom_up_slots"], t["bottom_up_tier_slots"])
+                for t in bottom_up
+            ])
+        for t, (read, held) in zip(bottom_up, counted):
+            t["bottom_up_slots"], t["bottom_up_tier_slots"] = (
+                int(read), int(held))
+        read = sum(t["bottom_up_slots"] for t in bottom_up)
+        registry.counter("olap.frontier.bottom_up_rounds").inc(len(bottom_up))
+        registry.counter("olap.frontier.bottom_up_slots").inc(read)
+        record = self._record(
+            "frontier", trace, und, parent_slots=parent_slots,
+            bottom_up_rounds=len(bottom_up),
+            # row positions the bottom-up hops read, and their blocks held
+            bottom_up_slots=read,
+            bottom_up_tier_slots=sum(
+                t["bottom_up_tier_slots"] for t in bottom_up),
         )
+        # Beamer's "edges examined": relaxed top-down plus read bottom-up
+        record["examined_slots"] = record["relaxed_slots"] + read
+        return out, record
 
     # -------------------------------------------------------------- afforest
     def _afforest_sample_fn(self):

@@ -35,7 +35,18 @@ class ShortestPathProgram(VertexProgram):
     The modes, and who runs them:
 
     - ``weighted=False`` (BFS hop counts), with or without ``track_paths``
-      and ``undirected``: every executor.
+      and ``undirected``: every executor. The levels are the same on
+      every one. On the single-device frontier engine (what
+      ``frontier="auto"`` picks) a hop whose frontier is large runs
+      BOTTOM-UP (Beamer's direction-optimising rule, GAP's constants;
+      ``olap/frontier.py``): the unreached vertices read their own rows
+      (the simple closure's where ``undirected``, in-rows where not), and
+      a vertex reached there takes as its predecessor its FIRST frontier
+      neighbour by row position, not the smallest index. Either is a
+      neighbour one level up, so every parent array passes Graph500's
+      validation (kernel 2's result with ``undirected=True``); the
+      top-down hops, the dense path, ``CPUExecutor`` and the mesh give
+      the smallest-index one.
     - ``weighted=True`` (distances only), directed or ``undirected``: every
       executor. ``distance`` is the greatest fixpoint below the start
       vector of ``d[v] = min(d[v], fl32(d[u] + w(u, v)))`` over every edge

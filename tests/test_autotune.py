@@ -431,9 +431,14 @@ def test_frontier_uses_tuned_schedule():
     info = ex.last_run_info
     assert info["path"] == "frontier"
     sched = tuple(info["autotune"]["e_schedule"])
+    rows = tuple(info["autotune"]["f_schedule"])
     for tier in info["tiers"]:
         assert tier["tier_source"] == "autotune"
-        assert tier["E_cap"] in sched or tier["E_cap"] == g.num_edges
+        if tier["direction"] == "bottom_up":
+            # no edge rung: its unreached rows on the F ladder
+            assert tier["F_cap"] in rows or tier["F_cap"] == g.num_vertices
+        else:
+            assert tier["E_cap"] in sched or tier["E_cap"] == g.num_edges
 
 
 def test_computer_config_keys_flow_through():

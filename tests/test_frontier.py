@@ -76,9 +76,30 @@ def test_frontier_matches_cpu_and_dense(name, kw):
     np.testing.assert_allclose(_dist(sparse), _dist(cpu), rtol=1e-6)
     np.testing.assert_allclose(_dist(sparse), _dist(dense), rtol=1e-6)
     if "predecessor" in sparse:
+        # a bottom-up hop's parent is the first frontier neighbour by row
+        # position: valid, not always the dense path's smallest index
+        assert_bfs_parents(csr, sparse, 0, kw.get("undirected", False))
+        ex._frontier().direction = "top_down"
         np.testing.assert_array_equal(
-            sparse["predecessor"], dense["predecessor"]
+            ex.run(prog())["predecessor"], dense["predecessor"]
         )
+
+
+def assert_bfs_parents(csr, result, root, undirected):
+    """Every reached vertex but the root has a parent joined to it by an
+    edge (either way where undirected) one level up; the root is its own
+    parent and an unreached vertex has -1."""
+    level = _dist(result)
+    parent = np.asarray(result["predecessor"]).astype(np.int64)
+    src = np.repeat(np.arange(csr.num_vertices), np.diff(csr.out_indptr))
+    edges = set(zip(src.tolist(), csr.out_dst.tolist()))
+    assert parent[root] == root and level[root] == 0
+    for v in np.flatnonzero(np.isfinite(level)):
+        if v != root:
+            p = parent[v]
+            assert level[p] == level[v] - 1, v
+            assert (p, v) in edges or (undirected and (v, p) in edges), v
+    assert np.all(parent[~np.isfinite(level)] == -1)
 
 
 def test_frontier_supernode_deg0():
@@ -433,6 +454,9 @@ def test_wide_round_is_the_narrow_step_bit_for_bit(flavour, ladder, seed):
     options, expect = WIDE_LADDERS[ladder]
     csr = awkward_multigraph(seed)
     ex = TPUExecutor(csr, frontier="always", **options)
+    # top-down hops alone: the rule's bottom-up hops read no rung
+    # (tests/test_bottom_up.py holds them to the same levels)
+    ex._frontier().direction = "top_down"
     got = ex.run(make())
     assert ex.last_run_info["path"] == "frontier"
     assert_hop_mix(ex.last_run_info, csr.num_edges, expect)
@@ -459,6 +483,7 @@ def test_wide_executable_is_one_per_flavour_and_top_rung_steps_are_gone():
     csr = awkward_multigraph(2)
     ex = TPUExecutor(csr, frontier_e_min=64, frontier_f_min=16,
                      autotune_max_tiers=2)
+    ex._frontier().direction = "top_down"  # hops that read the rungs
     assert ex._hybrid_packs == {}
     for root in (3, 5, 9):
         ex.run(ShortestPathProgram(seed_index=root))
@@ -512,7 +537,13 @@ def test_last_run_info_records_paths_and_tiers():
     assert info["path"] == "frontier"
     assert 1 <= info["supersteps"] <= 4
     assert info["tiers"][0]["frontier"] == 1  # hop 0: the seed alone
-    assert all(t["E_cap"] >= t["edges"] for t in info["tiers"])
+    for t in info["tiers"]:
+        if t["direction"] == "bottom_up":
+            # its blocks held what it read, of the unreached rows' slots
+            assert t["bottom_up_slots"] <= t["bottom_up_tier_slots"]
+            assert t["bottom_up_slots"] <= t["unreached_slots"]
+        else:
+            assert t["E_cap"] >= t["edges"]
     from janusgraph_tpu.olap.programs import PageRankProgram
 
     ex.run(PageRankProgram(max_iterations=5, tol=0.0))

@@ -2,7 +2,9 @@
 path writes: one small graph a route, the run record's `path`, its exact
 set of keys, the slot and round totals, and the moves of the registry
 counters the engines and the dense pack count (`olap.frontier.*`,
-`olap.intersect.*`, `olap.pack.row_gathers`)."""
+`olap.intersect.*`, `olap.pack.row_gathers`): an unweighted search
+moves the bottom-up counters where its hops went bottom-up, a weighted one
+never does."""
 
 import numpy as np
 import pytest
@@ -25,6 +27,7 @@ from janusgraph_tpu.olap.tpu_executor import TPUExecutor
 COUNTERS = (
     "olap.frontier.rounds", "olap.frontier.relaxed_slots",
     "olap.frontier.tier_slots", "olap.frontier.wide_rounds",
+    "olap.frontier.bottom_up_rounds", "olap.frontier.bottom_up_slots",
     "olap.intersect.runs", "olap.intersect.candidates",
     "olap.intersect.probe_slots", "olap.pack.row_gathers",
 )
@@ -41,6 +44,11 @@ FUSED = DENSE | {"compile_in_first_dispatch", "first_dispatch_s"}
 HOPS = SHARED | {
     "autotune", "relaxed_slots", "rounds", "tier_slots", "tiers",
     "wide_rounds",
+}
+#: a search's record (`FrontierEngine.run`), weighted or not
+SEARCH = HOPS | {
+    "bottom_up_rounds", "bottom_up_slots", "bottom_up_tier_slots",
+    "examined_slots", "gather_words", "parent_slots",
 }
 
 
@@ -69,21 +77,42 @@ ROUTES = {
     ),
     "frontier-bfs": dict(
         program=lambda: ShortestPathProgram(seed_index=0),
-        path="frontier", keys=HOPS | {"gather_words", "parent_slots"},
-        values=dict(supersteps=6, rounds=6, relaxed_slots=360,
-                    tier_slots=2238, wide_rounds=6, parent_slots=0,
+        path="frontier", keys=SEARCH,
+        values=dict(supersteps=6, rounds=6, relaxed_slots=10,
+                    tier_slots=746, wide_rounds=2, parent_slots=0,
+                    bottom_up_rounds=4, bottom_up_slots=473,
+                    bottom_up_tier_slots=1980, examined_slots=483,
                     gather_words=2, combiner="min", pad_ratio=1.0361),
-        moved={"olap.frontier.rounds": 6, "olap.frontier.relaxed_slots": 360,
-               "olap.frontier.tier_slots": 2238,
-               "olap.frontier.wide_rounds": 6, "olap.pack.row_gathers": 6},
+        moved={"olap.frontier.rounds": 6, "olap.frontier.relaxed_slots": 10,
+               "olap.frontier.tier_slots": 746,
+               "olap.frontier.wide_rounds": 2, "olap.pack.row_gathers": 2,
+               "olap.frontier.bottom_up_rounds": 4,
+               "olap.frontier.bottom_up_slots": 473},
+    ),
+    "frontier-bfs-undirected-parents": dict(
+        program=lambda: ShortestPathProgram(
+            seed_index=0, undirected=True, track_paths=True),
+        path="frontier", keys=SEARCH,
+        values=dict(supersteps=5, rounds=5, relaxed_slots=9,
+                    tier_slots=1454, wide_rounds=2, parent_slots=0,
+                    bottom_up_rounds=3, bottom_up_slots=541,
+                    bottom_up_tier_slots=1620, examined_slots=550,
+                    gather_words=2, combiner="min", pad_ratio=1.0097),
+        moved={"olap.frontier.rounds": 5, "olap.frontier.relaxed_slots": 9,
+               "olap.frontier.tier_slots": 1454,
+               "olap.frontier.wide_rounds": 2, "olap.pack.row_gathers": 2,
+               "olap.frontier.bottom_up_rounds": 3,
+               "olap.frontier.bottom_up_slots": 541},
     ),
     "frontier-weighted-sssp-parents": dict(
         program=lambda: ShortestPathProgram(
             seed_index=0, weighted=True, track_paths=True, undirected=True),
         graph=dict(weights=True),
-        path="frontier", keys=HOPS | {"gather_words", "parent_slots"},
+        path="frontier", keys=SEARCH,
         values=dict(supersteps=5, rounds=5, relaxed_slots=875,
                     tier_slots=3635, wide_rounds=5, parent_slots=727,
+                    bottom_up_rounds=0, bottom_up_slots=0,
+                    bottom_up_tier_slots=0, examined_slots=875,
                     gather_words=2, combiner="min", pad_ratio=1.0097),
         moved={"olap.frontier.rounds": 5, "olap.frontier.relaxed_slots": 875,
                "olap.frontier.tier_slots": 3635,

@@ -379,8 +379,10 @@ def test_frontier_run_record_totals_and_registry_counters(program,
         return got
 
     # the ladder (128, m): every search here has wide and narrow hops
+    # (top-down alone: the BFS's bottom-up hops read no rung)
     ex = TPUExecutor(_csr(multigraph(3)), frontier_e_min=64,
                      frontier_f_min=16, autotune_max_tiers=2)
+    ex._frontier().direction = "top_down"
     before = counters()
     program = program()
     ex.run(program)
